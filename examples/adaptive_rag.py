@@ -9,15 +9,17 @@ then:
     curl -X POST localhost:8080/v1/pw_ai_answer \
          -d '{"prompt": "what is a quokka?"}'
 
-Uses the local BGE checkpoint when present in the HF cache; otherwise a
-deterministic hash embedder so the template runs anywhere (the reference's
-test-suite pattern: fake embedder standing in for the model).
+Embeds on the device with JaxEncoderEmbedder either way: the local BGE
+checkpoint when the HF cache holds one, otherwise seeded random weights at
+the BGE-small shape (same kernels, same device traffic; ranking is then
+structural only, and one log line says so).
 """
 
 from __future__ import annotations
 
 import argparse
 import hashlib
+import logging
 
 import numpy as np
 
@@ -29,11 +31,22 @@ from pathway_tpu.xpacks.llm.splitters import TokenCountSplitter
 from pathway_tpu.xpacks.llm.vector_store import VectorStoreServer
 
 
+MODEL = "BAAI/bge-small-en-v1.5"
+
+
 def make_embedder(force_hash: bool = False):
-    if not force_hash and find_local_checkpoint("BAAI/bge-small-en-v1.5"):
+    """The serving embedder; ``force_hash`` is for graph-only collection
+    (``__pathway_check__``), which must build no model."""
+    if not force_hash:
         from pathway_tpu.xpacks.llm.embedders import JaxEncoderEmbedder
 
-        return JaxEncoderEmbedder(model="BAAI/bge-small-en-v1.5")
+        if find_local_checkpoint(MODEL):
+            return JaxEncoderEmbedder(model=MODEL)
+        logging.getLogger("adaptive_rag").warning(
+            "no %s checkpoint in the local HF cache: embedding with seeded "
+            "random weights at the BGE-small shape — ranking is structural "
+            "only", MODEL)
+        return JaxEncoderEmbedder()
 
     @pw.udf(deterministic=True)
     def hash_embed(text: str) -> np.ndarray:

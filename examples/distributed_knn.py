@@ -23,6 +23,7 @@ from __future__ import annotations
 
 import argparse
 import hashlib
+import logging
 
 import numpy as np
 
@@ -32,11 +33,23 @@ from pathway_tpu.stdlib.indexing import default_brute_force_knn_document_index
 from pathway_tpu.io.http import PathwayWebserver, rest_connector
 
 
+MODEL = "BAAI/bge-small-en-v1.5"
+
+
 def make_embedder(dim_holder: dict, force_hash: bool = False):
-    if not force_hash and find_local_checkpoint("BAAI/bge-small-en-v1.5"):
+    """The serving embedder; ``force_hash`` is for graph-only collection
+    (``__pathway_check__``), which must build no model."""
+    if not force_hash:
         from pathway_tpu.xpacks.llm.embedders import JaxEncoderEmbedder
 
-        emb = JaxEncoderEmbedder(model="BAAI/bge-small-en-v1.5")
+        if find_local_checkpoint(MODEL):
+            emb = JaxEncoderEmbedder(model=MODEL)
+        else:
+            logging.getLogger("distributed_knn").warning(
+                "no %s checkpoint in the local HF cache: embedding with "
+                "seeded random weights at the BGE-small shape — ranking "
+                "is structural only", MODEL)
+            emb = JaxEncoderEmbedder()
         dim_holder["dim"] = emb.get_embedding_dimension()
         return emb
 

@@ -2,9 +2,14 @@
 (reference: python/pathway/cli.py:53-280 — spawn / replay / spawn-from-env).
 
 ``spawn -t T -n N program.py`` forks N processes of the user program with
-``PATHWAY_THREADS/PROCESSES/PROCESS_ID/FIRST_PORT/RUN_ID`` set — each
-process hosts its shard of the device mesh (the reference's timely cluster
-topology, re-aimed at multi-host TPU). ``replay`` re-runs a program against
+``PATHWAY_THREADS/PROCESSES/PROCESS_ID/FIRST_PORT/RUN_ID`` set (the
+reference's timely cluster topology): the processes shard the HOST
+dataflow and exchange rows over TCP/shm. It assigns no accelerator — there
+is no ``jax.distributed`` set-up and no per-child visible-chip variable —
+so every child that touches JAX claims all local chips, and on a TPU host
+the second one fails. With a chip in the pipeline, run one process (it can
+drive all chips of the host through a mesh), or force the children onto
+the CPU backend (``JAX_PLATFORMS=cpu``). ``replay`` re-runs a program against
 a recorded snapshot directory with batch/speedrun timing, optionally
 continuing live afterwards. Recording/replay wiring rides the persistence
 env vars consumed by ``pw.run`` (internals/run.py)."""
@@ -31,7 +36,8 @@ def spawn_program(*, threads: int, processes: int, first_port: int,
     """Fork N processes of the user program, each owning T logical workers
     (reference: cli.py:53-110,166 — PATHWAY_THREADS/PROCESSES/PROCESS_ID/
     FIRST_PORT envs; processes cluster over TCP at FIRST_PORT+i,
-    engine/multiproc.py)."""
+    engine/multiproc.py). Host dataflow only: no child is given a chip
+    (module docstring)."""
     click.echo(
         f"Preparing {_plural(processes, 'process', 'processes')} "
         f"({_plural(processes * threads, 'total worker', 'total workers')})",

@@ -47,31 +47,11 @@ from __future__ import annotations
 import os
 import threading
 import time as _time
-import weakref
 from collections import deque
 from typing import Callable
 
 from pathway_tpu.engine.profiler import current_profiler
 from pathway_tpu.testing import faults
-
-# live bridges (weak: a bridge dies with its scheduler). Out-of-band
-# observers — bench.py's flight beacon, post-mortem dumps — read depth and
-# the in-flight leg without a reference threaded through every layer.
-_LIVE: "weakref.WeakSet[DeviceBridge]" = weakref.WeakSet()
-
-
-def live_bridge_snapshot() -> dict | None:
-    """Stats + in-flight leg of any live bridge (None when no bridge
-    exists). With several bridges, prefers one with a leg in flight."""
-    best = None
-    for b in list(_LIVE):
-        snap = b.stats()
-        snap["inflight"] = b.inflight()
-        if snap["inflight"] is not None:
-            return snap
-        best = best or snap
-    return best
-
 
 def device_inflight_from_env() -> int:
     """The configured in-flight window (>=1); 1 means synchronous."""
@@ -102,7 +82,6 @@ class DeviceBridge:
         # loop progress here so a slow-but-advancing device never reads
         # as a commit stall
         self.on_advance: Callable[[int], None] | None = None
-        _LIVE.add(self)
         from pathway_tpu.engine.locking import create_condition
 
         self._cv = create_condition("DeviceBridge._cv")

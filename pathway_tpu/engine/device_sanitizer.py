@@ -204,13 +204,9 @@ def install_compile_counter():
 
 
 def _set_transfer_guard(mode: str) -> None:
-    try:
-        import jax
+    import jax
 
-        jax.config.update("jax_transfer_guard_host_to_device", mode)
-    except Exception:
-        # pre-guard jax: compile discipline still enforced, transfers not
-        logger.debug("transfer guard unavailable", exc_info=True)
+    jax.config.update("jax_transfer_guard_host_to_device", mode)
 
 
 def arm() -> bool:

@@ -495,15 +495,22 @@ class MonitoringHttpServer:
             # cost model (the same math bench.py reports), per-family
             # device time + arithmetic intensity, and the host sampler's
             # self-accounting (its <2% overhead contract, measurable)
-            lines.append("# TYPE pathway_tpu_mfu_rolling gauge")
-            lines.append(f"pathway_tpu_mfu_rolling {prof['mfu_rolling']}")
-            lines.append("# TYPE pathway_tpu_hbm_bw_util gauge")
-            lines.append(f"pathway_tpu_hbm_bw_util {prof['hbm_bw_util']}")
+            # the utilization gauges exist only where the device's peaks
+            # are known (profiler.DEVICE_PEAKS) — absent, never defaulted
+            rated = prof["machine"] is not None
+            if rated:
+                lines.append("# TYPE pathway_tpu_mfu_rolling gauge")
+                lines.append(
+                    f"pathway_tpu_mfu_rolling {prof['mfu_rolling']}")
+                lines.append("# TYPE pathway_tpu_hbm_bw_util gauge")
+                lines.append(
+                    f"pathway_tpu_hbm_bw_util {prof['hbm_bw_util']}")
             fams = prof["families"]
             if fams:
                 lines.append("# TYPE pathway_tpu_kernel_device_ms counter")
                 lines.append("# TYPE pathway_tpu_kernel_dispatches counter")
-                lines.append("# TYPE pathway_tpu_kernel_mfu gauge")
+                if rated:
+                    lines.append("# TYPE pathway_tpu_kernel_mfu gauge")
                 lines.append("# TYPE pathway_tpu_kernel_arithmetic_intensity"
                              " gauge")
                 for fam, st in sorted(fams.items()):
@@ -512,8 +519,9 @@ class MonitoringHttpServer:
                                  f"{st['device_ms_total']}")
                     lines.append(f"pathway_tpu_kernel_dispatches{flab} "
                                  f"{st['dispatches']}")
-                    lines.append(
-                        f"pathway_tpu_kernel_mfu{flab} {st['mfu']}")
+                    if rated:
+                        lines.append(
+                            f"pathway_tpu_kernel_mfu{flab} {st['mfu']}")
                     lines.append(
                         f"pathway_tpu_kernel_arithmetic_intensity{flab} "
                         f"{st['roofline']['arithmetic_intensity']}")

@@ -37,10 +37,11 @@ On-demand XLA capture: ``/profile/device/start`` / ``stop`` drive
 ``jax.profiler.start_trace`` into an artifact directory, for the deep
 dives the analytic model only points at.
 
-Machine parameters default to TPU v5e (bf16 peak 197 TFLOP/s, HBM
-~819 GB/s) and are overridable with ``BENCH_PEAK_TFLOPS`` /
-``BENCH_HBM_GBPS`` — the same envs bench.py honors, so the roofline's
-machine balance and the bench MFU always describe the same chip.
+Machine peaks come from ONE table keyed by the ``device_kind`` JAX
+reports (``DEVICE_PEAKS``, shared with bench.py, each row with its
+source). A device that is not in the table has no MFU, bandwidth
+utilization or roofline verdict: the gauges are absent and the status
+fields are None ("not measured") — never another chip's numbers.
 """
 
 from __future__ import annotations
@@ -53,7 +54,7 @@ import time
 
 __all__ = [
     "Profiler", "current_profiler", "install_profiler", "live_profiler_stats",
-    "machine_params", "machine_balance",
+    "DEVICE_PEAKS", "machine_params", "machine_balance",
     "encoder_flops_per_token", "encoder_cost", "segment_attention_cost",
     "knn_search_cost", "ingest_scatter_cost",
     "diff_profiles",
@@ -63,31 +64,39 @@ __all__ = [
 # machine parameters (shared with bench.py)
 # ---------------------------------------------------------------------------
 
-_DEFAULT_PEAK_TFLOPS = 197.0   # TPU v5e bf16
-_DEFAULT_HBM_GBPS = 819.0      # TPU v5e HBM bandwidth
+#: Peak rates by ``jax.devices()[0].device_kind``; one row per chip this
+#: repo has been run on. A kind that is not here yields no MFU/roofline
+#: figure — add the row (with its source) rather than a default.
+DEVICE_PEAKS: dict[str, dict] = {
+    "TPU v5 lite": {
+        "peak_tflops": 197.0,   # bf16
+        "hbm_gbps": 819.0,
+        "source": "Google Cloud documentation, \"TPU v5e\": 197 TFLOP/s "
+                  "bf16 and 819 GB/s of HBM bandwidth per chip",
+    },
+}
 
 
-def machine_params() -> dict:
-    """{"peak_tflops", "hbm_gbps"} from the BENCH_* envs (v5e defaults).
-    Read per call — tests flip the envs; the values are two floats."""
-    try:
-        peak = float(os.environ.get("BENCH_PEAK_TFLOPS",
-                                    _DEFAULT_PEAK_TFLOPS))
-    except ValueError:
-        peak = _DEFAULT_PEAK_TFLOPS
-    try:
-        bw = float(os.environ.get("BENCH_HBM_GBPS", _DEFAULT_HBM_GBPS))
-    except ValueError:
-        bw = _DEFAULT_HBM_GBPS
-    return {"peak_tflops": peak, "hbm_gbps": bw}
+def machine_params(device_kind: str | None = None) -> dict | None:
+    """``{"device_kind", "peak_tflops", "hbm_gbps"}`` for ``device_kind``
+    (default: the first device JAX reports — this initialises the
+    backend), or None when the table has no row for it."""
+    if device_kind is None:
+        import jax
+
+        device_kind = jax.devices()[0].device_kind
+    row = DEVICE_PEAKS.get(device_kind)
+    if row is None:
+        return None
+    return {"device_kind": device_kind, "peak_tflops": row["peak_tflops"],
+            "hbm_gbps": row["hbm_gbps"]}
 
 
-def machine_balance() -> float:
+def machine_balance(machine: dict) -> float:
     """Machine balance in FLOP/byte: the arithmetic intensity at which
     the roofline's compute and bandwidth ceilings intersect. A kernel
     family whose AI sits below this is bandwidth-bound on this chip."""
-    mp = machine_params()
-    return (mp["peak_tflops"] * 1e12) / (mp["hbm_gbps"] * 1e9)
+    return (machine["peak_tflops"] * 1e12) / (machine["hbm_gbps"] * 1e9)
 
 
 # ---------------------------------------------------------------------------
@@ -253,7 +262,11 @@ class Profiler:
     :func:`current_profiler` so the uninstalled state costs a branch."""
 
     def __init__(self, sample_interval_ms: float | None = None,
-                 window_s: float | None = None):
+                 window_s: float | None = None,
+                 machine: dict | None = None):
+        """``machine``: ``{"peak_tflops", "hbm_gbps"}`` to rate dispatches
+        against; by default the ``DEVICE_PEAKS`` row of the device the
+        first recorded dispatch ran on (None when it has no row)."""
         from pathway_tpu.internals.config import _env_float
 
         if sample_interval_ms is None:
@@ -268,6 +281,10 @@ class Profiler:
 
         self._lock = create_lock("Profiler._lock")
         # -- device side ---------------------------------------------------
+        self._machine = machine
+        # resolved from the device at the first dispatch: by then this
+        # process has touched JAX, so the lookup starts no backend
+        self._machine_resolved = machine is not None
         self._families: dict[str, _FamilyStats] = {}
         self._leg_local = threading.local()  # .buf: _LegBuffer | None
         # -- host sampler --------------------------------------------------
@@ -483,8 +500,17 @@ class Profiler:
             self._commit(family, flops, nbytes, exec_ms * w,
                          attributed=True)
 
+    @property
+    def machine(self) -> dict | None:
+        """The peaks in use, or None: no dispatch seen yet, or a device
+        with no ``DEVICE_PEAKS`` row."""
+        return self._machine
+
     def _commit(self, family: str, flops: float, bytes_moved: float,
                 device_ms: float, attributed: bool) -> None:
+        if not self._machine_resolved:
+            self._machine = machine_params()
+            self._machine_resolved = True
         now = time.monotonic()
         with self._lock:
             st = self._families.get(family)
@@ -512,11 +538,21 @@ class Profiler:
         return flops, nbytes, ms, n
 
     def family_stats(self) -> dict[str, dict]:
-        """Per-family totals + rolling window + roofline classification."""
-        mp = machine_params()
-        peak_fps = mp["peak_tflops"] * 1e12
-        peak_bps = mp["hbm_gbps"] * 1e9
-        balance = peak_fps / peak_bps
+        """Per-family totals + rolling window, and — where the machine's
+        peaks are known — utilization and the roofline classification
+        (None otherwise: not measured)."""
+        mp = self._machine
+        peak_fps = peak_bps = balance = None
+        if mp is not None:
+            peak_fps = mp["peak_tflops"] * 1e12
+            peak_bps = mp["hbm_gbps"] * 1e9
+            balance = peak_fps / peak_bps
+
+        def util(amount: float, ms: float, peak: float | None):
+            if peak is None:
+                return None
+            return round(amount / (ms / 1e3) / peak, 6) if ms > 0 else 0.0
+
         now = time.monotonic()
         out: dict[str, dict] = {}
         with self._lock:
@@ -525,7 +561,18 @@ class Profiler:
             r_flops, r_bytes, r_ms, r_n = self._rolling(st, now)
             ai = (st.flops_total / st.bytes_total
                   if st.bytes_total > 0 else 0.0)
-            dev_s = st.device_ms_total / 1e3
+            roofline = {"arithmetic_intensity": round(ai, 4),
+                        "machine_balance": None, "bound_by": None,
+                        "attainable_mfu": None}
+            if balance is not None:
+                roofline.update({
+                    "machine_balance": round(balance, 4),
+                    "bound_by": ("compute" if ai >= balance
+                                 else "bandwidth"),
+                    # attainable fraction of peak at this AI — the
+                    # roofline ceiling the family could reach at best
+                    "attainable_mfu": round(min(1.0, ai / balance), 6),
+                })
             out[family] = {
                 "dispatches": st.dispatches,
                 "attributed_dispatches": st.attributed,
@@ -535,59 +582,43 @@ class Profiler:
                 "rolling": {
                     "dispatches": r_n,
                     "device_ms": round(r_ms, 3),
-                    "mfu": round(r_flops / (r_ms / 1e3) / peak_fps, 6)
-                    if r_ms > 0 else 0.0,
-                    "hbm_bw_util": round(
-                        r_bytes / (r_ms / 1e3) / peak_bps, 6)
-                    if r_ms > 0 else 0.0,
+                    "mfu": util(r_flops, r_ms, peak_fps),
+                    "hbm_bw_util": util(r_bytes, r_ms, peak_bps),
                 },
-                "mfu": round(st.flops_total / dev_s / peak_fps, 6)
-                if dev_s > 0 else 0.0,
-                "hbm_bw_util": round(st.bytes_total / dev_s / peak_bps, 6)
-                if dev_s > 0 else 0.0,
-                "roofline": {
-                    "arithmetic_intensity": round(ai, 4),
-                    "machine_balance": round(balance, 4),
-                    "bound_by": ("compute" if ai >= balance
-                                 else "bandwidth"),
-                    # attainable fraction of peak at this AI — the
-                    # roofline ceiling the family could reach at best
-                    "attainable_mfu": round(
-                        min(1.0, ai / balance), 6),
-                },
+                "mfu": util(st.flops_total, st.device_ms_total, peak_fps),
+                "hbm_bw_util": util(st.bytes_total, st.device_ms_total,
+                                    peak_bps),
+                "roofline": roofline,
             }
         return out
 
-    def rolling_mfu(self) -> float:
-        """Rolling model-FLOPs utilization across every family: window
-        FLOPs over window device-seconds, against peak."""
-        mp = machine_params()
+    def _rolling_util(self, column: int, peak_key: str,
+                      scale: float) -> float | None:
+        mp = self._machine
+        if mp is None:
+            return None
         now = time.monotonic()
-        flops = ms = 0.0
+        amount = ms = 0.0
         with self._lock:
             fams = list(self._families.values())
         for st in fams:
-            f, _b, m, _n = self._rolling(st, now)
-            flops += f
-            ms += m
+            rolled = self._rolling(st, now)
+            amount += rolled[column]
+            ms += rolled[2]
         if ms <= 0.0:
             return 0.0
-        return flops / (ms / 1e3) / (mp["peak_tflops"] * 1e12)
+        return amount / (ms / 1e3) / (mp[peak_key] * scale)
 
-    def rolling_hbm_bw_util(self) -> float:
-        """Rolling HBM bandwidth utilization across every family."""
-        mp = machine_params()
-        now = time.monotonic()
-        nbytes = ms = 0.0
-        with self._lock:
-            fams = list(self._families.values())
-        for st in fams:
-            _f, b, m, _n = self._rolling(st, now)
-            nbytes += b
-            ms += m
-        if ms <= 0.0:
-            return 0.0
-        return nbytes / (ms / 1e3) / (mp["hbm_gbps"] * 1e9)
+    def rolling_mfu(self) -> float | None:
+        """Rolling model-FLOPs utilization across every family: window
+        FLOPs over window device-seconds, against peak. None when the
+        machine's peaks are not known."""
+        return self._rolling_util(0, "peak_tflops", 1e12)
+
+    def rolling_hbm_bw_util(self) -> float | None:
+        """Rolling HBM bandwidth utilization across every family (None
+        when the machine's peaks are not known)."""
+        return self._rolling_util(1, "hbm_gbps", 1e9)
 
     # -- on-demand XLA capture ---------------------------------------------
     def start_device_capture(self, out_dir: str | None = None) -> str:
@@ -642,11 +673,12 @@ class Profiler:
                 "overhead_ratio": round(self.overhead_ratio(), 6),
                 "top_frame": self.top_host_frame(),
             },
-            "machine": {**machine_params(),
-                        "balance_flop_per_byte": round(machine_balance(),
-                                                       4)},
-            "mfu_rolling": round(self.rolling_mfu(), 6),
-            "hbm_bw_util": round(self.rolling_hbm_bw_util(), 6),
+            "machine": None if self._machine is None else {
+                **self._machine,
+                "balance_flop_per_byte": round(
+                    machine_balance(self._machine), 4)},
+            "mfu_rolling": _round6(self.rolling_mfu()),
+            "hbm_bw_util": _round6(self.rolling_hbm_bw_util()),
             "families": self.family_stats(),
             "capture": {
                 "running": self._capture_dir is not None,
@@ -666,9 +698,9 @@ class Profiler:
         top = sorted(frames.items(), key=lambda kv: -kv[1])[:40]
         return {
             "at": time.time(),
-            "machine": machine_params(),
-            "mfu_rolling": round(self.rolling_mfu(), 6),
-            "hbm_bw_util": round(self.rolling_hbm_bw_util(), 6),
+            "machine": self._machine,
+            "mfu_rolling": _round6(self.rolling_mfu()),
+            "hbm_bw_util": _round6(self.rolling_hbm_bw_util()),
             "families": self.family_stats(),
             "host": {
                 "samples_total": self.samples_total,
@@ -676,6 +708,10 @@ class Profiler:
                 "top_frames": [{"frame": f, "samples": n} for f, n in top],
             },
         }
+
+
+def _round6(x: float | None) -> float | None:
+    return None if x is None else round(x, 6)
 
 
 # ---------------------------------------------------------------------------

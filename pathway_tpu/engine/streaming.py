@@ -26,6 +26,12 @@ from pathway_tpu.testing import faults
 _ACTIVE_RUNTIMES: "weakref.WeakSet[StreamingRuntime]" = weakref.WeakSet()
 
 
+def live_runtimes() -> list["StreamingRuntime"]:
+    """The StreamingRuntimes alive in this process — for in-process reads
+    of a server started on a background thread (scheduler, bridge stats)."""
+    return list(_ACTIVE_RUNTIMES)
+
+
 def stop_all(join_timeout: float = 5.0) -> None:
     """Request stop on every live StreamingRuntime and join their reader
     threads; also stops static-mode connectors sleeping between polls

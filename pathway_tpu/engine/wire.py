@@ -1,9 +1,10 @@
 """Self-describing columnar wire format for the cluster exchange plane.
 
 Replaces the ``pickle.dumps((tag, packed))`` round-trip the exchange path
-paid per peer per round (the r05 regression surface — BENCH_r04→r05 took
-encode+decode from 1.453 to 6.495 µs/row). The dominant payload — lists of
-``(Pointer, row, diff)`` entries — serializes **column-wise** into
+paid per peer per round (the r05 regression surface — between the driver's
+r04 and r05 records encode+decode went from 1.453 to 6.495 µs/row). The
+dominant payload — lists of ``(Pointer, row, diff)`` entries — serializes
+**column-wise** into
 contiguous buffers, the shape timely's ``communication/`` crate ships
 (length-prefixed byte slabs, no per-row object graph):
 

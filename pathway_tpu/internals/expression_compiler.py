@@ -140,13 +140,11 @@ class ExpressionCompiler:
             if self.has_non_deterministic:
                 nondet_idx.add(i)
             self.has_non_deterministic = outer or self.has_non_deterministic
-        fused: list = []
-        try:
-            from pathway_tpu.internals import autojit
+        # fuse_program keeps its own failures (a body that will not build
+        # logs and returns []); what it lets through is a bug to surface
+        from pathway_tpu.internals import autojit
 
-            fused = autojit.fuse_program(exprs, self.ctx)
-        except Exception:
-            fused = []
+        fused = autojit.fuse_program(exprs, self.ctx)
         self.autojit = fused or None
         if fused and nondet_idx <= {i for g in fused for i in g.expr_idx}:
             # Every "non-deterministic" expression fused. Fusion only

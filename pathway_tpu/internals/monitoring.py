@@ -326,17 +326,21 @@ class StatsMonitor:
             return None
         if st is None:
             return None
-        line = (f"MFU {st['mfu_rolling']:.1%}  "
-                f"HBM {st['hbm_bw_util']:.1%}  "
-                f"samples {st['host']['samples_total']} "
-                f"({st['host']['device_attributed_samples']} on-device)  "
-                f"overhead {st['host']['overhead_ratio']:.2%}")
+        if st["machine"] is None:  # no peaks for this device: no rating
+            line = "MFU/HBM not measured  "
+        else:
+            line = (f"MFU {st['mfu_rolling']:.1%}  "
+                    f"HBM {st['hbm_bw_util']:.1%}  ")
+        line += (f"samples {st['host']['samples_total']} "
+                 f"({st['host']['device_attributed_samples']} on-device)  "
+                 f"overhead {st['host']['overhead_ratio']:.2%}")
         top = st["host"].get("top_frame")
         if top:
             line += f"\nhot: {top}"
         fams = st.get("families") or {}
         bound = [f"{name}:{fam['roofline']['bound_by'][:4]}"
-                 for name, fam in sorted(fams.items()) if fam["dispatches"]]
+                 for name, fam in sorted(fams.items())
+                 if fam["dispatches"] and fam["roofline"]["bound_by"]]
         if bound:
             line += "\nroofline " + "  ".join(bound)
         return line

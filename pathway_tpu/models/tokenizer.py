@@ -102,6 +102,7 @@ class WordPieceTokenizer:
     implementation, and the batch C++ kernel (native/wordpiece.cpp) used
     automatically when the toolchain is available — tokenization is
     host-side work that otherwise rate-limits the TPU embed pipeline.
+    ``uses_native`` says which one this instance got.
 
     Known simplification vs HF BertTokenizer: no unicode accent stripping
     (NFD) and no in-text special-token passthrough.
@@ -130,13 +131,17 @@ class WordPieceTokenizer:
                       if not tok.startswith("##")}
         self._native = None
         if prefer_native:
-            try:
-                from pathway_tpu.native import NativeWordPiece
+            from pathway_tpu.native import NativeBuildError, NativeWordPiece
 
+            try:
                 self._native = NativeWordPiece(self.vocab_list,
                                                do_lower=do_lower)
-            except Exception:
-                self._native = None
+            except NativeBuildError:
+                pass  # the loader already warned, once, with the cause
+
+    @property
+    def uses_native(self) -> bool:
+        return self._native is not None
 
     @classmethod
     def from_vocab_file(cls, path: str, **kw) -> "WordPieceTokenizer":

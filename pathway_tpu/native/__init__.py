@@ -1,18 +1,22 @@
 """ctypes bindings to the framework's native C++ engines (native/*.cpp).
 
 Reference parity: the reference's non-matmul native components are Rust
-(tantivy text index, connectors); here they are C++ behind a C ABI. Each
-binding degrades gracefully — callers use ``native_available()`` /
-factories that fall back to the pure-Python engine when no toolchain is
-present."""
+(tantivy text index, connectors); here they are C++ behind a C ABI. Where
+the toolchain cannot build one, callers fall back to the pure-Python
+engine (the reference the tests compare against) — and the loader says so
+once, at WARNING, naming the build error: the fallback is an order of
+magnitude slower and must not pass for the native path."""
 
 from __future__ import annotations
 
 import ctypes
+import logging
 import threading
 from typing import Any
 
 from pathway_tpu.native.build import NativeBuildError, ensure_built
+
+log = logging.getLogger("pathway_tpu.native")
 
 _text_index_lib = None
 _text_index_err: Exception | None = None
@@ -28,8 +32,10 @@ def _load_text_index():
             return _text_index_lib
         try:
             lib = ctypes.CDLL(ensure_built("text_index"))
-        except Exception as e:  # missing toolchain, sandboxed fs, …
+        except (NativeBuildError, OSError) as e:  # no toolchain, ro fs, …
             _text_index_err = e
+            log.warning("native text index unavailable, using the "
+                        "pure-Python BM25 engine: %s", e)
             return None
         lib.ti_new.restype = ctypes.c_void_p
         lib.ti_new.argtypes = [ctypes.c_double, ctypes.c_double,
@@ -73,8 +79,10 @@ def _load_wordpiece():
             return _wordpiece_lib
         try:
             lib = ctypes.CDLL(ensure_built("wordpiece"))
-        except Exception as e:
+        except (NativeBuildError, OSError) as e:
             _wordpiece_err = e
+            log.warning("native WordPiece unavailable, using the "
+                        "pure-Python tokenizer: %s", e)
             return None
         lib.wp_new.restype = ctypes.c_void_p
         lib.wp_new.argtypes = [ctypes.c_char_p, ctypes.c_int64,

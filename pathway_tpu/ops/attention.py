@@ -2,7 +2,7 @@
 
 The XLA fallback (models/encoder.py _dense_attention) materializes the
 (B, H, S, S) float32 score tensor in HBM — at encoder bench shapes
-(B=1024, H=6, S=128) that is ~400 MB written+read per layer, and HBM
+(B=1024, H=12, S=128) that is ~800 MB written+read per layer, and HBM
 bandwidth, not MXU, bounds the forward pass. This kernel keeps each
 (S, S) score tile in VMEM for one (batch, head) grid cell: qk^T → masked
 softmax → @v with no HBM round-trip, f32 accumulation on the MXU
@@ -12,12 +12,12 @@ Scope: bidirectional (encoder) attention with a key-validity mask, whole
 sequence resident per grid cell — right for S ≤ ~1k (VMEM budget). Longer
 sequences use the separate sequence-parallel path
 (pathway_tpu/parallel/ring_attention.py, its own online-softmax blockwise
-attention over the mesh). Measured note: at the bench shape (S=128) XLA's
-fused dense attention is faster than both this kernel and
-jax.experimental's tuned TPU flash kernel — the scores tile is small enough
-that XLA's fusion already avoids the HBM round-trip, so the encoder uses
-the XLA path by default and this kernel is the building block for
-larger-S single-chip use.
+attention over the mesh). The encoder uses the XLA path by default; how
+this kernel compares with it on the chip is not measured on the current
+machine (CHANGES.md PR 21 records whether Mosaic compiles it at all).
+``interpret`` is explicit and defaults to compiled: nothing here guesses
+the backend, so a chip whose platform name is unexpected cannot land on
+the interpreter unnoticed. CPU tests pass ``interpret=True``.
 """
 
 from __future__ import annotations
@@ -94,16 +94,3 @@ def flash_attention(q, k, v, mask, *, interpret: bool = False):
         interpret=interpret,
     )(q, k, v, mask_i)
     return out
-
-
-def make_attn_fn(*, interpret: bool | None = None):
-    """``attn_fn`` for models/encoder.encode backed by the Pallas kernel.
-    interpret=None auto-selects: compiled on TPU, interpreter elsewhere
-    (CPU tests run the same kernel code path)."""
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-
-    def attn(q, k, v, mask):
-        return flash_attention(q, k, v, mask, interpret=interpret)
-
-    return attn

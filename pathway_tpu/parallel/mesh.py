@@ -30,6 +30,8 @@ def validate_shard_specs(mesh, in_specs, out_specs) -> None:
     jax's shard_map lowering. (The static counterpart — rank consistency
     against plan-propagated operand shapes — is PWT103 in
     internals/static_check/shard_check.py.)"""
+    from jax.sharding import PartitionSpec
+
     axes = set(getattr(mesh, "axis_names", ()))
     if not axes:
         return
@@ -37,10 +39,7 @@ def validate_shard_specs(mesh, in_specs, out_specs) -> None:
     def walk(spec):
         if spec is None:
             return
-        # PartitionSpec may or may not subclass tuple depending on the jax
-        # version, so detect it by mro name before treating tuples as
-        # containers of further specs
-        if any(c.__name__ == "PartitionSpec" for c in type(spec).__mro__):
+        if isinstance(spec, PartitionSpec):
             for entry in spec:  # iterates the per-dim entries
                 names = entry if isinstance(entry, tuple) else (entry,)
                 for a in names:
@@ -58,20 +57,12 @@ def validate_shard_specs(mesh, in_specs, out_specs) -> None:
 
 
 def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = True):
-    """``jax.shard_map`` across jax versions: newer jax exposes it at the
-    top level with ``check_vma``; older releases only have
-    ``jax.experimental.shard_map.shard_map`` with the same flag spelled
-    ``check_rep``."""
+    """``jax.shard_map`` behind :func:`validate_shard_specs`."""
     import jax
 
     validate_shard_specs(mesh, in_specs, out_specs)
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, check_rep=check_vma)
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check_vma)
 
 
 @dataclass(frozen=True)

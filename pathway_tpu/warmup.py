@@ -8,12 +8,13 @@ round-5 finding: two in-window compiles cost 1.48 s of a 2.76 s window).
 
 Two fixes, composable:
 
-- ``enable_compilation_cache()`` points jax's persistent compilation cache
-  at a per-machine directory (``PATHWAY_COMPILATION_CACHE`` or
-  ``~/.cache/pathway_tpu/xla_cache``): every shape compiles once per
-  MACHINE instead of once per process. ``maybe_enable_compilation_cache``
-  is the opt-in hook wired into the embedders: it activates only when the
-  env var is set.
+- ``enable_compilation_cache()`` turns on jax's persistent compilation
+  cache, so every shape compiles once per MACHINE instead of once per
+  process. The embedders call it, so it is on by default. Where
+  ``JAX_COMPILATION_CACHE_DIR`` is set, jax already knows the directory
+  and this module sets none; otherwise the cache lives at one fixed path
+  inside the checkout (``<repo>/.jax_cache``) — fixed because the path is
+  what a later process must find again.
 - ``pw.warmup(embedder, index=...)`` eagerly walks the bucket shapes
   (encoder forward, and the fused encode+scatter / search kernels when an
   index is given) so all compilation happens before the first real tick —
@@ -31,6 +32,8 @@ import os
 import time as _time
 from typing import Any
 
+from pathway_tpu.native.build import _REPO_ROOT
+
 #: The jitted serving entry points whose compile set warmup's ladder
 #: covers. This is the bucket registry the PWT4xx static pass audits:
 #: PWT407 flags any module/class-level jitted callable with a
@@ -44,51 +47,31 @@ WARMED_ENTRY_POINTS = frozenset({
     "encode_jit",   # models/encoder.py — packed encoder forward
 })
 
-_CACHE_WIRED = False
+_DEFAULT_CACHE_DIR = os.path.join(_REPO_ROOT, ".jax_cache")
 
 
-def enable_compilation_cache(path: str | None = None) -> str | None:
-    """Point jax's persistent compilation cache at ``path`` (default:
-    ``PATHWAY_COMPILATION_CACHE`` or ``~/.cache/pathway_tpu/xla_cache``).
-    Returns the directory in use, or None when the running jax has no
-    persistent-cache support (older versions — warmup still works, it just
-    compiles once per process)."""
-    global _CACHE_WIRED
+def enable_compilation_cache() -> str:
+    """Turn on jax's persistent compilation cache and return the directory
+    in use: ``JAX_COMPILATION_CACHE_DIR`` where that is set (jax reads it
+    itself, no directory is set in code), else ``<repo>/.jax_cache``.
+    Idempotent."""
     import jax
 
-    if path is None:
-        path = os.environ.get("PATHWAY_COMPILATION_CACHE") or os.path.join(
-            os.path.expanduser("~"), ".cache", "pathway_tpu", "xla_cache")
-    try:
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not path:
+        path = _DEFAULT_CACHE_DIR
         os.makedirs(path, exist_ok=True)
-        jax.config.update("jax_compilation_cache_dir", str(path))
-    except Exception:
-        return None
+        jax.config.update("jax_compilation_cache_dir", path)
     # cache every entry: the default thresholds skip sub-second compiles,
     # but 18 x 0.7 s is exactly the stall this exists to delete
-    for knob, val in (("jax_persistent_cache_min_compile_time_secs", 0.0),
-                      ("jax_persistent_cache_min_entry_size_bytes", -1)):
-        try:
-            jax.config.update(knob, val)
-        except Exception:
-            pass
-    _CACHE_WIRED = True
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
     return path
-
-
-def maybe_enable_compilation_cache() -> str | None:
-    """Activate the persistent cache iff ``PATHWAY_COMPILATION_CACHE`` is
-    set (idempotent; called from embedder constructors)."""
-    if _CACHE_WIRED:
-        return None
-    if not os.environ.get("PATHWAY_COMPILATION_CACHE"):
-        return None
-    return enable_compilation_cache()
 
 
 def warmup(embedder: Any = None, *, index: Any = None,
            batch_size: int | None = None, ks: tuple[int, ...] = (),
-           cache: bool = True, autojit_max_bucket: int | None = None) -> dict:
+           autojit_max_bucket: int | None = None) -> dict:
     """Pre-compile the serving-path kernels so no XLA compile lands inside
     a live tick.
 
@@ -111,8 +94,9 @@ def warmup(embedder: Any = None, *, index: Any = None,
     (``DeviceEmbeddingKnnIndex.search``) dispatch it, and it is a
     separate jit from the fused encode+scatter.
 
-    ``cache=True`` wires the persistent compilation cache first, so warmed
-    executables persist across processes on this machine.
+    The persistent compilation cache is switched on first
+    (:func:`enable_compilation_cache`), so warmed executables persist
+    across processes on this machine.
 
     Auto-jit (internals/autojit.py): every fused UDF program registered by
     the expression compiler has its power-of-two batch-bucket ladder
@@ -139,20 +123,16 @@ def warmup(embedder: Any = None, *, index: Any = None,
     _ds.arm()
     with _ds.suspend_steady_state("pw.warmup ladder walk"):
         out = _warmup_impl(embedder, index=index, batch_size=batch_size,
-                           ks=ks, cache=cache,
-                           autojit_max_bucket=autojit_max_bucket)
+                           ks=ks, autojit_max_bucket=autojit_max_bucket)
     _ds.declare_steady_state()
     return out
 
 
 def _warmup_impl(embedder: Any = None, *, index: Any = None,
                  batch_size: int | None = None, ks: tuple[int, ...] = (),
-                 cache: bool = True,
                  autojit_max_bucket: int | None = None) -> dict:
     t0 = _time.perf_counter()
-    out: dict = {"cache_dir": None, "compiled": []}
-    if cache:
-        out["cache_dir"] = enable_compilation_cache()
+    out: dict = {"cache_dir": enable_compilation_cache(), "compiled": []}
     from pathway_tpu.internals.autojit import warm_registered
 
     out["compiled"].extend(warm_registered(autojit_max_bucket))
@@ -162,6 +142,8 @@ def _warmup_impl(embedder: Any = None, *, index: Any = None,
 
     import jax
     import numpy as np
+
+    from pathway_tpu.ops.knn import FusedIngestUnplaceable
 
     if embedder is None and index is not None:
         embedder = getattr(index, "embedder", None)
@@ -192,7 +174,7 @@ def _warmup_impl(embedder: Any = None, *, index: Any = None,
                 scratch = [Pointer((1 << 62) + i) for i in range(n_docs)]
                 try:
                     fused(scratch, embedder.params, *ops, n_rows=n_docs)
-                except ValueError:
+                except FusedIngestUnplaceable:
                     jax.block_until_ready(embedder._encode_ragged(
                         embedder.params, *ops))
                     out["compiled"].append(("ragged_encode", (n_seqs, W)))
@@ -226,9 +208,7 @@ def _warmup_impl(embedder: Any = None, *, index: Any = None,
                 scratch = [Pointer((1 << 62) + i) for i in range(B)]
                 try:
                     fused(scratch, embedder.params, ids, lens)
-                except ValueError as e:
-                    if "cannot grow" not in str(e):
-                        raise
+                except FusedIngestUnplaceable:
                     # slab too full for scratch slots: live ingest will
                     # also take the growable two-dispatch fallback
                     # (DeviceEmbeddingKnnIndex.add_batch), so warm the

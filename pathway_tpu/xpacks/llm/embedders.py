@@ -58,11 +58,11 @@ class JaxEncoderEmbedder(BaseEmbedder):
 
         import jax
 
-        from pathway_tpu.warmup import maybe_enable_compilation_cache
+        from pathway_tpu.warmup import enable_compilation_cache
 
-        # opt-in persistent XLA cache (PATHWAY_COMPILATION_CACHE): the ~18
-        # bucket shapes compile once per machine, not once per process
-        maybe_enable_compilation_cache()
+        # persistent XLA cache: the ~18 bucket shapes compile once per
+        # machine, not once per process
+        enable_compilation_cache()
 
         from pathway_tpu.models.encoder import EncoderConfig, encode, \
             init_params

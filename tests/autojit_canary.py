@@ -1,5 +1,5 @@
 """Auto-jit canary: the framework-vs-raw throughput gate + the trace
-artifact (internals/autojit.py, VERDICT #5).
+artifact (internals/autojit.py, round-5 verdict #5).
 
 One gate, evidence-first (same pattern as paging_canary.py):
 
@@ -9,7 +9,7 @@ batch device embed payload — measured three ways in interleaved
 best-of-3 trials: raw hand-written kernels, Table path with auto-jit ON,
 Table path with auto-jit OFF. Gates:
 
-- ``framework_vs_raw_ratio`` (ON) >= 0.85 — the ROADMAP/VERDICT target;
+- ``framework_vs_raw_ratio`` (ON) >= 0.85 — the ROADMAP target;
 - the OFF ratio reproduces today's gap (strictly below the ON ratio —
   the artifact carries both numbers from the same run);
 - the three paths are byte-identical (asserted inside the leg);

@@ -133,13 +133,17 @@ def check_rooflines() -> str | None:
 
     import jax.numpy as jnp
     from pathway_tpu.engine.profiler import (KERNEL_FAMILIES, Profiler,
-                                             install_profiler)
+                                             install_profiler,
+                                             machine_params)
     from pathway_tpu.internals.keys import Pointer
     from pathway_tpu.models.encoder import EncoderConfig
     from pathway_tpu.ops.knn import BruteForceKnnIndex, KnnMetric
     from pathway_tpu.xpacks.llm.embedders import JaxEncoderEmbedder
 
-    prof = Profiler(sample_interval_ms=1e6)  # device side only
+    # device side only; classified against the v5e row by hand — the
+    # canary checks the cost model's arithmetic, not this host
+    prof = Profiler(sample_interval_ms=1e6,
+                    machine=machine_params("TPU v5 lite"))
     install_profiler(prof)
     try:
         # knn_search + ingest_scatter

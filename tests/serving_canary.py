@@ -14,8 +14,9 @@
    serve queries in steady state and gate ZERO post-warmup compiles and
    zero implicit host→device transfers (any violation raises).
 
-3. **bench serving leg** — run ``bench.py`` with only the ``serving``
-   leg enabled (CPU-sized slab) and assert ``knn_p50_e2e_ms`` and every
+3. **bench serving leg** — call ``bench.bench_serving()`` at a CPU-sized
+   slab (the function, not ``python bench.py``: the command line runs
+   device legs on a TPU only) and assert ``knn_p50_e2e_ms`` and every
    ``serving_stage_*_p50_ms`` field is present and positive in the bench
    JSON, and that ``BENCH_LASTGOOD.json`` captured the same numbers
    (values are REPORTED, not thresholded — CPU runners don't meet the
@@ -199,7 +200,7 @@ def gate_sanitized_serving() -> str | None:
                  for i in range(300)]  # 3 extents at page_rows=128
         idx.add_batch([Pointer(i) for i in range(300)], texts)
         idx.drain()
-        pw.warmup(emb, index=idx, ks=(3,), cache=False)
+        pw.warmup(emb, index=idx, ks=(3,))
         if not ds.in_steady_state():
             return "pw.warmup did not declare steady state"
         if ds.warmup_compiles() == 0:
@@ -230,18 +231,21 @@ def gate_bench_serving() -> str | None:
         lastgood = pathlib.Path(td) / "BENCH_LASTGOOD.json"
         env = dict(
             os.environ, JAX_PLATFORMS="cpu",
-            BENCH_SKIP="etl,embed,framework,knn",
             BENCH_SERVING_N="2000", BENCH_SERVING_QUERIES="12",
-            BENCH_SERVING_WARMUP="4", BENCH_PROBE_TRIES="1",
+            BENCH_SERVING_WARMUP="4",
             BENCH_LASTGOOD_PATH=str(lastgood))
         # the bench child re-warms mid-run with engine-driven (unpinned)
         # batch shapes — its compile/transfer-count COLUMNS watch that
         # leg; the sanitizer's raise-on-compile contract is gated by
         # gate_sanitized_serving above, on the pinned-shape path
         env.pop("PATHWAY_DEVICE_SANITIZER", None)
+        # the leg's function, not `python bench.py`: the command line
+        # runs device legs on a TPU only, and this is the CPU rehearsal
         proc = subprocess.run(
-            [sys.executable, str(repo / "bench.py")], env=env, cwd=repo,
-            capture_output=True, text=True, timeout=540)
+            [sys.executable, "-c",
+             "import json, bench; out = bench.bench_serving(); "
+             "bench._write_lastgood(out); print(json.dumps(out))"],
+            env=env, cwd=repo, capture_output=True, text=True, timeout=540)
         last = None
         for ln in reversed((proc.stdout or "").splitlines()):
             if ln.strip().startswith("{"):

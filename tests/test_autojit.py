@@ -275,6 +275,62 @@ def test_runtime_demotion_loud_once_and_identical(monkeypatch, caplog):
     assert 1 <= len(demote_logs) <= 2
 
 
+def test_moved_jax_api_raises_instead_of_demoting(monkeypatch):
+    """An ImportError (or a missing module attribute) while arming the XLA
+    tier is the installed JAX having dropped an API — it must raise. The
+    old handler demoted at INFO, which kept every output byte-identical
+    and hid a dead XLA tier for twenty PRs."""
+    import sys
+
+    import jax
+
+    rows = [(int(i), 0, 1) for i in range(64)]
+    schema = sch.schema_from_types(x=int)
+
+    def lower():  # programs arm when run_batch lowers the graph
+        G.clear()
+        t = table_from_rows(schema, list(rows), is_stream=True)
+        runner = GraphRunner()
+        runner.capture(t.select(sb=boost(t.x)))
+        runner.run_batch(n_workers=1)
+
+    monkeypatch.setenv("PATHWAY_AUTO_JIT", "1")
+    with monkeypatch.context() as m:
+        m.delattr(jax, "enable_x64")  # AttributeError on a module
+        with pytest.raises(AttributeError, match="enable_x64"):
+            lower()
+    with monkeypatch.context() as m:
+        m.setitem(sys.modules, "jax", None)  # `import jax` → ImportError
+        with pytest.raises(ImportError):
+            lower()
+    G.clear()
+    assert autojit.autojit_stats()["demotions"] == 0
+
+
+def test_untraceable_probe_still_demotes_at_warning(monkeypatch, caplog):
+    """A body that genuinely does not trace stays a demotion to numpy —
+    logged at WARNING like every other one."""
+    import jax
+
+    def refuses(*a, **kw):
+        raise TypeError("tracer leaked into a host call")
+
+    rows = [(int(i), 0, 1) for i in range(64)]
+    schema = sch.schema_from_types(x=int)
+    monkeypatch.setenv("PATHWAY_AUTO_JIT", "1")
+    monkeypatch.setattr(jax, "eval_shape", refuses)
+    G.clear()
+    t = table_from_rows(schema, list(rows), is_stream=True)
+    runner = GraphRunner()
+    runner.capture(t.select(sb=boost(t.x)))
+    with caplog.at_level(logging.WARNING, logger="pathway_tpu.autojit"):
+        runner.run_batch(n_workers=1)
+    G.clear()
+    assert autojit.autojit_stats()["demotions"] == 1
+    assert any("XLA trace probe failed" in r.message
+               and r.levelno == logging.WARNING for r in caplog.records)
+
+
 def test_verify_mismatch_demotes_and_keeps_interpreter_result(monkeypatch):
     """Verify-then-trust: a first-batch cell mismatch (simulated wrong
     compiled output) demotes and the interpreter's values win."""
@@ -418,7 +474,7 @@ def test_warmup_walks_buckets_then_serving_compiles_nothing(monkeypatch):
     prog = _live_program()
     if prog.backend != "xla":  # CI without a usable jax backend
         pytest.skip("XLA backend unavailable for the fused program")
-    warm = pw.warmup(cache=False)
+    warm = pw.warmup()
     entries = [e for e in warm["compiled"] if e[0] == "autojit"]
     # ladder 8,16,32,64,128,256 → 6 buckets, each counted as a compile
     assert len(entries) == 6

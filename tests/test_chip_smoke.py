@@ -1,0 +1,89 @@
+"""CPU rehearsal of chip_smoke.py: exactly the function the chip runs, at a
+tiny size — and the proof that the command line refuses anything but a TPU.
+"""
+
+from __future__ import annotations
+
+import os
+import subprocess
+import sys
+
+import pytest
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, REPO)
+
+import chip_smoke  # noqa: E402
+
+
+@pytest.fixture(autouse=True)
+def _clear():
+    from pathway_tpu.internals.parse_graph import G
+
+    G.clear()
+    yield
+    G.clear()
+
+
+def _tiny():
+    from pathway_tpu.models.encoder import EncoderConfig
+
+    # room for the 4096 whole-word entries of the synthetic vocab
+    return EncoderConfig.tiny(vocab_size=8192)
+
+
+def test_smoke_function_passes_on_cpu_at_tiny_size(tmp_path):
+    out = tmp_path / "topk.json"
+    summary = chip_smoke.run_smoke(
+        expected_platform="cpu", config=_tiny, n_docs=48, max_words=40,
+        max_len=48, scan_rows=2048, request_timeout_s=60,
+        out_path=str(out))
+    assert summary["device"]["platform"] == "cpu"
+    assert summary["n_docs"] == 48 + 3
+    assert summary["bridge_legs_resolved"] > 0
+    assert summary["bf16_vs_f32_min_cos"] >= chip_smoke.BF16_VS_F32_MIN_COS
+    assert summary["mesh"] is None and out.exists()
+
+
+def test_smoke_refuses_the_wrong_platform_before_building_anything():
+    built = []
+
+    def config():
+        built.append(1)
+        return _tiny()
+
+    with pytest.raises(chip_smoke.SmokeFailure, match="'cpu'.*needs 'tpu'"):
+        chip_smoke.run_smoke(
+            expected_platform="tpu", config=config, n_docs=8, max_words=8,
+            max_len=16, scan_rows=64)
+    assert not built
+
+
+def test_command_line_refuses_a_cpu_whatever_the_environment():
+    """`python chip_smoke.py` takes no flag and reads no variable that
+    lets it pass off the chip: non-zero exit, no result line."""
+    env = dict(os.environ, JAX_PLATFORMS="cpu", CHIP_SMOKE_PLATFORM="cpu",
+               EXPECTED_PLATFORM="cpu")
+    proc = subprocess.run(
+        [sys.executable, os.path.join(REPO, "chip_smoke.py"),
+         "--platform", "cpu", "--allow-cpu"],
+        capture_output=True, text=True, timeout=120, env=env, cwd=REPO)
+    assert proc.returncode != 0
+    assert '"ok"' not in proc.stdout
+    assert "needs 'tpu'" in proc.stderr
+
+
+def test_smoke_function_mesh_auto_on_virtual_devices():
+    """The four-chip variant's rehearsal: mesh="auto" over the suite's 8
+    virtual CPU devices must pick the sharded paged index and still pass
+    every check (the fused-path checks do not apply on a mesh)."""
+    from pathway_tpu.parallel import mesh as mesh_mod
+
+    try:
+        summary = chip_smoke.run_smoke(
+            expected_platform="cpu", config=_tiny, n_docs=48, max_words=40,
+            max_len=48, scan_rows=2048, mesh="auto", request_timeout_s=60)
+    finally:
+        mesh_mod._ACTIVE_MESH = None  # get_mesh() memoizes process-wide
+    assert summary["mesh"] == "auto"
+    assert summary["device"]["count"] == 8

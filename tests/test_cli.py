@@ -10,7 +10,9 @@ import textwrap
 
 import pytest
 
-_ENV = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH="/root/repo")
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+_ENV = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=REPO)
 
 
 def _run_cli(*args, env=None, timeout=120):

@@ -489,12 +489,11 @@ def test_bucket_widths_cover_every_bucket():
     assert {emb._bucket(n) for n in range(1, 513)} == set(widths)
 
 
-def test_warmup_compiles_bucket_shapes(tmp_path, monkeypatch):
+def test_warmup_compiles_bucket_shapes():
     from pathway_tpu.models.encoder import EncoderConfig, init_params
     from pathway_tpu.xpacks.llm.embedders import JaxEncoderEmbedder
     import jax
 
-    monkeypatch.setenv("PATHWAY_COMPILATION_CACHE", str(tmp_path / "xla"))
     cfg = EncoderConfig(vocab_size=64, hidden=16, layers=1, heads=2,
                         intermediate=32, max_len=48)
     emb = JaxEncoderEmbedder(
@@ -526,7 +525,7 @@ def test_warmup_fused_index_leaves_index_empty():
         max_len=32, max_batch_size=4)
     index = DeviceEmbeddingKnnIndex(
         emb, BruteForceKnnIndex(16, reserved_space=64))
-    report = pw.warmup(emb, index=index, cache=False)
+    report = pw.warmup(emb, index=index)
     assert [k for k, _ in report["compiled"] if k != "autojit"] \
         == ["fused_ingest"] * len(emb.bucket_widths())
     assert len(index) == 0  # scratch slots retracted
@@ -542,7 +541,9 @@ def test_warmup_full_slab_falls_back_and_flushes(monkeypatch):
     tick) and the remaining widths warm the plain encoder — the dispatch
     the live two-dispatch fallback actually uses."""
     from pathway_tpu.models.encoder import EncoderConfig, init_params
-    from pathway_tpu.ops.knn import BruteForceKnnIndex, DeviceEmbeddingKnnIndex
+    from pathway_tpu.ops.knn import (BruteForceKnnIndex,
+                                     DeviceEmbeddingKnnIndex,
+                                     FusedIngestUnplaceable)
     from pathway_tpu.xpacks.llm.embedders import JaxEncoderEmbedder
     import jax
 
@@ -560,12 +561,13 @@ def test_warmup_full_slab_falls_back_and_flushes(monkeypatch):
     def fused_then_full(keys, params, ids, lens):
         calls["n"] += 1
         if calls["n"] > 1:  # second width onward: pretend the slab is full
-            raise ValueError("fused ingest cannot grow the slab (donated "
-                             "shape is pinned) — reserve capacity up front")
+            raise FusedIngestUnplaceable(
+                "fused ingest cannot grow the slab (donated shape is "
+                "pinned) — reserve capacity up front")
         return real_fused(keys, params, ids, lens)
 
     index._fused = fused_then_full
-    report = pw.warmup(emb, index=index, cache=False)
+    report = pw.warmup(emb, index=index)
     kinds = [k for k, _ in report["compiled"] if k != "autojit"]
     assert kinds == ["fused_ingest"] + ["encode"] * (len(widths) - 1)
     # the width-1 scratch removals were flushed (dirty set drained), so
@@ -574,14 +576,52 @@ def test_warmup_full_slab_falls_back_and_flushes(monkeypatch):
     assert len(index) == 0
 
 
-def test_enable_compilation_cache_sets_jax_config(tmp_path):
+@pytest.fixture
+def _restore_cache_dir():
     import jax
 
-    path = pw.enable_compilation_cache(str(tmp_path / "cache"))
-    if path is None:  # ancient jax without persistent-cache support
-        pytest.skip("jax lacks persistent compilation cache")
-    assert (tmp_path / "cache").is_dir()
-    assert jax.config.jax_compilation_cache_dir == str(tmp_path / "cache")
+    prev = jax.config.jax_compilation_cache_dir
+    yield
+    jax.config.update("jax_compilation_cache_dir", prev)
+
+
+def test_compile_cache_env_var_set_leaves_jax_config_alone(
+        monkeypatch, tmp_path, _restore_cache_dir):
+    """JAX_COMPILATION_CACHE_DIR set: jax owns the directory — the program
+    must set none in code (the driver places the cache from outside)."""
+    import jax
+
+    from pathway_tpu.models.encoder import EncoderConfig
+    from pathway_tpu.xpacks.llm.embedders import JaxEncoderEmbedder
+
+    jax.config.update("jax_compilation_cache_dir", "/sentinel/untouched")
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "outer"))
+    assert pw.enable_compilation_cache() == str(tmp_path / "outer")
+    # every call site: the embedder constructor and pw.warmup
+    emb = JaxEncoderEmbedder(config=EncoderConfig.tiny(), max_len=32)
+    report = pw.warmup()
+    assert report["cache_dir"] == str(tmp_path / "outer")
+    assert jax.config.jax_compilation_cache_dir == "/sentinel/untouched"
+    assert not (tmp_path / "outer").exists()  # nor created: not ours
+    del emb
+
+
+def test_compile_cache_default_is_the_fixed_in_checkout_path(
+        monkeypatch, _restore_cache_dir):
+    """Unset: on by default, at one fixed path inside the checkout — the
+    path is part of the cache key, so it may never be a temp/pid/time
+    name."""
+    import pathlib
+
+    import jax
+
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    want = pathlib.Path(pw.__file__).resolve().parent.parent / ".jax_cache"
+    assert pw.enable_compilation_cache() == str(want)
+    assert jax.config.jax_compilation_cache_dir == str(want)
+    assert want.is_dir()
+    assert jax.config.jax_persistent_cache_min_compile_time_secs == 0.0
+    assert pw.enable_compilation_cache() == str(want)  # idempotent
 
 
 def test_device_bridge_standalone_fifo_order():

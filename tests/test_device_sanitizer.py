@@ -158,7 +158,7 @@ def test_warmup_declares_steady_state(monkeypatch):
     import pathway_tpu as pw
 
     monkeypatch.setenv("PATHWAY_DEVICE_SANITIZER", "1")
-    pw.warmup(cache=False)  # no embedder: still brackets the window
+    pw.warmup()  # no embedder: still brackets the window
     assert ds.in_steady_state()
     assert ds.post_warmup_compiles() == 0
 
@@ -167,9 +167,9 @@ def test_rewarmup_of_armed_process_is_not_a_violation(monkeypatch):
     import pathway_tpu as pw
 
     monkeypatch.setenv("PATHWAY_DEVICE_SANITIZER", "1")
-    pw.warmup(cache=False)
+    pw.warmup()
     assert ds.in_steady_state()
-    pw.warmup(cache=False)  # re-warm: suspends, never violates
+    pw.warmup()  # re-warm: suspends, never violates
     assert ds.in_steady_state()
     assert ds.violations() == []
 
@@ -183,7 +183,7 @@ def test_ragged_encoder_ladder_pin_under_sanitizer(monkeypatch):
 
     monkeypatch.setenv("PATHWAY_DEVICE_SANITIZER", "1")
     emb = JaxEncoderEmbedder(config=_tiny_cfg(), ragged=True, max_len=64)
-    out = pw.warmup(emb, cache=False)
+    out = pw.warmup(emb)
     assert ds.in_steady_state()
     ladder = [e for e in out["compiled"] if e[0] != "autojit"]
     assert 0 < len(ladder) <= 6, out["compiled"]
@@ -213,7 +213,7 @@ def test_paged_multi_extent_search_zero_compiles_in_steady_state(
     vecs = rng.normal(size=(300, 8)).astype(np.float32)  # 3 extents
     idx.add_batch([Pointer(i) for i in range(300)], vecs)
     idx.drain()
-    pw.warmup(index=idx, ks=(3,), cache=False)
+    pw.warmup(index=idx, ks=(3,))
     assert ds.in_steady_state()
     res1 = idx.search([(Pointer(10 ** 6), vecs[5], 3, None)])
     assert res1[0][0][0] == Pointer(5)

@@ -233,10 +233,9 @@ def test_streaming_trace_has_device_track(monkeypatch, tmp_path):
 # ---------------------------------------------------------------------------
 
 def test_seeded_device_leg_hang_named_in_postmortem(monkeypatch, caplog):
-    """The BENCH_r05 scenario, reproduced and attributed: a device leg
-    that hangs stalls the commit loop (backpressure), the watchdog fires,
-    and its post-mortem dump names the stuck operator with its user frame
-    — instead of 'tunnel unhealthy' naming nothing."""
+    """A device leg that hangs stalls the commit loop (backpressure), the
+    watchdog fires, and its post-mortem dump names the stuck operator with
+    its user frame — instead of a timeout naming nothing."""
     import numpy as np
 
     monkeypatch.setenv("PATHWAY_DEVICE_INFLIGHT", "2")

@@ -1051,9 +1051,12 @@ def _installed_profiler():
     """A live profiler with known device dispatches and folded stacks
     (sampler not started — the endpoints read state, not the thread)."""
     from pathway_tpu.engine.profiler import (Profiler, install_profiler,
-                                             knn_search_cost)
+                                             knn_search_cost, machine_params)
 
-    prof = Profiler(sample_interval_ms=1e6)
+    # rated against the v5e row by hand — the suite's own device (cpu)
+    # has no row, and an unrated profiler exports no utilization gauges
+    prof = Profiler(sample_interval_ms=1e6,
+                    machine=machine_params("TPU v5 lite"))
     f, b = knn_search_cost(4, 1024, 64)
     prof.record_dispatch("knn_search", f, b, 2.0)
     prof.record_dispatch("encoder_forward", 1e9, 1e6, 5.0)

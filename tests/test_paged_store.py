@@ -489,7 +489,7 @@ def test_ragged_warmup_compile_count_under_six():
     idx = DeviceEmbeddingKnnIndex(
         emb, BruteForceKnnIndex(cfg.hidden, metric=KnnMetric.COS,
                                 paged=True))
-    out = pw.warmup(emb, index=idx, cache=False)
+    out = pw.warmup(emb, index=idx)
     # leaked gc-pending fused programs from other tests may add autojit
     # entries — the ragged ladder is what this pin counts
     ladder = [e for e in out["compiled"] if e[0] != "autojit"]

@@ -18,6 +18,8 @@ import pathway_tpu as pw
 from pathway_tpu.engine.persistence import SnapshotLog
 from pathway_tpu.internals.parse_graph import G
 
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
 
 @pytest.fixture(autouse=True)
 def fresh_graph():
@@ -235,9 +237,9 @@ def test_wordcount_kill_and_recover(tmp_path):
         for w in words:
             expected[w] = expected.get(w, 0) + 1
 
-    env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH="/root/repo")
+    env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=REPO)
     proc = subprocess.Popen([sys.executable, str(script), str(inp), pdir, out],
-                            env=env, cwd="/root/repo")
+                            env=env, cwd=REPO)
     try:
         wait_result_with_checker(lambda: _read_counts(out), 60)
         assert _read_counts(out), "no output before kill"
@@ -252,7 +254,7 @@ def test_wordcount_kill_and_recover(tmp_path):
 
         proc = subprocess.Popen(
             [sys.executable, str(script), str(inp), pdir, out],
-            env=env, cwd="/root/repo")
+            env=env, cwd=REPO)
         wait_result_with_checker(
             lambda: _read_counts(out) == expected, 90, step=0.2)
         assert _read_counts(out) == expected
@@ -270,7 +272,7 @@ def test_wordcount_kill_and_recover(tmp_path):
                 expected[w] = expected.get(w, 0) + 1
         proc = subprocess.Popen(
             [sys.executable, str(script), str(inp), pdir, out],
-            env=env, cwd="/root/repo")
+            env=env, cwd=REPO)
         wait_result_with_checker(
             lambda: _read_counts(out) == expected, 90, step=0.2)
         assert _read_counts(out) == expected

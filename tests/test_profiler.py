@@ -23,6 +23,7 @@
 
 from __future__ import annotations
 
+import os
 import re
 import threading
 import time
@@ -38,6 +39,13 @@ from pathway_tpu.engine.profiler import (Profiler, current_profiler,
                                          live_profiler_stats,
                                          machine_balance, machine_params,
                                          segment_attention_cost)
+
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+# the v5e row, passed in by hand: these tests check the arithmetic against
+# stated peaks — they do not rate the CPU they run on
+V5E = machine_params("TPU v5 lite")
 
 
 @pytest.fixture(autouse=True)
@@ -96,14 +104,29 @@ def test_ingest_scatter_cost_pin():
     assert ingest_scatter_cost(8, 16, itemsize=1)[1] == 8 * 16 * 5.0
 
 
-def test_machine_balance_default_and_env(monkeypatch):
-    monkeypatch.delenv("BENCH_PEAK_TFLOPS", raising=False)
-    monkeypatch.delenv("BENCH_HBM_GBPS", raising=False)
-    assert machine_params() == {"peak_tflops": 197.0, "hbm_gbps": 819.0}
-    assert machine_balance() == pytest.approx(197e12 / 819e9)
-    monkeypatch.setenv("BENCH_PEAK_TFLOPS", "100")
-    monkeypatch.setenv("BENCH_HBM_GBPS", "1000")
-    assert machine_balance() == pytest.approx(100.0)  # 100e12 / 1000e9
+def test_machine_params_keyed_by_device_kind():
+    assert V5E == {"device_kind": "TPU v5 lite", "peak_tflops": 197.0,
+                   "hbm_gbps": 819.0}
+    assert machine_balance(V5E) == pytest.approx(197e12 / 819e9)
+    assert machine_balance({"peak_tflops": 100.0, "hbm_gbps": 1000.0}) \
+        == pytest.approx(100.0)  # 100e12 / 1000e9
+    # a device without a row has no peaks — never another chip's
+    assert machine_params("some future chip") is None
+    assert machine_params() is None  # the suite runs on the CPU
+
+
+def test_unrated_device_reports_no_utilization():
+    prof = Profiler(sample_interval_ms=1e6)  # machine from the device: cpu
+    prof.record_dispatch("knn_search", 5e8, 1e6, 1.0)
+    assert prof.machine is None
+    assert prof.rolling_mfu() is None and prof.rolling_hbm_bw_util() is None
+    fam = prof.family_stats()["knn_search"]
+    assert fam["device_ms_total"] == 1.0  # what was observed stays
+    assert fam["roofline"]["arithmetic_intensity"] == 500.0
+    assert fam["mfu"] is None and fam["rolling"]["mfu"] is None
+    assert fam["roofline"]["bound_by"] is None
+    st = prof.stats()
+    assert st["machine"] is None and st["mfu_rolling"] is None
 
 
 def test_bench_mfu_uses_shared_encoder_formula():
@@ -111,14 +134,13 @@ def test_bench_mfu_uses_shared_encoder_formula():
     here silently decouples the live MFU gauge from the benchmark."""
     import sys
 
-    sys.path.insert(0, "/root/repo")
+    sys.path.insert(0, REPO)
     import bench
 
     from pathway_tpu.models.encoder import EncoderConfig
     cfg = EncoderConfig(hidden=64, intermediate=128, layers=2)
     assert bench._encoder_flops_per_token(cfg, seq=16) == \
         encoder_flops_per_token(64, 128, 2, 16)
-    assert bench.PEAK_TFLOPS == machine_params()["peak_tflops"]
 
 
 def test_encoder_cost_helper_routes_ragged():
@@ -137,7 +159,7 @@ def test_encoder_cost_helper_routes_ragged():
 # ---------------------------------------------------------------------------
 
 def test_roofline_classification():
-    prof = Profiler(sample_interval_ms=1e6)
+    prof = Profiler(sample_interval_ms=1e6, machine=V5E)
     # knn search: AI = 2Q/itemsize ≈ 2 FLOP/byte at Q=4 — far below
     # machine balance → bandwidth-bound
     f, b = knn_search_cost(4, 1024, 64)
@@ -157,9 +179,9 @@ def test_roofline_classification():
     assert prof.rolling_hbm_bw_util() > 0.0
 
 
-def test_rolling_mfu_matches_hand_computation(monkeypatch):
-    monkeypatch.setenv("BENCH_PEAK_TFLOPS", "1")  # 1e12 FLOP/s peak
-    prof = Profiler(sample_interval_ms=1e6)
+def test_rolling_mfu_matches_hand_computation():
+    prof = Profiler(sample_interval_ms=1e6,  # 1e12 FLOP/s peak
+                    machine={"peak_tflops": 1.0, "hbm_gbps": 819.0})
     prof.record_dispatch("knn_search", 5e8, 1e6, 1.0)  # 5e8 FLOP in 1ms
     # 5e8 / 1e-3 s = 5e11 FLOP/s → 50% of the 1e12 peak
     assert prof.rolling_mfu() == pytest.approx(0.5, rel=1e-6)
@@ -332,7 +354,7 @@ def test_stack_table_overflow_folds_into_other_bucket():
 
 def test_live_profiler_stats_roundtrip():
     assert live_profiler_stats() is None
-    prof = Profiler(sample_interval_ms=1e6)
+    prof = Profiler(sample_interval_ms=1e6, machine=V5E)
     install_profiler(prof)
     assert current_profiler() is prof
     st = live_profiler_stats()
@@ -341,7 +363,7 @@ def test_live_profiler_stats_roundtrip():
                        "families", "capture"}
     assert st["host"]["sampling"] is False
     assert st["machine"]["balance_flop_per_byte"] == pytest.approx(
-        machine_balance(), abs=1e-3)
+        machine_balance(V5E), abs=1e-3)
 
 
 # ---------------------------------------------------------------------------
@@ -363,7 +385,7 @@ def _knn_roundtrip(n=48, dim=8, q=3):
 @pytest.mark.slow
 def test_knn_outputs_identical_with_profiler_on_and_off():
     off = _knn_roundtrip()
-    prof = Profiler(sample_interval_ms=1e6)
+    prof = Profiler(sample_interval_ms=1e6, machine=V5E)
     install_profiler(prof)
     on = _knn_roundtrip()
     assert on == off  # the profiler only observes shapes and clocks
@@ -390,7 +412,7 @@ def test_paged_knn_records_families_too():
     from pathway_tpu.internals.keys import Pointer
     from pathway_tpu.ops.knn import BruteForceKnnIndex, KnnMetric
 
-    prof = Profiler(sample_interval_ms=1e6)
+    prof = Profiler(sample_interval_ms=1e6, machine=V5E)
     install_profiler(prof)
     rng = np.random.default_rng(7)
     vecs = rng.normal(size=(48, 8)).astype(np.float32)

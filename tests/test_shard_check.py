@@ -30,6 +30,8 @@ from pathway_tpu.internals.static_check.shard_check import (
 )
 from tests.utils import T
 
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
 
 @pytest.fixture(autouse=True)
 def _clear():
@@ -719,7 +721,7 @@ def _run_check(*args):
     return subprocess.run(
         [sys.executable, "-m", "pathway_tpu", "check", *args],
         capture_output=True, text=True, env=env, timeout=300,
-        cwd="/root/repo")
+        cwd=REPO)
 
 
 NEGATIVE_EXAMPLE = os.path.join(

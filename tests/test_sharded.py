@@ -21,6 +21,8 @@ from pathway_tpu.engine.operators import (ColumnarGroupByOperator,
 from pathway_tpu.internals.runner import GraphRunner
 from tests.utils import T
 
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
 N_WORKERS = 8
 
 
@@ -307,7 +309,7 @@ def test_multi_process_batch_matches_single(tmp_path, transport, first_port):
 
     prog = tmp_path / "mp_prog.py"
     prog.write_text(_MP_PROGRAM)
-    base_env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH="/root/repo",
+    base_env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=REPO,
                     PATHWAY_RUN_ID=f"mp-test-{transport}",
                     PATHWAY_EXCHANGE_TRANSPORT=transport)
 
@@ -491,7 +493,7 @@ def test_cluster_peer_death_detected(tmp_path):
     prog = tmp_path / "dying.py"
     prog.write_text(_MP_DYING)
     env_base = dict(os.environ, JAX_PLATFORMS="cpu",
-                    PYTHONPATH="/root/repo", PATHWAY_RUN_ID="mp-die")
+                    PYTHONPATH=REPO, PATHWAY_RUN_ID="mp-die")
     handles = []
     for pid in range(2):
         env = dict(env_base, PATHWAY_PROCESSES="2",
