@@ -69,8 +69,9 @@ class DeviceBridge:
                  recorder=None):
         self.max_inflight = max(1, int(max_inflight))
         self.name = name
-        # flight recorder (engine/flight_recorder.py): leg-level spans
-        # (queue-wait vs execute) and the in-flight marker for post-mortems
+        # flight recorder (engine/flight_recorder.py): the ``bridge.wait``
+        # and ``bridge.leg`` spans of every resolved leg and the in-flight
+        # marker for post-mortems
         self.recorder = recorder
         self._current: tuple | None = None  # (tick, started_monotonic)
         # longest resolved prefix of submitted legs: the tick of the last
@@ -255,6 +256,8 @@ class DeviceBridge:
                 # a host thread already blocked on us? then this leg is
                 # (at least partially) serialized with host work
                 waited_at_start = self._waiters > 0
+                # legs in the window as this one starts, itself included
+                depth = len(self._queue) + 1
             rec = self.recorder
             recording = rec is not None and rec.enabled
             if recording:
@@ -304,8 +307,10 @@ class DeviceBridge:
             if prof is not None:
                 prof.end_leg((finished - started) * 1e3)
             if recording:
-                rec.record_leg(tick, (started - submitted_at) * 1e3,
-                               (finished - started) * 1e3)
+                cause = ("tick", tick)
+                rec.span("bridge.wait", submitted_at, started, cause,
+                         depth=depth)
+                rec.span("bridge.leg", started, finished, cause)
                 rec.clear_leg()
             with self._cv:
                 self.queue_wait_ms += (started - submitted_at) * 1e3

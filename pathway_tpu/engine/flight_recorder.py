@@ -3,10 +3,16 @@
 The reference engine exports per-operator latency gauges and OTLP spans
 (src/engine/telemetry.rs:196-366); this module is the port's in-process
 counterpart, sized for post-mortems rather than dashboards: a bounded ring
-of structured span events — tick, operator id + class + user frame
-(internals/trace.py), host vs. device leg, queue-wait vs. execute time,
-rows in/out — written by the Scheduler (engine/graph.py) and the device
-bridge (engine/device_bridge.py).
+of operator-step events — tick, operator id + class + user frame
+(internals/trace.py), host vs. device leg, rows in/out — written by the
+Scheduler (engine/graph.py), and beside it one bounded store of spans on
+the same ``perf_counter`` clock: ``tick`` / ``tick.drain`` / ``tick.host``
+from the commit loop (engine/streaming.py), ``bridge.wait`` /
+``bridge.leg`` from the bridge worker (engine/device_bridge.py) and
+``connector.pass`` from a polling source (io/fs). Spans of one piece of
+work share a ``cause`` — ``("tick", n)`` or ``("pass", source uid, n)`` —
+and a request carries its tick, so request -> tick -> leg -> operator
+steps is one chain by identifier.
 
 Consumers:
 
@@ -51,7 +57,12 @@ LATENCY_BUCKETS_MS = (
     100.0, 250.0, 500.0, 1000.0, 2500.0, 10_000.0,
 )
 
-_DEFAULT_BUFFER_EVENTS = 4096
+# the operator-event ring holds a 30 s window of a busy ingest (13 ticks a
+# second, some twenty steps a tick) and the minutes of checks after it
+_DEFAULT_BUFFER_EVENTS = 65_536
+# every tick of several minutes: one ``tick`` and two ``bridge.*`` spans a
+# tick, two more on a tick that carried rows
+_SPAN_BUFFER = 16_384
 _DEFAULT_TAIL_TICKS = 8
 
 
@@ -110,8 +121,8 @@ def fsync_dir(dirpath: str) -> None:
         os.close(fd)
 
 # live enabled recorders (weak: a recorder dies with its scheduler/run).
-# Lets out-of-band observers — bench.py's flight beacon — find the run's
-# in-flight operator without plumbing a reference through every layer.
+# Lets out-of-band observers — the profiler's host sampler — find the
+# run's in-flight operator without plumbing a reference through every layer.
 _LIVE: "weakref.WeakSet[FlightRecorder]" = weakref.WeakSet()
 
 
@@ -203,8 +214,12 @@ class FlightRecorder:
         self._events: collections.deque = collections.deque(
             maxlen=buffer_events)
         self._ops: dict[int, _OpStats] = {}
-        # device-leg level events: (tick, queue_wait_ms, exec_ms)
-        self._legs: collections.deque = collections.deque(maxlen=512)
+        # (name, t0_perf, t1_perf, cause, thread ident, counts or None)
+        self._spans: collections.deque = collections.deque(
+            maxlen=_SPAN_BUFFER)
+        # thread ident -> name of every thread that wrote a span: reader
+        # threads are gone by the time the trace file is written
+        self._span_threads: dict[int, str] = {}
         # in-flight markers, ONE SLOT PER STEPPING THREAD: host thread(s),
         # sharded pool workers and the bridge worker each own the slot
         # keyed by their thread id, so a device op hung for minutes keeps
@@ -314,10 +329,28 @@ class FlightRecorder:
     def clear_leg(self) -> None:
         self._inflight_leg = None
 
-    def record_leg(self, tick: int, queue_wait_ms: float,
-                   exec_ms: float) -> None:
+    def span(self, name: str, t0: float, t1: float, cause=None,
+             **counts) -> None:
+        """One finished span ``[t0, t1]`` (``perf_counter`` seconds) of the
+        calling thread. ``cause`` is the identifier the spans of one piece
+        of work share; ``counts`` is what the work amounted to."""
+        ident = threading.get_ident()
         with self._lock:
-            self._legs.append((tick, queue_wait_ms, exec_ms))
+            if ident not in self._span_threads:
+                self._span_threads[ident] = threading.current_thread().name
+            self._spans.append((name, t0, t1, cause, ident, counts or None))
+
+    def spans(self, t0: float | None = None,
+              t1: float | None = None) -> list[tuple]:
+        """The buffered spans that overlap ``[t0, t1]`` (an open end is
+        unbounded), oldest first."""
+        with self._lock:
+            out = list(self._spans)
+        if t0 is not None:
+            out = [sp for sp in out if sp[2] >= t0]
+        if t1 is not None:
+            out = [sp for sp in out if sp[1] <= t1]
+        return out
 
     def note_promotion(self, epoch: int, complete_tick: int) -> None:
         """Stamp the moment this process was promoted to primary
@@ -455,9 +488,9 @@ class FlightRecorder:
 
     def trace_payload(self, n_ticks: int | None = None) -> dict:
         """JSON-friendly snapshot for the ``/trace`` endpoint."""
+        evs = self.tail_events(n_ticks)
         events = []
-        for tick, op_id, leg, t0, dur_ms, rows_in, rows_out in \
-                self.tail_events(n_ticks):
+        for tick, op_id, leg, t0, dur_ms, rows_in, rows_out in evs:
             name, frame = self._op_meta(op_id)
             events.append({
                 "tick": tick, "operator": name, "id": op_id, "leg": leg,
@@ -466,10 +499,26 @@ class FlightRecorder:
                 "rows_in": rows_in, "rows_out": rows_out,
                 "user_frame": frame,
             })
+        # with a tick limit, the spans from the first kept event on
+        raw = self.spans(t0=evs[0][3] if n_ticks is not None and evs
+                         else None)
         with self._lock:
-            legs = [{"tick": t, "queue_wait_ms": round(q, 3),
-                     "exec_ms": round(e, 3)} for t, q, e in self._legs]
-        out = {"enabled": self.enabled, "events": events,
+            threads = dict(self._span_threads)
+        spans = [{"name": name,
+                  "ts_ms": round((t0 - self._epoch) * 1e3, 3),
+                  "dur_ms": round((t1 - t0) * 1e3, 3),
+                  "cause": list(cause) if cause is not None else None,
+                  "thread": threads.get(ident, str(ident)),
+                  "counts": counts}
+                 for name, t0, t1, cause, ident, counts in raw]
+        # a leg is a span; its wait is the ``bridge.wait`` of the same tick
+        waits = self._wait_ms_by_cause(raw)
+        legs = [{"tick": cause[1],
+                 "queue_wait_ms": waits.get(cause, 0.0),
+                 "exec_ms": round((t1 - t0) * 1e3, 3)}
+                for name, t0, t1, cause, _ident, _counts in raw
+                if name == "bridge.leg"]
+        out = {"enabled": self.enabled, "events": events, "spans": spans,
                "device_legs": legs, "inflight": self.inflight_summary()}
         if self.requests is not None:
             out["requests"] = {
@@ -482,6 +531,12 @@ class FlightRecorder:
                 ],
             }
         return out
+
+    @staticmethod
+    def _wait_ms_by_cause(spans: list[tuple]) -> dict:
+        """cause -> ms its device leg waited in the bridge's queue."""
+        return {sp[3]: round((sp[2] - sp[1]) * 1e3, 3) for sp in spans
+                if sp[0] == "bridge.wait"}
 
     def dominator(self) -> dict | None:
         """The operator that dominated the last complete tick (critical
@@ -503,11 +558,41 @@ class FlightRecorder:
                 "user_frame": frame}
 
     # -- Chrome trace-event export ----------------------------------------
+    @staticmethod
+    def _nested(pid: int, tid: int, slices: list[tuple]) -> list[dict]:
+        """B/E events of one track from ``(start_us, end_us, name, cat,
+        args)`` slices, nested like a call stack so the file opens in
+        Perfetto: a slice that outlasts the one it starts in (clock
+        rounding at a shared edge; sharded replicas stepping side by side)
+        is cut at that one's end."""
+        out: list[dict] = []
+        stack: list[tuple[float, str, str]] = []   # (end_us, name, cat)
+
+        def close_until(ts: float) -> None:
+            while stack and stack[-1][0] <= ts:
+                end, name, cat = stack.pop()
+                out.append({"ph": "E", "pid": pid, "tid": tid, "ts": end,
+                            "cat": cat, "name": name})
+
+        for start, end, name, cat, args in sorted(
+                slices, key=lambda sl: (sl[0], -sl[1])):
+            close_until(start)
+            if stack:
+                end = min(end, stack[-1][0])
+            out.append({"ph": "B", "pid": pid, "tid": tid, "ts": start,
+                        "cat": cat, "name": name, "args": args})
+            stack.append((end, name, cat))
+        close_until(float("inf"))
+        return out
+
     def chrome_trace_events(self) -> list[dict]:
-        """Trace-event list: host and device legs as separate tracks
-        (tid 0/1 with thread_name metadata), per-(tick, leg) wrapper spans
-        containing operator spans — all B/E pairs, properly nested, so the
-        file opens directly in Perfetto."""
+        """Trace-event list: the host leg (tid 0: ``tick`` spans holding
+        ``tick.drain``, ``tick.host`` and the host operators), the device
+        leg (tid 1: ``bridge.leg`` spans holding the device operators;
+        ``bridge.wait`` as async events, since a leg waits while the one
+        before it runs), requests (tid 2) and one track per other thread
+        that wrote spans (a connector's passes), named after the thread.
+        Every slice is a recorded span or operator step."""
         pid = int(os.environ.get("PATHWAY_PROCESS_ID", "0"))
         tids = {"host": 0, "device": 1}
         out = [
@@ -526,64 +611,74 @@ class FlightRecorder:
                 "ts": (t_p - self._epoch) * 1e6, "cat": "promotion",
                 "name": f"promoted to primary (epoch {epoch})",
                 "args": {"epoch": epoch, "complete_tick": complete_tick}})
-        evs = self.tail_events(None)
-        # group by (tick, leg) preserving order; events within a leg are
-        # sequential (one thread per leg), so wrapper = [min start, max end]
-        groups: dict[tuple, list] = {}
-        order: list[tuple] = []
-        for ev in evs:
-            k = (ev[0], ev[2])
-            if k not in groups:
-                groups[k] = []
-                order.append(k)
-            groups[k].append(ev)
-        leg_meta = {}
+
+        def us(t: float) -> float:
+            return (t - self._epoch) * 1e6
+
+        tracks: dict[int, list[tuple]] = {0: [], 1: []}
+        for tick, op_id, leg, t0, dur_ms, rows_in, rows_out in \
+                self.tail_events(None):
+            name, frame = self._op_meta(op_id)
+            args = {"tick": tick, "operator": name,
+                    "rows_in": rows_in, "rows_out": rows_out}
+            if frame:
+                args["user_frame"] = frame
+            tracks[tids[leg]].append(
+                (us(t0), us(t0) + dur_ms * 1e3, name, leg, args))
+        spans = self.spans()
         with self._lock:
-            for tick, q, e in self._legs:
-                leg_meta[tick] = (q, e)
-        # per-(tick, leg) wrapper start: flow arrows from request spans
-        # bind to these (the query <-> operator <-> device-leg causality
-        # link in the three-track Perfetto view)
-        wrapper_start_us: dict[tuple, float] = {}
-        for tick, leg in order:
-            g = groups[(tick, leg)]
-            tid = tids.get(leg, 2)
-            start_us = (g[0][3] - self._epoch) * 1e6
-            end_us = max((ev[3] - self._epoch + ev[4] / 1e3) * 1e6
-                         for ev in g)
-            wrapper_start_us[(tick, leg)] = start_us
-            wrap_args = {"tick": tick, "leg": leg}
-            if leg == "device" and tick in leg_meta:
-                wrap_args["queue_wait_ms"] = round(leg_meta[tick][0], 3)
-                wrap_args["exec_ms"] = round(leg_meta[tick][1], 3)
-            out.append({"ph": "B", "pid": pid, "tid": tid,
-                        "ts": start_us, "cat": leg,
-                        "name": f"tick {tick}", "args": wrap_args})
-            for _tick, op_id, _leg, t0, dur_ms, rows_in, rows_out in g:
-                name, frame = self._op_meta(op_id)
-                ts = (t0 - self._epoch) * 1e6
-                args = {"tick": tick, "operator": name,
-                        "rows_in": rows_in, "rows_out": rows_out}
-                if frame:
-                    args["user_frame"] = frame
-                out.append({"ph": "B", "pid": pid, "tid": tid, "ts": ts,
-                            "cat": leg, "name": name, "args": args})
-                out.append({"ph": "E", "pid": pid, "tid": tid,
-                            "ts": ts + dur_ms * 1e3, "cat": leg,
-                            "name": name})
-            out.append({"ph": "E", "pid": pid, "tid": tid, "ts": end_us,
-                        "cat": leg, "name": f"tick {tick}"})
-        out.extend(self._request_trace_events(pid, wrapper_start_us))
+            threads = dict(self._span_threads)
+        waits = self._wait_ms_by_cause(spans)
+        # where a request's flow arrows land: the start of its tick's
+        # ``tick`` span and of that tick's ``bridge.leg``
+        flow_start_us: dict[tuple, float] = {}
+        other_tids: dict[int, int] = {}
+        for name, t0, t1, cause, ident, counts in spans:
+            args = dict(counts or ())
+            label = name
+            if cause is not None:
+                args["cause"] = list(cause)
+                label = f"{name} {cause[-1]}"
+            if name == "bridge.wait":
+                fid = f"wait-{cause[-1]}"
+                for ph, t in (("b", t0), ("e", t1)):
+                    out.append({"ph": ph, "cat": "bridge", "id": fid,
+                                "pid": pid, "tid": 1, "ts": us(t),
+                                "name": label, "args": args})
+                continue
+            if name.startswith("tick"):
+                tid = 0
+                if name == "tick":
+                    flow_start_us[(cause[1], "host")] = us(t0)
+            elif name == "bridge.leg":
+                tid = 1
+                flow_start_us[(cause[1], "device")] = us(t0)
+                args["exec_ms"] = round((t1 - t0) * 1e3, 3)
+                if cause in waits:
+                    args["queue_wait_ms"] = waits[cause]
+            else:
+                tid = other_tids.get(ident)
+                if tid is None:
+                    # tid 2 is the requests track
+                    tid = other_tids[ident] = 3 + len(other_tids)
+                    tracks[tid] = []
+                    out.append({"ph": "M", "pid": pid, "tid": tid,
+                                "name": "thread_name", "args": {
+                                    "name": threads.get(ident, str(ident))}})
+            tracks[tid].append((us(t0), us(t1), label, "span", args))
+        for tid, slices in tracks.items():
+            out.extend(self._nested(pid, tid, slices))
+        out.extend(self._request_trace_events(pid, flow_start_us))
         return out
 
     def _request_trace_events(self, pid: int,
-                              wrapper_start_us: dict) -> list[dict]:
+                              flow_start_us: dict) -> list[dict]:
         """Third track: completed request spans as async (b/e) events —
         async because concurrent requests legitimately overlap, which
         B/E nesting cannot represent — with per-stage child spans and a
-        flow arrow (s -> t -> f) from each request's tick-start into the
-        tick's host and device wrappers, so clicking a query walks to the
-        operator spans that served it."""
+        flow arrow (s -> t -> f) from each request's tick-start into its
+        tick's ``tick`` span and ``bridge.leg``, so clicking a query walks
+        to the operator spans that served it."""
         tracker = self.requests
         spans = tracker.trace_spans() if tracker is not None else []
         if not spans:
@@ -618,14 +713,14 @@ class FlightRecorder:
             tick = r["tick"]
             if tick is None:
                 continue
-            host_us = wrapper_start_us.get((tick, "host"))
-            dev_us = wrapper_start_us.get((tick, "device"))
+            host_us = flow_start_us.get((tick, "host"))
+            dev_us = flow_start_us.get((tick, "device"))
             targets = [(0, host_us), (1, dev_us)]
             targets = [(tid, ts) for tid, ts in targets if ts is not None]
             if not targets:
                 continue
             # flow: s inside the request span at tick pickup, then one
-            # step/finish per leg wrapper the request crossed
+            # step/finish per leg the request crossed
             out.append({"ph": "s", "cat": "request", "id": fid,
                         "pid": pid, "tid": 2, "ts": stamps_us[2],
                         "name": "request"})

@@ -771,6 +771,7 @@ class _RecordingSession:
         self._mutex = create_lock("RecordingSession._mutex")
         self.closed = inner.closed
         self.stopping = inner.stopping
+        self.recorder = getattr(inner, "recorder", None)
 
     @property
     def stop_requested(self) -> bool:

@@ -250,6 +250,10 @@ class StreamingRuntime:
         # (ingest_rows, query_rows, deferred) of the latest drain — the
         # QoS feedback loop's per-tick input
         self._last_drain: tuple[int, int, bool] = (0, 0, False)
+        # while recording, what the latest drain took for the tick's
+        # spans: (rows by source name, requests picked up — a serving
+        # source's insertions; its retractions are rows, not requests)
+        self._last_drain_counts: tuple[dict[str, int], int] = ({}, 0)
         # cumulative bridge exec_ms at the last QoS tick (delta = this
         # tick's resolved device time, the cost-model signal)
         self._qos_exec_ms_seen = 0.0
@@ -282,6 +286,11 @@ class StreamingRuntime:
             for _node, _session, ds in self.sessions:
                 if hasattr(ds, "request_tracker"):
                     ds.request_tracker = self._request_tracker
+        # a polling source writes its ``connector.pass`` spans through the
+        # recorder slot of its session (io/_datasource.py; None = off)
+        if self.recorder is not None:
+            for _node, session, _ds in self.sessions:
+                session.recorder = self.recorder
         # QoS controller (engine/qos.py): turns the tracker's burn rate /
         # stage p50s into per-tick ingest budgets, admission decisions
         # and coalescing accounting. Wired into every serving source's
@@ -611,6 +620,9 @@ class StreamingRuntime:
         ingest_rows = 0
         query_rows = 0
         deferred = False
+        rec = self.recorder
+        by_source = {} if rec is not None and rec.enabled else None
+        requests = 0
         n = len(self.sessions)
         # rotate the drain order of INGEST sources by tick so a tight
         # budget cannot starve whichever source happens to sit last
@@ -647,6 +659,12 @@ class StreamingRuntime:
                     query_rows += len(entries)
                 else:
                     ingest_rows += len(entries)
+                if by_source is not None:
+                    by_source[f"{datasource.name}-{datasource._uid}"] = \
+                        len(entries)
+                    if serving:
+                        requests += sum(1 for _k, _row, diff in entries
+                                        if diff > 0)
                 if tracker is not None and \
                         getattr(datasource, "request_tracker", None) \
                         is tracker:
@@ -661,6 +679,8 @@ class StreamingRuntime:
             if not session.closed.is_set():
                 all_closed = False
         self._last_drain = (ingest_rows, query_rows, deferred)
+        if by_source is not None:
+            self._last_drain_counts = (by_source, requests)
         return any_data, all_closed, pushes
 
     def _tick_sync(self, tick, any_data, all_closed, pushes):
@@ -681,6 +701,24 @@ class StreamingRuntime:
             any_data = any_data or payload["any"]
             all_closed = all_closed and payload["closed"]
         return any_data, all_closed
+
+    def _record_tick(self, rec, tick: int, any_data: bool, t_wake: float,
+                     t_drain: float, t_host: float, t_end: float) -> None:
+        """The spans of one commit tick, sharing ``("tick", tick)`` with
+        the leg the tick submitted and, through ``RequestSpan.tick``, with
+        the requests it picked up: ``tick`` from the loop's wake-up to
+        ``run_time``'s return (every tick), and on a tick that carried
+        rows ``tick.drain`` around the drain and the cluster exchange and
+        ``tick.host`` around ``run_time``, which returns with the device
+        leg submitted (``t_host == t_end``: the tick skipped it)."""
+        cause = ("tick", tick)
+        by_source, requests = self._last_drain_counts
+        rec.span("tick", t_wake, t_end, cause,
+                 rows=sum(by_source.values()), requests=requests)
+        if any_data:
+            rec.span("tick.drain", t_drain, t_host, cause, **by_source)
+            if t_end > t_host:
+                rec.span("tick.host", t_host, t_end, cause)
 
     def run(self) -> None:
         _ACTIVE_RUNTIMES.add(self)
@@ -803,7 +841,12 @@ class StreamingRuntime:
             # Event wait, not time.sleep: a stop request wakes the loop
             # immediately instead of out-waiting the commit interval
             # (the PWT206 sleep-polling pattern this checker family bans)
+            rec = self.recorder
             while not self._stop.wait(commit_s):
+                # the tick's spans (engine/flight_recorder.py): four clock
+                # reads and up to three tuples a tick while recording
+                t_wake = (_time.perf_counter()
+                          if rec is not None and rec.enabled else None)
                 self.last_tick_at = _time.monotonic()
                 if self._promote_event.is_set():
                     # router-requested failover: runs HERE, synchronously
@@ -841,10 +884,14 @@ class StreamingRuntime:
                     # one local scheduler tick (engine/replica.py pump —
                     # advances applied_tick)
                     time_counter = self.replica.pump(self, time_counter)
+                if t_wake is not None:
+                    t_drain = _time.perf_counter()
                 any_data, all_closed, pushes = self._drain_and_forward(
                     time_counter)
                 any_data, all_closed = self._tick_sync(
                     time_counter, any_data, all_closed, pushes)
+                if t_wake is not None:
+                    t_host = t_end = _time.perf_counter()
                 # under a cluster an idle tick would still pay one TCP
                 # round per exchanged node inside run_time; the merged
                 # any_data is identical on every process, so skipping is
@@ -854,6 +901,8 @@ class StreamingRuntime:
                     t_tick0 = (_time.perf_counter()
                                if self.qos is not None else 0.0)
                     self.scheduler.run_time(time_counter)
+                    if t_wake is not None:
+                        t_end = _time.perf_counter()
                     # stamp after the step too: a long (healthy) batch
                     # counts as progress the moment it completes, so only
                     # a single step exceeding the deadline can ever be
@@ -890,6 +939,9 @@ class StreamingRuntime:
                             # snapshot anchored to the watermark + WAL
                             # compaction (engine/persistence.py)
                             self._snapshot_pass(time_counter)
+                if t_wake is not None:
+                    self._record_tick(rec, time_counter, any_data, t_wake,
+                                      t_drain, t_host, t_end)
                 time_counter += 1
                 if all_closed and not any_data:
                     # re-drain: a source may have pushed between its drain()

@@ -141,6 +141,7 @@ class _SupervisedSession:
         self.stopping = threading.Event()
         if inner.stopping.is_set():
             self.stopping.set()
+        self.recorder = getattr(inner, "recorder", None)
 
     @property
     def stop_requested(self) -> bool:

@@ -45,6 +45,10 @@ class Session:
         # interval — producers slow down instead of growing the backlog
         self.backpressure = threading.Event()
         self.backpressure_factor = 4.0
+        # the run's flight recorder (engine/flight_recorder.py), filled by
+        # the streaming runtime while one records: a polling source writes
+        # one ``connector.pass`` span per pass through it. None = off
+        self.recorder = None
 
     @property
     def stop_requested(self) -> bool:

@@ -24,6 +24,11 @@ from pathway_tpu.io._datasource import (DataSource, Session,
                                         apply_connector_policy)
 
 
+#: a pass that read more files than this (a backlog) records their count and
+#: not each one's instants
+_PASS_FILES_MAX = 64
+
+
 def _list_files(path: str) -> list[Path]:
     p = Path(path)
     if p.is_dir():
@@ -185,8 +190,23 @@ class FsSource(DataSource):
         emitted: dict[str, list] = dict(getattr(self, "_resume_emitted", {}))
         resume_skip: dict[str, tuple] = dict(getattr(self, "_resume_skip", {}))
         seq = getattr(self, "_resume_seq", 0)
+        # flight recorder (engine/flight_recorder.py): one ``connector.pass``
+        # span per polling pass while one records; off costs this lookup
+        # and one test per pass and per changed file, and no clock read
+        rec = getattr(session, "recorder", None)
+        n_pass = 0
         while not session.stop_requested:
-            for f in _list_files(self.path):
+            pushed = None
+            if rec is not None and rec.enabled:
+                # (st_mtime, perf_counter at its last push) of each of the
+                # pass's first changed files
+                pushed = []
+                changed = n_rows = 0
+                t_pass = _time.perf_counter()
+            files = _list_files(self.path)
+            if pushed is not None:
+                t_listed = _time.perf_counter()
+            for f in files:
                 mtime = f.stat().st_mtime
                 fkey = str(f)
                 if fkey in seen and seen[fkey] == mtime:
@@ -227,6 +247,22 @@ class FsSource(DataSource):
                                  offset=("row", fkey, mtime, idx, True))
                     rows.append((key, row))
                 emitted[fkey] = rows
+                if pushed is not None:
+                    changed += 1
+                    n_rows += max(0, idx + 1 - skip)
+                    if changed <= _PASS_FILES_MAX:
+                        pushed.append((mtime, _time.perf_counter()))
+            if pushed is not None:
+                counts = {"listed": len(files), "changed": changed,
+                          "rows": n_rows,
+                          "list_ms": (t_listed - t_pass) * 1e3}
+                if changed <= _PASS_FILES_MAX:
+                    # a trickle of live files: each one's write and push
+                    # instants, for the time to its commit
+                    counts["files"] = pushed
+                rec.span("connector.pass", t_pass, _time.perf_counter(),
+                         ("pass", self._uid, n_pass), **counts)
+                n_pass += 1
             if self.mode != "streaming":
                 return
             if not session.sleep(self.refresh_interval_s):

@@ -151,11 +151,12 @@ def test_router_failover_replay_carries_same_id_and_hop():
     (plus the hop counter) to the rescuing replica, the response echoes
     it, and the router-side span records forward(fail) + failover(ok)."""
     backend = _CaptureBackend()
-    # a dead endpoint: bind a listener and close it -> connection refused
+    # a dead endpoint: a port bound and never listened on -> connection
+    # refused, and held to the end so that no other socket (the router's
+    # own, another worker's) is handed the same number meanwhile
     dead = socket.socket()
     dead.bind(("127.0.0.1", 0))
     dead_port = dead.getsockname()[1]
-    dead.close()
     router = _make_router()
     try:
         _endpoint(router, "r-dead", "127.0.0.1", dead_port)
@@ -184,6 +185,7 @@ def test_router_failover_replay_carries_same_id_and_hop():
     finally:
         router.stop()
         backend.stop()
+        dead.close()
 
 
 def test_router_p50_skew_metric_exposed():

@@ -192,7 +192,8 @@ def test_batch_trace_file_is_valid_and_nested(monkeypatch, tmp_path):
 
 def test_streaming_trace_has_device_track(monkeypatch, tmp_path):
     """A pipelined streaming run writes device-leg spans on their own
-    track, with leg-level queue-wait/exec metadata on the tick wrapper."""
+    track, inside the recorded ``bridge.leg`` span of their tick, which
+    carries the leg's queue-wait/exec attribution."""
     import numpy as np
 
     path = tmp_path / "trace.json"
@@ -222,10 +223,17 @@ def test_streaming_trace_has_device_track(monkeypatch, tmp_path):
     dev_b = [e for e in events if e["ph"] == "B" and e.get("cat") == "device"]
     assert host_b and dev_b, "expected spans on both tracks"
     assert {e["tid"] for e in host_b} != {e["tid"] for e in dev_b}
-    wrappers = [e for e in dev_b if e["name"].startswith("tick ")
-                and "queue_wait_ms" in e["args"]]
-    assert wrappers, "device tick wrappers carry no queue-wait attribution"
+    legs = [e for e in events if e["ph"] == "B" and e["tid"] == 1
+            and e["name"].startswith("bridge.leg ")]
+    assert legs and all({"queue_wait_ms", "exec_ms"} <= set(e["args"])
+                        for e in legs)
     assert any(e["name"].startswith("map:") for e in dev_b)
+    # the operator slices of a leg lie inside the leg's own slice
+    ends = {e["name"]: e["ts"] for e in events
+            if e["ph"] == "E" and e["tid"] == 1}
+    op = next(e for e in dev_b if e["name"].startswith("map:"))
+    leg = next(e for e in legs if e["args"]["cause"][1] == op["args"]["tick"])
+    assert leg["ts"] <= op["ts"] <= ends[leg["name"]]
 
 
 # ---------------------------------------------------------------------------
@@ -342,3 +350,329 @@ def test_recorded_spans_flow_through_telemetry_provider():
     rec2.set_telemetry(_ApiOnly())
     rec2.record(1, node, "host", 0.0, 1.0, 1, 1)
     assert len(spans) == 1
+
+
+# ---------------------------------------------------------------------------
+# the span store: tick, bridge-leg and connector-pass spans
+# ---------------------------------------------------------------------------
+
+def test_span_store_overlap_query_and_bound():
+    from pathway_tpu.engine.flight_recorder import _SPAN_BUFFER
+
+    rec = FlightRecorder()
+    rec.enabled = True
+    rec.span("tick", 1.0, 2.0, ("tick", 1), rows=3, requests=0)
+    rec.span("tick", 3.0, 4.0, ("tick", 2))
+    rec.span("connector.pass", 0.5, 3.5, ("pass", 0, 0), listed=2)
+    name, t0, t1, cause, ident, counts = rec.spans()[0]
+    assert (name, t0, t1, cause) == ("tick", 1.0, 2.0, ("tick", 1))
+    assert ident == threading.get_ident()
+    assert counts == {"rows": 3, "requests": 0}
+    assert rec.spans()[1][5] is None  # no counts: no dict
+    # overlap with [t0, t1], oldest first; an open end is unbounded
+    assert [s[3] for s in rec.spans(2.5, 3.2)] == [("tick", 2),
+                                                   ("pass", 0, 0)]
+    assert [s[3] for s in rec.spans(t1=0.7)] == [("pass", 0, 0)]
+    assert [s[3] for s in rec.spans(t0=3.8)] == [("tick", 2)]
+    assert not hasattr(rec, "_legs") and not hasattr(rec, "record_leg")
+    # bounded: the oldest spans go, the operator ring is its own
+    for i in range(_SPAN_BUFFER + 10):
+        rec.span("tick", float(i), float(i) + 0.5, ("tick", i))
+    assert len(rec.spans()) == _SPAN_BUFFER
+    assert rec.spans()[0][3] == ("tick", 10)
+    assert rec._events.maxlen == 65_536
+
+
+def test_trace_payload_derives_device_legs_from_spans():
+    rec = FlightRecorder()
+    rec.enabled = True
+    rec.span("bridge.wait", 1.0, 1.002, ("tick", 4), depth=1)
+    rec.span("bridge.leg", 1.002, 1.012, ("tick", 4))
+    rec.span("tick", 0.9, 1.001, ("tick", 4), rows=1, requests=1)
+    payload = rec.trace_payload()
+    assert payload["device_legs"] == [
+        {"tick": 4, "queue_wait_ms": 2.0, "exec_ms": 10.0}]
+    by_name = {s["name"]: s for s in payload["spans"]}
+    assert set(by_name) == {"bridge.wait", "bridge.leg", "tick"}
+    assert by_name["tick"]["cause"] == ["tick", 4]
+    assert by_name["tick"]["counts"] == {"rows": 1, "requests": 1}
+    assert by_name["bridge.leg"]["dur_ms"] == 10.0
+    assert by_name["tick"]["thread"] == threading.current_thread().name
+    json.dumps(payload)
+
+
+def test_chrome_export_draws_recorded_spans_not_a_wrapper():
+    """Operator steps with no tick span around them (a batch run, a static
+    feed) lie bare on their track: no slice is made up from them. A
+    connector's passes get a track named after its thread, and a wait
+    that overlaps the leg before it is an async event."""
+    rec = FlightRecorder()
+    rec.enabled = True
+    rec._epoch = 0.0
+    rec.record(1, _FakeNode(0, "bare"), "host", 0.10, 5.0, 1, 1)
+    rec.span("tick", 1.00, 1.05, ("tick", 2), rows=1, requests=0)
+    rec.span("tick.drain", 1.01, 1.02, ("tick", 2), **{"fs-0": 1})
+    rec.span("tick.host", 1.02, 1.05, ("tick", 2))
+    rec.record(2, _FakeNode(1, "inside"), "host", 1.03, 10.0, 1, 1)
+    rec.span("bridge.wait", 1.05, 1.06, ("tick", 2), depth=1)
+    rec.span("bridge.leg", 1.06, 1.10, ("tick", 2))
+    rec.span("bridge.wait", 1.08, 1.10, ("tick", 3), depth=2)  # overlaps
+    rec.span("bridge.leg", 1.10, 1.12, ("tick", 3))
+
+    def reader():
+        rec.span("connector.pass", 0.5, 1.5, ("pass", 0, 7), listed=9)
+
+    th = threading.Thread(target=reader, name="src-fs-0")
+    th.start()
+    th.join()
+    events = rec.chrome_trace_events()
+    _check_nesting(events)
+    tracks = {e["tid"]: e["args"]["name"] for e in events
+              if e["ph"] == "M" and e["name"] == "thread_name"}
+    assert tracks == {0: "host leg", 1: "device leg", 3: "src-fs-0"}
+    host = [e["name"] for e in events if e["ph"] == "B" and e["tid"] == 0]
+    assert host == ["bare", "tick 2", "tick.drain 2", "tick.host 2",
+                    "inside"]
+    dev = [e["name"] for e in events if e["ph"] == "B" and e["tid"] == 1]
+    assert dev == ["bridge.leg 2", "bridge.leg 3"]
+    waits = [e for e in events if e["ph"] in ("b", "e")
+             and e["cat"] == "bridge"]
+    assert [e["name"] for e in waits if e["ph"] == "b"] == \
+        ["bridge.wait 2", "bridge.wait 3"]
+    (conn,) = [e for e in events if e["ph"] == "B" and e["tid"] == 3]
+    assert conn["name"] == "connector.pass 7"
+    assert conn["args"]["listed"] == 9
+    leg2 = next(e for e in events if e["ph"] == "B"
+                and e["name"] == "bridge.leg 2")
+    assert leg2["args"]["queue_wait_ms"] == pytest.approx(10.0)
+
+
+def _self_time(parent, children):
+    """A span's duration minus the part its children cover."""
+    return (parent[2] - parent[1]) - sum(
+        min(c[2], parent[2]) - max(c[1], parent[1]) for c in children)
+
+
+@pytest.fixture
+def live_rag(monkeypatch, tmp_path):
+    """A live-RAG server on this process's threads with the recorder on:
+    fs connector -> embedder -> KNN index -> REST. Yields (runtime,
+    client, watched directory); stops the server afterwards."""
+    import hashlib
+    import socket
+
+    import numpy as np
+
+    from pathway_tpu.engine import streaming
+    from pathway_tpu.stdlib.indexing import (
+        default_brute_force_knn_document_index)
+    from pathway_tpu.xpacks.llm.vector_store import (VectorStoreClient,
+                                                     VectorStoreServer)
+
+    monkeypatch.setenv("PATHWAY_DEVICE_INFLIGHT", "2")
+
+    @pw.udf
+    def embed(text: str) -> np.ndarray:
+        vec = np.zeros(16)
+        for w in str(text).lower().split():
+            vec[int(hashlib.md5(w.encode()).hexdigest(), 16) % 16] += 1.0
+        n = np.linalg.norm(vec)
+        return vec / n if n else vec
+
+    watched = tmp_path / "watched"
+    watched.mkdir()
+    (watched / "old.txt").write_text("the quick brown fox")
+    source = pw.io.fs.read(str(watched), format="plaintext_by_file",
+                           mode="streaming", with_metadata=True,
+                           refresh_interval_s=0.05)
+
+    def build_index(chunks):
+        # the device-resident index the benchmark serves: its operator is
+        # device-bound, so every tick has a bridge leg
+        return default_brute_force_knn_document_index(
+            chunks.text, chunks, embedder=embed, dimensions=16,
+            metadata_column=chunks.metadata)
+
+    server = VectorStoreServer(source, embedder=embed,
+                               index_builder=build_index)
+    with socket.socket() as s:
+        s.bind(("127.0.0.1", 0))
+        port = s.getsockname()[1]
+    thread = server.run_server(host="127.0.0.1", port=port, threaded=True,
+                               with_cache=False,
+                               trace_path=str(tmp_path / "flight.json"))
+    client = VectorStoreClient("127.0.0.1", port, timeout=30)
+    deadline = time.monotonic() + 30
+    while True:
+        try:
+            if client.get_vectorstore_statistics()["file_count"]:
+                break
+        except OSError:
+            pass
+        assert time.monotonic() < deadline, "the server did not come up"
+        time.sleep(0.05)
+    (runtime,) = streaming.live_runtimes()
+    try:
+        yield runtime, client, watched
+    finally:
+        streaming.stop_all()
+        thread.join(15.0)
+
+
+def test_streaming_run_records_one_chain_of_spans(live_rag):
+    """One REST query and one new file: request -> tick -> leg ->
+    operator steps is one chain by identifier, the connector's pass names
+    the file's write and push instants, and the commit stamp derived from
+    them lies before the first answer that returns the file."""
+    import os
+
+    runtime, client, watched = live_rag
+    rec = runtime.recorder
+    assert all(session.recorder is rec
+               for _n, session, _d in runtime.sessions)
+
+    new = watched / "new.txt"
+    new.write_text("systolic arrays multiply matrices")
+    first_seen = None
+    deadline = time.monotonic() + 30
+    while first_seen is None:
+        hits = client.query("systolic arrays multiply", k=1)
+        if hits and hits[0]["metadata"]["path"].endswith("new.txt"):
+            first_seen = time.perf_counter()
+        assert time.monotonic() < deadline, "the new file never surfaced"
+    spans = rec.spans()
+    by_name: dict = {}
+    for sp in spans:
+        by_name.setdefault(sp[0], {}).setdefault(sp[3], sp)
+
+    # -- request -> tick -> leg -> operator steps ---------------------------
+    request = [r for r in rec.requests.trace_spans()
+               if r["route"] == "/v1/retrieve"][-1]
+    cause = ("tick", request["tick"])
+    tick = by_name["tick"][cause]
+    drain, host = by_name["tick.drain"][cause], by_name["tick.host"][cause]
+    wait, leg = by_name["bridge.wait"][cause], by_name["bridge.leg"][cause]
+    assert tick[5]["requests"] >= 1 and tick[5]["rows"] >= 1
+    assert any(k.startswith("rest-") for k in drain[5])
+    assert tick[1] <= drain[1] <= drain[2] <= host[1] <= host[2] <= tick[2]
+    assert _self_time(tick, [drain, host]) >= 0.0
+    assert wait[2] == leg[1] and wait[1] <= wait[2] <= leg[2]
+    assert host[1] <= wait[1] <= host[2]   # submitted inside run_time
+    assert wait[5]["depth"] >= 1
+    steps = [ev for ev in rec.tail_events(None) if ev[0] == request["tick"]]
+    device_steps = [ev for ev in steps if ev[2] == "device"]
+    assert device_steps, "the request's tick recorded no device step"
+    for _t, _op, _leg, t0, dur_ms, _ri, _ro in device_steps:
+        assert leg[1] <= t0 and t0 + dur_ms / 1e3 <= leg[2] + 1e-6
+    # the tracker's stamps fall where the spans say
+    assert tick[1] <= request["stamps"][3] <= tick[2]     # picked up
+    assert request["stamps"][5] <= leg[2] + 1e-6          # resolved
+    # written on the threads that did the work
+    assert tick[4] != leg[4] and wait[4] == leg[4]
+
+    # -- the connector's pass and the commit stamp ---------------------------
+    mtime = os.stat(new).st_mtime
+    passes = [sp for sp in spans if sp[0] == "connector.pass"]
+    assert passes and passes[0][4] not in (tick[4], leg[4])
+    assert [sp[3][2] for sp in passes] == list(range(len(passes)))
+    (found,) = [sp for sp in passes
+                if any(m == mtime for m, _t in sp[5].get("files", ()))]
+    (fs_source,) = [ds for _n, _s, ds in runtime.sessions
+                    if ds.name == "fs"]
+    assert found[3][:2] == ("pass", fs_source._uid)
+    counts = found[5]
+    assert counts["listed"] == 2 and counts["changed"] == 1
+    assert counts["rows"] == 1 and counts["list_ms"] >= 0.0
+    ((_m, push),) = [f for f in counts["files"] if f[0] == mtime]
+    assert found[1] <= push <= found[2]
+    push_wall = push + rec._wall_ns_offset / 1e9
+    assert push_wall >= mtime
+    # the commit stamp: the end of the leg of the first tick whose drain
+    # started at or after the push
+    drains = sorted((sp for sp in spans if sp[0] == "tick.drain"
+                     and sp[1] >= push), key=lambda sp: sp[1])
+    commit = by_name["bridge.leg"][drains[0][3]][2]
+    assert push <= commit <= first_seen
+    assert any(k.startswith("fs-") for k in drains[0][5])
+
+    # -- the surfaces ----------------------------------------------------------
+    payload = rec.trace_payload()
+    assert {s["name"] for s in payload["spans"]} >= {
+        "tick", "tick.drain", "tick.host", "bridge.wait", "bridge.leg",
+        "connector.pass"}
+    assert payload["device_legs"] and all(
+        set(leg_) == {"tick", "queue_wait_ms", "exec_ms"}
+        for leg_ in payload["device_legs"])
+    events = rec.chrome_trace_events()
+    _check_nesting(events)
+    tracks = {e["args"]["name"] for e in events
+              if e["ph"] == "M" and e["name"] == "thread_name"}
+    assert {"host leg", "device leg", "requests"} <= tracks
+    assert any("src-fs-" in t for t in tracks)
+
+
+def test_bridge_off_writes_no_bridge_spans(monkeypatch):
+    """``max_inflight`` 1: no bridge, no ``bridge.*`` span, and
+    ``tick.host`` covers the device work."""
+    monkeypatch.setenv("PATHWAY_DEVICE_INFLIGHT", "1")
+    monkeypatch.setenv("PATHWAY_FLIGHT_RECORDER", "1")
+    from pathway_tpu.engine import streaming
+
+    @pw.udf(batch=True, device=True, deterministic=True, return_type=int)
+    def dev_len(ws):
+        return [len(w) for w in ws]
+
+    class Subj(pw.io.python.ConnectorSubject):
+        def run(self):
+            self.next(word="hello")
+
+    t = pw.io.python.read(Subj(), schema=pw.schema_from_types(word=str),
+                          autocommit_duration_ms=10)
+    pw.io.subscribe(t.select(n=dev_len(t.word)), lambda *a, **k: None)
+    seen: list = []
+    orig = streaming.StreamingRuntime.run
+
+    def run(self):
+        seen.append(self.recorder)
+        return orig(self)
+
+    monkeypatch.setattr(streaming.StreamingRuntime, "run", run)
+    pw.run()
+    (rec,) = seen
+    names = {sp[0] for sp in rec.spans()}
+    assert "tick" in names and "tick.host" in names
+    assert not {n for n in names if n.startswith("bridge.")}
+    (host,) = [sp for sp in rec.spans() if sp[0] == "tick.host"
+               and any(ev[0] == sp[3][1] and ev[2] == "device"
+                       for ev in rec.tail_events(None))]
+    step = next(ev for ev in rec.tail_events(None) if ev[2] == "device")
+    assert host[1] <= step[3] and step[3] + step[4] / 1e3 <= host[2] + 1e-6
+    assert rec.trace_payload()["device_legs"] == []
+
+
+def test_recorder_off_session_has_no_recorder_and_pass_reads_no_clock(
+        monkeypatch, tmp_path):
+    """Off is free: ``Session.recorder`` stays None, and the fs source's
+    pass reads ``perf_counter`` only while a recorder is on (the guard is
+    tests/trace_canary.py's, which CI also runs whole)."""
+    from pathway_tpu.io._datasource import Session
+    from tests.trace_canary import fs_pass_clock_reads
+
+    assert Session().recorder is None
+    monkeypatch.setenv("PATHWAY_FLIGHT_RECORDER", "0")
+    from pathway_tpu.engine.streaming import StreamingRuntime
+    from pathway_tpu.internals.runner import GraphRunner
+
+    t = pw.io.fs.read(str(tmp_path), format="plaintext_by_file",
+                      mode="streaming")
+    runner = GraphRunner()
+    runner.capture(t)
+    rt = StreamingRuntime(runner)
+    try:
+        assert rt.recorder is None
+        assert rt.sessions and all(s.recorder is None
+                                   for _n, s, _d in rt.sessions)
+    finally:
+        rt.scheduler.close()
+    assert fs_pass_clock_reads(tmp_path, recording=False) == 0
+    assert fs_pass_clock_reads(tmp_path, recording=True) > 0

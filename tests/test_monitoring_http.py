@@ -271,6 +271,10 @@ def test_paged_store_metrics_exposed():
 
 def test_trace_endpoint_serves_span_buffer():
     rt = _recording_runtime()
+    rec = rt.scheduler.recorder
+    rec.span("tick", 0.0, 0.004, ("tick", 0), rows=10, requests=0)
+    rec.span("bridge.wait", 0.004, 0.005, ("tick", 0), depth=1)
+    rec.span("bridge.leg", 0.005, 0.009, ("tick", 0))
     server = MonitoringHttpServer(rt, port=0)
     server.start()
     try:
@@ -282,6 +286,12 @@ def test_trace_endpoint_serves_span_buffer():
         assert ev["operator"] == _AWKWARD
         assert ev["leg"] == "host"
         assert ev["rows_in"] == 10 and ev["rows_out"] == 9
+        # the span store rides along, and a leg is one of its spans
+        assert [sp["name"] for sp in payload["spans"]] == [
+            "tick", "bridge.wait", "bridge.leg"]
+        assert payload["spans"][0]["counts"] == {"rows": 10, "requests": 0}
+        assert payload["device_legs"] == [
+            {"tick": 0, "queue_wait_ms": 1.0, "exec_ms": 4.0}]
         # /status names the operator that dominated the last tick
         status = json.loads(
             urllib.request.urlopen(base + "/status").read())
