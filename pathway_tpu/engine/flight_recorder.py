@@ -167,10 +167,13 @@ def attach_note(e: BaseException, note: str) -> None:
 
 class _OpStats:
     """Per-operator aggregate: fixed-bucket latency histogram + row
-    counters + identity (name, operator class, user frame) captured once."""
+    counters + identity (name, operator class, user frame) captured once.
+    ``rederived``: times a reducer state of the operator derived its order
+    from a whole group (engine/reducers.py); one that grows with the ticks
+    means a step costs time linear in the group again."""
 
     __slots__ = ("name", "op_class", "frame", "bucket_counts", "sum_ms",
-                 "count", "rows_in", "rows_out")
+                 "count", "rows_in", "rows_out", "rederived")
 
     def __init__(self, name: str, op_class: str, frame: str | None):
         self.name = name
@@ -181,8 +184,10 @@ class _OpStats:
         self.count = 0
         self.rows_in = 0
         self.rows_out = 0
+        self.rederived = 0
 
-    def observe(self, ms: float, rows_in: int, rows_out: int) -> None:
+    def observe(self, ms: float, rows_in: int, rows_out: int,
+                rederived: int = 0) -> None:
         i = 0
         for b in LATENCY_BUCKETS_MS:
             if ms <= b:
@@ -193,6 +198,7 @@ class _OpStats:
         self.count += 1
         self.rows_in += rows_in
         self.rows_out += rows_out
+        self.rederived += rederived
 
 
 class FlightRecorder:
@@ -307,7 +313,7 @@ class FlightRecorder:
         return out
 
     def record(self, tick: int, node, leg: str, t0: float, dur_ms: float,
-               rows_in: int, rows_out: int) -> None:
+               rows_in: int, rows_out: int, rederived: int = 0) -> None:
         with self._lock:
             st = self._ops.get(node.id)
             if st is None:
@@ -316,7 +322,7 @@ class FlightRecorder:
                     node.name or type(node.op).__name__,
                     type(node.op).__name__,
                     str(trace) if trace is not None else None)
-            st.observe(dur_ms, rows_in, rows_out)
+            st.observe(dur_ms, rows_in, rows_out, rederived)
             self._events.append(
                 (tick, node.id, leg, t0, dur_ms, rows_in, rows_out))
         if self._otel is not None:
@@ -399,11 +405,11 @@ class FlightRecorder:
         with self._lock:
             items = [(op_id, st.name, st.op_class, st.frame,
                       list(st.bucket_counts), st.sum_ms, st.count,
-                      st.rows_in, st.rows_out)
+                      st.rows_in, st.rows_out, st.rederived)
                      for op_id, st in self._ops.items()]
         out = []
         for (op_id, name, op_class, frame, counts, sum_ms, count,
-             rows_in, rows_out) in items:
+             rows_in, rows_out, rederived) in items:
             cum = []
             acc = 0
             for le, c in zip(LATENCY_BUCKETS_MS, counts):
@@ -414,6 +420,7 @@ class FlightRecorder:
                 "id": op_id, "name": name, "op_class": op_class,
                 "frame": frame, "buckets": cum, "sum_ms": sum_ms,
                 "count": count, "rows_in": rows_in, "rows_out": rows_out,
+                "rederived": rederived,
             })
         return out
 
@@ -747,6 +754,12 @@ class FlightRecorder:
         return {
             "traceEvents": self.chrome_trace_events(),
             "displayTimeUnit": "ms",
+            # per-operator totals over the whole run (the ring above holds
+            # the newest steps only)
+            "pathway_operators": [
+                {k: st[k] for k in ("id", "name", "count", "sum_ms",
+                                    "rows_in", "rows_out", "rederived")}
+                for st in self.op_stats()],
             "pathway_meta": {
                 "pid": os.getpid(),
                 "process": self.process,

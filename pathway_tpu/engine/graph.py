@@ -659,7 +659,7 @@ class Scheduler:
             # post-mortems and the Perfetto request flows need
             if rows_in or delta.entries or ms >= 1.0:
                 rec.record(time, node, leg, t0, ms, rows_in,
-                           len(delta.entries))
+                           len(delta.entries), op.take_rederived())
             # cleared on success only: an operator that raised (or is
             # still raising through the bridge) stays named in the
             # in-flight slot for the post-mortem dump

@@ -293,6 +293,8 @@ class MonitoringHttpServer:
                              " histogram")
                 lines.append("# TYPE pathway_tpu_operator_rows_in counter")
                 lines.append("# TYPE pathway_tpu_operator_rows_out counter")
+                lines.append("# TYPE pathway_tpu_operator_reducer_rederived"
+                             " counter")
                 for st in ops:
                     base = f'operator="{esc(st["name"])}",id="{st["id"]}"'
                     for le, c in st["buckets"]:
@@ -313,6 +315,9 @@ class MonitoringHttpServer:
                     lines.append(
                         f"pathway_tpu_operator_rows_out{{{base}}} "
                         f"{st['rows_out']}")
+                    lines.append(
+                        f"pathway_tpu_operator_reducer_rederived{{{base}}} "
+                        f"{st['rederived']}")
         tracker = self._request_tracker()
         if tracker is not None and tracker.count:
             # serving-path SLO families (engine/request_tracker.py):

@@ -27,7 +27,8 @@ from pathway_tpu.engine.delta import (
     row_fingerprint,
     upsert_delta,
 )
-from pathway_tpu.engine.reducers import _orderable, make_reducer_state
+from pathway_tpu.engine.reducers import (REDUCER_FACTORIES, _MultisetState,
+                                         _orderable, make_reducer_state)
 from pathway_tpu.internals.keys import (Pointer, canonical_shard_value,
                                         hash_values, mix_pointers)
 
@@ -106,6 +107,12 @@ class Operator:
         """Called for every committed timestamp (even with no input) so
         buffering operators (temporal behaviors) can release rows."""
         return Delta()
+
+    def take_rederived(self) -> int:
+        """Times since the last call that a reducer state of this operator
+        derived its order from a whole group (engine/reducers.py
+        ``_MultisetState.rederived``); the flight recorder sums them."""
+        return 0
 
     def flush(self, time: int) -> Delta:
         """End-of-stream: release anything still held (the reference flushes
@@ -500,10 +507,17 @@ class GroupByOperator(Operator):
     retraction of the old reduced row and the new one.
     """
 
+    # a counter the flight recorder drains, no part of the state
+    _snapshot_sanitizer_exempt = ("rederived",)
+
     def __init__(self, group_fn, reducer_specs,
                  force_order_sensitive: bool = False):
         self.group_fn = group_fn
         self.reducer_specs = reducer_specs
+        self.rederived = 0
+        self._multiset_idx = [
+            i for i, (name, _, _) in enumerate(reducer_specs)
+            if issubclass(REDUCER_FACTORIES[name], _MultisetState)]
         self.group_states: dict[Pointer, list] = {}   # gkey -> [states...]
         self.group_vals: dict[Pointer, tuple] = {}
         self.group_counts: dict[Pointer, int] = {}    # membership multiset size
@@ -643,11 +657,23 @@ class GroupByOperator(Operator):
                       for name, _, kw in self.reducer_specs]
             for st, d in zip(states, dicts):
                 st.load_state(d)
+            self._collect_rederived(states)
             self.group_states[gkey] = states
         self.group_vals = dict(state["vals"])
         self.group_counts = dict(state["counts"])
         self.out.rows = dict(state["out"])
         self.seq = state["seq"]
+
+    def _collect_rederived(self, states) -> None:
+        for i in self._multiset_idx:
+            st = states[i]
+            if st.rederived:
+                self.rederived += st.rederived
+                st.rederived = 0
+
+    def take_rederived(self):
+        n, self.rederived = self.rederived, 0
+        return n
 
     def step(self, time, in_deltas):
         delta = in_deltas[0]
@@ -697,18 +723,29 @@ class GroupByOperator(Operator):
             for gkey, (total, count) in per_group.items():
                 self.group_states[gkey][ri].set_total(total, count)
         out = Delta()
+        rows = self.out.rows
         for gkey in touched:
             states = self.group_states[gkey]
+            cur = rows.get(gkey)
             if self.group_counts.get(gkey, 0) <= 0:
-                new_row = None
                 del self.group_states[gkey]
                 self.group_vals.pop(gkey, None)
                 self.group_counts.pop(gkey, None)
-            else:
-                gvals = self.group_vals[gkey]
-                new_row = (*gvals, *[st.emit() for st in states])
-            upsert_delta(self.out, gkey, new_row, out)
-        self.out.update(out)
+                if cur is not None:
+                    out.append(gkey, cur, -1)
+                    del rows[gkey]
+                continue
+            gvals = self.group_vals[gkey]
+            new_row = (*gvals, *[st.emit() for st in states])
+            self._collect_rederived(states)
+            # compared by value, first difference first: a row may hold a
+            # tuple of the whole group, which a fingerprint would walk
+            if cur is not None:
+                if _rows_equal(cur, new_row):
+                    continue
+                out.append(gkey, cur, -1)
+            out.append(gkey, new_row, 1)
+            rows[gkey] = new_row
         return out
 
 

@@ -676,3 +676,101 @@ def test_recorder_off_session_has_no_recorder_and_pass_reads_no_clock(
         rt.scheduler.close()
     assert fs_pass_clock_reads(tmp_path, recording=False) == 0
     assert fs_pass_clock_reads(tmp_path, recording=True) > 0
+
+
+# ---------------------------------------------------------------------------
+# the reducers' re-derivation counter rides the per-operator stats
+# ---------------------------------------------------------------------------
+
+def _statistics_like_run(tmp_path, restore=None):
+    """One group holding every row (count, max, tuple over ``apply``
+    expressions, so the row-path ``GroupByOperator`` runs): eight
+    insert-only ticks of ten rows. Returns (scheduler, recorder)."""
+    from pathway_tpu.debug import table_from_rows
+    from pathway_tpu.engine.graph import Scheduler
+    from pathway_tpu.engine.delta import Delta
+    from pathway_tpu.internals import schema as sch
+    from pathway_tpu.internals.runner import GraphRunner
+
+    G.clear()
+    rows = [(f"doc{i:03d}", 1000 + i, 2 * (i // 10), 1) for i in range(80)]
+    t = table_from_rows(sch.schema_from_types(name=str, at=int), rows,
+                        is_stream=True)
+    stats = t.reduce(
+        count=pw.reducers.count(),
+        newest=pw.reducers.max(pw.apply_with_type(lambda v: v, int, t.at)),
+        names=pw.reducers.tuple(
+            pw.apply_with_type(lambda s: s, str, t.name)))
+    runner = GraphRunner()
+    cap = runner.capture(stats)
+    rec = FlightRecorder(trace_path=str(tmp_path / "trace.json"))
+    rec.enabled = True
+    sched = Scheduler(runner.graph, n_workers=1, recorder=rec)
+    if restore is not None:
+        sched.restore_operator_states(restore)
+    by_time, times = runner.static_feeds_by_time()
+    for tick in sorted(times):
+        for node, groups in by_time:
+            if groups.get(tick):
+                sched.push_source(node, Delta(groups[tick]))
+        sched.run_time(tick)
+    sched.close()
+    return sched, rec, cap, runner
+
+
+def _groupby_stats(rec):
+    [st] = [st for st in rec.op_stats() if st["op_class"] == "GroupByOperator"]
+    return st
+
+
+def test_rederived_counter_on_metrics_and_in_the_trace_file(tmp_path):
+    from pathway_tpu.engine.http_server import MonitoringHttpServer
+    from pathway_tpu.engine.operators import GroupByOperator
+
+    sched, rec, cap, runner_ = _statistics_like_run(tmp_path)
+    [(count, newest, names)] = cap.snapshot().values()
+    assert (count, newest, len(names)) == (80, 1079, 80)
+    st = _groupby_stats(rec)
+    assert st["rows_in"] == 80
+    # insert-only ticks past _ORDER_FROM entries: nothing walked
+    assert st["rederived"] == 0
+
+    def metric():
+        class _Runtime:
+            scheduler = sched
+            runner = runner_
+            sessions = ()
+
+        server = MonitoringHttpServer(_Runtime(), port=0)
+        server.start()
+        try:
+            import urllib.request
+
+            text = urllib.request.urlopen(
+                f"http://127.0.0.1:{server.port}/metrics").read().decode()
+        finally:
+            server.stop()
+        assert "# TYPE pathway_tpu_operator_reducer_rederived counter" in text
+        [line] = [ln for ln in text.splitlines()
+                  if ln.startswith("pathway_tpu_operator_reducer_rederived{")
+                  and 'operator="groupby:' in ln]
+        return int(line.rsplit(" ", 1)[1])
+
+    def in_trace_file():
+        data = json.loads(open(rec.write_chrome_trace()).read())
+        [op] = [op for op in data["pathway_operators"]
+                if op["name"].startswith("groupby:")]
+        assert {"rows_in", "rows_out", "rederived"} <= set(op)
+        return op["rederived"]
+
+    assert metric() == 0 and in_trace_file() == 0
+
+    # a restore derives the order of the `max` and the `tuple` state anew
+    snapshot = sched.snapshot_operator_states()
+    sched, rec, cap, runner_ = _statistics_like_run(tmp_path,
+                                                    restore=snapshot)
+    [op] = [op for reps in sched._replicas.values() for op in reps
+            if isinstance(op, GroupByOperator)]
+    assert op.take_rederived() == 0          # the recorder took them
+    assert _groupby_stats(rec)["rederived"] == 2
+    assert metric() == 2 and in_trace_file() == 2

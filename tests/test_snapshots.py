@@ -529,6 +529,132 @@ def test_multiset_reducer_state_rekeys_fingerprints_on_load():
     assert fresh.emit() == "apple"
 
 
+_RESTORE_IN_NEW_PROCESS = """
+import json, pickle, sys
+from pathway_tpu.engine import reducers
+from pathway_tpu.engine.persistence import _safe_loads
+from pathway_tpu.engine.reducers import make_reducer_state
+# an order kept from few entries on, for every kind
+reducers._MultisetState._ORDER_FROM = reducers._SortedTupleState._ORDER_FROM = 32
+with open(sys.argv[1], "rb") as f:
+    case = _safe_loads(f.read())
+out = {}
+for name, (kwargs, state, later) in case.items():
+    st = make_reducer_state(name, **kwargs)
+    st.load_state(state)
+    answers = [repr(st.emit())]
+    for args, diff in later:
+        st.add(args, diff)
+        answers.append(repr(st.emit()))
+    out[name] = [answers, st.rederived, st._order is not None]
+print(json.dumps(out))
+"""
+
+
+def test_ordered_multiset_states_restore_under_another_hash_seed(tmp_path):
+    """``state_dict()`` through the restricted unpickler into a process
+    with another string-hash seed: ``load_state`` re-keys the fingerprints
+    and derives the order again, so the retractions that follow find their
+    entries and every answer equals the writer's."""
+    import pickle
+    import subprocess
+    import sys
+
+    from pathway_tpu.engine.reducers import make_reducer_state
+
+    words = ["w%03d" % ((i * 37) % 101) for i in range(60)]
+    feeds = {
+        "min": ({}, [((w,), 1) for w in words]),
+        "max": ({}, [((w,), 1) for w in words]),
+        "argmin": ({}, [((len(w) + i % 3, w), 1)
+                        for i, w in enumerate(words)]),
+        "argmax": ({}, [((i % 5, w), 1) for i, w in enumerate(words)]),
+        # picked by fingerprint: ints, whose hash no seed moves
+        "any": ({}, [((i * 7919,), 1) for i in range(60)]),
+        "unique": ({}, [(("same",), 1)] + [((w,), -1) for w in words[:40]]),
+        "sorted_tuple": ({"skip_nones": True},
+                         [((w,), 1) for w in words] + [((None,), 1)]),
+        "tuple": ({}, [((w, 1000 - i), 1) for i, w in enumerate(words)]
+                  + [((words[0], 1000), 1)]),
+        "ndarray": ({}, [((w, i * 7 % 60), 1)
+                         for i, w in enumerate(words)]),
+    }
+    case, expected = {}, {}
+    for name, (kwargs, feed) in feeds.items():
+        st = make_reducer_state(name, **kwargs)
+        for args, diff in feed:
+            st.add(args, diff)
+        # a copy: the state goes on below, and its dict is its own dicts
+        state = pickle.loads(pickle.dumps(st.state_dict()))
+        assert set(state) - {"skip_nones"} == {"counts", "values", "n"}
+        # what follows in the new process: retractions of entries the
+        # snapshot holds (the smallest and the largest among them), then
+        # an insertion
+        later = [(a, -1) for a, d in (feed[1:4] + feed[-3:-1]) if d > 0] \
+            + [feed[5]]
+        if name == "unique":
+            later = [(feed[1][0], 1), (feed[1][0], -1)]
+        case[name] = (kwargs, state, later)
+        answers = [repr(st.emit())]
+        for args, diff in later:
+            st.add(args, diff)
+            answers.append(repr(st.emit()))
+        expected[name] = answers
+    blob = tmp_path / "states.pickle"
+    blob.write_bytes(pickle.dumps(case, protocol=pickle.HIGHEST_PROTOCOL))
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = dict(os.environ, PYTHONPATH=repo, JAX_PLATFORMS="cpu",
+               PYTHONHASHSEED="4242")
+    res = subprocess.run(
+        [sys.executable, "-c", _RESTORE_IN_NEW_PROCESS, str(blob)],
+        env=env, capture_output=True, text=True, timeout=120)
+    assert res.returncode == 0, res.stderr[-2000:]
+    got = json.loads(res.stdout.strip().splitlines()[-1])
+    for name, (answers, rederived, ordered) in got.items():
+        assert answers == expected[name], name
+        assert rederived == 1 and ordered, name
+
+
+@pytest.mark.parametrize("padded", [False, True], ids=["small", "ordered"])
+@pytest.mark.parametrize("name,kwargs,entries,pad,expect", [
+    ("max", {}, [(("b",), 1), (("c",), 2), (("a",), 1)],
+     lambda i: ("b%02d" % i,), "c"),
+    ("min", {}, [(("b",), 1), (("c",), 2), (("a",), -1)],
+     lambda i: ("b%02d" % i,), "b"),
+    ("sorted_tuple", {"skip_nones": False},
+     [((3,), 1), ((1,), 2), ((2,), 1)], lambda i: (2.5 + i / 1000,),
+     (1, 1, 2, 3)),
+    ("tuple", {"skip_nones": True},
+     [(("x", 30), 2), ((None, 10), 1), (("y", 20), 1)],
+     lambda i: ("p%02d" % i, 15 + i / 1000), ("y", "x", "x")),
+])
+def test_multiset_state_restores_from_the_shape_before_it_kept_order(
+        name, kwargs, entries, pad, expect, padded):
+    """A snapshot written before the states kept their order holds
+    ``counts``, ``values``, ``n`` (and ``skip_nones``) under the writer's
+    fingerprints; it restores to the same answers, also in a group large
+    enough to be ordered, and the state still writes that shape."""
+    from pathway_tpu.engine.reducers import REDUCER_FACTORIES, make_reducer_state
+
+    padding = [(pad(i), 1) for i in range(REDUCER_FACTORIES[name]._ORDER_FROM)] \
+        if padded else []
+    held = entries[:2] + padding + entries[2:]
+    old = {
+        "counts": {1000 + i: c for i, (_, c) in enumerate(held)},
+        "values": {1000 + i: args for i, (args, _) in enumerate(held)},
+        "n": sum(c for _, c in held),
+        **kwargs,
+    }
+    st = make_reducer_state(name, **kwargs)
+    st.load_state(old)
+    st.emit()   # a restored group of _ORDER_FROM or more is ordered when read
+    assert (st._order is not None) == padded
+    for args, c in padding:
+        st.add(args, -c)
+    assert st.emit() == expect
+    assert st.state_dict().keys() == old.keys()
+
+
 def test_buffer_operator_rekeys_held_rows_on_restore():
     from pathway_tpu.engine.delta import Delta, row_fingerprint
     from pathway_tpu.engine.temporal_ops import BufferOperator
