@@ -101,6 +101,20 @@ def module_p50_ms(run, kernels: tuple[str, ...]) -> float | None:
     return 1e3 * stats.median([s for chip in runs for s in chip])
 
 
+def scope_seconds(run, kernel: str, scope: str) -> float | None:
+    """Device self seconds in the traced window of the operations under the
+    named scope ``scope`` (``jax.named_scope``, or a nested function's
+    ``jit(name)``) in the programs of ``kernel``: a name of
+    benchmark.lib.trace.MODULE_PATTERNS or a pattern over module names. On
+    the chip that spent most there; None where the trace holds no such
+    operation (as every trace of the CPU backend, which keeps no scopes).
+    The time a kernel's reader divides its least time by, where the kernel
+    is part of a larger program."""
+    if run.trace is None:
+        return None
+    return max(run.trace.scope_seconds(kernel, scope)) or None
+
+
 def scan_roofline(run) -> float | None:
     """The scan kernel's share of its roofline, in percent: the least time
     the chip could take for one search of the established slab (the larger

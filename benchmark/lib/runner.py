@@ -39,6 +39,7 @@ from benchmark.lib.hostsampler import HostSampler
 from benchmark.lib.loadgen import OpenLoop, Sent
 from benchmark.lib.record import JitLog, Run
 from benchmark.lib.spec import Cell, SpecError
+from benchmark.lib.stalls import StallWatch
 from benchmark.lib.traffic import Event
 from benchmark.lib.vector_store import System
 
@@ -259,7 +260,7 @@ def session(cell: Cell, *, seed: int, expected_platform: str, t_start: float,
     jit = JitLog()
     jit.install()
     workdir = tempfile.mkdtemp(prefix="bench_")
-    system = System(cell.config, seed, workdir, log=log,
+    system = System(cell, seed, workdir, log=log,
                     flight_trace=flight_trace)
     s = Session(system, jit, Phases(t_start, log), workdir, devices, device,
                 cache_dir, 0)
@@ -425,8 +426,9 @@ def _measure(cell: Cell, system, *, seed: int, seconds: float, trace: bool,
         or mix.get("documents") is not None
 
     gen = None
-    timeline, gc_log = _RowsTimeline(system), _GcLog()
+    timeline, gc_log, watch = _RowsTimeline(system), _GcLog(), StallWatch()
     timeline.start()
+    watch.start()
     gc.callbacks.append(gc_log)
     if open_loop:
         settle = float(mix["settle_s"])
@@ -477,6 +479,7 @@ def _measure(cell: Cell, system, *, seed: int, seconds: float, trace: bool,
     if gen is not None:
         gen.join()
     timeline.stop()
+    watch.stop()
     gc.callbacks.remove(gc_log)
     if trace:
         _collect_requests(system.tracker(), tracker_seen)
@@ -539,7 +542,8 @@ def _measure(cell: Cell, system, *, seed: int, seconds: float, trace: bool,
     n_sample = mix.get("after", {}).get("embedding_sample", 64)
     sample = [sample_from[i] for i in _spread_by_length(sample_from,
                                                         n_sample)]
-    fails, info["min_cos"] = check.embeddings_agree(system, sample)
+    fails, cosines = check.embeddings_agree(system, sample)
+    info.update(cosines)
     failures += fails
     failures += wrong[:10]
     if after["extents"] != before["extents"] or after["extents"] != 1:
@@ -552,6 +556,21 @@ def _measure(cell: Cell, system, *, seed: int, seconds: float, trace: bool,
     phases.mark("checks")
     for f in failures:
         log(f"FAILED: {f}")
+    # each number compared, beside its limit: last in the result line and on
+    # standard error, which is what the driver keeps of a run that fails
+    compared = {
+        "min_cos": (info["min_cos"], system.reference.MIN_COS),
+        "mean_cos": (info["mean_cos"], system.reference.MIN_MEAN_COS),
+        "requests_failed": (len(failed_requests), 0),
+        "first_hits_wrong": (len(wrong), 0),
+        "extents": (after["extents"], 1)}
+    if info["reference_rank"]["checked"]:
+        compared["rank_deficit_max"] = (
+            info["reference_rank"]["max_deficit"], check.RANK_TOLERANCE)
+    if backlog is not None:
+        compared["rows_off_file_count"] = (drift, 0.05 * max(ingested, 1))
+    compared = {name: {"value": value, "limit": limit}
+                for name, (value, limit) in compared.items()}
 
     # -- metrics ----------------------------------------------------------------
     end_to_end = {}
@@ -569,6 +588,15 @@ def _measure(cell: Cell, system, *, seed: int, seconds: float, trace: bool,
         log("gc in the window: " + "; ".join(
             f"gen{g} {len(v)} collections, {sum(v) * 1e3:.0f} ms in all, "
             f"longest {max(v) * 1e3:.0f} ms" for g, v in by_gen.items() if v))
+    # a stall of the whole process from the settling to the window's end,
+    # with what stood still: in the line too, since of a run that fails the
+    # driver keeps that and the end of standard error alone
+    stalls = [dict(st, at=st["at"] - w0) for st in watch.stalls]
+    for st in stalls:
+        log(f"STALL of {st['gap_s']:.2f}s at {st['at']:+.2f}s of the "
+            f"window: {st['verdict']}; " + json.dumps(
+                {k_: st[k_] for k_ in ("process_cpu_s", "threads",
+                                       "machine_core_s")}))
     late = [(q.sent - q.due) * 1e3 for q in window_q]
     if late:
         log(f"generator lateness ms: p50 {stats.percentile(late, 50):.3f} "
@@ -590,10 +618,13 @@ def _measure(cell: Cell, system, *, seed: int, seconds: float, trace: bool,
               "latencies_ms": [(q.done - q.due) * 1e3 for q in window_q
                                if q.error is None],
               "due_s": [q.due - w0 for q in window_q if q.error is None],
-              "lateness_ms": late}
+              "lateness_ms": late, "stalls": stalls}
     line = {"correct": not failures, "attempted": int(attempted),
             "failed": int(failed), "metrics": end_to_end, "device": dev_out}
+    if stalls:
+        line["stalls"] = stalls[:5]
     if not trace:
+        line["compared"] = compared
         return line, detail
 
     # -- the traced run's own: per-layer metrics and the breakdown ---------------
@@ -609,7 +640,9 @@ def _measure(cell: Cell, system, *, seed: int, seconds: float, trace: bool,
         f"{len(run.samples)} host samples, {len(run.requests)} requests")
     for d in run.trace.devices:
         log(f"trace {d.name}: busy {d.busy_s:.4f}s; modules " + json.dumps(
-            {n: [len(r), sum(r)] for n, r in sorted(d.modules.items())}))
+            {n: [len(r), sum(r)] for n, r in sorted(d.modules.items())})
+            + "; scopes " + json.dumps(dict(sorted(
+                d.scopes.items(), key=lambda kv: -kv[1])[:16])))
     layers = {}
     for layer in cell.layers:
         value = layer.read(run)
@@ -624,7 +657,7 @@ def _measure(cell: Cell, system, *, seed: int, seconds: float, trace: bool,
     detail.update(per_layer={k_: v["value"] for k_, v in layers.items()},
                   breakdown=breakdown,
                   request_stages_ms=_stage_table(run.requests))
-    line.update(metrics=layers, breakdown=breakdown)
+    line.update(metrics=layers, breakdown=breakdown, compared=compared)
     return line, detail
 
 

@@ -4,8 +4,14 @@ A cell is ``{name, config, traffic, chips}`` in ``workloads`` and nothing
 else. Its configuration is the ``file`` of its ``configs`` entry, its mix
 ``benchmark/traffic/<traffic>.json``, and every metric a reader of its own:
 ``benchmark/end_to_end/<metric>.py`` or ``benchmark/layers/<metric>.py``,
-each defining ``read(run)``. :func:`load` resolves all of them before
-anything is built, so a later PR's added file fails loudly and early.
+each defining ``read(run)``. A configuration names its architecture
+(``"model"``), and that name finds the two files that know it:
+``benchmark/models/<model>.py`` (how the program's embedder is built, what
+it is fed and what a dispatch costs) and ``benchmark/reference/<model>.py``
+(the weights from the seed, the plain forward pass over them, its control
+in a lower precision and the tolerance between the two). :func:`load`
+resolves all of them before anything is built, so a later PR's added file
+fails loudly and early.
 """
 
 from __future__ import annotations
@@ -13,12 +19,18 @@ from __future__ import annotations
 import dataclasses
 import importlib.util
 import json
+import numbers
 import os
+from types import ModuleType
 from typing import Callable
 
 ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 SOURCES = ("device_trace", "program_span", "program_counter", "host_clock")
+#: what a file found by name has to define (benchmark/README.md gives the
+#: signatures)
+MODEL_CALLABLES = ("build", "tokens", "dispatch_cost")
+REFERENCE_CALLABLES = ("weights", "embed", "control")
 
 
 class SpecError(ValueError):
@@ -44,6 +56,8 @@ class Cell:
     chips: int
     end_to_end: tuple[Metric, ...]   # the metrics reported in this cell
     layers: tuple[Metric, ...]
+    model: ModuleType       # benchmark/models/<config["model"]>.py
+    reference: ModuleType   # benchmark/reference/<config["model"]>.py
 
 
 @dataclasses.dataclass(frozen=True)
@@ -70,18 +84,50 @@ def _read_json(path: str) -> dict:
         raise SpecError(f"{os.path.relpath(path, ROOT)}: {e}") from None
 
 
-def _load_reader(root: str, kind: str, name: str):
+def _load_file(root: str, kind: str, name: str, callables: tuple[str, ...],
+               missing: str) -> ModuleType:
+    """The module of ``benchmark/<kind>/<name>.py`` under ``root``, which
+    has to define every one of ``callables``."""
+    rel = f"benchmark/{kind}/{name}.py"
     path = os.path.join(root, "benchmark", kind, name + ".py")
     if not os.path.isfile(path):
-        raise SpecError(f"metric {name!r} has no reader "
-                        f"benchmark/{kind}/{name}.py")
+        raise SpecError(f"{missing} {rel}")
     spec = importlib.util.spec_from_file_location(
         f"benchmark.{kind}." + name.replace(".", "_"), path)
     module = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(module)
-    if not callable(getattr(module, "read", None)):
-        raise SpecError(f"benchmark/{kind}/{name}.py defines no read(run)")
-    return module.read
+    for fn in callables:
+        if not callable(getattr(module, fn, None)):
+            raise SpecError(f"{rel} defines no {fn}()")
+    return module
+
+
+def _load_reader(root: str, kind: str, name: str):
+    return _load_file(root, kind, name, ("read",),
+                      f"metric {name!r} has no reader").read
+
+
+def _load_model(root: str, config_name: str, config: dict
+                ) -> tuple[ModuleType, ModuleType]:
+    """The two files of the architecture a configuration names."""
+    name = config.get("model")
+    if not isinstance(name, str) or not name:
+        raise SpecError(f"configuration {config_name!r} names no \"model\": "
+                        f"the key finds benchmark/models/<model>.py and "
+                        f"benchmark/reference/<model>.py")
+    what = f"configuration {config_name!r} names the model {name!r}, which"
+    model = _load_file(root, "models", name, MODEL_CALLABLES,
+                       f"{what} has no")
+    reference = _load_file(root, "reference", name, REFERENCE_CALLABLES,
+                           f"{what} has no reference")
+    for limit in ("MIN_COS", "MIN_MEAN_COS"):
+        value = getattr(reference, limit, None)
+        if not isinstance(value, numbers.Real) or not 0.0 < value <= 1.0:
+            raise SpecError(
+                f"benchmark/reference/{name}.py defines no {limit} in (0, 1]:"
+                f" the least cosine between a served embedding and the "
+                f"reference's, of one text and in the mean")
+    return model, reference
 
 
 def _applies(metric: dict, cell_name: str) -> bool:
@@ -117,6 +163,7 @@ def load(root: str = ROOT) -> Spec:
         readers[m["name"]] = _load_reader(root, "layers", m["name"])
     used = set()
     cells: dict[str, Cell] = {}
+    models: dict[str, tuple[ModuleType, ModuleType]] = {}
     for name, w in workloads.items():
         if w["config"] not in configs:
             raise SpecError(f"workload {name!r} names the configuration "
@@ -125,6 +172,8 @@ def load(root: str = ROOT) -> Spec:
         config = _read_json(os.path.join(root, configs[w["config"]]["file"]))
         traffic = _read_json(os.path.join(
             root, "benchmark", "traffic", w["traffic"] + ".json"))
+        if w["config"] not in models:
+            models[w["config"]] = _load_model(root, w["config"], config)
         if config.get("chips") != w["chips"]:
             raise SpecError(
                 f"workload {name!r} asks for {w['chips']} chips but its "
@@ -147,7 +196,7 @@ def load(root: str = ROOT) -> Spec:
         if not layers:
             raise SpecError(f"workload {name!r} reports no per-layer metric")
         cells[name] = Cell(name, config, traffic, int(w["chips"]), e2e,
-                           tuple(layers))
+                           tuple(layers), *models[w["config"]])
     unused = set(configs) - used
     if unused:
         raise SpecError(f"configurations used by no workload: "

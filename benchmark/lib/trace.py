@@ -17,6 +17,23 @@ and tested without a chip. No number from such a plane is a device metric.
 
 XLA names programs after the program's function names today; the patterns
 that map them to kernels sit in :data:`MODULE_PATTERNS` alone.
+
+Inside a program an operation is found by its **scope**. The compiler keeps,
+for every HLO operation, JAX's name stack of the instruction it came from
+(of a fusion: of its root), ``jit(step)/encoder.mlp/dot_general``: the jitted
+function, every ``jax.named_scope`` and nested function around the call,
+the primitive. On a TPU the profile carries it as the stat ``tf_op`` (with
+a trailing ``:``) of the operation's *event metadata* in the plane, beside
+the ``program_id`` of the program the operation belongs to, which is the
+number in the name of that program's ``XLA Modules`` events
+(``jit_search(4586580180248420438)``). ``ProfileData`` hands out neither
+(it gives an event's own stats: offset, duration): :func:`_op_paths` reads
+the metadata, and nothing else, from the file's protobuf wire format, and
+an operation's event is joined to it by (program, name of the event), which
+names one instruction.
+The CPU backend's events carry no such path; its operations stay under
+their module alone. An operation the compiler made itself (a ``copy-done``)
+has none either.
 """
 
 from __future__ import annotations
@@ -43,6 +60,11 @@ END_MARK = "bench.trace.end"
 CLOCK_MARK = "bench.clock"
 
 _DEVICE_PLANE = re.compile(r"^/device:TPU:\d+$")
+#: the stats of an operation's event metadata that hold JAX's name stack and
+#: the program the operation belongs to (found on the chip: PR 22's and PR
+#: 27's recorded profiles)
+_SCOPE_STAT = "tf_op"
+_PROGRAM_STAT = "program_id"
 
 
 @dataclasses.dataclass
@@ -52,9 +74,14 @@ class DeviceTrace:
 
     name: str
     busy_s: float                         # union of operation intervals
-    ops: dict[str, float]                 # self seconds by "module/op"
+    #: self seconds by "module/path/op"; path, where the profile has one, is
+    #: the operation's scope and primitive (``search.score/dot_general``)
+    ops: dict[str, float]
     modules: dict[str, list[float]]       # seconds per execution, by module
     gaps: list[tuple[float, float]]       # idle intervals (start, end)
+    #: self seconds by "module/scope", by "module" alone where an operation
+    #: has no scope: they add up to the module's operations' self time
+    scopes: dict[str, float] = dataclasses.field(default_factory=dict)
 
 
 @dataclasses.dataclass
@@ -85,6 +112,26 @@ class Reduced:
         return [[s for name, runs in d.modules.items() if pat.match(name)
                  for s in runs] for d in self.devices]
 
+    def scope_seconds(self, kernel: str, scope: str) -> list[float]:
+        """Per chip, the self seconds in the window of the operations under
+        ``scope`` in the modules of ``kernel``: a name of
+        :data:`MODULE_PATTERNS`, or a pattern over module names of the
+        caller's own. ``scope`` is one name or several joined by ``/``, and
+        is found wherever it stands in an operation's scope
+        (``encoder.mlp`` in ``while/body/encoder.mlp``), since a loop or a
+        nested function around a kernel is not the kernel's to know."""
+        pat = re.compile(MODULE_PATTERNS.get(kernel, kernel))
+        want = scope.split("/")
+
+        def under(key: str) -> bool:
+            module, _sep, path = key.partition("/")
+            parts = path.split("/")
+            return bool(pat.match(module)) and any(
+                parts[i:i + len(want)] == want for i in range(len(parts)))
+
+        return [sum(s for key, s in d.scopes.items() if under(key))
+                for d in self.devices]
+
 
 def _op_label(name: str) -> str:
     """A TPU operation's event is named by its whole HLO line,
@@ -98,9 +145,27 @@ def _op_label(name: str) -> str:
     return f"{head.lstrip('%')} {result}"
 
 
+def _path(tf_op: str) -> str:
+    """``jit(step)/jit(main)/encoder.mlp/dot_general:`` ->
+    ``encoder.mlp/dot_general``: the name stack without the jitted function
+    the program is named after."""
+    parts = tf_op.partition(":")[0].split("/")
+    if parts[0].startswith("jit("):
+        parts = parts[1:]
+    if parts and parts[0] == "jit(main)":
+        parts = parts[1:]
+    return "/".join(parts)
+
+
 def _module_base(name: str) -> str:
     """``jit_search(1234567)`` -> ``jit_search``."""
     return re.sub(r"\(\d+\)$", "", name).strip()
+
+
+def _program_id(name: str) -> int:
+    """``jit_search(1234567)`` -> 1234567, the program's id; 0 without."""
+    found = re.search(r"\((\d+)\)$", name)
+    return int(found.group(1)) if found else 0
 
 
 def _union(intervals: list[tuple[float, float]]) -> list[tuple[float, float]]:
@@ -136,8 +201,12 @@ def _self_seconds(events: list[tuple[float, float, str]]) -> dict[str, float]:
 
 def _reduce_device(name: str, ops: list[tuple[float, float, str]],
                    modules: list[tuple[float, float, str]], t0: float,
-                   t1: float) -> DeviceTrace:
-    """``ops`` and ``modules`` are (start, end, name) in seconds."""
+                   t1: float, paths: dict[tuple[int, str], str] | None = None
+                   ) -> DeviceTrace:
+    """``ops`` and ``modules`` are (start, end, name) in seconds; ``paths``
+    gives the scope and primitive of an operation by (the id of the program
+    it ran in, its event's name)."""
+    paths = paths or {}
     clipped = [(max(s, t0), min(e, t1), n) for s, e, n in ops
                if e > t0 and s < t1]
     busy = _union([(s, e) for s, e, _n in clipped])
@@ -148,8 +217,10 @@ def _reduce_device(name: str, ops: list[tuple[float, float, str]],
     labelled = []
     for s, e, n in clipped:
         i = bisect.bisect_right(starts, s) - 1
-        mod = _module_base(mods[i][2]) if i >= 0 and s < mods[i][1] else "?"
-        labelled.append((s, e, f"{mod}/{_op_label(n)}"))
+        inside = i >= 0 and s < mods[i][1]
+        mod = _module_base(mods[i][2]) if inside else "?"
+        path = paths.get((_program_id(mods[i][2]), n), "") if inside else ""
+        labelled.append((s, e, (mod, path, _op_label(n))))
     runs: dict[str, list[float]] = {}
     for s, e, n in mods:
         if s >= t0 and e <= t1:
@@ -161,12 +232,110 @@ def _reduce_device(name: str, ops: list[tuple[float, float, str]],
         cur = max(cur, e)
     if cur < t1:
         gaps.append((cur, t1))
-    return DeviceTrace(name, sum(e - s for s, e in busy),
-                       _self_seconds(labelled), runs, gaps)
+    by_op: dict[str, float] = {}
+    by_scope: dict[str, float] = {}
+    for (mod, path, op), sec in _self_seconds(labelled).items():
+        scope = path.rpartition("/")[0]
+        for total, key in ((by_op, (mod, path, op)),
+                           (by_scope, (mod, scope))):
+            key = "/".join(part for part in key if part)
+            total[key] = total.get(key, 0.0) + sec
+    return DeviceTrace(name, sum(e - s for s, e in busy), by_op, runs, gaps,
+                       by_scope)
 
 
 def _stats(event) -> dict:
     return {k: v for k, v in event.stats}
+
+
+def _varint(buf, i: int) -> tuple[int, int]:
+    value = shift = 0
+    while True:
+        byte = buf[i]
+        i += 1
+        value |= (byte & 0x7F) << shift
+        if byte < 0x80:
+            return value, i
+        shift += 7
+
+
+def _fields(buf):
+    """(field number, value) over one protobuf message: a varint's number,
+    or the bytes of a length-delimited or fixed-width field as a view."""
+    i, n = 0, len(buf)
+    while i < n:
+        tag, i = _varint(buf, i)
+        wire = tag & 7
+        if wire == 0:
+            value, i = _varint(buf, i)
+        else:
+            if wire == 2:
+                size, i = _varint(buf, i)
+            elif wire in (1, 5):
+                size = 8 if wire == 1 else 4
+            else:
+                raise ValueError(f"protobuf wire type {wire}")
+            value, i = buf[i:i + size], i + size
+        yield tag >> 3, value
+
+
+def _op_paths(path: str) -> dict[str, dict[tuple[int, str], str]]:
+    """Per device plane, :func:`_path` of every operation that has one, by
+    (the operation's program id, the name of its events). Read from the
+    file's wire format (tsl's ``xplane.proto``): ``XSpace.planes = 1``;
+    ``XPlane.name = 2``, ``.event_metadata = 4`` and ``.stat_metadata = 5``
+    (maps: key 1, value 2); ``XEventMetadata.name = 2``, ``.stats = 5``;
+    ``XStatMetadata.name = 2``; ``XStat.metadata_id = 1``, ``.uint64_value =
+    3``, ``.str_value = 5``, ``.ref_value = 7`` (the id of a stat metadata
+    whose name is the string). A plane's lines, which hold the events and
+    nearly all the bytes, are skipped whole."""
+    with open(path, "rb") as f:
+        space = memoryview(f.read())
+    out: dict[str, dict[tuple[int, str], str]] = {}
+
+    def entry(buf) -> tuple[int, dict]:
+        """A map entry: (key, the value message's fields by number, the
+        repeated ``stats`` as a list)."""
+        key, message = 0, {}
+        for num, value in _fields(buf):
+            if num == 1:
+                key = value
+            elif num == 2:
+                for n, v in _fields(value):
+                    message.setdefault(n, []).append(v)
+        return key, message
+
+    for num, plane in _fields(space):
+        if num != 1:
+            continue
+        name, events, stat_names = "", [], {}
+        for n, value in _fields(plane):
+            if n == 2:
+                name = bytes(value).decode()
+            elif n == 4:
+                events.append(value)
+            elif n == 5:
+                key, message = entry(value)
+                stat_names[key] = bytes(message[2][0]).decode() \
+                    if 2 in message else ""
+        if not _DEVICE_PLANE.match(name):
+            continue
+        found: dict[tuple[int, str], str] = {}
+        for buf in events:
+            _key, message = entry(buf)
+            text, program = "", 0
+            for raw in message.get(5, ()):
+                fields = dict(_fields(raw))
+                stat = stat_names.get(fields.get(1))
+                if stat == _SCOPE_STAT:
+                    text = bytes(fields[5]).decode() if 5 in fields \
+                        else stat_names.get(fields.get(7), "")
+                elif stat == _PROGRAM_STAT:
+                    program = fields.get(3, 0)
+            if text and 2 in message:
+                found[program, bytes(message[2][0]).decode()] = _path(text)
+        out[name] = found
+    return out
 
 
 def reduce_xplane(path: str) -> Reduced:
@@ -234,8 +403,10 @@ def reduce_xplane(path: str) -> Reduced:
         s, _e, st = marks[CLOCK_MARK][0]
         if "perf_counter_ns" in st:
             offset = s - int(st["perf_counter_ns"]) / 1e9
+    paths = _op_paths(path)
     devices = [_reduce_device(name, dev_ops[name], dev_mods.get(name, []),
-                              t0, t1) for name in sorted(dev_ops)]
+                              t0, t1, paths.get(name))
+               for name in sorted(dev_ops)]
     spans = [sp for sp in host_spans if sp[3] > t0 and sp[2] < t1]
     return Reduced(t0, t1, devices, spans, offset)
 

@@ -7,13 +7,15 @@ points a user calls and run on a thread of the benchmark's process:
           -> run_server(threaded=True)  <-  HTTP on 127.0.0.1
 
 From the program the benchmark takes the system, its counters and its
-spans, and nothing else.
+spans, and nothing else. Which embedder that is, what the reference is fed
+and what a dispatch costs are the cell's architecture's
+(``benchmark/models/<model>.py``); the harness relies on the embedder
+protocol of :data:`EMBEDDER_PROTOCOL`, which is the program's own.
 """
 
 from __future__ import annotations
 
 import gc
-import inspect
 import os
 import socket
 import time
@@ -24,12 +26,29 @@ import numpy as np
 # query keys for direct searches and the filler rows of the fill
 _SCRATCH_KEY = 1 << 62
 _FILLER_KEY = 1 << 61
+#: bytes of filler drawn and added a chunk: 524,288 rows of 384 bf16
+_FILL_CHUNK_BYTES = (1 << 19) * 384 * 2
+
+#: what the harness, the index that embeds text itself and ``pw.warmup``
+#: call on an embedder (benchmark/README.md says what each is for)
+EMBEDDER_PROTOCOL = ("params", "tokenizer", "ragged", "encode_batch_device",
+                     "get_embedding_dimension")
+
+
+def _require(obj, attrs, what: str) -> None:
+    missing = [a for a in attrs if not hasattr(obj, a)]
+    if missing:
+        raise TypeError(f"{what} ({type(obj).__name__}) lacks "
+                        f"{', '.join(missing)}: benchmark/README.md, "
+                        f"\"The embedder protocol\"")
 
 
 class System:
-    def __init__(self, config: dict, seed: int, workdir: str, *,
+    def __init__(self, cell, seed: int, workdir: str, *,
                  flight_trace: str | None = None, log=print):
-        self.config = config
+        self.config = cell.config
+        self.model = cell.model          # benchmark/models/<model>.py
+        self.reference = cell.reference  # benchmark/reference/<model>.py
         self.seed = seed
         self.log = log
         self.flight_trace = flight_trace
@@ -40,7 +59,6 @@ class System:
         for d in (self.corpus_dir, self.live_dir, self.stage_dir):
             os.makedirs(d, exist_ok=True)
         self.embedder = None
-        self.encoder_config = None
         self.index = None
         self.filler_rows = 0
         self.runtime = None
@@ -49,47 +67,15 @@ class System:
 
     # -- build ---------------------------------------------------------------
     def make_embedder(self):
-        """Seeded weights made on the device in one jitted call, in the type
-        the program serves them in; the synthetic WordPiece vocab."""
-        import jax
-        import jax.numpy as jnp
-
-        from pathway_tpu.models.encoder import EncoderConfig, init_params
-        from pathway_tpu.models.tokenizer import (WordPieceTokenizer,
-                                                  make_synthetic_vocab)
-        from pathway_tpu.xpacks.llm.embedders import JaxEncoderEmbedder
-
-        c, serving = self.config, self.config["serving"]
-        cfg = EncoderConfig(
-            vocab_size=c["vocab_size"], hidden=c["hidden_size"],
-            layers=c["num_hidden_layers"], heads=c["num_attention_heads"],
-            intermediate=c["intermediate_size"],
-            max_len=c["max_position_embeddings"],
-            type_vocab_size=c["type_vocab_size"],
-            layer_norm_eps=c["layer_norm_eps"], pooling=c["pooling"],
-            normalize=c["normalize"],
-            compute_dtype=getattr(jnp, serving["compute_dtype"]))
-        params = jax.jit(lambda key: init_params(key, cfg))(
-            jax.random.PRNGKey(self.seed))
-        tokenizer = WordPieceTokenizer(
-            make_synthetic_vocab(
-                [f"word{i}" for i in range(serving["vocab_words"])],
-                vocab_size=cfg.vocab_size),
-            max_len=serving["max_len"])
-        if not tokenizer.uses_native:
-            raise RuntimeError("the native WordPiece did not build; the "
-                               "Python twin is not what a deployment runs")
-        kwargs = {}
-        # the packer is a constructor argument only while the constructor
-        # takes it: once one path is the only one, the key is ignored
-        if "ragged" in inspect.signature(
-                JaxEncoderEmbedder.__init__).parameters:
-            kwargs["ragged"] = bool(serving["ragged"])
-        self.encoder_config = cfg
-        self.embedder = JaxEncoderEmbedder(
-            config=cfg, params=params, tokenizer=tokenizer,
-            max_len=serving["max_len"], **kwargs)
-        return self.embedder
+        """The architecture's embedder, holding the weights its reference
+        makes from the seed."""
+        emb = self.model.build(
+            self.config, self.reference.weights(self.config, self.seed))
+        _require(emb, EMBEDDER_PROTOCOL, "the embedder that "
+                 f"{self.model.__name__}.build() returned")
+        _require(emb.tokenizer, ("batch",), "its tokenizer")
+        self.embedder = emb
+        return emb
 
     def start(self) -> None:
         import pathway_tpu as pw
@@ -148,7 +134,7 @@ class System:
         self.log(f"system: {type(self.index).__name__}"
                  f"({type(self.index.inner).__name__}) "
                  f"capacity_rows={self._pages()['capacity_rows']} "
-                 f"ragged={getattr(emb, 'ragged', None)}")
+                 f"ragged={emb.ragged}")
 
     def stop(self) -> None:
         from pathway_tpu.engine import streaming
@@ -210,8 +196,9 @@ class System:
 
         from pathway_tpu.internals.keys import Pointer
 
-        store, dim = self._store(), self.encoder_config.hidden
-        rows, chunk = self.config["index"]["rows"], 1 << 19
+        store, dim = self._store(), self.embedder.get_embedding_dimension()
+        rows = self.config["index"]["rows"]
+        chunk = min(rows, _FILL_CHUNK_BYTES // (2 * dim))   # bf16 rows
         gen = jax.jit(lambda key: jax.random.uniform(
             key, (chunk, dim), jnp.bfloat16, -1.0, 1.0))
         key = jax.random.PRNGKey(self.seed + 1)
@@ -282,9 +269,11 @@ class System:
             setattr(obj, attr, wrapper)
 
         emb = self.embedder
+        _require(emb, ("pack_ragged" if emb.ragged else "pack_tokens",),
+                 "the embedder, whose packer a traced run times,")
         wrap(emb.tokenizer, "batch", "tokenizer.batch",
              lambda a, out: {"texts": len(a[0])})
-        if getattr(emb, "ragged", False):
+        if emb.ragged:
             wrap(emb, "pack_ragged", "pack",
                  lambda a, out: {"texts": len(a[0]), "ragged": True,
                                  "shapes": [c[0][0].shape for c in out]})
@@ -299,14 +288,7 @@ class System:
 
     def encoder_cost(self, shape: tuple, ragged: bool) -> tuple[float, float]:
         """(flops, bytes) of one encoder dispatch of packed ``shape``."""
-        from benchmark.lib import costs
-
-        cfg = self.encoder_config
-        kw = dict(hidden=cfg.hidden, intermediate=cfg.intermediate,
-                  layers=cfg.layers)
-        if ragged:
-            return costs.segment_attention_cost(*shape, heads=cfg.heads, **kw)
-        return costs.encoder_cost(*shape, **kw)
+        return self.model.dispatch_cost(self.config, shape, ragged)
 
     def scan_cost(self, queries: int) -> tuple[float, float]:
         """(flops, bytes) of one scan: every established row (every extent
@@ -316,18 +298,13 @@ class System:
         rows = self._pages()["capacity_rows"]
         itemsize = {"float32": 4, "bfloat16": 2,
                     "int8": 1}[self.config["index"]["dtype"]]
-        return costs.knn_search_cost(queries, rows,
-                                     self.encoder_config.hidden, itemsize)
+        return costs.knn_search_cost(
+            queries, rows, self.embedder.get_embedding_dimension(), itemsize)
 
     # -- the reference's inputs ---------------------------------------------------
     def tokens(self, texts: list[str]) -> tuple[np.ndarray, np.ndarray]:
-        """(ids, lengths) of ``texts`` from the program's tokenizer, padded
-        to the serving width."""
-        width = self.config["serving"]["max_len"]
-        ids, mask = self.embedder.tokenizer.batch(
-            [t or "." for t in texts], max_len=width)
-        ids = np.pad(ids, ((0, 0), (0, width - ids.shape[1])))
-        return ids.astype(np.int32), mask.sum(axis=1).astype(np.int32)
+        """(ids, lengths) of ``texts``, as the reference takes them."""
+        return self.model.tokens(self.embedder, self.config, texts)
 
     def served_embeddings(self, texts: list[str]) -> np.ndarray:
         """What the program's encoder path makes of ``texts`` (the packer

@@ -7,7 +7,8 @@ from the root of a checkout, on a machine that holds the chips the cell
 asks for. Everything before the last line of standard output is progress
 and detail for a reader; the last line is the one JSON object of the
 contract (``correct``, ``attempted``, ``failed``, ``metrics``, ``device``,
-and ``breakdown`` in a traced run). Any error, a platform other than a TPU
+``breakdown`` in a traced run, and last ``compared``: each number the checks
+compared beside its limit, which are also the last lines of standard error). Any error, a platform other than a TPU
 and too few chips are a non-zero exit with no such line. No flag and no
 variable makes this command run off the chip: the CPU rehearsal calls
 :func:`benchmark.lib.runner.run_cell` with the platform as an argument
@@ -58,6 +59,9 @@ def main(argv: list[str] | None = None) -> int:
               flush=True)
         return 1
     print(json.dumps(line), flush=True)
+    for name, c in line["compared"].items():
+        print(f"compared {name}: {c['value']!r} (limit {c['limit']!r})",
+              file=sys.stderr, flush=True)
     return 0
 
 

@@ -5,6 +5,7 @@ speed."""
 
 from __future__ import annotations
 
+import dataclasses
 import json
 import os
 import subprocess
@@ -15,7 +16,7 @@ import pytest
 
 from benchmark.lib import runner
 
-from conftest import FAKE_PEAKS, ROOT, tiny_cell
+from conftest import FAKE_PEAKS, ROOT, TOY, tiny_cell, toy_cell
 
 
 def _run(cell, tmp_path, **kw):
@@ -33,6 +34,13 @@ def _well_formed(line: dict, cell, traced: bool) -> None:
     for value in line["metrics"].values():
         assert set(value) == {"value", "unit"}
         assert isinstance(value["value"], float)
+    # each number compared beside its limit, last in the line
+    assert list(line)[-1] == "compared"
+    assert {"min_cos", "mean_cos", "requests_failed", "first_hits_wrong",
+            "extents"} <= set(line["compared"])
+    for pair in line["compared"].values():
+        assert set(pair) == {"value", "limit"}
+    assert line["compared"]["min_cos"]["limit"] == cell.reference.MIN_COS
     json.dumps(line)
 
 
@@ -96,6 +104,54 @@ def test_ingest_backlog_traced(tmp_path):
     assert detail["checks"]["min_cos"] >= 0.999
     # 32 self-retrievals over the length range came back first
     assert not [f for f in detail["failures"] if "self-retrieval" in f]
+
+
+def test_another_architecture_is_new_files_and_entries(tmp_path):
+    cell = toy_cell(tmp_path / "checkout")
+    assert cell.model.__file__.startswith(str(tmp_path))
+    line = _run(cell, tmp_path, seconds=4, trace=False)
+    _well_formed(line, cell, traced=False)
+    assert line["correct"] and line["failed"] == 0
+    assert set(line["metrics"]) == {"setup_s", "query_p50_ms",
+                                    "query_p95_ms", "doc_visible_p50_ms",
+                                    "peak_hbm_gib"}
+    with open(tmp_path / f"{cell.name}.seed3.trace0.json") as f:
+        detail = json.load(f)
+    assert detail["checks"]["min_cos"] >= cell.reference.MIN_COS
+    assert detail["checks"]["reference_rank"]["checked"] > 20
+
+    from pathway_tpu.internals.parse_graph import G
+
+    G.clear()
+    traced = _run(cell, tmp_path, seconds=4, trace=True)
+    _well_formed(traced, cell, traced=True)
+    assert traced["correct"]
+    got = traced["metrics"]
+    # the toy's jitted forward is named as the program's own is, so the
+    # query path's encoder readers find it; the scan is the index's
+    assert got["encoder.device_ms_p50"]["value"] > 0
+    assert got["scan.device_ms_p50"]["value"] > 0
+    assert got["scan_roofline"]["value"] > 0
+    assert 0 < len(traced["breakdown"]["device_ops"]) <= 10
+
+
+def test_an_architecture_s_wrong_reference_fails_correct(tmp_path):
+    with open(os.path.join(TOY, "reference.py")) as f:
+        source = f.read()
+    # pools the padding too: a structural fault, far under MIN_COS
+    wrong = source.replace("table[ids[:n]].mean(axis=0)",
+                           "table[ids].mean(axis=0)")
+    assert wrong != source
+    cell = toy_cell(tmp_path / "checkout", reference=wrong)
+    line = _run(cell, tmp_path, seconds=3, trace=False)
+    assert line["correct"] is False
+    min_cos = line["compared"]["min_cos"]
+    assert min_cos["value"] < 0.99 < min_cos["limit"] == 0.999995
+    mean_cos = line["compared"]["mean_cos"]
+    assert mean_cos["value"] < mean_cos["limit"] == 0.999998
+    with open(tmp_path / f"{cell.name}.seed3.trace0.json") as f:
+        failures = json.load(f)["failures"]
+    assert any("encoder disagrees with the reference" in f for f in failures)
 
 
 def test_the_function_refuses_the_wrong_platform_before_building(tmp_path):

@@ -30,7 +30,7 @@ def _distinct_shapes(ragged: bool) -> tuple[int, int, int]:
     cell.traffic["warm"].update(ticks=1, quiet_ticks=0)
     jit = JitLog()
     with tempfile.TemporaryDirectory() as workdir:
-        system = System(cell.config, 5, workdir, log=lambda _m: None)
+        system = System(cell, 5, workdir, log=lambda _m: None)
         try:
             ready = runner.prepare(
                 cell, system, seed=5, trace=True, jit=jit, workdir=workdir,

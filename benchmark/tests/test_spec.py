@@ -20,9 +20,11 @@ def checkout(tmp_path):
     """BENCHMARK.json and the benchmark's data and readers, copied where a
     test may add to them."""
     shutil.copy(os.path.join(ROOT, "BENCHMARK.json"), tmp_path)
-    for part in ("configs", "traffic", "layers", "end_to_end"):
+    for part in ("configs", "traffic", "layers", "end_to_end", "models",
+                 "reference"):
         shutil.copytree(os.path.join(ROOT, "benchmark", part),
-                        tmp_path / "benchmark" / part)
+                        tmp_path / "benchmark" / part,
+                        ignore=shutil.ignore_patterns("__pycache__"))
     return tmp_path
 
 
@@ -41,6 +43,11 @@ def test_the_repo_s_own_benchmark_resolves():
         assert "setup_s" in {m.name for m in cell.end_to_end}
         assert len(cell.end_to_end) >= 2 and cell.layers
         assert cell.chips == cell.config["chips"] == 1
+        # the configuration's "model" found both of the architecture's files
+        assert cell.config["model"] == "bert"
+        assert cell.model.__file__.endswith("benchmark/models/bert.py")
+        assert cell.reference.__file__.endswith(
+            "benchmark/reference/bert.py")
     with pytest.raises(spec.SpecError, match="no workload 'nope'"):
         loaded.cell("nope")
 
@@ -120,5 +127,51 @@ def test_a_mix_a_cell_and_a_metric_are_added_by_files_and_entries(checkout):
 def test_what_does_not_resolve_is_named_before_anything_is_built(
         checkout, change, message):
     _edit(checkout, change)
+    with pytest.raises(spec.SpecError, match=message):
+        spec.load(str(checkout))
+
+
+def _config(checkout, change) -> None:
+    path = checkout / "benchmark/configs/bge-small-10m.json"
+    config = json.loads(path.read_text())
+    change(config)
+    path.write_text(json.dumps(config))
+
+
+def _strip(checkout, rel: str, name: str) -> None:
+    """Take the definition of ``name`` out of the file ``rel``."""
+    path = checkout / rel
+    path.write_text(path.read_text().replace(f"def {name}(", f"def _{name}(")
+                    .replace(f"\n{name} = ", f"\n_{name} = "))
+
+
+@pytest.mark.parametrize("change, message", [
+    (lambda c: _config(c, lambda cfg: cfg.pop("model")),
+     r"configuration 'bge-small-10m' names no \"model\""),
+    (lambda c: _config(c, lambda cfg: cfg.update(model="mamba")),
+     r"names the model 'mamba', which has no benchmark/models/mamba.py"),
+    (lambda c: os.remove(c / "benchmark/reference/bert.py"),
+     r"names the model 'bert', which has no reference "
+     r"benchmark/reference/bert.py"),
+    (lambda c: _strip(c, "benchmark/models/bert.py", "build"),
+     r"benchmark/models/bert.py defines no build\(\)"),
+    (lambda c: _strip(c, "benchmark/models/bert.py", "tokens"),
+     r"benchmark/models/bert.py defines no tokens\(\)"),
+    (lambda c: _strip(c, "benchmark/models/bert.py", "dispatch_cost"),
+     r"benchmark/models/bert.py defines no dispatch_cost\(\)"),
+    (lambda c: _strip(c, "benchmark/reference/bert.py", "embed"),
+     r"benchmark/reference/bert.py defines no embed\(\)"),
+    (lambda c: _strip(c, "benchmark/reference/bert.py", "weights"),
+     r"benchmark/reference/bert.py defines no weights\(\)"),
+    (lambda c: _strip(c, "benchmark/reference/bert.py", "control"),
+     r"benchmark/reference/bert.py defines no control\(\)"),
+    (lambda c: _strip(c, "benchmark/reference/bert.py", "MIN_COS"),
+     r"benchmark/reference/bert.py defines no MIN_COS in \(0, 1\]"),
+    (lambda c: _strip(c, "benchmark/reference/bert.py", "MIN_MEAN_COS"),
+     r"benchmark/reference/bert.py defines no MIN_MEAN_COS in \(0, 1\]"),
+])
+def test_a_model_s_missing_file_or_callable_is_named(checkout, change,
+                                                     message):
+    change(checkout)
     with pytest.raises(spec.SpecError, match=message):
         spec.load(str(checkout))

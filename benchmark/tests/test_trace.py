@@ -3,11 +3,15 @@ whole reduction against small recorded profiles kept under data/."""
 
 from __future__ import annotations
 
+import json
 import os
+import types
 
 import pytest
 
-from benchmark.lib import trace
+from benchmark.lib import readers, trace
+
+from conftest import load_tool
 
 DATA = os.path.join(os.path.dirname(os.path.abspath(__file__)), "data")
 
@@ -39,6 +43,55 @@ def test_a_device_is_reduced_inside_the_window_only():
         "jit_search/c": 1.0})
     # only executions that lie whole inside the window are timed
     assert dev.modules == {"jit_step": [2.0]}
+
+
+def test_operations_are_kept_by_scope_and_without_one_by_module():
+    ops = [(0.0, 4.0, "%while = (s32[], f32[8]{0}) while(%tuple)"),
+           (1.0, 2.0, "%fusion.1 = f32[8]{0} x"),
+           (2.0, 3.5, "%fusion.2 = f32[8]{0} y"),
+           (5.0, 6.0, "%copy-done = f32[8]{0} copy-done(%start)"),
+           (6.0, 7.0, "%fusion.3 = f32[8]{0} w")]
+    modules = [(0.0, 7.0, "jit_step(1)")]
+    paths = {(1, "%fusion.1 = f32[8]{0} x"):
+             "while/body/encoder.mlp/dot_general",
+             (1, "%fusion.2 = f32[8]{0} y"):
+             "while/body/encoder.attention/exp",
+             # the same line of another program: not this one's path
+             (2, "%copy-done = f32[8]{0} copy-done(%start)"): "other.scope/x",
+             (1, "%fusion.3 = f32[8]{0} w"): "mul"}   # a primitive, no scope
+    dev = trace._reduce_device("d0", ops, modules, 0.0, 10.0, paths)
+    assert dev.ops == pytest.approx({
+        "jit_step/while (tuple)": 1.5,
+        "jit_step/while/body/encoder.mlp/dot_general/fusion.1 f32[8]": 1.0,
+        "jit_step/while/body/encoder.attention/exp/fusion.2 f32[8]": 1.5,
+        "jit_step/copy-done f32[8]": 1.0,
+        "jit_step/mul/fusion.3 f32[8]": 1.0})
+    assert dev.scopes == pytest.approx({
+        "jit_step": 3.5, "jit_step/while/body/encoder.mlp": 1.0,
+        "jit_step/while/body/encoder.attention": 1.5})
+    assert sum(dev.scopes.values()) == pytest.approx(dev.busy_s)
+    r = trace.Reduced(0.0, 10.0, [dev], [], None)
+    # a scope is found wherever it stands, by whole names
+    assert r.scope_seconds("fused_ingest", "encoder.mlp") == [1.0]
+    assert r.scope_seconds("fused_ingest", "while/body") == [2.5]
+    assert r.scope_seconds("fused_ingest", "encoder") == [0.0]
+    assert r.scope_seconds("scan", "encoder.mlp") == [0.0]
+    assert r.scope_seconds(r"^jit_st", "body/encoder.attention") == [1.5]
+    run = types.SimpleNamespace(trace=r)
+    assert readers.scope_seconds(run, "fused_ingest", "encoder.mlp") == 1.0
+    assert readers.scope_seconds(run, "fused_ingest", "nothing") is None
+    assert readers.scope_seconds(types.SimpleNamespace(trace=None),
+                                 "fused_ingest", "encoder.mlp") is None
+
+
+@pytest.mark.parametrize("tf_op, path", [
+    ("jit(step)/jit(main)/encoder.mlp/dot_general:", "encoder.mlp/dot_general"),
+    ("jit(search)/while/body/closed_call/top_k:", "while/body/closed_call/top_k"),
+    ("jit(search)/jit(norm)/reduce_sum:", "jit(norm)/reduce_sum"),
+    ("jit(iota)/iota:", "iota"), ("", ""),
+])
+def test_a_path_is_the_name_stack_without_the_module_s_function(tf_op, path):
+    assert trace._path(tf_op) == path
 
 
 def _reduced(devices):
@@ -152,3 +205,77 @@ def test_the_recorded_tpu_profile_reduces_to_the_numbers_read_by_hand():
         assert len(got) == runs["count"]
         assert sum(got) == pytest.approx(runs["seconds"], rel=1e-9)
     assert trace.top_ops(r, 3)[0][0] == want["top_op"]
+
+
+def _module(key: str) -> str:
+    return key.split("/")[0]
+
+
+def test_the_recorded_scopes_reduce_to_what_the_compiled_proto_read():
+    """Scopes read from the wire format and reduced by ``_self_seconds``,
+    against TensorFlow's compiled xplane.proto and a union of intervals
+    (tools/record_fixture.py expect), to the profile's nanosecond."""
+    with open(os.path.join(DATA, "tpu_scopes.expected.json")) as f:
+        want = json.load(f)
+    r = trace.reduce_xplane(os.path.join(DATA, "tpu_scopes.xplane.pb"))
+    (dev,) = r.devices
+    assert r.window_s == pytest.approx(want["window_s"], rel=1e-6)
+    assert set(dev.scopes) == set(want["scope_self_s"])
+    for key, seconds in want["scope_self_s"].items():
+        assert dev.scopes[key] == pytest.approx(seconds, rel=2e-3, abs=2e-8)
+    # self time by scope adds up to the module's, and all of it to busy
+    for module, seconds in want["module_ops_self_s"].items():
+        assert sum(s for k, s in dev.scopes.items() if _module(k) == module) \
+            == pytest.approx(seconds, rel=2e-3, abs=2e-8)
+        assert sum(s for k, s in dev.ops.items() if _module(k) == module) \
+            == pytest.approx(seconds, rel=2e-3, abs=2e-8)
+    assert sum(dev.scopes.values()) == pytest.approx(dev.busy_s)
+    # the toy's scopes, the one inside the loop under the loop's own
+    step = "jit_toy_step"
+    inner = f"{step}/toy.blocks/while/body/closed_call/toy.mlp"
+    assert {k for k in dev.scopes if "toy." in k} == {
+        f"{step}/toy.embed", f"{step}/toy.pool", f"{step}/toy.blocks", inner}
+    mlp = r.scope_seconds(f"^{step}$", "toy.mlp")[0]
+    assert mlp == pytest.approx(dev.scopes[inner])
+    assert r.scope_seconds(f"^{step}$", "toy.blocks")[0] > mlp > 0
+    assert r.scope_seconds(f"^{step}$", "toy.absent") == [0.0]
+    # an operation without a scope is kept under its module alone: the
+    # scaling outside every scope, and what the compiler added
+    assert dev.scopes[step] > 0
+    assert any(k.startswith(f"{step}/mul/") for k in dev.ops)
+    for op in want["no_tf_op"]:
+        assert any(k.split(" ")[0] == f"{step}/{op}" for k in dev.ops), op
+    # the breakdown names an operation by module/scope/primitive/op
+    assert trace.top_ops(r, 1)[0][0].startswith(f"{step}/toy.")
+
+
+def test_the_cpu_twin_of_the_recorded_scopes_has_modules_alone(tmp_path):
+    """The same toy program traced here: the CPU backend's events carry no
+    name stack, so every operation stays under its module and no scope's
+    reader finds anything."""
+    import glob
+
+    import jax
+
+    toy = load_tool("record_fixture")  # which recorded tpu_scopes
+    step = jax.jit(toy.toy_step)
+    operands = toy.toy_operands()
+    step(*operands).block_until_ready()
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=options)
+    for _ in range(3):
+        step(*operands).block_until_ready()
+    jax.profiler.stop_trace()
+    (path,) = glob.glob(str(tmp_path / "plugins" / "profile" / "*"
+                            / "*.xplane.pb"))
+    r = trace.reduce_xplane(path)
+    (dev,) = r.devices
+    assert dev.name == "xla-cpu"
+    assert len(dev.modules["jit_toy_step"]) == 3
+    assert set(dev.scopes) == {"jit_toy_step"}
+    assert dev.scopes["jit_toy_step"] == pytest.approx(
+        sum(dev.ops.values()))
+    assert r.scope_seconds("^jit_toy_step$", "toy.mlp") == [0.0]
+    assert readers.scope_seconds(types.SimpleNamespace(trace=r),
+                                 "^jit_toy_step$", "toy.mlp") is None
