@@ -1,0 +1,8 @@
+"""Percent of the fused ingest program's device time in the traced window
+spent in the gated attention mixers."""
+
+from benchmark.lib.scope_readers import share
+
+
+def read(run):
+    return share(run, ("decoder.attention",))

@@ -94,6 +94,9 @@ class DeviceBridge:
         self._waiters = 0  # host threads blocked in submit/barrier
         # -- instrumentation (read via stats(); exported on /metrics) ------
         self.legs_dispatched = 0
+        # submits that found the window full and had to wait: the device
+        # (the legs' side) was slower than the host just then
+        self.submits_blocked = 0
         self.legs_resolved = 0
         # legs that finished with no host thread waiting on the bridge at
         # any point of their execution: fully overlapped with host work
@@ -126,6 +129,9 @@ class DeviceBridge:
                 from pathway_tpu.engine.threads import spawn
 
                 self._thread = spawn(self._work, name=self.name)
+            if (len(self._queue) + (1 if self._running else 0)
+                    >= self.max_inflight):
+                self.submits_blocked += 1
             while (len(self._queue) + (1 if self._running else 0)
                    >= self.max_inflight):
                 self._waiters += 1
@@ -227,6 +233,7 @@ class DeviceBridge:
                 "depth": len(self._queue) + (1 if self._running else 0),
                 "resolved_watermark": self._watermark,
                 "legs_dispatched": self.legs_dispatched,
+                "submits_blocked": self.submits_blocked,
                 "legs_resolved": resolved,
                 "legs_overlapped": self.legs_overlapped,
                 "overlap_ratio": (self.legs_overlapped / resolved

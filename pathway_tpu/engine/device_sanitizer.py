@@ -54,7 +54,8 @@ logger = logging.getLogger(__name__)
 
 __all__ = [
     "DeviceDisciplineViolation", "arm", "declare_steady_state",
-    "in_steady_state", "install_compile_counter", "post_warmup_compiles",
+    "in_steady_state", "install_compile_clock", "install_compile_counter",
+    "post_warmup_compiles",
     "sanitizer_enabled", "suspend_steady_state", "violations",
     "warmup_compiles",
 ]
@@ -95,6 +96,7 @@ class _SanitizerState:
         self.warmup_compiles = 0
         self.post_warmup_compiles = 0
         self.total_compiles = 0
+        self.total_compile_s = 0.0
         self.violation_log: list[dict] = []
 
 
@@ -168,6 +170,7 @@ def _on_compile_event(event: str, duration: float, **_kw) -> None:
         return
     with _STATE.mutex:
         _STATE.total_compiles += 1
+        _STATE.total_compile_s += duration
         if not _STATE.armed:
             return
         if not _STATE.steady:
@@ -201,6 +204,14 @@ def install_compile_counter():
     per-leg compile columns diff it around each leg."""
     _install_listener()
     return lambda: _STATE.total_compiles
+
+
+def install_compile_clock():
+    """As :func:`install_compile_counter`, but the callable yields the
+    seconds the process has spent in backend compiles so far: a device
+    leg's cost model (engine/qos.py) takes them out of the leg's time."""
+    _install_listener()
+    return lambda: _STATE.total_compile_s
 
 
 def _set_transfer_guard(mode: str) -> None:

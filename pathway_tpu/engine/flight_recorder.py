@@ -149,6 +149,23 @@ def live_inflight_by_thread() -> dict:
     return out
 
 
+def live_span(name: str, t0: float, t1: float, **counts) -> None:
+    """One finished span into every live enabled recorder, from code that
+    holds no reference to the run (an index inside a device leg). Its
+    cause is the leg in flight, so it joins that tick's other spans."""
+    for rec in list(_LIVE):
+        if rec.enabled:
+            leg = rec._inflight_leg
+            rec.span(name, t0, t1, ("tick", leg[0]) if leg else None,
+                     **counts)
+
+
+def recording() -> bool:
+    """Whether any live recorder is on: callers that must compute a span's
+    counts ask first."""
+    return any(rec.enabled for rec in list(_LIVE))
+
+
 def attach_note(e: BaseException, note: str) -> None:
     """PEP 678 note with the pre-3.11 emulation (same storage contract as
     internals/trace.py add_trace_note, shared here so exceptions raised on

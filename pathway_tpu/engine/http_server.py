@@ -32,6 +32,18 @@ def _paged_stats() -> dict | None:
         return None
 
 
+def _expert_load() -> dict | None:
+    """Tokens per held expert of the live embedders whose model routes to
+    experts (xpacks/llm/embedders.py), or None. The one place the sum
+    leaves the device: a metrics request, never a tick."""
+    try:
+        from pathway_tpu.xpacks.llm.embedders import expert_load_stats
+
+        return expert_load_stats()
+    except Exception:
+        return None
+
+
 def _cache_stats() -> dict | None:
     """Aggregate semantic-result-cache stats (engine/result_cache.py),
     or None when no cache is live in this process."""
@@ -649,6 +661,18 @@ class MonitoringHttpServer:
                          f"{round(persistence.commit_wait.sum_ms, 6)}")
             lines.append(f"pathway_tpu_commit_wait_ms_count "
                          f"{persistence.commit_wait.count}")
+        experts = _expert_load()
+        if experts is not None:
+            lines.append("# TYPE pathway_tpu_moe_tokens_per_expert_max gauge")
+            lines.append(
+                f"pathway_tpu_moe_tokens_per_expert_max {experts['max']}")
+            lines.append(
+                "# TYPE pathway_tpu_moe_tokens_per_expert_mean gauge")
+            lines.append(
+                f"pathway_tpu_moe_tokens_per_expert_mean {experts['mean']}")
+            lines.append("# TYPE pathway_tpu_moe_dispatches counter")
+            lines.append(
+                f"pathway_tpu_moe_dispatches {experts['dispatches']}")
         paged = _paged_stats()
         if paged is not None:
             # paged vector store occupancy (engine/paged_store.py): pool

@@ -18,7 +18,12 @@ index work; HedraRAG's coalescing of concurrent retrieval — PAPERS.md):
    burning budget halves the ingest allowance down to a progress floor,
    a healthy window grows it back. ``PATHWAY_QOS_QUERY_BUDGET=<ms>``
    pins a fixed per-tick device-time reservation for queries instead
-   (translated to rows via an EWMA of observed ingest cost per row).
+   (translated to rows via an EWMA of observed ingest cost per row,
+   :class:`IngestCost`). A runtime with **no** QoS armed asks the same
+   question of :class:`DeviceBackpressure`, whose answer is "all" until
+   the device is the slower side: one budget interface
+   (``ingest_row_budget`` / ``note_deferral`` / ``on_tick``), one cost
+   model, two policies.
 
 2. **Admission control** — a bounded queue ahead of the webserver's
    ``session.push``: when the depth cap is hit, or the burn rate crosses
@@ -61,6 +66,7 @@ acting.
 
 from __future__ import annotations
 
+import collections
 import os
 import time as _time
 import weakref
@@ -210,6 +216,119 @@ class QosConfig:
         return cls()
 
 
+class IngestCost:
+    """Device milliseconds one ingest row costs, smoothed over the legs
+    that retired: what turns a stretch of device time into a row
+    allowance. Both ingest budgets reckon with it: the controller's fixed
+    query reservation and :class:`DeviceBackpressure`'s leg length."""
+
+    def __init__(self):
+        self.ms_per_row: float | None = None
+
+    def sample(self, rows: int, device_ms: float) -> None:
+        cost = device_ms / rows
+        self.ms_per_row = cost if self.ms_per_row is None \
+            else 0.8 * self.ms_per_row + 0.2 * cost
+
+    def rows_in(self, ms: float) -> int | None:
+        """Rows that fit ``ms`` of device time (None before a sample)."""
+        if not self.ms_per_row:
+            return None
+        return int(ms / self.ms_per_row)
+
+
+#: commit intervals a device leg lasts while :class:`DeviceBackpressure`
+#: bounds the drain: long enough that the partial dispatch a tick ends in
+#: is a small share of its leg, short enough that tick edges (freshness,
+#: any reader of progress) keep falling several times a second. Swept on
+#: the chip behind a decoder embedder of 70 ms a dispatch, 50 ms ticks
+#: (PERF.md, PR 28): 1, 2, 4 intervals ingest 126-127 docs/s, 8 136, 16 139
+#: with a leg's p95 at 0.94 s; under a dispatch a leg the cost of a row
+#: is all fixed cost and the bound falls to one row
+LEG_TICKS = 8
+
+
+class DeviceBackpressure:
+    """The ingest budget of a runtime with no QoS armed: none, until the
+    device is the slower side.
+
+    ``DeviceBridge.submit`` blocks on a full window, and an unbounded
+    drain then takes everything the connectors pushed meanwhile: behind a
+    device three times slower than a reader each tick holds three times
+    the rows of the one before, until one leg runs for minutes and no
+    tick edge falls for as long. So a tick whose submit found the window
+    full bounds the next drain to the rows the device retires in
+    ``LEG_TICKS`` commit intervals, by the cost of the legs that have
+    retired (:class:`IngestCost`; the first leg has retired by the first
+    submit that waits, so the bound stands from the third tick after a
+    release). The rest stays in its session and rides later ticks, as
+    under the controller's budget. A submit that did not wait raises the
+    bound by half while rows are held back and lifts it once a drain
+    left nothing behind: a device that keeps up is never held back, and
+    what a bound held back does not arrive as one tick.
+
+    The time a leg spent in XLA's compiler is taken out of its cost, and
+    a submit that waited for legs which spent most of their time there
+    counts as one that did not wait: a compile says nothing of the pace
+    at which the device takes rows, and a bound reckoned with it shrinks
+    ticks without shortening their legs (the padded encoder path meets a
+    new shape, and a compile, nearly every tick)."""
+
+    def __init__(self, tick_interval_s: float):
+        from pathway_tpu.engine.device_sanitizer import \
+            install_compile_clock
+
+        self.tick_interval_ms = max(1.0, tick_interval_s * 1e3)
+        self._cost = IngestCost()
+        self._rows: int | None = None
+        # (tick, ingest rows, query rows) of the ticks whose legs have
+        # not retired, and what the last look saw of the bridge
+        self._unretired: collections.deque = collections.deque()
+        self._exec_ms_seen = 0.0
+        self._blocked_seen = 0
+        self._compile_s = install_compile_clock()
+        self._compile_s_seen = self._compile_s()
+
+    def ingest_row_budget(self) -> int | None:
+        """Max ingest rows the next drain may take (None: all)."""
+        return self._rows
+
+    def note_deferral(self, n_rows: int) -> None:
+        """Nothing to count: the drain's ``deferred`` flag comes back
+        through :meth:`on_tick`."""
+
+    def on_tick(self, tick: int, *, ingest_rows: int, query_rows: int,
+                deferred: bool, bridge: dict) -> None:
+        """One look, after tick ``tick``'s submit returned: ``bridge`` is
+        ``DeviceBridge.stats()``."""
+        self._unretired.append((tick, ingest_rows, query_rows))
+        retired, clean = 0, True
+        while self._unretired \
+                and self._unretired[0][0] <= bridge["resolved_watermark"]:
+            _tick, rows, queries = self._unretired.popleft()
+            retired += rows
+            clean = clean and not queries
+        compile_ms = (self._compile_s() - self._compile_s_seen) * 1e3
+        exec_ms = bridge["exec_ms"] - self._exec_ms_seen
+        # a submit that waited for a leg which spent most of its time in
+        # XLA's compiler waited for the compiler, not for the device
+        waited = bridge["submits_blocked"] != self._blocked_seen \
+            and compile_ms <= 0.5 * exec_ms
+        self._exec_ms_seen += exec_ms
+        self._blocked_seen = bridge["submits_blocked"]
+        self._compile_s_seen += compile_ms / 1e3
+        if not waited:
+            if self._rows is not None:
+                self._rows = (self._rows + (self._rows + 1) // 2
+                              if deferred else None)
+            return
+        if retired and clean and exec_ms > compile_ms:
+            self._cost.sample(retired, exec_ms - compile_ms)
+        rows = self._cost.rows_in(LEG_TICKS * self.tick_interval_ms)
+        if rows is not None:
+            self._rows = max(1, rows)
+
+
 class QosController:
     """One per streaming runtime (created iff QoS is armed). Thread
     crossings: the webserver's event loop calls :meth:`admit` /
@@ -235,7 +354,7 @@ class QosController:
         # EWMA ingest device-cost (ms per row), learned from ticks that
         # carried ingest but no query work — translates a fixed
         # PATHWAY_QOS_QUERY_BUDGET (ms) into a row allowance
-        self._ingest_ms_per_row: float | None = None
+        self._cost = IngestCost()
         self._serving_active_until = 0.0
         self._last_count = 0
         # -- counters (exported: /metrics pathway_tpu_qos_*) ---------------
@@ -356,9 +475,8 @@ class QosController:
             # the adaptive allowance
             ingest_ms = max(0.0, self.tick_interval_ms
                             - cfg.query_budget_ms)
-            cost = self._ingest_ms_per_row
-            if cost is not None and cost > 0:
-                rows = int(ingest_ms / cost)
+            rows = self._cost.rows_in(ingest_ms)
+            if rows is not None:
                 return max(cfg.min_ingest_rows,
                            min(cfg.max_ingest_rows, rows))
         return max(cfg.min_ingest_rows,
@@ -385,12 +503,7 @@ class QosController:
                 # clean cost sample: this tick's (retired) device time
                 # was all ingest. A zero device delta means the leg has
                 # not resolved yet — no sample, never a zero-cost one.
-                cost_ms = spent_ms / ingest_rows
-                if self._ingest_ms_per_row is None:
-                    self._ingest_ms_per_row = cost_ms
-                else:
-                    self._ingest_ms_per_row = (
-                        0.8 * self._ingest_ms_per_row + 0.2 * cost_ms)
+                self._cost.sample(ingest_rows, spent_ms)
         if not self.serving_active():
             # no queries around: relax the partition back toward wide
             # open — GRADUALLY (x4 per tick), so the backlog deferred
@@ -444,7 +557,7 @@ class QosController:
         cfg = self.config
         if cfg.query_budget_ms is not None:
             return cfg.query_budget_ms
-        cost = self._ingest_ms_per_row
+        cost = self._cost.ms_per_row
         if cost is None or not self.serving_active():
             return 0.0
         ingest_ms = min(self.tick_interval_ms,
@@ -493,8 +606,8 @@ class QosController:
                          else "adaptive"),
                 "ingest_rows_per_tick": int(self._rows_per_tick),
                 "ingest_ms_per_row": (
-                    None if self._ingest_ms_per_row is None
-                    else round(self._ingest_ms_per_row, 6)),
+                    None if self._cost.ms_per_row is None
+                    else round(self._cost.ms_per_row, 6)),
                 "admission_queue_depth": self._queue_depth,
                 "admission_queue_cap": cfg.admission_queue,
                 "admitted_total": self.admitted_total,

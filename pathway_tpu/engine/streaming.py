@@ -250,6 +250,9 @@ class StreamingRuntime:
         # (ingest_rows, query_rows, deferred) of the latest drain — the
         # QoS feedback loop's per-tick input
         self._last_drain: tuple[int, int, bool] = (0, 0, False)
+        # the ingest budget with no QoS armed (engine/qos.py
+        # DeviceBackpressure): made by the first tick that has a bridge
+        self._backpressure = None
         # while recording, what the latest drain took for the tick's
         # spans: (rows by source name, requests picked up — a serving
         # source's insertions; its retractions are rows, not requests)
@@ -475,14 +478,28 @@ class StreamingRuntime:
             if release is not None:
                 release(watermark)
 
-    def _qos_tick_feedback(self, tick_ms: float) -> None:
-        """Close the loop for one tick: feed the controller what the
-        tick actually did (rows drained, host wall time, the device
-        time that retired on the bridge since the last tick) and
-        propagate deferral backpressure to the connector readers."""
+    def _tick_feedback(self, tick: int, tick_ms: float,
+                       commit_s: float) -> None:
+        """Close the loop for one tick: feed the ingest budget what the
+        tick actually did (rows drained, host wall time, what retired on
+        the bridge since the last tick). With QoS armed the budget is the
+        controller's, and its deferral backpressure goes on to the
+        connector readers; without, it is ``DeviceBackpressure``'s, which
+        holds nothing back until a submit finds the bridge's window
+        full."""
         ingest_rows, query_rows, deferred = self._last_drain
-        device_ms = None
         bridge = self.scheduler.bridge_stats()
+        if self.qos is None:
+            if bridge is not None:
+                if self._backpressure is None:
+                    from pathway_tpu.engine.qos import DeviceBackpressure
+
+                    self._backpressure = DeviceBackpressure(commit_s)
+                self._backpressure.on_tick(
+                    tick, ingest_rows=ingest_rows, query_rows=query_rows,
+                    deferred=deferred, bridge=bridge)
+            return
+        device_ms = None
         if bridge is not None:
             # cumulative resolved-leg exec time: the per-tick delta lags
             # the submitting tick by the in-flight depth, which is fine
@@ -603,10 +620,12 @@ class StreamingRuntime:
         peer -> {source index -> entries}.
 
         With QoS armed (and ``budgeted``), ingest sources drain at most
-        the controller's per-tick row budget (engine/qos.py): clipped
-        rows stay *in their session* and ride later ticks through this
-        same path, so seals keep covering exactly what each tick drained
-        — deferral moves timestamps, never durability or content.
+        the controller's per-tick row budget (engine/qos.py); without it,
+        at most what ``DeviceBackpressure`` allows while the device is the
+        slower side. Clipped rows stay *in their session* and ride later
+        ticks through this same path, so seals keep covering exactly what
+        each tick drained — deferral moves timestamps, never durability
+        or content.
         Serving sources (request-tracking) are never clipped; the
         end-of-stream re-drain passes ``budgeted=False`` (latency has no
         meaning once every source closed — finish at full throughput)."""
@@ -614,9 +633,9 @@ class StreamingRuntime:
         all_closed = True
         tracker = self._request_tracker
         pushes: dict[int, dict[int, list]] = {}
-        qos = self.qos
-        budget = (qos.ingest_row_budget()
-                  if qos is not None and budgeted else None)
+        limiter = self.qos if self.qos is not None else self._backpressure
+        budget = (limiter.ingest_row_budget()
+                  if limiter is not None and budgeted else None)
         ingest_rows = 0
         query_rows = 0
         deferred = False
@@ -652,7 +671,7 @@ class StreamingRuntime:
                 # the budget clipped this source: the remainder rides a
                 # later tick (never dropped — visible in the counters)
                 deferred = True
-                qos.note_deferral(session.backlog())
+                limiter.note_deferral(session.backlog())
             if entries:
                 any_data = True
                 if serving:
@@ -898,8 +917,7 @@ class StreamingRuntime:
                 # SPMD-consistent (single-process keeps ticking — empty
                 # ticks are near-free and drive as-of-now retractions)
                 if self.cluster is None or any_data:
-                    t_tick0 = (_time.perf_counter()
-                               if self.qos is not None else 0.0)
+                    t_tick0 = _time.perf_counter()
                     self.scheduler.run_time(time_counter)
                     if t_wake is not None:
                         t_end = _time.perf_counter()
@@ -913,9 +931,9 @@ class StreamingRuntime:
                     # stamps progress via the watermark listener).
                     self.last_tick_at = _time.monotonic()
                     self._last_completed_tick = time_counter
-                    if self.qos is not None:
-                        self._qos_tick_feedback(
-                            (_time.perf_counter() - t_tick0) * 1e3)
+                    self._tick_feedback(
+                        time_counter,
+                        (_time.perf_counter() - t_tick0) * 1e3, commit_s)
                     # close every live semantic result cache's
                     # invalidations/tick window (engine/result_cache.py)
                     # — the basis of the exported invalidations-per-tick

@@ -6,7 +6,9 @@ xpacks/llm/llms.py:438). Here the flagship embedder is a pure-JAX
 transformer encoder designed for the MXU: bfloat16 matmuls, static shapes,
 mesh-sharded weights (tensor parallel), batch sharded over the data axis,
 and optional ring/Ulysses attention for long sequences
-(pathway_tpu/parallel/ring_attention.py).
+(pathway_tpu/parallel/ring_attention.py). ``decoder.DecoderConfig`` is a
+hybrid decoder backbone (gated delta-rule and attention layers, routed
+experts) served the same way: prefill only, last-token pooling.
 """
 
 from pathway_tpu.models.clip import (
@@ -16,6 +18,7 @@ from pathway_tpu.models.clip import (
     encode_text,
     init_clip_params,
 )
+from pathway_tpu.models.decoder import DecoderConfig
 from pathway_tpu.models.encoder import (
     EncoderConfig,
     encode,
@@ -31,6 +34,7 @@ from pathway_tpu.models.train import (
 
 __all__ = [
     "ClipConfig",
+    "DecoderConfig",
     "EncoderConfig",
     "clip_train_step",
     "encode",

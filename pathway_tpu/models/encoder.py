@@ -63,6 +63,32 @@ class EncoderConfig:
     def head_dim(self) -> int:
         return self.hidden // self.heads
 
+    # what an embedder calls on a config, whatever the architecture
+    # (xpacks/llm/embedders.py JaxEncoderEmbedder)
+    def encode(self, params, token_ids, attention_mask):
+        return encode(params, token_ids, attention_mask, config=self)
+
+    def encode_ragged(self, params, token_ids, doc_map, position_ids,
+                      doc_seq, doc_off):
+        return encode_ragged(params, token_ids, doc_map, position_ids,
+                             doc_seq, doc_off, config=self)
+
+    def init_params(self, key) -> dict:
+        return init_params(key, self)
+
+    def cost(self, batch: int, seq: int, *, ragged: bool):
+        """(kernel name, flops, bytes) of one forward of ``batch x seq``
+        token slots for the engine's profiler, or None where a model has
+        no such reckoning."""
+        from pathway_tpu.engine.profiler import encoder_cost, \
+            segment_attention_cost
+
+        name, fn = (("segment_attention", segment_attention_cost) if ragged
+                    else ("encoder_forward", encoder_cost))
+        return (name, *fn(batch, seq, hidden=self.hidden,
+                          intermediate=self.intermediate,
+                          layers=self.layers))
+
     @staticmethod
     def tiny(**kw) -> "EncoderConfig":
         """Small config for tests/dryruns."""
@@ -280,7 +306,11 @@ def _mlp_block(x, p, config: EncoderConfig):
 
 def _moe_block(x, p, config: EncoderConfig):
     """Top-1 switch MoE: one-hot dispatch keeps everything a dense einsum
-    (MXU-friendly; no dynamic shapes), experts sharded over the model axis."""
+    (MXU-friendly; no dynamic shapes), experts sharded over the model axis.
+    Every expert runs on every token, which a handful of experts in a BERT
+    block can afford. Routed experts at scale (top-k of hundreds, a held
+    range, a grouped product over the chosen pairs only) live in
+    models/decoder.py ``moe_layer`` and ops/moe.py."""
     cd = config.compute_dtype
     E = config.num_experts
     logits = x.astype(jnp.float32) @ p["router"].astype(jnp.float32)

@@ -51,6 +51,7 @@ from typing import Any, Callable
 
 import numpy as np
 
+from pathway_tpu.engine import flight_recorder as _fr
 from pathway_tpu.engine.profiler import (current_profiler,
                                          ingest_scatter_cost,
                                          knn_search_cost)
@@ -326,11 +327,12 @@ def _fused_step_fns(producer: Callable, dtype: str):
     if dtype == "int8":
         @functools.partial(jax.jit, donate_argnums=(0, 1, 2, 3))
         def step_i8(slab, scales, vsq, valid, slots, *args):
-            q, scale, vn = _quantize_i8(producer(*args))
+            out, aux = _split_aux(producer(*args))
+            q, scale, vn = _quantize_i8(out)
             return (slab.at[slots].set(q, mode="drop"),
                     scales.at[slots].set(scale, mode="drop"),
                     vsq.at[slots].set(vn, mode="drop"),
-                    valid.at[slots].set(True, mode="drop"))
+                    valid.at[slots].set(True, mode="drop")) + aux
 
         return step_i8
 
@@ -338,12 +340,22 @@ def _fused_step_fns(producer: Callable, dtype: str):
 
     @functools.partial(jax.jit, donate_argnums=(0, 1))
     def step(slab, valid, slots, *args):
-        out = producer(*args)
+        out, aux = _split_aux(producer(*args))
         slab = slab.at[slots].set(out.astype(slab_dtype), mode="drop")
         valid = valid.at[slots].set(True, mode="drop")
-        return slab, valid
+        return (slab, valid) + aux
 
     return step
+
+
+def _split_aux(out) -> tuple:
+    """A producer returns its rows, or ``(rows, aux)``: a small array it
+    wants summed outside the step (a routed model's tokens per expert).
+    Returns (rows, () or (aux,)): the step hands ``aux`` back beside the
+    slab, and the ingest passes it to ``on_aux``."""
+    if isinstance(out, tuple):
+        return out[0], (out[1],)
+    return out, ()
 
 
 class BruteForceKnnIndex:
@@ -624,7 +636,8 @@ class BruteForceKnnIndex:
                 # and the uncovered-page rule dooms every entry anyway
                 self.result_cache.invalidate_all()
 
-    def make_fused_ingest(self, producer: Callable):
+    def make_fused_ingest(self, producer: Callable,
+                          on_aux: Callable | None = None):
         """Fuse a producer (e.g. the encoder forward pass) with the slab
         scatter into ONE jitted dispatch, donating the slab so XLA updates
         it in place (no copy, no extra dispatch, nothing returns to the
@@ -633,7 +646,9 @@ class BruteForceKnnIndex:
         (xpacks/llm/embedders.py + brute_force_knn_integration.rs); here
         the embedding tensor never leaves the chip.
 
-        ``producer(*args) -> (n, dim) array``. Returns
+        ``producer(*args) -> (n, dim) array``, or ``(array, aux)``: ``aux``
+        comes back from the step still on the device and goes to
+        ``on_aux``. Returns
         ``ingest(keys, *args, n_rows=None)``; ``n_rows`` is the producer's
         output row count when it exceeds ``len(keys)`` (ragged-packed
         batches pad their doc dimension) — padding rows scatter to an
@@ -650,10 +665,12 @@ class BruteForceKnnIndex:
         def ingest(keys: list[Pointer], *args,
                    n_rows: int | None = None) -> None:
             with self._lock:
-                self._fused_ingest(step, keys, args, n_rows)
+                aux = self._fused_ingest(step, keys, args, n_rows)
                 if self.result_cache is not None:
                     # donated device scatter: same rule as add_batch_device
                     self.result_cache.invalidate_all()
+            if aux and on_aux is not None:
+                on_aux(aux[0])
 
         return ingest
 
@@ -688,7 +705,7 @@ class BruteForceKnnIndex:
         return jnp.asarray(slots)
 
     def _fused_ingest(self, step, keys: list[Pointer], args,
-                      n_rows: int | None) -> None:
+                      n_rows: int | None) -> list:
         n_new = len({k for k in keys if k not in self._key_to_slot})
         if len(self._free) < n_new:
             raise FusedIngestUnplaceable(
@@ -699,16 +716,17 @@ class BruteForceKnnIndex:
         dev_slots = self._pad_slots(slots, n_rows, self.capacity)
         if self._is_int8:
             (self._dev_vectors, self._dev_scales, self._dev_vsq,
-             self._dev_valid) = step(
+             self._dev_valid, *aux) = step(
                 self._dev_vectors, self._dev_scales, self._dev_vsq,
                 self._dev_valid, dev_slots, *args)
         else:
-            self._dev_vectors, self._dev_valid = step(
+            self._dev_vectors, self._dev_valid, *aux = step(
                 self._dev_vectors, self._dev_valid, dev_slots, *args)
         self._host_valid[slots] = True
         slot_list = slots.tolist()
         self._stale.update(slot_list)
         self._dirty.difference_update(slot_list)
+        return aux
 
     def _sync_mirror(self) -> None:
         """Pull device-authoritative rows back into the host mirror (lock
@@ -1378,7 +1396,7 @@ class PagedKnnIndex(BruteForceKnnIndex):
 
     # -- fused ingest: grow by allocating pages/extents -------------------
     def _fused_ingest(self, step, keys: list[Pointer], args,
-                      n_rows: int | None) -> None:
+                      n_rows: int | None) -> list:
         from pathway_tpu.engine.paged_store import PageQuotaExceeded
 
         alloc = self._pool.allocator
@@ -1428,16 +1446,17 @@ class PagedKnnIndex(BruteForceKnnIndex):
         local = slots - ext.base
         dev_slots = self._pad_slots(local, n_rows, ext.rows)
         if self._is_int8:
-            (ext.vectors, ext.scales, ext.vsq, ext.valid) = step(
+            (ext.vectors, ext.scales, ext.vsq, ext.valid, *aux) = step(
                 ext.vectors, ext.scales, ext.vsq, ext.valid,
                 dev_slots, *args)
         else:
-            ext.vectors, ext.valid = step(
+            ext.vectors, ext.valid, *aux = step(
                 ext.vectors, ext.valid, dev_slots, *args)
         self._host_valid[slots] = True
         slot_list = slots.tolist()
         self._stale.update(slot_list)
         self._dirty.difference_update(slot_list)
+        return aux
 
 
 class DeviceEmbeddingKnnIndex:
@@ -1471,12 +1490,14 @@ class DeviceEmbeddingKnnIndex:
         self.fused_batches = 0
         self.fused_fallbacks = 0
         self._ragged = bool(getattr(embedder, "ragged", False))
+        on_aux = getattr(embedder, "note_producer_aux", None)
         if self._ragged and hasattr(embedder, "ragged_device_producer"):
             self._fused = inner.make_fused_ingest(
-                embedder.ragged_device_producer)
+                embedder.ragged_device_producer, on_aux)
         elif hasattr(embedder, "pack_tokens") and \
                 hasattr(embedder, "device_producer"):
-            self._fused = inner.make_fused_ingest(embedder.device_producer)
+            self._fused = inner.make_fused_ingest(embedder.device_producer,
+                                                  on_aux)
 
     def add_batch(self, keys: list[Pointer], texts,
                   filter_data: list[Any] | None = None) -> None:
@@ -1487,11 +1508,20 @@ class DeviceEmbeddingKnnIndex:
                     # ragged-packed fused ingest: one donated dispatch per
                     # fixed-shape chunk; padded doc rows scatter-drop
                     d0 = 0
+                    spans = _fr.recording()
                     for args, n_docs, n_pad in \
                             self.embedder.pack_ragged(texts):
+                        t0 = _time.perf_counter()
                         self._fused(keys[d0:d0 + n_docs],
                                     self.embedder.params, *args,
                                     n_rows=n_pad)
+                        if spans:
+                            # args[1] is the packed rows' document map
+                            _fr.live_span(
+                                "embedder.dispatch", t0,
+                                _time.perf_counter(), docs=n_docs,
+                                rows=int(args[0].shape[0]),
+                                tokens=int((args[1] >= 0).sum()))
                         d0 += n_docs
                 else:
                     ids, lens = self.embedder.pack_tokens(texts)

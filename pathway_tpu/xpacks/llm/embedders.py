@@ -11,6 +11,8 @@ tokenized and encoded in one device call, never per row.
 from __future__ import annotations
 
 import asyncio
+import threading
+import weakref
 from time import perf_counter as _perf_counter
 from typing import Any
 
@@ -34,13 +36,44 @@ class BaseEmbedder(udfs.UDF):
         return int(arr.shape[0])
 
 
+# live embedders that hold an expert-load sum (each joins at its first
+# ``note_producer_aux``): /metrics reads them (engine/http_server.py)
+# without a reference plumbed through the graph
+_AUX_EMBEDDERS: "weakref.WeakSet" = weakref.WeakSet()
+
+
+def expert_load_stats() -> dict | None:
+    """Expert load of the live embedders whose model routes tokens to
+    experts: {"max", "mean" tokens an expert, "dispatches"} over all of
+    them; None where there is none. Fetches from the device."""
+    loads = [ld for e in list(_AUX_EMBEDDERS)
+             if (ld := e.expert_load()) is not None]
+    if not loads:
+        return None
+    tokens = np.concatenate([ld["tokens_per_expert"] for ld in loads])
+    return {"max": float(tokens.max()), "mean": float(tokens.mean()),
+            "dispatches": sum(ld["dispatches"] for ld in loads)}
+
+
 class JaxEncoderEmbedder(BaseEmbedder):
-    """TPU-native embedder over the flagship JAX encoder.
+    """TPU-native embedder over an in-repo JAX model.
 
     Tokenizes with models.tokenizer (HashTokenizer by default, or a local HF
     tokenizer), bf16 forward under jit, sequence-length bucketing to bound
     recompilation. This replaces the reference's torch
     SentenceTransformerEmbedder as the local-model path.
+
+    The architecture is the ``config`` object's: it supplies ``encode``
+    (padded batch), ``encode_ragged`` (ragged-packed rows), ``init_params``,
+    ``cost`` (a forward's operations and bytes for the engine's profiler,
+    or None), ``hidden``, ``vocab_size``, ``max_len`` and ``pooling``
+    (models/encoder.py ``EncoderConfig``, the default;
+    models/decoder.py ``DecoderConfig``). A forward may return ``(embeddings,
+    aux)``: ``aux`` is a small device array (a decoder's tokens per expert)
+    that is summed on the device and fetched only by :meth:`expert_load`.
+
+    ``ragged_max_seqs``: packed rows a ragged dispatch holds at most
+    (default ``PATHWAY_RAGGED_MAX_SEQS``, else 8).
     """
 
     _BUCKETS = (32, 64, 128, 256, 512)
@@ -49,6 +82,7 @@ class JaxEncoderEmbedder(BaseEmbedder):
                  params=None, tokenizer=None,
                  seed: int = 0, max_len: int = 512,
                  ragged: bool | None = None,
+                 ragged_max_seqs: int | None = None,
                  call_kwargs: dict = {}, **kwargs):
         kwargs.setdefault("batch", True)
         kwargs.setdefault("deterministic", True)
@@ -64,8 +98,7 @@ class JaxEncoderEmbedder(BaseEmbedder):
         # machine, not once per process
         enable_compilation_cache()
 
-        from pathway_tpu.models.encoder import EncoderConfig, encode, \
-            init_params
+        from pathway_tpu.models.encoder import EncoderConfig
         from pathway_tpu.models.tokenizer import HashTokenizer
 
         if model is not None:
@@ -76,14 +109,16 @@ class JaxEncoderEmbedder(BaseEmbedder):
 
             params, config, tokenizer = load_model(model)
         self.config = config or EncoderConfig.bge_small()
-        self.params = params if params is not None else init_params(
-            jax.random.PRNGKey(seed), self.config)
+        self.params = params if params is not None else \
+            self.config.init_params(jax.random.PRNGKey(seed))
         self.tokenizer = tokenizer or HashTokenizer(
             vocab_size=self.config.vocab_size, max_len=max_len)
         self.max_len = min(max_len, self.config.max_len)
-        cfg = self.config
-        self._encode = jax.jit(
-            lambda p, ids, mask: encode(p, ids, mask, config=cfg))
+        # tokens per held expert, summed over every dispatch of a model
+        # with routed experts: one device array, never fetched in a tick
+        self._aux_lock = threading.Lock()
+        self._aux_sum = None
+        self._aux_dispatches = 0
         # packed hot path: int16 ids + per-row lengths instead of int32
         # ids + a (B, S) bool mask — a quarter of the host→device bytes;
         # the mask is rebuilt on device (iota < len). Usable whenever the
@@ -103,7 +138,9 @@ class JaxEncoderEmbedder(BaseEmbedder):
         self.ragged = bool(ragged)
         from pathway_tpu.internals.config import _env_int
 
-        self._ragged_max_seqs = max(1, _env_int("PATHWAY_RAGGED_MAX_SEQS", 8))
+        if ragged_max_seqs is None:
+            ragged_max_seqs = _env_int("PATHWAY_RAGGED_MAX_SEQS", 8)
+        self._ragged_max_seqs = max(1, int(ragged_max_seqs))
         # docs-per-sequence cap bounds the padded doc dimension of a chunk
         # (W//16: a doc is never shorter than CLS+token+SEP anyway)
         self._ragged_doc_cap = max(1, self.max_len // 16)
@@ -158,21 +195,48 @@ class JaxEncoderEmbedder(BaseEmbedder):
         scatter into ONE donated dispatch."""
         import jax.numpy as jnp
 
-        from pathway_tpu.models.encoder import encode
-
         ids32 = ids.astype(jnp.int32)
         mask = jnp.arange(ids32.shape[1])[None, :] < lens[:, None]
-        return encode(params, ids32, mask, config=self.config)
+        return self.config.encode(params, ids32, mask)
 
     def ragged_device_producer(self, params, ids, doc_map, pos_ids,
                                doc_seq, doc_off):
-        """Pure (traceable) forward over a ragged-packed chunk
-        (models/encoder.py encode_ragged) — the fused-ingest producer of
-        the ragged path, returning (n_docs_padded, hidden)."""
-        from pathway_tpu.models.encoder import encode_ragged
+        """Pure (traceable) forward over a ragged-packed chunk (the
+        config's ``encode_ragged``) — the fused-ingest producer of the
+        ragged path, returning (n_docs_padded, hidden)."""
+        return self.config.encode_ragged(params, ids, doc_map, pos_ids,
+                                         doc_seq, doc_off)
 
-        return encode_ragged(params, ids, doc_map, pos_ids, doc_seq,
-                             doc_off, config=self.config)
+    def note_producer_aux(self, aux) -> None:
+        """Sum what a forward returned beside its embeddings into the one
+        device array this embedder keeps (a small asynchronous add: no
+        transfer, no wait)."""
+        with self._aux_lock:
+            if self._aux_sum is None:
+                _AUX_EMBEDDERS.add(self)
+                self._aux_sum = aux
+            else:
+                self._aux_sum = self._aux_sum + aux
+            self._aux_dispatches += 1
+
+    def _embeddings(self, out):
+        """A forward's embeddings, its ``aux`` (if any) noted."""
+        if isinstance(out, tuple):
+            out, aux = out
+            self.note_producer_aux(aux)
+        return out
+
+    def expert_load(self) -> dict | None:
+        """Tokens each held expert took, summed over every dispatch so
+        far and every layer, fetched from the device now (the one
+        transfer: call it from a metrics request, not from a tick). None
+        where the model routes nothing."""
+        with self._aux_lock:
+            total, dispatches = self._aux_sum, self._aux_dispatches
+        if total is None:
+            return None
+        return {"tokens_per_expert": np.asarray(total),
+                "dispatches": dispatches}
 
     def ragged_buckets(self) -> list[int]:
         """Sequence-count buckets the ragged path can dispatch: powers of
@@ -194,11 +258,14 @@ class JaxEncoderEmbedder(BaseEmbedder):
         in input order, where ``args = (ids, doc_map, pos_ids, doc_seq,
         doc_off)`` feed ragged_device_producer and ``n_docs_padded`` is
         its static output row count (pad rows carry doc_map -1 and are
-        dropped by the caller / the fused scatter)."""
+        dropped by the caller / the fused scatter). ``doc_off`` is the
+        offset of the token the model pools: a document's first, or under
+        ``pooling: "last"`` its last."""
         ids, mask = self.tokenizer.batch(
             [t or "." for t in texts], max_len=self.max_len)
         lens = mask.sum(axis=1).astype(np.int64)
         W, cap = self.max_len, self._ragged_doc_cap
+        pool_last = self.config.pooling == "last"
         # assign each doc a (sequence, offset) first-fit in order
         seq_of = np.empty(len(texts), np.int64)
         off_of = np.empty(len(texts), np.int64)
@@ -234,7 +301,7 @@ class JaxEncoderEmbedder(BaseEmbedder):
                 c_ids[s, o:o + n] = ids[d, :n]
                 c_map[s, o:o + n] = j
                 c_pos[s, o:o + n] = np.arange(n)
-                c_dseq[j], c_doff[j] = s, o
+                c_dseq[j], c_doff[j] = s, o + n - 1 if pool_last else o
             chunks.append(((c_ids, c_map, c_pos, c_dseq, c_doff),
                            n_docs, n_pad))
             d0 = d1
@@ -280,33 +347,24 @@ class JaxEncoderEmbedder(BaseEmbedder):
             outs = []
             for args, n_docs, _n_pad in self.pack_ragged(texts):
                 t0 = _perf_counter() if prof is not None else 0.0
-                outs.append(self._encode_ragged(
-                    self.params, *(jnp.asarray(a) for a in args))[:n_docs])
+                outs.append(self._embeddings(self._encode_ragged(
+                    self.params, *(jnp.asarray(a) for a in args)))[:n_docs])
                 if prof is not None:
-                    from pathway_tpu.engine.profiler import \
-                        segment_attention_cost
-
                     b, s = args[0].shape  # packed (n_seqs, W) token ids
-                    flops, nbytes = segment_attention_cost(
-                        int(b), int(s), hidden=cfg.hidden,
-                        intermediate=cfg.intermediate, layers=cfg.layers)
-                    prof.record_dispatch(
-                        "segment_attention", flops, nbytes,
-                        (_perf_counter() - t0) * 1e3)
+                    cost = cfg.cost(int(b), int(s), ragged=True)
+                    if cost is not None:
+                        prof.record_dispatch(
+                            *cost, (_perf_counter() - t0) * 1e3)
             return outs[0] if len(outs) == 1 else jnp.concatenate(outs, 0)
         ids, lens = self.pack_tokens(texts)
         t0 = _perf_counter() if prof is not None else 0.0
-        out = self._encode_packed(self.params, jnp.asarray(ids),
-                                  jnp.asarray(lens))
+        out = self._embeddings(self._encode_packed(
+            self.params, jnp.asarray(ids), jnp.asarray(lens)))
         if prof is not None:
-            from pathway_tpu.engine.profiler import encoder_cost
-
             b, s = ids.shape
-            flops, nbytes = encoder_cost(
-                int(b), int(s), hidden=cfg.hidden,
-                intermediate=cfg.intermediate, layers=cfg.layers)
-            prof.record_dispatch("encoder_forward", flops, nbytes,
-                                 (_perf_counter() - t0) * 1e3)
+            cost = cfg.cost(int(b), int(s), ragged=False)
+            if cost is not None:
+                prof.record_dispatch(*cost, (_perf_counter() - t0) * 1e3)
         return out
 
     def embed_batch(self, texts: list[str]) -> np.ndarray:

@@ -1,0 +1,159 @@
+"""A tick's ingest drain stays bounded while the device is the slower side
+(engine/qos.py ``DeviceBackpressure``, the ingest budget of a runtime with
+no QoS armed): ``submit`` blocks on a full bridge window, and without a
+bound the next drain takes everything the source pushed meanwhile, so ticks
+grow until one leg holds the whole backlog."""
+
+from __future__ import annotations
+
+import time as _time
+
+import pytest
+
+import pathway_tpu as pw
+from pathway_tpu.engine import qos
+from pathway_tpu.engine.device_bridge import DeviceBridge
+from pathway_tpu.internals import schema as sch
+from pathway_tpu.internals.parse_graph import G
+from pathway_tpu.testing.faults import flaky_subject
+
+ROWS = 3000
+PER_ROW_S = 0.0005     # the "device": 2,000 rows/s
+PUSH_EVERY_S = 0.0001  # the source: several times faster
+
+
+@pytest.fixture(autouse=True)
+def _fresh():
+    G.clear()
+    yield
+    G.clear()
+
+
+def _run(monkeypatch, rows: int, per_row_s: float, push_every_s: float):
+    """Every batch the device UDF saw, and every row that came out."""
+    monkeypatch.setenv("PATHWAY_DEVICE_INFLIGHT", "2")
+    subject = flaky_subject([{"x": float(i)} for i in range(rows)],
+                            fail_after=0, fail_attempts=0,
+                            delay_s=push_every_s)
+    batches: list[int] = []
+
+    @pw.udf(batch=True, device=True, deterministic=True, return_type=float)
+    def producer(xs):
+        batches.append(len(xs))
+        _time.sleep(per_row_s * len(xs))
+        return [2.0 * x for x in xs]
+
+    t = pw.io.python.read(subject, schema=sch.schema_from_types(x=float),
+                          autocommit_duration_ms=10)
+    out = t.select(x=t.x, y=producer(t.x))
+    seen = {}
+
+    def on_change(key, row, time, is_addition):
+        if is_addition:
+            seen[row["x"]] = row["y"]
+
+    pw.io.subscribe(out, on_change)
+    pw.run()
+    return batches, seen
+
+
+def test_ticks_stay_bounded_behind_a_slow_device(monkeypatch):
+    batches, seen = _run(monkeypatch, ROWS, PER_ROW_S, PUSH_EVERY_S)
+    # every row arrives, once
+    assert seen == {float(i): 2.0 * i for i in range(ROWS)}
+    assert sum(batches) == ROWS
+    # unbounded, each tick holds what the source pushed during the leg
+    # before the last, several times its rows, and the fourth or fifth
+    # tick holds all that is left. Bounded, a leg lasts about LEG_TICKS
+    # commit intervals (160 rows at 2,000 rows/s) once the window has been
+    # found full, whatever the first ticks swallowed
+    late = batches[len(batches) // 2:]
+    assert len(batches) >= 12, batches
+    assert max(late) <= 400, batches
+    assert max(batches) < ROWS // 2, batches
+
+
+def test_a_device_that_keeps_up_is_never_held_back(monkeypatch):
+    """No leg outlasts a tick: the window is never full, the bound never
+    arms, and each tick takes all there is."""
+    blocked = []
+    submit = DeviceBridge.submit
+
+    def watched(self, tick, fn):
+        submit(self, tick, fn)
+        blocked.append(self.submits_blocked)
+
+    monkeypatch.setattr(DeviceBridge, "submit", watched)
+    batches, seen = _run(monkeypatch, 400, 0.0, PUSH_EVERY_S)
+    assert len(seen) == 400 and sum(batches) == 400
+    assert blocked and blocked[-1] == 0
+    from pathway_tpu.engine import streaming
+
+    assert all(rt._backpressure.ingest_row_budget() is None
+               for rt in streaming.live_runtimes())
+
+
+def test_bridge_counts_the_submits_that_waited():
+    bridge = DeviceBridge(max_inflight=2)
+    for tick in range(1, 6):
+        bridge.submit(tick, lambda: _time.sleep(0.02))
+    bridge.barrier()
+    stats = bridge.stats()
+    assert stats["resolved_watermark"] == 5
+    # the first two found room; the rest waited for a leg to retire
+    assert stats["submits_blocked"] == 3
+    bridge.close()
+
+
+def _look(limiter, tick, rows, *, watermark, exec_ms, blocked,
+          deferred=True, queries=0):
+    limiter.on_tick(tick, ingest_rows=rows, query_rows=queries,
+                    deferred=deferred,
+                    bridge={"resolved_watermark": watermark,
+                            "exec_ms": exec_ms, "submits_blocked": blocked})
+    return limiter.ingest_row_budget()
+
+
+def test_the_bound_stands_from_the_first_submit_that_waits():
+    """Three ticks after a release: two find room in the window, the third
+    waits for the first leg, and that leg's cost sets the bound at once."""
+    limiter = qos.DeviceBackpressure(0.05)
+    leg_ms = qos.LEG_TICKS * 50.0
+    assert _look(limiter, 1, 60, watermark=0, exec_ms=0.0, blocked=0) is None
+    assert _look(limiter, 2, 60, watermark=0, exec_ms=0.0, blocked=0) is None
+    # 60 rows took 420 ms: 7 ms a row
+    assert _look(limiter, 3, 60, watermark=1, exec_ms=420.0,
+                 blocked=1) == int(leg_ms / 7.0)
+    # a submit that did not wait raises the bound by half while rows are
+    # held back, and a drain that left nothing behind lifts it
+    bound = limiter.ingest_row_budget()
+    assert _look(limiter, 4, bound, watermark=3, exec_ms=1260.0,
+                 blocked=1) == bound + (bound + 1) // 2
+    assert _look(limiter, 5, 10, watermark=4, exec_ms=1660.0, blocked=1,
+                 deferred=False) is None
+
+
+def test_a_leg_that_compiled_or_served_queries_is_no_reading():
+    """The padded encoder path compiles nearly every tick: its legs are
+    long whatever they hold, and a bound would shrink ticks without
+    shortening them."""
+    limiter = qos.DeviceBackpressure(0.05)
+    compile_s = [0.0]
+    limiter._compile_s = lambda: compile_s[0]
+    _look(limiter, 1, 500, watermark=0, exec_ms=0.0, blocked=0)
+    _look(limiter, 2, 50, watermark=0, exec_ms=0.0, blocked=0, queries=4)
+    # 550 of the first leg's 600 ms were XLA's: the submit waited for the
+    # compiler, not for the device
+    compile_s[0] += 0.55
+    assert _look(limiter, 3, 50, watermark=1, exec_ms=600.0,
+                 blocked=1) is None
+    assert limiter._cost.ms_per_row is None
+    # a leg that served queries beside its rows is no cost sample either,
+    # and with no sample a submit that waited bounds nothing
+    assert _look(limiter, 4, 50, watermark=2, exec_ms=900.0,
+                 blocked=2) is None
+    # 20 of this leg's 120 ms were a small program's compile: taken out,
+    # 50 rows took 100 ms
+    compile_s[0] += 0.02
+    assert _look(limiter, 5, 50, watermark=3, exec_ms=1020.0,
+                 blocked=3) == int(qos.LEG_TICKS * 50.0 / 2.0)

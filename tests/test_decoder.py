@@ -1,0 +1,298 @@
+"""The hybrid decoder as an embedder (models/decoder.py, ops/deltanet.py,
+ops/moe.py) against its plain reference (benchmark/reference/qwen3_next.py:
+token-by-token recurrence, every held expert through a mask, one document
+at a time), at a small size on the CPU: hidden 64, one period of four
+layers, 8 experts top-2, 2 key/value heads."""
+
+from __future__ import annotations
+
+import os
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
+from benchmark.reference import qwen3_next as reference  # noqa: E402
+from pathway_tpu.models import decoder  # noqa: E402
+from pathway_tpu.ops import deltanet, moe  # noqa: E402
+
+CONFIG = decoder.DecoderConfig.tiny(compute_dtype=jnp.float32)
+#: the same model as the benchmark's configuration files state one
+REF_CONFIG = dict(
+    vocab_size=CONFIG.vocab_size, hidden_size=64, num_hidden_layers=4,
+    full_attention_interval=4, rms_norm_eps=1e-6, num_attention_heads=4,
+    num_key_value_heads=2, head_dim=32, partial_rotary_factor=0.25,
+    rope_theta=1e7, linear_num_key_heads=2, linear_num_value_heads=4,
+    linear_key_head_dim=16, linear_value_head_dim=16,
+    linear_conv_kernel_dim=4, num_experts=8, num_experts_routed=8,
+    experts_held=[0, 8], num_experts_per_tok=2, moe_intermediate_size=32,
+    shared_expert_intermediate_size=32, norm_topk_prob=True)
+
+
+@pytest.fixture(scope="module")
+def weights():
+    return reference.weights(REF_CONFIG, 7)
+
+
+@pytest.fixture(autouse=True)
+def _float32_products():
+    with jax.default_matmul_precision("highest"):
+        yield
+
+
+def _one_minus_cos(a, b):
+    a, b = np.asarray(a, np.float64), np.asarray(b, np.float64)
+    return 1.0 - np.sum(a * b, axis=1) / (
+        np.linalg.norm(a, axis=1) * np.linalg.norm(b, axis=1))
+
+
+def test_reference_weights_depend_on_the_seed_alone(weights):
+    again = reference.weights(REF_CONFIG, 7)
+    other = reference.weights(REF_CONFIG, 8)
+    leaves = jax.tree_util.tree_leaves
+    assert all(np.array_equal(a, b)
+               for a, b in zip(leaves(weights), leaves(again)))
+    assert not np.array_equal(weights["embed"], other["embed"])
+    tree = lambda t: jax.tree_util.tree_map(lambda a: a.shape, t)
+    assert tree(weights) == tree(decoder.init_params(
+        jax.random.PRNGKey(0), CONFIG))
+    assert abs(float(weights["embed"].std()) - 0.02) < 1e-3
+
+
+def test_padded_batch_agrees_with_the_reference(weights):
+    rng = np.random.default_rng(0)
+    lens = np.array([200, 65, 64, 63, 1, 130])
+    ids = rng.integers(0, CONFIG.vocab_size, (len(lens), 256)).astype(
+        np.int32)
+    mask = np.arange(256)[None] < lens[:, None]
+    got, load = jax.jit(CONFIG.encode)(weights, ids, mask)
+    want = reference.embed(weights, ids, lens, REF_CONFIG)
+    assert _one_minus_cos(got, want).max() < 1e-5
+    # every real token chose two experts in each of four layers; padding
+    # chose none
+    assert int(load.sum()) == int(lens.sum()) * 2 * 4
+
+
+def _embedder(weights, **kw):
+    from pathway_tpu.xpacks.llm.embedders import JaxEncoderEmbedder
+
+    config = decoder.DecoderConfig.tiny(compute_dtype=jnp.float32,
+                                        max_len=128)
+    return JaxEncoderEmbedder(config=config, params=jax.device_put(weights),
+                              max_len=128, **kw)
+
+
+def _texts(lengths):
+    rng = np.random.default_rng(1)
+    return [" ".join(f"w{rng.integers(0, 300)}" for _ in range(n))
+            for n in lengths]
+
+
+def test_a_packed_row_of_three_documents_equals_the_three_alone(weights):
+    """The state-reset test: recurrent state, convolution, rotary positions
+    and attention's reach all restart at a document's first token."""
+    emb = _embedder(weights, ragged=True, ragged_max_seqs=2)
+    texts = _texts((30, 50, 20, 100, 3, 60))
+    packs = emb.pack_ragged(texts)
+    (ids, doc_map, _pos, doc_seq, doc_off), n_docs, _n_pad = packs[0]
+    assert (doc_seq[:3] == 0).all() and n_docs > 3      # three in row 0
+    # last-token pooling: the packer hands the last token's offset
+    assert doc_map[0, doc_off[0]] == 0 and doc_map[0, doc_off[0] + 1] == 1
+    together = np.asarray(emb.encode_batch_device(texts))
+    alone = np.concatenate([np.asarray(emb.encode_batch_device([t]))
+                            for t in texts])
+    assert np.abs(together - alone).max() < 1e-5
+    ids, mask = emb.tokenizer.batch(texts, max_len=128)
+    want = reference.embed(weights, ids, mask.sum(axis=1), REF_CONFIG)
+    assert _one_minus_cos(together, want).max() < 1e-5
+    padded = _embedder(weights, ragged=False)
+    assert _one_minus_cos(padded.encode_batch_device(texts),
+                          want).max() < 1e-5
+
+
+def test_expert_load_is_summed_on_the_device_and_fetched_on_request(weights):
+    from pathway_tpu.xpacks.llm import embedders
+
+    emb = _embedder(weights, ragged=True, ragged_max_seqs=2)
+    assert emb.expert_load() is None
+    texts = _texts((30, 50, 20))
+    emb.encode_batch_device(texts)
+    load = emb.expert_load()
+    ids, mask = emb.tokenizer.batch(texts, max_len=128)
+    assert load["dispatches"] == 1
+    assert load["tokens_per_expert"].shape == (8,)
+    assert int(load["tokens_per_expert"].sum()) == int(mask.sum()) * 2 * 4
+    stats = embedders.expert_load_stats()
+    assert stats["max"] >= stats["mean"] > 0 and stats["dispatches"] >= 1
+
+
+def test_the_shares_of_an_expert_layer_sum_to_the_whole(weights):
+    """Experts 0-3 on one chip and 4-7 on another, each routing over all
+    eight: their routed parts plus the shared expert, counted once, are the
+    uncut layer, which is the reference's."""
+    p = jax.tree_util.tree_map(jnp.asarray, weights["layers"][0]["moe"])
+    x = jnp.asarray(np.random.default_rng(2).standard_normal(
+        (2, 24, 64)).astype(np.float32))
+    valid = jnp.ones((2, 24), bool)
+
+    def layer(lo, hi):
+        part = dict(p, gate=p["gate"][lo:hi], up=p["up"][lo:hi],
+                    down=p["down"][lo:hi])
+        config = decoder.DecoderConfig.tiny(compute_dtype=jnp.float32,
+                                            experts_held=(lo, hi))
+        return decoder.moe_layer(x, part, valid, config)
+
+    whole, load = layer(0, 8)
+    (low, load_low), (high, load_high) = layer(0, 4), layer(4, 8)
+    flat = x.reshape(-1, 64)
+    shared = ((jax.nn.silu(flat @ p["shared_gate"]) * (flat @ p["shared_up"]))
+              @ p["shared_down"]) * jax.nn.sigmoid(flat @ p["shared_router"])
+    assert np.allclose(low + high - shared.reshape(x.shape), whole,
+                       atol=1e-6)
+    assert np.array_equal(np.concatenate([load_low, load_high]), load)
+    assert int(load.sum()) == 2 * 24 * 2
+    sizes = reference._sizes(REF_CONFIG)
+    want = np.stack([np.asarray(reference._moe(row, p, sizes, jnp.matmul))
+                     for row in x])
+    assert np.allclose(whole, want, atol=1e-6)
+    # a share's reference holds the same share
+    half = reference._sizes(dict(REF_CONFIG, experts_held=[4, 8]))
+    part = dict(p, gate=p["gate"][4:], up=p["up"][4:], down=p["down"][4:])
+    want_high = np.stack([np.asarray(reference._moe(row, part, half,
+                                                    jnp.matmul))
+                          for row in x])
+    assert np.allclose(high, want_high, atol=1e-6)
+
+
+def test_grouped_product_drops_no_token_when_one_expert_takes_all():
+    """A router that sends every token to expert 5: the grouped product
+    against a loop over the tokens."""
+    rng = np.random.default_rng(3)
+    n, h, f, e = 40, 16, 8, 8
+    x = jnp.asarray(rng.standard_normal((n, h)).astype(np.float32))
+    w_gate, w_up = (jnp.asarray(rng.standard_normal((e, h, f)).astype(
+        np.float32)) for _ in range(2))
+    w_down = jnp.asarray(rng.standard_normal((e, f, h)).astype(np.float32))
+    router = np.zeros((h, e), np.float32)
+    router[:, 5] = 1.0
+    weights, experts = moe.route(jnp.abs(x), jnp.asarray(router), 2, True)
+    assert (np.asarray(experts[:, 0]) == 5).all()
+    got, sizes = moe.grouped_experts(x, weights, experts, w_gate, w_up,
+                                     w_down, (0, e))
+    assert int(sizes[5]) == n and int(sizes.sum()) == 2 * n
+    want = np.zeros((n, h), np.float32)
+    for t in range(n):
+        for w, ex in zip(np.asarray(weights[t]), np.asarray(experts[t])):
+            hidden = jax.nn.silu(x[t] @ w_gate[ex]) * (x[t] @ w_up[ex])
+            want[t] += w * np.asarray(hidden @ w_down[ex])
+    assert np.allclose(got, want, rtol=1e-5, atol=1e-5)
+    # held elsewhere: nothing is added here, and nothing is counted
+    got, sizes = moe.grouped_experts(x, weights, experts, w_gate[:2],
+                                     w_up[:2], w_down[:2], (6, 8))
+    mask = np.asarray(experts) >= 6
+    assert int(sizes.sum()) == int(mask.sum())
+    assert np.allclose(np.asarray(got)[~mask.any(axis=1)], 0.0)
+
+
+def _recurrence(q, k, v, g, beta, starts):
+    """Token by token, one row: the definition."""
+    t, h, dk = q.shape
+    state = np.zeros((h, dk, v.shape[-1]), np.float64)
+    out = np.zeros(v.shape, np.float64)
+    for i in range(t):
+        if starts[i]:
+            state[:] = 0.0
+        state *= np.exp(g[i])[:, None, None]
+        read = np.einsum("hk,hkv->hv", k[i], state)
+        state += np.einsum("hk,hv->hkv", k[i],
+                           (v[i] - read) * beta[i][:, None])
+        out[i] = np.einsum("hk,hkv->hv", q[i], state)
+    return out
+
+
+@pytest.mark.parametrize("length", [1, 63, 64, 65, 200])
+def test_chunked_scan_equals_the_recurrence(length):
+    rng = np.random.default_rng(length)
+    b, h, dk, dv = 2, 3, 8, 8
+    q, k = (rng.standard_normal((b, length, h, dk)) for _ in range(2))
+    q, k = (a / np.linalg.norm(a, axis=-1, keepdims=True) for a in (q, k))
+    v = rng.standard_normal((b, length, h, dv))
+    g = -rng.uniform(0.0, 2.0, (b, length, h))
+    beta = rng.uniform(0.0, 1.0, (b, length, h))
+    starts = rng.random((b, length)) < 0.03
+    starts[:, 0] = True
+    got = np.asarray(deltanet.gated_delta_rule(
+        *(jnp.asarray(a, jnp.float32) for a in (q, k, v, g, beta)),
+        jnp.asarray(starts)))
+    assert got.shape == (b, length, h, dv)
+    for row in range(b):
+        want = _recurrence(q[row], k[row], v[row], g[row], beta[row],
+                           starts[row])
+        assert np.allclose(got[row], want, atol=2e-4), \
+            np.abs(got[row] - want).max()
+
+
+def test_causal_convolution_restarts_at_a_document():
+    rng = np.random.default_rng(4)
+    x = rng.standard_normal((1, 12, 5)).astype(np.float32)
+    w = rng.standard_normal((4, 5)).astype(np.float32)
+    pos = np.array([[0, 1, 2, 3, 4, 0, 1, 2, 3, 4, 5, 6]], np.int32)
+    got = np.asarray(deltanet.causal_conv(jnp.asarray(x), jnp.asarray(w),
+                                          jnp.asarray(pos)))[0]
+    for lo, hi in ((0, 5), (5, 12)):
+        doc = np.pad(x[0, lo:hi], ((3, 0), (0, 0)))
+        want = sum(doc[i:i + hi - lo] * w[i] for i in range(4))
+        assert np.allclose(got[lo:hi], want, atol=1e-6)
+
+
+def test_expert_load_is_exposed_on_metrics(weights):
+    """/metrics names the busiest and the mean expert and the dispatches
+    counted, once an embedder that routes tokens has run."""
+    from test_monitoring_http import (_FakeRuntime, _metrics_lines,
+                                      _parse_samples)
+
+    emb = _embedder(weights, ragged=True, ragged_max_seqs=2)
+    emb.encode_batch_device(_texts((30, 50)))
+    samples = {f: v for f, _labels, v in
+               _parse_samples(_metrics_lines(_FakeRuntime()))}
+    assert samples["pathway_tpu_moe_tokens_per_expert_max"] \
+        >= samples["pathway_tpu_moe_tokens_per_expert_mean"] > 0
+    assert samples["pathway_tpu_moe_dispatches"] >= 1
+
+
+def test_a_fused_dispatch_writes_an_embedder_dispatch_span(weights,
+                                                           monkeypatch):
+    """``embedder.dispatch`` (tokens, docs, rows) around each fused
+    dispatch, in the live recorder's store, under the leg in flight; the
+    expert load comes back from the fused step too. No recorder, no span."""
+    from pathway_tpu.engine.flight_recorder import FlightRecorder
+    from pathway_tpu.internals.keys import Pointer
+    from pathway_tpu.ops.knn import (BruteForceKnnIndex,
+                                     DeviceEmbeddingKnnIndex)
+
+    emb = _embedder(weights, ragged=True, ragged_max_seqs=2)
+    index = DeviceEmbeddingKnnIndex(
+        emb, BruteForceKnnIndex(64, reserved_space=256, metric="cos"))
+    texts = _texts((30, 50, 20, 100, 3, 60))
+    index.add_batch([Pointer(i) for i in range(3)], texts[:3])
+    assert index.fused_batches == 1 and emb.expert_load()["dispatches"] == 1
+    monkeypatch.setenv("PATHWAY_FLIGHT_RECORDER", "1")
+    rec = FlightRecorder.from_env()
+    rec.mark_leg(41)
+    index.add_batch([Pointer(10 + i) for i in range(6)], texts)
+    rec.clear_leg()
+    spans = [sp for sp in rec.spans() if sp[0] == "embedder.dispatch"]
+    _ids, mask = emb.tokenizer.batch(texts, max_len=128)
+    assert [sp[3] for sp in spans] == [("tick", 41)] * 2
+    assert sum(sp[5]["docs"] for sp in spans) == 6
+    assert sum(sp[5]["tokens"] for sp in spans) == int(mask.sum())
+    assert [sp[5]["rows"] for sp in spans] == [2, 1]
+    assert len(index) == 9 and index.fused_fallbacks == 0
+    rec.enabled = False
+    # the rows are the embedder's: a document finds itself first
+    ((key, _score),), = index.search([(Pointer(99), texts[3], 1, None)])
+    assert key == Pointer(13)
