@@ -673,6 +673,14 @@ class MonitoringHttpServer:
             lines.append("# TYPE pathway_tpu_moe_dispatches counter")
             lines.append(
                 f"pathway_tpu_moe_dispatches {experts['dispatches']}")
+            # the routed experts' pair buffer (ops/moe.py): how often a
+            # layer fell back to a buffer as long as every pair
+            lines.append("# TYPE pathway_tpu_moe_full_buffer_layers counter")
+            lines.append(f"pathway_tpu_moe_full_buffer_layers "
+                         f"{experts['full_buffer_layers']}")
+            lines.append("# TYPE pathway_tpu_moe_buffer_rows_mean gauge")
+            lines.append(f"pathway_tpu_moe_buffer_rows_mean "
+                         f"{experts['buffer_rows_mean']}")
         paged = _paged_stats()
         if paged is not None:
             # paged vector store occupancy (engine/paged_store.py): pool

@@ -272,34 +272,43 @@ def attention_layer(x, p, pos, seg, config: DecoderConfig):
 def moe_layer(x, p, valid, config: DecoderConfig):
     """Routed experts (the held range's part) plus the shared expert.
     x (B, T, H) normed input; valid (B, T) False at padding. Returns
-    (y (B, T, H) float32, tokens each held expert took)."""
+    (y (B, T, H) float32, tokens each held expert took, what this
+    execution adds to the pair buffer's counters: ``moe.buffer_use``)."""
     c = config
     b, t, h = x.shape
     flat = x.reshape(b * t, h).astype(c.compute_dtype)
+    lo, hi = c.held
+    lengths = moe.buffer_lengths(b * t * c.num_experts_per_tok,
+                                 (hi - lo) / c.num_experts)
     with jax.named_scope("decoder.moe.route"):
         weights, experts = moe.route(flat, p["router"],
                                      c.num_experts_per_tok, c.norm_topk_prob)
     with jax.named_scope("decoder.moe.experts"):
         routed, load = moe.grouped_experts(
             flat, weights, experts, p["gate"], p["up"], p["down"], c.held,
-            valid.reshape(b * t))
+            valid.reshape(b * t), lengths)
+        buffer = moe.buffer_use(load, lengths)
     with jax.named_scope("decoder.moe.shared"):
         hidden = jax.nn.silu(_proj(flat, p["shared_gate"], c)) \
             * _proj(flat, p["shared_up"], c)
         shared = _proj(hidden, p["shared_down"], c) * jax.nn.sigmoid(
             _proj(flat, p["shared_router"], c))
-    return (routed + shared).reshape(b, t, h), load
+    return (routed + shared).reshape(b, t, h), load, buffer
 
 
 def _forward(params, token_ids, pos, seg, config: DecoderConfig):
     """Embedding + stack -> (final-norm hidden states (B, T, H) float32,
-    tokens each held expert took, summed over the layers)."""
+    the expert layers' counters summed over the layers:
+    ``{"tokens_per_expert": (held,) int32, "buffer": (3,) float32
+    [executions, those at the full length, pair-buffer rows]}``, which an
+    embedder sums as its ``aux``)."""
     c = config
     with jax.named_scope("decoder.embed"):
         x = params["embed"][token_ids].astype(jnp.float32)
     valid = seg >= 0
     lo, hi = c.held
     load = jnp.zeros((hi - lo,), jnp.int32)
+    buffer = jnp.zeros((3,), jnp.float32)
     for i, layer in enumerate(params["layers"]):
         normed = _rms_norm(x, layer["norm1"], c.rms_norm_eps)
         if c.is_attention(i):
@@ -308,10 +317,12 @@ def _forward(params, token_ids, pos, seg, config: DecoderConfig):
         else:
             with jax.named_scope("decoder.deltanet"):
                 x = x + deltanet_layer(normed, layer["mixer"], pos, c)
-        y, took = moe_layer(_rms_norm(x, layer["norm2"], c.rms_norm_eps),
-                            layer["moe"], valid, c)
-        x, load = x + y, load + took
-    return _rms_norm(x, params["final_norm"], c.rms_norm_eps), load
+        y, took, used = moe_layer(
+            _rms_norm(x, layer["norm2"], c.rms_norm_eps), layer["moe"],
+            valid, c)
+        x, load, buffer = x + y, load + took, buffer + used
+    return (_rms_norm(x, params["final_norm"], c.rms_norm_eps),
+            {"tokens_per_expert": load, "buffer": buffer})
 
 
 def _pool(x, rows, at, config: DecoderConfig):
@@ -324,24 +335,27 @@ def _pool(x, rows, at, config: DecoderConfig):
 
 
 def encode(params, token_ids, attention_mask, *, config: DecoderConfig):
-    """Padded batch -> ((B, H) float32 embeddings, expert load).
+    """Padded batch -> ((B, H) float32 embeddings, the expert layers'
+    counters: :func:`_forward`).
     token_ids, attention_mask (B, T): a row is one document, its real
     tokens first."""
     b, t = token_ids.shape
     mask = attention_mask.astype(bool)
     pos = jnp.broadcast_to(jnp.arange(t, dtype=jnp.int32), (b, t))
     seg = jnp.where(mask, jnp.arange(b, dtype=jnp.int32)[:, None], -1)
-    x, load = _forward(params, token_ids.astype(jnp.int32), pos, seg, config)
+    x, counters = _forward(params, token_ids.astype(jnp.int32), pos, seg,
+                           config)
     last = jnp.maximum(jnp.sum(mask, axis=1) - 1, 0)
-    return _pool(x, jnp.arange(b), last, config), load
+    return _pool(x, jnp.arange(b), last, config), counters
 
 
 def encode_ragged(params, token_ids, doc_map, position_ids, doc_seq,
                   doc_off, *, config: DecoderConfig):
-    """Ragged-packed rows -> ((n_docs, H) float32 embeddings, expert
-    load). The operands are ``models/encoder.py`` ``encode_ragged``'s;
-    ``doc_off`` is the offset of the token that is pooled, which under
-    last-token pooling the packer sets to a document's last."""
-    x, load = _forward(params, token_ids.astype(jnp.int32), position_ids,
-                       doc_map, config)
-    return _pool(x, doc_seq, doc_off, config), load
+    """Ragged-packed rows -> ((n_docs, H) float32 embeddings, the expert
+    layers' counters). The operands are ``models/encoder.py``
+    ``encode_ragged``'s; ``doc_off`` is the offset of the token that is
+    pooled, which under last-token pooling the packer sets to a document's
+    last."""
+    x, counters = _forward(params, token_ids.astype(jnp.int32),
+                           position_ids, doc_map, config)
+    return _pool(x, doc_seq, doc_off, config), counters

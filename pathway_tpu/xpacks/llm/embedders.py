@@ -11,6 +11,7 @@ tokenized and encoded in one device call, never per row.
 from __future__ import annotations
 
 import asyncio
+import operator
 import threading
 import weakref
 from time import perf_counter as _perf_counter
@@ -44,15 +45,23 @@ _AUX_EMBEDDERS: "weakref.WeakSet" = weakref.WeakSet()
 
 def expert_load_stats() -> dict | None:
     """Expert load of the live embedders whose model routes tokens to
-    experts: {"max", "mean" tokens an expert, "dispatches"} over all of
-    them; None where there is none. Fetches from the device."""
+    experts: {"max", "mean" tokens an expert, "dispatches",
+    "full_buffer_layers": expert-layer executions whose pair buffer took
+    its full length, "buffer_rows_mean": the buffer's rows an execution
+    (ops/moe.py)} over all of them; None where there is none. Fetches from
+    the device."""
     loads = [ld for e in list(_AUX_EMBEDDERS)
              if (ld := e.expert_load()) is not None]
     if not loads:
         return None
     tokens = np.concatenate([ld["tokens_per_expert"] for ld in loads])
+    layers = sum(ld["expert_layers"] for ld in loads)
     return {"max": float(tokens.max()), "mean": float(tokens.mean()),
-            "dispatches": sum(ld["dispatches"] for ld in loads)}
+            "dispatches": sum(ld["dispatches"] for ld in loads),
+            "full_buffer_layers": sum(ld["full_buffer_layers"]
+                                      for ld in loads),
+            "buffer_rows_mean": sum(ld["buffer_rows"] for ld in loads)
+            / layers if layers else 0.0}
 
 
 class JaxEncoderEmbedder(BaseEmbedder):
@@ -69,8 +78,10 @@ class JaxEncoderEmbedder(BaseEmbedder):
     or None), ``hidden``, ``vocab_size``, ``max_len`` and ``pooling``
     (models/encoder.py ``EncoderConfig``, the default;
     models/decoder.py ``DecoderConfig``). A forward may return ``(embeddings,
-    aux)``: ``aux`` is a small device array (a decoder's tokens per expert)
-    that is summed on the device and fetched only by :meth:`expert_load`.
+    aux)``: ``aux`` is a decoder's expert-layer counters, a dict of small
+    device arrays (``tokens_per_expert``; ``buffer``: executions, those at
+    the full pair-buffer length, buffer rows), summed on the device and
+    fetched only by :meth:`expert_load`.
 
     ``ragged_max_seqs``: packed rows a ragged dispatch holds at most
     (default ``PATHWAY_RAGGED_MAX_SEQS``, else 8).
@@ -114,11 +125,17 @@ class JaxEncoderEmbedder(BaseEmbedder):
         self.tokenizer = tokenizer or HashTokenizer(
             vocab_size=self.config.vocab_size, max_len=max_len)
         self.max_len = min(max_len, self.config.max_len)
-        # tokens per held expert, summed over every dispatch of a model
-        # with routed experts: one device array, never fetched in a tick
+        # the expert layers' counters, summed over every dispatch of a
+        # model with routed experts: device arrays, never fetched in a tick
         self._aux_lock = threading.Lock()
         self._aux_sum = None
         self._aux_dispatches = 0
+
+        def add_aux(total, aux):
+            return jax.tree.map(operator.add, total, aux)
+
+        # one program and one dispatch however many arrays ``aux`` holds
+        self._add_aux = jax.jit(add_aux)
         # packed hot path: int16 ids + per-row lengths instead of int32
         # ids + a (B, S) bool mask — a quarter of the host→device bytes;
         # the mask is rebuilt on device (iota < len). Usable whenever the
@@ -208,15 +225,15 @@ class JaxEncoderEmbedder(BaseEmbedder):
                                          doc_seq, doc_off)
 
     def note_producer_aux(self, aux) -> None:
-        """Sum what a forward returned beside its embeddings into the one
-        device array this embedder keeps (a small asynchronous add: no
-        transfer, no wait)."""
+        """Sum what a forward returned beside its embeddings into the
+        device arrays this embedder keeps (one small asynchronous
+        dispatch: no transfer, no wait)."""
         with self._aux_lock:
             if self._aux_sum is None:
                 _AUX_EMBEDDERS.add(self)
                 self._aux_sum = aux
             else:
-                self._aux_sum = self._aux_sum + aux
+                self._aux_sum = self._add_aux(self._aux_sum, aux)
             self._aux_dispatches += 1
 
     def _embeddings(self, out):
@@ -228,15 +245,19 @@ class JaxEncoderEmbedder(BaseEmbedder):
 
     def expert_load(self) -> dict | None:
         """Tokens each held expert took, summed over every dispatch so
-        far and every layer, fetched from the device now (the one
-        transfer: call it from a metrics request, not from a tick). None
-        where the model routes nothing."""
+        far and every layer, and the pair buffer's counters (ops/moe.py:
+        expert-layer executions, those that took the full length, the
+        buffer's rows summed over them), fetched from the device now (the
+        one transfer: call it from a metrics request, not from a tick).
+        None where the model routes nothing."""
         with self._aux_lock:
             total, dispatches = self._aux_sum, self._aux_dispatches
         if total is None:
             return None
-        return {"tokens_per_expert": np.asarray(total),
-                "dispatches": dispatches}
+        layers, full, rows = np.asarray(total["buffer"]).tolist()
+        return {"tokens_per_expert": np.asarray(total["tokens_per_expert"]),
+                "dispatches": dispatches, "expert_layers": int(layers),
+                "full_buffer_layers": int(full), "buffer_rows": rows}
 
     def ragged_buckets(self) -> list[int]:
         """Sequence-count buckets the ragged path can dispatch: powers of

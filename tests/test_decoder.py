@@ -74,7 +74,12 @@ def test_padded_batch_agrees_with_the_reference(weights):
     assert _one_minus_cos(got, want).max() < 1e-5
     # every real token chose two experts in each of four layers; padding
     # chose none
-    assert int(load.sum()) == int(lens.sum()) * 2 * 4
+    assert int(load["tokens_per_expert"].sum()) == int(lens.sum()) * 2 * 4
+    # four expert layers, each with two thirds of the slots padding: the
+    # pair buffer took its short length (2,560 of 6 x 256 x 2 rows), never
+    # the full one
+    assert moe.buffer_lengths(6 * 256 * 2, 1.0) == (2560, 3072)
+    assert load["buffer"].tolist() == [4.0, 0.0, 4 * 2560.0]
 
 
 def _embedder(weights, **kw):
@@ -114,20 +119,50 @@ def test_a_packed_row_of_three_documents_equals_the_three_alone(weights):
                           want).max() < 1e-5
 
 
-def test_expert_load_is_summed_on_the_device_and_fetched_on_request(weights):
+def _buffer_expected(emb, texts):
+    """(expert-layer executions, those at the full length, buffer rows)
+    of embedding ``texts`` with every expert held: a real token's two
+    pairs are both held, and each of four layers takes the shortest length
+    that holds a dispatch's."""
+    layers = full = rows = 0
+    for (ids, doc_map, *_), _n_docs, _n_pad in emb.pack_ragged(texts):
+        lengths = moe.buffer_lengths(ids.size * 2, 1.0)
+        took = min(c for c in lengths if c >= 2 * int((doc_map >= 0).sum()))
+        layers, rows = layers + 4, rows + 4 * took
+        full += 4 * (took == lengths[-1])
+    return layers, full, rows
+
+
+@pytest.mark.parametrize("lengths, full_layers", [
+    ((30, 50, 20), 0),          # most of one row: the short buffer
+    ((30, 50, 20, 60, 70), 0),  # two dispatches, a third empty: the same
+    ((120, 126, 127), 8),       # two dispatches of full rows: every pair
+])
+def test_expert_load_is_summed_on_the_device_and_fetched_on_request(
+        weights, monkeypatch, lengths, full_layers):
     from pathway_tpu.xpacks.llm import embedders
 
+    # rows of 128 slots x 2: lengths (224, 256) for one, (416, 512) for two
+    monkeypatch.setattr(moe, "ROW_TILE", 32)
     emb = _embedder(weights, ragged=True, ragged_max_seqs=2)
     assert emb.expert_load() is None
-    texts = _texts((30, 50, 20))
+    texts = _texts(lengths)
     emb.encode_batch_device(texts)
+    # summed where they were made: nothing has come to the host yet
+    assert all(isinstance(a, jax.Array) for a in emb._aux_sum.values())
     load = emb.expert_load()
     ids, mask = emb.tokenizer.batch(texts, max_len=128)
-    assert load["dispatches"] == 1
+    assert load["dispatches"] == len(emb.pack_ragged(texts))
+    assert isinstance(load["tokens_per_expert"], np.ndarray)
     assert load["tokens_per_expert"].shape == (8,)
     assert int(load["tokens_per_expert"].sum()) == int(mask.sum()) * 2 * 4
+    assert (load["expert_layers"], load["full_buffer_layers"],
+            load["buffer_rows"]) == _buffer_expected(emb, texts)
+    assert load["full_buffer_layers"] == full_layers
     stats = embedders.expert_load_stats()
     assert stats["max"] >= stats["mean"] > 0 and stats["dispatches"] >= 1
+    assert stats["full_buffer_layers"] >= full_layers
+    assert 224 <= stats["buffer_rows_mean"] <= 512
 
 
 def test_the_shares_of_an_expert_layer_sum_to_the_whole(weights):
@@ -146,8 +181,10 @@ def test_the_shares_of_an_expert_layer_sum_to_the_whole(weights):
                                             experts_held=(lo, hi))
         return decoder.moe_layer(x, part, valid, config)
 
-    whole, load = layer(0, 8)
-    (low, load_low), (high, load_high) = layer(0, 4), layer(4, 8)
+    whole, load, used = layer(0, 8)
+    (low, load_low, _), (high, load_high, _) = layer(0, 4), layer(4, 8)
+    # 48 tokens x 2 pairs: too few rows for a short buffer
+    assert used.tolist() == [1.0, 1.0, 96.0]
     flat = x.reshape(-1, 64)
     shared = ((jax.nn.silu(flat @ p["shared_gate"]) * (flat @ p["shared_up"]))
               @ p["shared_down"]) * jax.nn.sigmoid(flat @ p["shared_router"])
@@ -196,6 +233,119 @@ def test_grouped_product_drops_no_token_when_one_expert_takes_all():
     mask = np.asarray(experts) >= 6
     assert int(sizes.sum()) == int(mask.sum())
     assert np.allclose(np.asarray(got)[~mask.any(axis=1)], 0.0)
+
+
+def _pairs(n, held_pairs, lo=0):
+    """(n, 2) experts of 8: the first ``held_pairs`` tokens choose one of
+    experts lo..lo+3 and one of the other four, the rest two of the other
+    four."""
+    i = np.arange(n)
+    other = (lo + 4 + i % 4) % 8
+    first = np.where(i < held_pairs, lo + i % 4, (lo + 4 + (i + 1) % 4) % 8)
+    return np.stack([first, other], axis=1).astype(np.int32)
+
+
+#: 64 tokens x 2: with half the experts held and a tile of 8 rows the pair
+#: buffer is 56, 72 or 128 rows long; with all held 104 or 128
+BUFFER_CASES = {
+    # name: (experts (64, 2), valid (64,) or None, held, branch)
+    "mostly_padding": (_pairs(64, 64), np.arange(64) < 16, (0, 4), 0),
+    "full_rows_even_routing": (_pairs(64, 64), None, (0, 4), 1),
+    "count_at_the_short_length": (_pairs(64, 56), None, (0, 4), 0),
+    "count_one_over_the_short_length": (_pairs(64, 57), None, (0, 4), 1),
+    "count_at_the_middle_length": (_pairs(64, 64), np.arange(64) != 9,
+                                   (4, 8), 1),
+    "count_one_over_the_middle_length": (
+        np.where(np.arange(64)[:, None] < 9, [[1, 2]], _pairs(64, 64)),
+        None, (0, 4), 2),
+    "every_pair_on_one_held_expert": (np.full((64, 2), 1, np.int32), None,
+                                      (0, 4), 2),
+    "all_held_a_quarter_padding": (_pairs(64, 64), np.arange(64) < 48,
+                                   (0, 8), 0),
+    "all_held_full_rows": (_pairs(64, 64), None, (0, 8), 1),
+}
+
+
+@pytest.mark.parametrize("case", BUFFER_CASES)
+def test_pair_buffer_takes_the_shortest_length_that_holds_the_held_pairs(
+        case, monkeypatch):
+    """``grouped_experts`` against the dense sum over a token's chosen
+    held experts, at every length of the buffer; the counter says which
+    length ran."""
+    experts, valid, (lo, hi), branch = BUFFER_CASES[case]
+    monkeypatch.setattr(moe, "ROW_TILE", 8)
+    rng = np.random.default_rng(5)
+    n, h, f = 64, 16, 8
+    x = rng.standard_normal((n, h)).astype(np.float32)
+    w_gate, w_up = (rng.standard_normal((hi - lo, h, f)).astype(np.float32)
+                    for _ in range(2))
+    w_down = rng.standard_normal((hi - lo, f, h)).astype(np.float32)
+    weights = rng.uniform(0.1, 1.0, (n, 2)).astype(np.float32)
+    lengths = moe.buffer_lengths(n * 2, (hi - lo) / 8)
+    assert lengths == ((56, 72, 128) if hi - lo == 4 else (104, 128))
+    got, sizes = jax.jit(moe.grouped_experts, static_argnums=(6, 8))(
+        x, weights, experts, w_gate, w_up, w_down, (lo, hi),
+        None if valid is None else jnp.asarray(valid), lengths)
+    want = np.zeros((n, h), np.float64)
+    count = np.zeros(hi - lo, np.int64)
+    for t in range(n):
+        if valid is not None and not valid[t]:
+            continue
+        for w, e in zip(weights[t], experts[t] - lo):
+            if 0 <= e < hi - lo:
+                hidden = np.asarray(jax.nn.silu(x[t] @ w_gate[e])) \
+                    * (x[t] @ w_up[e])
+                want[t] += w * (hidden @ w_down[e])
+                count[e] += 1
+    assert np.array_equal(sizes, count)
+    assert np.allclose(got, want, rtol=1e-5, atol=1e-5)
+    took = int(moe.buffer_branch(sizes, lengths))
+    assert took == branch and lengths[took] >= count.sum()
+    assert took == 0 or lengths[took - 1] < count.sum()
+    assert moe.buffer_use(sizes, lengths).tolist() == [
+        1.0, float(took == len(lengths) - 1), float(lengths[took])]
+    # no shorter length would have done: cut there the buffer loses a pair,
+    # and the token its part of the answer
+    if took:
+        order, _ = moe.group_by_expert(
+            jnp.asarray(experts), (lo, hi),
+            None if valid is None else jnp.asarray(valid))
+        short = moe._held_pairs(lengths[took - 1], x, weights, order,
+                                jnp.argsort(order), sizes, w_gate, w_up,
+                                w_down)
+        assert not np.allclose(short, want, rtol=1e-5, atol=1e-5)
+
+
+def test_the_experts_products_run_over_the_static_lengths():
+    """In ``moe_layer``'s program the grouped products' rows are the
+    buffer's static lengths, three products a length, each length a branch
+    of one switch under the experts' scope; the full length is one of
+    them in every program."""
+    config = decoder.DecoderConfig.tiny(compute_dtype=jnp.float32,
+                                        experts_held=(0, 4), max_len=512)
+    p = decoder.init_params(jax.random.PRNGKey(0), config)["layers"][0]["moe"]
+    x, valid = jnp.zeros((2, 512, 64)), jnp.ones((2, 512), bool)
+    layer = lambda x, p, valid: decoder.moe_layer(x, p, valid, config)
+    lengths = moe.buffer_lengths(2 * 512 * 2, 0.5)
+    assert lengths == (1024, 1280, 2048)
+
+    def products(jaxpr, found):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name.startswith("ragged_dot"):
+                found.append(eqn.invars[0].aval.shape[0])
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                products(sub, found)
+        return found
+
+    rows = products(jax.make_jaxpr(layer)(x, p, valid).jaxpr, [])
+    assert sorted(rows) == sorted(lengths * 3)
+    text = jax.jit(layer).lower(x, p, valid).as_text(debug_info=True)
+    for branch in range(3):
+        assert f"decoder.moe.experts/cond/branch_{branch}_fun" in text
+    # a handful of pairs keep the one length, and no switch
+    few = jax.make_jaxpr(layer)(x[:, :32], p, valid[:, :32])
+    assert products(few.jaxpr, []) == [128] * 3
+    assert "cond" not in {e.primitive.name for e in few.jaxpr.eqns}
 
 
 def _recurrence(q, k, v, g, beta, starts):
@@ -249,19 +399,31 @@ def test_causal_convolution_restarts_at_a_document():
         assert np.allclose(got[lo:hi], want, atol=1e-6)
 
 
-def test_expert_load_is_exposed_on_metrics(weights):
-    """/metrics names the busiest and the mean expert and the dispatches
-    counted, once an embedder that routes tokens has run."""
+@pytest.mark.parametrize("lengths", [(30, 50), (126, 127)])
+def test_expert_load_is_exposed_on_metrics(weights, monkeypatch, lengths):
+    """/metrics names the busiest and the mean expert, the dispatches
+    counted, and how long the experts' pair buffer was (most of a row: the
+    short length; two full rows: every pair), once an embedder that routes
+    tokens has run."""
     from test_monitoring_http import (_FakeRuntime, _metrics_lines,
                                       _parse_samples)
+    from pathway_tpu.xpacks.llm import embedders
 
+    monkeypatch.setattr(moe, "ROW_TILE", 32)
+    # the other tests' embedders are no part of this reading
+    monkeypatch.setattr(embedders, "_AUX_EMBEDDERS", set())
     emb = _embedder(weights, ragged=True, ragged_max_seqs=2)
-    emb.encode_batch_device(_texts((30, 50)))
+    texts = _texts(lengths)
+    emb.encode_batch_device(texts)
     samples = {f: v for f, _labels, v in
                _parse_samples(_metrics_lines(_FakeRuntime()))}
     assert samples["pathway_tpu_moe_tokens_per_expert_max"] \
         >= samples["pathway_tpu_moe_tokens_per_expert_mean"] > 0
-    assert samples["pathway_tpu_moe_dispatches"] >= 1
+    assert samples["pathway_tpu_moe_dispatches"] == 1
+    layers, full, rows = _buffer_expected(emb, texts)
+    assert full == (4 if lengths == (126, 127) else 0)
+    assert samples["pathway_tpu_moe_full_buffer_layers"] == full
+    assert samples["pathway_tpu_moe_buffer_rows_mean"] == rows / layers
 
 
 def test_a_fused_dispatch_writes_an_embedder_dispatch_span(weights,
