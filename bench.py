@@ -487,7 +487,7 @@ def main() -> None:
         sys.exit(2)
 
     # the device legs first, in this process: a chip belongs to one process,
-    # and the host-only legs below also touch JAX (autojit, paging)
+    # and the host-only legs below also touch JAX (autojit)
     device_legs = [leg for leg in _DEVICE_LEG_NAMES if leg not in SKIP]
     if device_legs:
         if stamp["platform"] != "tpu":
@@ -531,16 +531,6 @@ def main() -> None:
             _append_bench_history("scaleout", leg_out)
         except Exception as e:  # noqa: BLE001
             errors["scaleout_error"] = f"{type(e).__name__}: {str(e)[:300]}"
-
-    if "paging" not in SKIP:
-        # paged-store leg (CPU-runnable): ingest stall across online
-        # growth paged-vs-slab + ragged warmup compile count
-        try:
-            leg_out = bench_paging()
-            result.update(leg_out)
-            _append_bench_history("paging", leg_out)
-        except Exception as e:  # noqa: BLE001
-            errors["paging_error"] = f"{type(e).__name__}: {str(e)[:300]}"
 
     if "durability" not in SKIP:
         # watermark-durability leg (CPU-runnable): bridge overlap with
@@ -2118,94 +2108,6 @@ def bench_scaleout() -> dict:
         out["etl_scaleout_efficiency"] = round(
             (best_rate / rate_1p) / min(workers, cores), 3)
         out["scaleout_best_transport"] = best_transport
-    return out
-
-
-def bench_paging() -> dict:
-    """Paged-store leg (CPU-runnable, also meaningful on device): the two
-    acceptance numbers of the paged HBM vector store.
-
-    1. **Ingest stall during online growth**: identical chunked ingest
-       into the paged store and the contiguous slab, growth forced
-       mid-stream, each chunk flushed+drained so its wall time includes
-       its device work. The slab pays a stop-the-world full re-upload on
-       the first flush after every growth; the paged store only
-       establishes a fresh extent — ``paging_grow_stall_ms_paged`` vs
-       ``_slab`` is that difference, measured.
-    2. **Warmup compile count under ragged batching**: the encoder's
-       width-bucket zoo (~18 shapes) vs the ragged sequence-count buckets
-       ``pw.warmup`` actually compiles (≤ 6).
-    """
-    import pathway_tpu as pw
-    from pathway_tpu.internals.keys import Pointer
-    from pathway_tpu.models.encoder import EncoderConfig
-    from pathway_tpu.ops.knn import (BruteForceKnnIndex,
-                                     DeviceEmbeddingKnnIndex, KnnMetric)
-    from pathway_tpu.xpacks.llm.embedders import JaxEncoderEmbedder
-
-    dim = int(os.environ.get("BENCH_PAGING_DIM", 256))
-    chunk = int(os.environ.get("BENCH_PAGING_CHUNK", 4096))
-    total = int(os.environ.get("BENCH_PAGING_ROWS", 16 * 4096))
-    rng = np.random.default_rng(0)
-    vecs = (rng.random((total, dim), np.float32) * 2.0 - 1.0)
-
-    def run_mode(paged: bool) -> dict:
-        index = BruteForceKnnIndex(dim, reserved_space=2 * chunk,
-                                   metric=KnnMetric.COS, paged=paged)
-        chunk_ms: list[float] = []
-        grow_chunks: list[float] = []
-        for base in range(0, total, chunk):
-            m = min(chunk, total - base)
-            keys = [Pointer(base + i) for i in range(m)]
-            cap_before = index.capacity
-            t0 = time.perf_counter()
-            index.add_batch(keys, vecs[base:base + m])
-            index.flush_device()
-            index.drain()
-            ms = (time.perf_counter() - t0) * 1e3
-            chunk_ms.append(ms)
-            if index.capacity > cap_before:
-                grow_chunks.append(ms)
-        res = index.search([(Pointer(10**9), vecs[7], 5, None)])
-        out = {
-            "ingest_p50_ms": round(float(np.percentile(chunk_ms, 50)), 2),
-            "ingest_p99_ms": round(float(np.percentile(chunk_ms, 99)), 2),
-            "grow_stall_ms": round(max(grow_chunks), 2) if grow_chunks
-            else None,
-            "grow_events": len(grow_chunks),
-            # rows written to device / rows ingested: the slab re-ships
-            # every occupied slot after each growth (stop-the-world
-            # re-upload); the paged store writes each row ONCE. This is
-            # the environment-independent form of the growth stall (on
-            # CPU, wall-ms mostly measures XLA compile churn instead)
-            "upload_amplification": round(
-                index.upload_rows_total / total, 3),
-        }
-        return out, res
-
-    paged, res_p = run_mode(True)
-    slab, res_s = run_mode(False)
-    out = {"paging_rows": total, "paging_dim": dim,
-           "paging_chunk": chunk,
-           "paging_identical_topk": res_p == res_s}
-    for k, v in paged.items():
-        out[f"paging_{k}_paged"] = v
-    for k, v in slab.items():
-        out[f"paging_{k}_slab"] = v
-
-    # warmup compile count: ragged buckets vs the width-bucket zoo (tiny
-    # encoder shape — the COUNT is the metric, the model size is not)
-    cfg = EncoderConfig.tiny(max_len=512)
-    emb = JaxEncoderEmbedder(config=cfg, ragged=True, max_len=512)
-    idx = DeviceEmbeddingKnnIndex(
-        emb, BruteForceKnnIndex(cfg.hidden, metric=KnnMetric.COS,
-                                paged=True))
-    t0 = time.perf_counter()
-    warm = pw.warmup(emb, index=idx)
-    out["paging_warmup_compiles_ragged"] = len(warm["compiled"])
-    out["paging_warmup_seconds_ragged"] = round(
-        time.perf_counter() - t0, 2)
-    out["paging_warmup_bucket_shapes"] = len(emb.bucket_widths())
     return out
 
 

@@ -188,7 +188,9 @@ def _serve_and_check(emb, docs: list[str], *, max_words: int,
     from pathway_tpu.engine import streaming
     from pathway_tpu.engine.threads import crashed_threads
     from pathway_tpu.internals import autojit
-    from pathway_tpu.ops.knn import DeviceEmbeddingKnnIndex, KnnMetric
+    from pathway_tpu.ops.knn import (BruteForceKnnIndex,
+                                     DeviceEmbeddingKnnIndex, KnnMetric)
+    from pathway_tpu.parallel.sharded_knn import ShardedKnnIndex
     from pathway_tpu.stdlib.indexing import (
         default_brute_force_knn_document_index)
     from pathway_tpu.xpacks.llm.vector_store import (VectorStoreClient,
@@ -323,7 +325,7 @@ def _serve_and_check(emb, docs: list[str], *, max_words: int,
                f"({type(index).__name__})")
         if mesh is None:
             _check(isinstance(index, DeviceEmbeddingKnnIndex)
-                   and type(index.inner).__name__ == "PagedKnnIndex",
+                   and isinstance(index.inner, BruteForceKnnIndex),
                    "index is the fused DeviceEmbeddingKnnIndex over the "
                    "paged store")
             _check(index.fused_batches > 0 and index.fused_fallbacks == 0
@@ -332,8 +334,8 @@ def _serve_and_check(emb, docs: list[str], *, max_words: int,
                    f"({index.fused_batches} batches, 0 fallbacks, 0 rows "
                    "through the two-dispatch scatter)")
         else:
-            _check(type(index).__name__ == "PagedShardedKnnIndex",
-                   f"mesh={mesh!r} built the PagedShardedKnnIndex")
+            _check(isinstance(index, ShardedKnnIndex),
+                   f"mesh={mesh!r} built the ShardedKnnIndex")
         _check(autojit.autojit_stats()["demotions"] == 0,
                "auto-jit demoted nothing")
         new_crashes = crashed_threads()[crashes_before:]

@@ -1,14 +1,12 @@
 """Paged HBM vector store: device page pool + host-side page table.
 
-The KNN slab (ops/knn.py) historically was ONE contiguous device array:
-growth doubled capacity with a stop-the-world host realloc + full device
-re-upload, and the fused donated-slab ingest could not grow at all (the
-donated shape is pinned). This module adopts the paged-memory design from
-Ragged Paged Attention (PAPERS.md): HBM is carved into fixed-size pages
-(``PATHWAY_PAGE_ROWS`` vector rows each, plus per-row validity and — for
-int8 slabs — quantization scale/norm side columns), a host-side page table
-maps logical slots to (page, offset), and device memory is allocated in
-page-aligned **extents** that are never moved or copied once created:
+The KNN index (ops/knn.py, parallel/sharded_knn.py) keeps its vectors in
+the paged-memory design from Ragged Paged Attention (PAPERS.md): HBM is
+carved into fixed-size pages (``PATHWAY_PAGE_ROWS`` vector rows each, plus
+per-row validity and — for int8 rows — quantization scale/norm side
+columns), a host-side page table maps logical slots to (page, offset), and
+device memory is allocated in page-aligned **extents** that are never
+moved or copied once created:
 
 - growth appends a new extent (fresh device allocation, established as
   zeros ON DEVICE) — existing extents, and the donated buffers the fused
@@ -40,16 +38,6 @@ class PageQuotaExceeded(RuntimeError):
     """A tenant asked for pages beyond its configured quota. Growth cannot
     help (the quota, not the pool, is the limit), so this escapes instead
     of looping the grow path."""
-
-
-def paged_store_enabled(override: bool | None = None) -> bool:
-    """Paged device storage is the default; ``PATHWAY_PAGED_STORE=0``
-    selects the legacy contiguous-slab path (kept for rollback and as the
-    byte-identical reference the paged tests pin against)."""
-    if override is not None:
-        return bool(override)
-    return os.environ.get("PATHWAY_PAGED_STORE", "1").lower() not in (
-        "0", "false", "off", "no")
 
 
 def page_rows(override: int | None = None) -> int:

@@ -287,22 +287,13 @@ class ResultCache:
 
 
 def maybe_result_cache(index: Any) -> "ResultCache | None":
-    """Cache instance for a KNN index (or None when disabled). Geometry
-    comes from the index's page allocator when paged, or the configured
-    page size for the contiguous slab (``slot // page_rows`` is then a
-    synthetic-but-consistent page id over the slab's address space)."""
+    """Cache instance for a KNN index (or None when disabled). Page
+    geometry comes from the index's page allocator."""
     if not result_cache_enabled():
         return None
-    pool = getattr(index, "_pool", None)
-    if pool is not None:
-        pr = pool.allocator.page_rows
-    else:
-        from pathway_tpu.engine.paged_store import page_rows
-
-        pr = page_rows()
     return ResultCache(
-        pr, metric=getattr(index, "metric", None),
-        beat_test=(getattr(index, "dtype", "float32") == "float32"))
+        index._pool.allocator.page_rows, metric=index.metric,
+        beat_test=(index.dtype == "float32"))
 
 
 # -- process-wide registry (mirrors paged_store's pool registry) ----------

@@ -79,9 +79,6 @@ CODES: dict[str, tuple[Severity, str]] = {
     "PWT107": (Severity.INFO,
                "model axis configured but nothing in the pipeline is "
                "model-parallel (silent weight replication)"),
-    "PWT108": (Severity.WARNING,
-               "fused donated ingest slab has no reserved capacity: first "
-               "growth silently drops the fused path"),
     "PWT109": (Severity.WARNING,
                "host-only UDF on a streaming hot path"),
     "PWT110": (Severity.INFO,

@@ -39,10 +39,10 @@ a post-mortem dump is the tool working, not a footgun.
 
 **Device residency.** Locals assigned from ``jnp.*`` / jitted calls /
 ``device_put`` — and attrs assigned one anywhere in their class, or
-named like device state (``_dev_vectors``) — are device-resident; a
-sync construct only fires on a device-resident operand, so the host-side
-``slots.tolist()`` bookkeeping the slab index does every batch stays
-silent. PWT402 *supersedes and widens* PWT105's narrower sync list
+named with a ``dev``/``device`` segment (``_DEVICE_ATTR_RE``) — are
+device-resident; a sync construct only fires on a device-resident operand,
+so the host-side ``slots.tolist()`` bookkeeping the KNN index does every
+batch stays silent. PWT402 *supersedes and widens* PWT105's narrower sync list
 (which missed ``.tolist()`` and ``int()``/``float()`` casts): when both
 families run in one ``check --all`` invocation, PWT105 defers to this
 family for any UDF defined in a file this pass scanned.

@@ -619,28 +619,24 @@ class ShardChecker:
 
             reserved = getattr(factory, "reserved_space", None)
             if isinstance(reserved, int) and reserved > 0:
-                # layout-accurate message: the paged store (default)
-                # page-aligns each shard's slab, so the predicted cost
-                # must use the same page_rows the runtime will
-                pr = None
-                from pathway_tpu.engine.paged_store import (
-                    page_rows, paged_store_enabled)
+                # layout-accurate message: the store page-aligns each
+                # shard's slab, so the predicted cost must use the same
+                # page_rows the runtime will
+                from pathway_tpu.engine.paged_store import page_rows
 
-                if paged_store_enabled():
-                    try:
-                        pr = page_rows()
-                    except ValueError:
-                        pr = None  # reported separately as PWT111
+                try:
+                    cap = slab_cap_per_shard(slab_data, reserved,
+                                             page_rows())
+                    costs = (f"; the slab allocates {cap} rows/shard "
+                             f"({cap * slab_data} total)")
+                except ValueError:
+                    costs = ""  # reported separately as PWT111
                 for d in check_sharded_dim(
                         reserved, slab_data,
                         what=f"KNN slab reservation (reserved_space="
                              f"{reserved} over {slab_data} shards)"):
-                    cap = slab_cap_per_shard(slab_data, reserved, pr)
-                    self.a._report(
-                        d.code,
-                        d.message + f"; the slab allocates {cap} rows/shard "
-                        f"({cap * slab_data} total)",
-                        node, severity=d.severity)
+                    self.a._report(d.code, d.message + costs, node,
+                                   severity=d.severity)
 
             # PWT103: the search kernel's spec/rank contract on this mesh
             layout = search_operand_layout(getattr(factory, "dtype",
@@ -652,27 +648,7 @@ class ShardChecker:
                     [rank for _, rank in layout]):
                 self.a._report(d.code, d.message, node, severity=d.severity)
 
-        # PWT108: fused donated ingest with no reserved capacity
-        # (contiguous slab only — the paged store grows the fused path by
-        # allocating pages, so no fallback cliff exists there)
-        fused = (getattr(factory, "fuse", False) and device_embedder
-                 and getattr(factory, "mesh", None) is None)
         reserved = getattr(factory, "reserved_space", None)
-        from pathway_tpu.engine.paged_store import paged_store_enabled
-
-        if fused and isinstance(reserved, int) and reserved <= 0 \
-                and not paged_store_enabled():
-            from pathway_tpu.ops.knn import planned_capacity
-
-            cap = planned_capacity(reserved or 0)
-            self.a._report(
-                "PWT108",
-                f"fused on-device ingest with reserved_space={reserved}: "
-                f"the donated slab is pinned at the {cap}-row minimum and "
-                f"cannot grow — past {cap} docs every batch silently falls "
-                f"back to the slow two-dispatch path — fix: reserve the "
-                f"expected corpus size up front",
-                node)
         self._check_paged_layout(node, factory, reserved, slab_data)
         return device_embedder
 
@@ -681,12 +657,9 @@ class ShardChecker:
         """PWT111: paged-store reservations and tenant quotas. Alignment
         findings are warnings (the allocator rounds UP, silently
         over-reserving); quotas summing past device HBM are errors."""
-        from pathway_tpu.engine.paged_store import (page_rows,
-                                                    paged_store_enabled)
+        from pathway_tpu.engine.paged_store import page_rows
         from pathway_tpu.internals.static_check.diagnostics import Severity
 
-        if not paged_store_enabled():
-            return
         try:
             pr = page_rows()
         except ValueError as e:

@@ -29,16 +29,14 @@ Two scale features target the 10M-vector p50 budget (BASELINE.md):
   slab chunks with a per-chunk top-k and a final merge, bounding the
   (B, N) score buffer at (B, chunk) regardless of slab size.
 
-Device storage is PAGED by default (engine/paged_store.py, Ragged Paged
-Attention's memory design): HBM is allocated in page-aligned extents that
-are never moved once created, a host page table maps slots to (page,
-offset), growth appends an extent instead of discarding + re-uploading the
-slab, frees return pages to a free list, and the fused donated ingest can
-grow (it allocates pages in one extent, or a fresh extent). Search runs
-the SAME kernels per extent and merges per-extent top-k — byte-identical
-results vs the contiguous slab, which stays available behind
-``PATHWAY_PAGED_STORE=0`` (and is the reference the paged tests pin
-against).
+Device storage is PAGED (engine/paged_store.py, Ragged Paged Attention's
+memory design): HBM is allocated in page-aligned extents that are never
+moved once created, a host page table maps slots to (page, offset), growth
+appends an extent, frees return pages to a free list, and the fused
+donated ingest grows by allocating pages in one extent, or a fresh extent.
+Search runs the same kernels per extent and merges the per-extent top-k,
+so the extent layout does not change an answer; an index reserved in one
+extent makes one kernel call and no merge.
 """
 
 from __future__ import annotations
@@ -64,10 +62,10 @@ class KnnMetric(enum.Enum):
 
 
 class FusedIngestUnplaceable(ValueError):
-    """The donated one-dispatch ingest cannot take this batch — the slab
-    is full (its donated shape is pinned) or the batch does not fit one
-    paged extent. Raised BEFORE any slot is assigned, so the caller may
-    re-add every key through the growable two-dispatch path. The only
+    """The donated one-dispatch ingest cannot take this batch — its rows
+    already sit in more than one extent, or no single extent can hold it.
+    Raised BEFORE any slot is assigned, so the caller may re-add every
+    key through the two-dispatch path, which spans extents. The only
     error that path change may catch: any other ValueError out of the
     fused step (a shape or lowering error) is a bug and must surface."""
 
@@ -97,10 +95,9 @@ def passes_filter(filter_data: dict, key: Pointer, filt: Any) -> bool:
 
 
 def planned_capacity(reserved_space: int) -> int:
-    """Slab capacity the index constructor will actually allocate for a
-    reservation — minimum floor, 128-lane rounding, chunk alignment. Shared
-    by ``BruteForceKnnIndex.__init__`` and the static shard checker
-    (PWT108), which uses it to explain what an unreserved fused slab pins."""
+    """Rows the first extent holds for a reservation — minimum floor,
+    128-lane rounding, chunk alignment (the page pool then rounds up to
+    whole pages)."""
     cap = max(_MIN_CAPACITY, _round_up(max(reserved_space, 1), 128))
     if cap > _CHUNK_ROWS:
         # the chunked kernel reshapes the slab to (C, chunk, D)
@@ -316,9 +313,8 @@ def _shared_scatter_fn():
 
 
 def _fused_step_fns(producer: Callable, dtype: str):
-    """The donated producer+scatter step of a fused ingest — shared by the
-    slab and paged stores (shape-polymorphic: the paged variant passes one
-    extent's arrays instead of the whole slab). ``mode="drop"`` makes the
+    """The donated producer+scatter step of a fused ingest, over one
+    extent's arrays (shape-polymorphic). ``mode="drop"`` makes the
     out-of-range sentinel slots of ragged padding rows a guaranteed no-op;
     in-range scatters are unaffected."""
     import jax
@@ -359,32 +355,44 @@ def _split_aux(out) -> tuple:
 
 
 class BruteForceKnnIndex:
-    """Incremental exact KNN over a device-resident vector slab.
+    """Incremental exact KNN over device-resident pages of vectors.
 
     add/remove mutate a host mirror and enqueue dirty slots; search flushes
-    pending updates to the device (single scatter), then runs the jitted
-    scores+top-k kernel. Capacity doubles on overflow (reference: doubling
-    realloc, brute_force_knn_integration.rs).
+    pending updates to the device, then runs the jitted scores+top-k kernel
+    over every extent and merges the candidates.
+
+    Device memory is a :class:`~pathway_tpu.engine.paged_store.DevicePagePool`
+    of page-aligned extents; the host page table (PageAllocator) maps
+    slots to (page, offset):
+
+    - **growth is online**: a new extent is appended (established as zeros
+      on device); existing extents are never discarded, re-uploaded or
+      re-quantized, and the dirty set is untouched — device-authoritative
+      rows need no mirror round-trip before growing;
+    - **fused donated ingest grows**: new keys allocate pages inside one
+      extent (or a fresh extent when none fits the batch) and the donated
+      step scatters into that extent only;
+    - **frees return pages** to the allocator's free list for reuse —
+      ingest/delete churn keeps occupancy bounded;
+    - **search is per-extent + merge**: each established extent runs the
+      same shared kernel; per-extent top-k candidates merge on the host by
+      (score desc, slot asc), so the extent layout never changes an answer
+      (an index reserved in one extent makes one kernel call, no merge);
+    - ``tenant`` / ``tenant_quotas`` tag this index's pages in the
+      allocator and cap them (PageQuotaExceeded past the cap) — the
+      accounting unit for many small indexes on one device.
+
+    The host mirror is one contiguous array indexed by global slot (its
+    growth is a host-RAM memcpy; device extents are never copied).
     """
 
     # adds/searches dispatch XLA work: eligible for the scheduler's
     # pipelined device leg (engine/device_bridge.py)
     device_bound = True
 
-    def __new__(cls, *args, **kwargs):
-        # paged device storage is the default; PATHWAY_PAGED_STORE=0 (or
-        # paged=False) selects this legacy contiguous-slab class itself
-        if cls is BruteForceKnnIndex:
-            from pathway_tpu.engine.paged_store import paged_store_enabled
-
-            if paged_store_enabled(kwargs.get("paged")):
-                cls = PagedKnnIndex
-        return object.__new__(cls)
-
     def __init__(self, dimensions: int, *, reserved_space: int = 0,
                  metric: KnnMetric | str = KnnMetric.L2SQ,
-                 dtype: str = "float32", device=None,
-                 paged: bool | None = None, page_rows: int | None = None,
+                 dtype: str = "float32", page_rows: int | None = None,
                  tenant: Any = None,
                  tenant_quotas: dict[Any, int] | None = None):
         if isinstance(metric, str):
@@ -407,12 +415,12 @@ class BruteForceKnnIndex:
         self._dirty: set[int] = set()    # host → device pending
         self._stale: set[int] = set()    # device → host pending (add_batch_device)
         # rows written to device storage (scatters + dense uploads).
-        # upload_rows_total / rows ingested is the re-upload amplification
-        # the paged store exists to delete: the slab re-ships every
-        # occupied slot after a growth, pages never re-ship
+        # upload_rows_total / rows ingested is the re-upload amplification:
+        # pages never re-ship, so it stays at 1 through any growth (and at
+        # 0 where every batch takes the fused donated dispatch)
         self.upload_rows_total = 0
-        self._init_storage(reserved_space, device, page_rows=page_rows,
-                           tenant=tenant, tenant_quotas=tenant_quotas)
+        self._tenant = tenant
+        self._init_storage(reserved_space, page_rows, tenant_quotas)
         # semantic result cache (engine/result_cache.py): fed from the
         # add/remove paths below, filled by the external-index operator.
         # Page geometry comes from storage, so this follows _init_storage.
@@ -424,55 +432,83 @@ class BruteForceKnnIndex:
         # search ran or when the cache is disabled
         self.last_search_coverage: frozenset | None = None
 
-    # ------------------------------------------------------------------
-    # storage hooks — the paged subclass swaps slot allocation + device
-    # layout here; everything else (key maps, mirror semantics, search
-    # ranking, filters) is shared
-    # ------------------------------------------------------------------
-    def _init_storage(self, reserved_space: int, device, *,
-                      page_rows: int | None = None, tenant: Any = None,
-                      tenant_quotas: dict[Any, int] | None = None) -> None:
-        if tenant_quotas:
-            # quota accounting lives in the page allocator — the
-            # contiguous slab has none. Loud, not silent: a quota the
-            # runtime will not enforce is a security config bug
-            import logging
+    def _init_storage(self, reserved_space: int, page_rows: int | None,
+                      tenant_quotas: dict[Any, int] | None) -> None:
+        from pathway_tpu.engine.paged_store import DevicePagePool
 
-            logging.getLogger("pathway_tpu.paged_store").warning(
-                "tenant_quotas are only enforced by the paged store — "
-                "the contiguous slab (PATHWAY_PAGED_STORE=0 / "
-                "paged=False) ignores them")
-        self.capacity = planned_capacity(reserved_space)
-        # host mirror
-        self._host_vectors = np.zeros((self.capacity, self.dim),
+        self._pool = DevicePagePool(
+            self.dim, reserved_space=reserved_space,
+            rows_per_page=page_rows, tenant_quotas=tenant_quotas,
+            lock=self._lock)
+        self._host_vectors = np.zeros((self._pool.capacity, self.dim),
                                       dtype=self._np_dtype)
-        self._host_valid = np.zeros((self.capacity,), dtype=bool)
-        self._free: list[int] = list(range(self.capacity - 1, -1, -1))
-        # device state (lazy); _dev_scales/_dev_vsq only for int8
-        # (per-row quantization scale + INT-domain squared norm, f32)
-        self._dev_vectors = None
-        self._dev_valid = None
-        self._dev_scales = None
-        self._dev_vsq = None
-        self._device = device
+        self._host_valid = np.zeros((self._pool.capacity,), dtype=bool)
 
+    @property
+    def capacity(self) -> int:
+        return self._pool.capacity
+
+    def page_stats(self) -> dict:
+        with self._lock:
+            return self._pool.stats()
+
+    # ------------------------------------------------------------------
+    # slot allocation through the page table
+    # ------------------------------------------------------------------
     def _ensure_free(self, n: int) -> None:
         """Guarantee ``n`` subsequent ``_take_slot`` calls succeed."""
-        while len(self._free) < n:
-            self._grow()
+        self._pool.ensure_free(n, self._tenant)
+        self._extend_mirror()
 
     def _take_slot(self) -> int:
-        return self._free.pop()
-
-    def _release_slot(self, slot: int) -> None:
-        self._free.append(slot)
+        return self._pool.allocator.take_slot(self._tenant)
 
     def reserve_rows(self, n: int) -> None:
         """Pre-size storage for ``n`` upcoming adds (used by the snapshot
-        restore path so a bulk re-establish does one sizing step instead
-        of a doubling cascade). Lock taken here — call before add_batch."""
+        restore path): one right-sized extent instead of the doubling
+        cascade, so a bulk re-establish uploads into fewer extents. Lock
+        taken here — call before add_batch."""
         with self._lock:
-            self._ensure_free(n)
+            self._pool.reserve_rows(n, self._tenant)
+            self._extend_mirror()
+
+    def _grow(self, min_rows: int = 0) -> None:
+        """One more extent of at least ``min_rows`` rows. Host-side only
+        until the next flush: the device extents are untouched (no
+        re-upload, dirty set unchanged, device-authoritative rows stay
+        put)."""
+        self._pool.grow(min_rows=min_rows)
+        self._extend_mirror()
+
+    def _extend_mirror(self) -> None:
+        """Track pool capacity in the host mirror."""
+        cap = self._pool.capacity
+        old = self._host_vectors.shape[0]
+        if cap <= old:
+            return
+        new_vec = np.zeros((cap, self.dim), dtype=self._np_dtype)
+        new_vec[:old] = self._host_vectors
+        self._host_vectors = new_vec
+        new_valid = np.zeros((cap,), dtype=bool)
+        new_valid[:old] = self._host_valid
+        self._host_valid = new_valid
+
+    def _assign_slots(self, keys: list[Pointer],
+                      take: Callable | None = None) -> np.ndarray:
+        """Slot per key (existing or freshly taken). Lock held; room for
+        the new keys has already been ensured — ``take`` must not fail.
+        The fused ingest passes a ``take`` pinned to one extent."""
+        slots = np.empty(len(keys), dtype=np.int32)
+        k2s, s2k = self._key_to_slot, self._slot_to_key
+        take = take or self._take_slot
+        for i, key in enumerate(keys):
+            slot = k2s.get(key)
+            if slot is None:
+                slot = take()
+                k2s[key] = slot
+                s2k[slot] = key
+            slots[i] = slot
+        return slots
 
     # ------------------------------------------------------------------
     # operator-state snapshots (engine/persistence.py): capture the host
@@ -516,23 +552,15 @@ class BruteForceKnnIndex:
     # ------------------------------------------------------------------
     # maintenance (called from the external-index operator on data diffs)
     # ------------------------------------------------------------------
-    def _alloc_slot(self, key: Pointer) -> int:
-        """Slot for ``key``, allocating (and growing) if new. Lock held."""
-        slot = self._key_to_slot.get(key)
-        if slot is None:
-            self._ensure_free(1)
-            slot = self._take_slot()
-            self._key_to_slot[key] = slot
-            self._slot_to_key[slot] = key
-        return slot
-
     def add(self, key: Pointer, vector: Any, filter_data: Any | None = None) -> None:
         with self._lock:
             vec = np.asarray(vector, dtype=self._np_dtype).reshape(-1)
             if vec.shape[0] != self.dim:
                 raise ValueError(
                     f"vector dim {vec.shape[0]} != index dim {self.dim}")
-            slot = self._alloc_slot(key)
+            if key not in self._key_to_slot:
+                self._ensure_free(1)
+            slot = int(self._assign_slots([key])[0])
             self._host_vectors[slot] = vec
             self._host_valid[slot] = True
             if filter_data is not None:
@@ -546,7 +574,7 @@ class BruteForceKnnIndex:
                         filter_data: list[Any] | None) -> None:
         """Record per-key metadata-filter payloads (None entries skipped).
         The single write path for every add variant — incl. the fused
-        text ingest, which updates the slab without a vector call."""
+        text ingest, which updates the store without a vector call."""
         if filter_data is None:
             return
         if len(filter_data) != len(keys):
@@ -560,7 +588,7 @@ class BruteForceKnnIndex:
 
     def add_batch(self, keys: list[Pointer], vectors,
                   filter_data: list[Any] | None = None) -> None:
-        """Vectorized add: one slab write for a whole batch of rows."""
+        """Vectorized add: one mirror write for a whole batch of rows."""
         if len(keys) == 0:
             return
         vecs = np.asarray(vectors, dtype=self._np_dtype)
@@ -574,17 +602,7 @@ class BruteForceKnnIndex:
         with self._lock:
             n_new = len({k for k in keys if k not in self._key_to_slot})
             self._ensure_free(n_new)
-            slots = np.empty(len(keys), dtype=np.int64)
-            k2s = self._key_to_slot  # bulk ingest: locals beat attr lookups
-            s2k = self._slot_to_key
-            take = self._take_slot
-            for i, key in enumerate(keys):
-                slot = k2s.get(key)
-                if slot is None:
-                    slot = take()
-                    k2s[key] = slot
-                    s2k[slot] = key
-                slots[i] = slot
+            slots = self._assign_slots(keys)
             self._host_vectors[slots] = vecs
             self._host_valid[slots] = True
             slot_list = slots.tolist()
@@ -596,7 +614,7 @@ class BruteForceKnnIndex:
     def add_batch_device(self, keys: list[Pointer], vectors,
                          filter_data: list[Any] | None = None) -> None:
         """Device-to-device add: ``vectors`` is a jax (n, dim) array already
-        resident on the chip (e.g. fresh encoder output). The slab is
+        resident on the chip (e.g. fresh encoder output). The extents are
         updated by an on-device scatter and the host mirror is marked stale
         (synced lazily, only when a host-side read needs it) — embeddings
         never round-trip through the host (~1.5 KB/doc of download+upload
@@ -614,19 +632,9 @@ class BruteForceKnnIndex:
         with self._lock:
             n_new = len({k for k in keys if k not in self._key_to_slot})
             self._ensure_free(n_new)
-            slots = np.empty(len(keys), dtype=np.int32)
-            k2s, s2k = self._key_to_slot, self._slot_to_key
-            take = self._take_slot
-            for i, key in enumerate(keys):
-                slot = k2s.get(key)
-                if slot is None:
-                    slot = take()
-                    k2s[key] = slot
-                    s2k[slot] = key
-                slots[i] = slot
-            self._flush_to_device()  # establish the slab before scattering
-            self._scatter(jnp.asarray(slots), vectors,
-                          jnp.ones(len(keys), dtype=bool))
+            slots = self._assign_slots(keys)
+            self._flush_to_device()  # pending host rows first: this write wins
+            self._scatter(slots, vectors, jnp.ones(len(keys), dtype=bool))
             self._host_valid[slots] = True
             slot_list = slots.tolist()
             self._stale.update(slot_list)
@@ -638,10 +646,10 @@ class BruteForceKnnIndex:
 
     def make_fused_ingest(self, producer: Callable,
                           on_aux: Callable | None = None):
-        """Fuse a producer (e.g. the encoder forward pass) with the slab
-        scatter into ONE jitted dispatch, donating the slab so XLA updates
-        it in place (no copy, no extra dispatch, nothing returns to the
-        host). This is the hot embed+index path: the reference runs
+        """Fuse a producer (e.g. the encoder forward pass) with the
+        scatter into ONE jitted dispatch, donating the extent so XLA
+        updates it in place (no copy, no extra dispatch, nothing returns to
+        the host). This is the hot embed+index path: the reference runs
         embedder UDF → index.add per row on the CPU
         (xpacks/llm/embedders.py + brute_force_knn_integration.rs); here
         the embedding tensor never leaves the chip.
@@ -654,11 +662,10 @@ class BruteForceKnnIndex:
         batches pad their doc dimension) — padding rows scatter to an
         out-of-range sentinel slot and are dropped.
 
-        On the contiguous slab, capacity must not grow mid-stream —
-        reserve up front (FusedIngestUnplaceable otherwise, donation pins
-        the shape).
-        The paged store (default) grows instead: new keys allocate pages
-        in one extent, or a fresh extent.
+        One donated step scatters into ONE extent: new keys allocate pages
+        in the extent with the most room, or in a fresh extent sized for
+        the batch (FusedIngestUnplaceable where no single extent can take
+        it).
         """
         step = _fused_step_fns(producer, self.dtype)
 
@@ -673,23 +680,6 @@ class BruteForceKnnIndex:
                 on_aux(aux[0])
 
         return ingest
-
-    def _fused_take_slots(self, keys: list[Pointer],
-                          take: Callable | None = None) -> np.ndarray:
-        """Slot per key (existing or freshly taken). Lock held; capacity
-        for the new keys has already been ensured — ``take`` must not
-        fail. The paged subclass passes a region-pinned ``take``."""
-        slots = np.empty(len(keys), dtype=np.int32)
-        k2s, s2k = self._key_to_slot, self._slot_to_key
-        take = take or self._take_slot
-        for i, key in enumerate(keys):
-            slot = k2s.get(key)
-            if slot is None:
-                slot = take()
-                k2s[key] = slot
-                s2k[slot] = key
-            slots[i] = slot
-        return slots
 
     @staticmethod
     def _pad_slots(slots: np.ndarray, n_rows: int | None, sentinel: int):
@@ -706,22 +696,58 @@ class BruteForceKnnIndex:
 
     def _fused_ingest(self, step, keys: list[Pointer], args,
                       n_rows: int | None) -> list:
+        from pathway_tpu.engine.paged_store import PageQuotaExceeded
+
+        alloc = self._pool.allocator
         n_new = len({k for k in keys if k not in self._key_to_slot})
-        if len(self._free) < n_new:
+        ext_ids = {self._pool.extent_index_of(self._key_to_slot[k])
+                   for k in keys if k in self._key_to_slot}
+        if len(ext_ids) > 1:
+            # a batch updating rows already spread across extents takes
+            # the two-dispatch fallback (DeviceEmbeddingKnnIndex catches
+            # this)
             raise FusedIngestUnplaceable(
-                "fused ingest cannot grow the slab (donated shape "
-                "is pinned) — reserve capacity up front")
-        self._flush_to_device()
-        slots = self._fused_take_slots(keys)
-        dev_slots = self._pad_slots(slots, n_rows, self.capacity)
-        if self._is_int8:
-            (self._dev_vectors, self._dev_scales, self._dev_vsq,
-             self._dev_valid, *aux) = step(
-                self._dev_vectors, self._dev_scales, self._dev_vsq,
-                self._dev_valid, dev_slots, *args)
+                "fused ingest cannot update rows spanning multiple "
+                "extents in one donated step")
+        capped = alloc.quota_capped_slots(self._tenant)
+        if capped is not None and capped < n_new:
+            raise PageQuotaExceeded(
+                f"tenant {self._tenant!r} needs {n_new} slots but its "
+                f"page quota caps it at {capped} more")
+        if ext_ids:
+            eidx = next(iter(ext_ids))
         else:
-            self._dev_vectors, self._dev_valid, *aux = step(
-                self._dev_vectors, self._dev_valid, dev_slots, *args)
+            eidx = max(range(len(self._pool.extents)),
+                       key=lambda e: alloc.free_slots_available(
+                           self._tenant, regions=[e]))
+            if alloc.free_slots_available(
+                    self._tenant, regions=[eidx]) < n_new:
+                # ONLINE GROWTH under donation: a fresh extent sized for
+                # the batch — the previously donated extents are untouched
+                self._grow(min_rows=n_new)
+                eidx = len(self._pool.extents) - 1
+        if alloc.free_slots_available(self._tenant, regions=[eidx]) < n_new:
+            # the one extent cannot hold the batch (updated rows pin it,
+            # or the tenant's quota caps it below the batch even after a
+            # grow): take the two-dispatch fallback, which allocates
+            # across extents — checked BEFORE any slot is assigned, so a
+            # failed fused attempt never leaks phantom key mappings
+            raise FusedIngestUnplaceable(
+                "fused ingest cannot place this batch in one extent")
+        self._flush_to_device()
+        ext = self._pool.extents[eidx]
+        self._establish_extent(ext)
+        slots = self._assign_slots(
+            keys, take=lambda: alloc.take_slot(self._tenant,
+                                               regions=[eidx]))
+        dev_slots = self._pad_slots(slots - ext.base, n_rows, ext.rows)
+        if self._is_int8:
+            (ext.vectors, ext.scales, ext.vsq, ext.valid, *aux) = step(
+                ext.vectors, ext.scales, ext.vsq, ext.valid,
+                dev_slots, *args)
+        else:
+            ext.vectors, ext.valid, *aux = step(
+                ext.vectors, ext.valid, dev_slots, *args)
         self._host_valid[slots] = True
         slot_list = slots.tolist()
         self._stale.update(slot_list)
@@ -730,25 +756,23 @@ class BruteForceKnnIndex:
 
     def _sync_mirror(self) -> None:
         """Pull device-authoritative rows back into the host mirror (lock
-        held). Needed before _grow (the realloc copies the mirror) and
-        before host-side exact reads."""
-        if not self._stale or self._dev_vectors is None:
-            self._stale.clear()
+        held). Needed before host-side exact reads and snapshots."""
+        if not self._stale:
             return
-        idxs = np.fromiter(self._stale, dtype=np.int32)
+        idxs = np.fromiter(self._stale, dtype=np.int64)
         self._stale.clear()
-        if self._is_int8:
-            # pwt-ok: PWT402 — deliberate consolidation read at a mirror
-            # boundary (pre-grow realloc / host exact reads), amortized
-            # over the whole stale set, not a per-batch sync
-            rows = np.asarray(self._dev_vectors[idxs], dtype=np.float32)
-            # pwt-ok: PWT402 — same consolidation read (int8 scales leg)
-            scales = np.asarray(self._dev_scales[idxs], dtype=np.float32)
-            self._host_vectors[idxs] = rows * scales[:, None]
-            return
-        # pwt-ok: PWT402 — same consolidation read (float slab path)
-        self._host_vectors[idxs] = np.asarray(
-            self._dev_vectors[idxs]).astype(self._np_dtype)
+        for ext, local, pos in self._pool.split_by_extent(idxs):
+            if not ext.established:
+                continue
+            rows_global = idxs[pos]
+            local = local.astype(np.int32)
+            # a consolidation read at a mirror boundary (host exact reads,
+            # snapshots), amortized over the whole stale set
+            rows = np.asarray(ext.vectors[local])
+            if self._is_int8:
+                scales = np.asarray(ext.scales[local], dtype=np.float32)
+                rows = rows.astype(np.float32) * scales[:, None]
+            self._host_vectors[rows_global] = rows.astype(self._np_dtype)
 
     def remove(self, key: Pointer) -> None:
         with self._lock:
@@ -758,7 +782,7 @@ class BruteForceKnnIndex:
             del self._slot_to_key[slot]
             self._filter_data.pop(key, None)
             self._host_valid[slot] = False
-            self._release_slot(slot)
+            self._pool.allocator.release_slot(slot)
             self._dirty.add(slot)
             self._stale.discard(slot)
             if self.result_cache is not None:
@@ -767,100 +791,91 @@ class BruteForceKnnIndex:
     def __len__(self) -> int:
         return len(self._key_to_slot)
 
-    def _grow(self) -> None:
-        # device-authoritative rows must land in the mirror before the
-        # realloc copies it (the old device slab is discarded below)
-        self._sync_mirror()
-        old_cap = self.capacity
-        self.capacity = old_cap * 2
-        if self.capacity > _CHUNK_ROWS:
-            self.capacity = _round_up(self.capacity, _CHUNK_ROWS)
-        new_vec = np.zeros((self.capacity, self.dim), dtype=self._np_dtype)
-        new_vec[:old_cap] = self._host_vectors
-        self._host_vectors = new_vec
-        new_valid = np.zeros((self.capacity,), dtype=bool)
-        new_valid[:old_cap] = self._host_valid
-        self._host_valid = new_valid
-        self._free.extend(range(self.capacity - 1, old_cap - 1, -1))
-        self._dev_vectors = None  # device slab is re-created at next search
-        self._dev_valid = None
-        self._dev_scales = None
-        self._dev_vsq = None
-        # every occupied slot must re-ship: the next flush may take the
-        # zero-slab + scatter path, which uploads only dirty rows
-        self._dirty.update(self._slot_to_key.keys())
-
     # ------------------------------------------------------------------
-    # device sync + search
+    # device sync + search, per extent
     # ------------------------------------------------------------------
     def _slab_itemsize(self) -> int:
-        """Bytes per element of the DEVICE slab (the host mirror may be
+        """Bytes per element of the DEVICE rows (the host mirror may be
         wider: int8 keeps an exact f32 mirror)."""
         if self._is_int8:
             return 1
         return 2 if self.dtype == "bfloat16" else 4
 
-    def _scatter(self, idxs, vals, valid_vals):
-        """Slab-donating scatter through the shared jitted kernel."""
-        rows = int(idxs.shape[0])
-        self.upload_rows_total += rows
-        prof = current_profiler()
-        if prof is not None:
-            t0 = _time.perf_counter()
-            self._scatter_dispatch(idxs, vals, valid_vals)
-            flops, nbytes = ingest_scatter_cost(
-                rows, self.dim, itemsize=self._slab_itemsize())
-            prof.record_dispatch("ingest_scatter", flops, nbytes,
-                                 (_time.perf_counter() - t0) * 1e3)
+    def _establish_extent(self, ext) -> None:
+        """Zero device arrays for one extent (on-device allocation, no
+        host transfer) — rows arrive by scatter only, so establishment is
+        one-time and extents are never re-created."""
+        if ext.established:
             return
-        self._scatter_dispatch(idxs, vals, valid_vals)
-
-    def _scatter_dispatch(self, idxs, vals, valid_vals):
-        if self._is_int8:
-            (self._dev_vectors, self._dev_scales, self._dev_vsq,
-             self._dev_valid) = _shared_scatter_i8_fn()(
-                self._dev_vectors, self._dev_scales, self._dev_vsq,
-                self._dev_valid, idxs, vals, valid_vals)
-            return
-        self._dev_vectors, self._dev_valid = _shared_scatter_fn()(
-            self._dev_vectors, self._dev_valid, idxs, vals, valid_vals)
-
-    def _flush_to_device(self):
-        import jax
         import jax.numpy as jnp
 
-        if self._dev_vectors is None:
-            if self._is_int8:
-                # always zero-slab + scatter: quantization happens in the
-                # scatter kernel, so the dense f32-mirror upload shortcut
-                # does not apply
-                self._dev_vectors = jnp.zeros(
-                    (self.capacity, self.dim), dtype=jnp.int8)
-                self._dev_scales = jnp.zeros((self.capacity,), jnp.float32)
-                self._dev_vsq = jnp.zeros((self.capacity,), jnp.float32)
-                self._dev_valid = jnp.zeros((self.capacity,), dtype=bool)
-                self._dirty.update(np.flatnonzero(self._host_valid).tolist())
-            elif len(self._dirty) * 2 < self.capacity:
-                # sparse occupancy: materialize a zero slab ON DEVICE (no
-                # host transfer) and fall through to the dirty scatter —
-                # incremental ingest then ships only written rows
-                slab_dtype = (jnp.bfloat16 if self.dtype == "bfloat16"
-                              else jnp.float32)
-                self._dev_vectors = jnp.zeros(
-                    (self.capacity, self.dim), dtype=slab_dtype)
-                self._dev_valid = jnp.zeros((self.capacity,), dtype=bool)
+        if self._is_int8:
+            ext.vectors = jnp.zeros((ext.rows, self.dim), dtype=jnp.int8)
+            # per-row quantization scale + INT-domain squared norm
+            ext.scales = jnp.zeros((ext.rows,), jnp.float32)
+            ext.vsq = jnp.zeros((ext.rows,), jnp.float32)
+        else:
+            slab_dtype = (jnp.bfloat16 if self.dtype == "bfloat16"
+                          else jnp.float32)
+            ext.vectors = jnp.zeros((ext.rows, self.dim), dtype=slab_dtype)
+        ext.valid = jnp.zeros((ext.rows,), dtype=bool)
+
+    def _scatter(self, idxs: np.ndarray, vals, valid_vals):
+        """Extent-donating scatter of global slots ``idxs`` through the
+        shared jitted kernels, one dispatch per extent touched."""
+        import jax.numpy as jnp
+
+        self.upload_rows_total += len(idxs)
+        prof = current_profiler()
+        t0 = _time.perf_counter() if prof is not None else 0.0
+        groups = list(self._pool.split_by_extent(idxs))
+        for ext, local, pos in groups:
+            self._establish_extent(ext)
+            if len(groups) == 1:
+                vsub, valsub = vals, valid_vals
             else:
-                self._dev_vectors = jnp.asarray(self._host_vectors)
-                self._dev_valid = jnp.asarray(self._host_valid)
-                self.upload_rows_total += self.capacity
-                self._dirty.clear()
-                return
-        if self._dirty:
-            idxs = np.fromiter(self._dirty, dtype=np.int32)
-            self._dirty.clear()
-            self._scatter(jnp.asarray(idxs),
-                          jnp.asarray(self._host_vectors[idxs]),
-                          jnp.asarray(self._host_valid[idxs]))
+                vsub, valsub = vals[pos], valid_vals[pos]
+            if self._is_int8:
+                (ext.vectors, ext.scales, ext.vsq,
+                 ext.valid) = _shared_scatter_i8_fn()(
+                    ext.vectors, ext.scales, ext.vsq, ext.valid,
+                    jnp.asarray(local, dtype=jnp.int32), vsub, valsub)
+            else:
+                ext.vectors, ext.valid = _shared_scatter_fn()(
+                    ext.vectors, ext.valid,
+                    jnp.asarray(local, dtype=jnp.int32), vsub, valsub)
+        if prof is not None:
+            flops, nbytes = ingest_scatter_cost(
+                len(idxs), self.dim, itemsize=self._slab_itemsize())
+            prof.record_dispatch("ingest_scatter", flops, nbytes,
+                                 (_time.perf_counter() - t0) * 1e3)
+
+    def _flush_to_device(self):
+        import jax.numpy as jnp
+
+        if not self._dirty:
+            return
+        idxs = np.fromiter(self._dirty, dtype=np.int64)
+        self._dirty.clear()
+        scatter_rows: list[np.ndarray] = []
+        for ext, local, pos in self._pool.split_by_extent(idxs):
+            if not ext.established and not self._is_int8 \
+                    and len(pos) * 2 >= ext.rows:
+                # bulk load of a fresh extent: one dense upload of its
+                # mirror range — rows outside the dirty set are zeros with
+                # valid False. int8 always scatters: quantization happens
+                # in the scatter kernel
+                ext.vectors = jnp.asarray(
+                    self._host_vectors[ext.base:ext.base + ext.rows])
+                ext.valid = jnp.asarray(
+                    self._host_valid[ext.base:ext.base + ext.rows])
+                self.upload_rows_total += ext.rows
+            else:
+                scatter_rows.append(idxs[pos])
+        if scatter_rows:
+            rows = np.concatenate(scatter_rows)
+            self._scatter(rows, jnp.asarray(self._host_vectors[rows]),
+                          jnp.asarray(self._host_valid[rows]))
 
     def flush_device(self) -> None:
         """Push pending host-mirror changes to the device now (async
@@ -876,59 +891,86 @@ class BruteForceKnnIndex:
         import jax
 
         with self._lock:
-            if self._dev_valid is not None:
-                # pwt-ok: PWT402 — deliberate barrier: drain() exists to
-                # block until dispatched device work resolves
-                jax.block_until_ready((self._dev_vectors, self._dev_valid))
+            # pwt-ok: PWT402 — deliberate barrier: drain() exists to
+            # block until dispatched device work resolves
+            jax.block_until_ready(
+                [(ext.vectors, ext.valid) for ext in self._pool.extents
+                 if ext.established])
 
     def _get_search_fn(self, k: int):
         """Jitted search(queries, vectors, extras, valid) — pair with
-        ``_search_extras()`` at the call site."""
+        ``_extent_extras(ext)`` at the call site."""
         if self._is_int8:
             return _shared_search_i8_fn(k, self.metric)
         return _shared_search_fn(k, self.metric)
 
-    def _search_extras(self) -> tuple:
-        """Per-row side columns the search kernel needs next to the slab
-        ((scales, vsq) for int8, () otherwise). Call after
-        _flush_to_device."""
+    def _extent_extras(self, ext) -> tuple:
+        """Per-row side columns the search kernel needs next to the rows
+        ((scales, vsq) for int8, () otherwise)."""
         if self._is_int8:
-            return (self._dev_scales, self._dev_vsq)
+            return (ext.scales, ext.vsq)
         return ()
 
-    def _fetch_cap(self) -> int:
-        """Upper bound on per-search candidate fetch (the chunked kernel's
-        per-chunk top-k bounds it at the chunk size)."""
-        return min(self.capacity, _CHUNK_ROWS)
-
-    def _coverage_pages(self) -> frozenset:
-        """Page-touch set of a search (lock held, device flushed): the
-        slab kernel scans the whole slab, so coverage is every page over
-        the slab address space (page ids are ``slot // page_rows`` with
-        the configured page size — synthetic for the slab, but consistent
-        with the add/remove hooks feeding the result cache)."""
-        pr = self.result_cache.page_rows
-        return frozenset(range(-(-self.capacity // pr)))
+    @staticmethod
+    def _extent_fetch_cap(ext) -> int:
+        """The chunked kernel's per-chunk top-k bounds one extent's
+        candidate fetch at the chunk size."""
+        return min(ext.rows, _CHUNK_ROWS)
 
     def _device_topk(self, qmat, fetch_k: int):
         """(scores, global slot ids) as host arrays, exactly ``fetch_k``
         columns, best first. Lock held, device state flushed."""
-        search_fn = self._get_search_fn(fetch_k)
         prof = current_profiler()
-        t0 = _time.perf_counter() if prof is not None else 0.0
-        ts, ti = search_fn(qmat, self._dev_vectors, self._search_extras(),
-                           self._dev_valid)
-        out = np.asarray(ts), np.asarray(ti)
+        t0 = _time.perf_counter()
+        out = self._device_topk_parts(qmat, fetch_k)
         if prof is not None:
-            # np.asarray above materializes the result, so the call-site
-            # wall below is honest device time even outside a bridge leg
-            flops, nbytes = knn_search_cost(
-                int(qmat.shape[0]), self.capacity, self.dim,
-                itemsize=self._slab_itemsize(),
-                extra_row_bytes=8 if self._is_int8 else 0)
-            prof.record_dispatch("knn_search", flops, nbytes,
-                                 (_time.perf_counter() - t0) * 1e3)
+            # the per-extent kernels scan exactly the established rows
+            # (each np.asarray in the parts loop materializes, so the wall
+            # is honest device time); cost the scan over those rows, not
+            # the pool's capacity
+            rows = sum(e.rows for e in self._pool.extents if e.established)
+            if rows:
+                flops, nbytes = knn_search_cost(
+                    int(qmat.shape[0]), rows, self.dim,
+                    itemsize=self._slab_itemsize(),
+                    extra_row_bytes=8 if self._is_int8 else 0)
+                prof.record_dispatch("knn_search", flops, nbytes,
+                                     (_time.perf_counter() - t0) * 1e3)
         return out
+
+    def _device_topk_parts(self, qmat, fetch_k: int):
+        parts = []
+        for ext in self._pool.extents:
+            if not ext.established:
+                continue  # never written → no valid rows to score
+            k_e = min(fetch_k, self._extent_fetch_cap(ext))
+            fn = self._get_search_fn(k_e)
+            ts, ti = fn(qmat, ext.vectors, self._extent_extras(ext),
+                        ext.valid)
+            parts.append((np.asarray(ts), np.asarray(ti) + ext.base))
+        if not parts:
+            B = int(qmat.shape[0])
+            return (np.full((B, fetch_k), -np.inf, np.float32),
+                    np.zeros((B, fetch_k), np.int64))
+        if len(parts) == 1 and parts[0][0].shape[1] == fetch_k:
+            return parts[0]
+        # merge per-extent candidates: stable argsort on descending score
+        # reproduces top_k's tie order (candidates are laid out in global
+        # slot order: extents by base, top_k ties by ascending local slot)
+        cand_s = np.concatenate([p[0] for p in parts], axis=1)
+        cand_i = np.concatenate([p[1] for p in parts], axis=1)
+        order = np.argsort(-cand_s, axis=1, kind="stable")[:, :fetch_k]
+        top_s = np.take_along_axis(cand_s, order, axis=1)
+        top_i = np.take_along_axis(cand_i, order, axis=1)
+        if top_s.shape[1] < fetch_k:
+            # capacity counts not-yet-established extents, so the
+            # established candidates can undershoot an escalated fetch_k —
+            # pad to the contract width (-inf rows read as exhausted)
+            pad = fetch_k - top_s.shape[1]
+            top_s = np.pad(top_s, ((0, 0), (0, pad)),
+                           constant_values=-np.inf)
+            top_i = np.pad(top_i, ((0, 0), (0, pad)))
+        return top_s, top_i
 
     def search(self, queries: list[tuple]) -> list[tuple]:
         """Batched search: [(qkey, vector, limit, filter)] →
@@ -937,15 +979,14 @@ class BruteForceKnnIndex:
         reported as distance) or cosine distance 1-cos_sim."""
         if not queries:
             return []
-        tenant = getattr(self, "_tenant", None)
-        if tenant is not None:
+        if self._tenant is not None:
             # per-tenant serving metrics: the query keys ARE the engine
             # keys the request tracker registered at enqueue, so this is
             # where tenant identity meets the request span
             from pathway_tpu.engine.request_tracker import live_trackers
 
             for trk in live_trackers():
-                trk.attribute_tenant((q[0] for q in queries), tenant)
+                trk.attribute_tenant((q[0] for q in queries), self._tenant)
         with self._lock:
             if not self._key_to_slot:
                 # empty-index scan touches nothing: an entry filled from
@@ -956,8 +997,9 @@ class BruteForceKnnIndex:
             self._flush_to_device()
             if self.result_cache is not None:
                 # coverage AFTER the flush — it must describe exactly the
-                # device state the kernel below scans
-                self.last_search_coverage = self._coverage_pages()
+                # device state the kernels below scan: the established
+                # extents (the ISSUE-19 page-touch contract)
+                self.last_search_coverage = self._pool.touched_page_ids()
             import jax.numpy as jnp
 
             max_k = max(int(q[2] or 3) for q in queries)
@@ -965,7 +1007,7 @@ class BruteForceKnnIndex:
             # k; the chunked kernel's per-chunk top-k bounds fetch at the
             # chunk size
             has_filter = any(q[3] is not None for q in queries)
-            fetch_cap = self._fetch_cap()
+            fetch_cap = min(self.capacity, _CHUNK_ROWS)
             fetch_k = min(fetch_cap,
                           max_k * 4 if has_filter else max_k)
             fetch_k = max(fetch_k, 1)
@@ -1088,285 +1130,9 @@ class BruteForceKnnIndex:
 
     def _probe_searcher(self, k: int):
         """``(run, operands)`` with ``run(qbatch, operands) -> (ts, ti)``
-        jit-traceable — the device side of one search, parameterized so
-        latency_probe measures the REAL storage layout (slab or paged)."""
-        search_fn = self._get_search_fn(k)
-        operands = (self._dev_vectors, self._search_extras(),
-                    self._dev_valid)
-
-        def run(q, operands):
-            vectors, extras, valid = operands
-            return search_fn(q, vectors, extras, valid)
-
-        return run, operands
-
-    def _passes_filter(self, key: Pointer, filt: Any) -> bool:
-        return passes_filter(self._filter_data, key, filt)
-
-
-class PagedKnnIndex(BruteForceKnnIndex):
-    """BruteForceKnnIndex over the paged device store (the default —
-    ``BruteForceKnnIndex(...)`` constructs this class unless
-    ``PATHWAY_PAGED_STORE=0`` / ``paged=False``).
-
-    Device memory is a :class:`~pathway_tpu.engine.paged_store.DevicePagePool`
-    of page-aligned extents; the host page table (PageAllocator) maps
-    slots to (page, offset). What changes vs the slab:
-
-    - **growth is online**: a new extent is appended (established as zeros
-      on device); existing extents are never discarded, re-uploaded or
-      re-quantized, and the dirty set is untouched — no stop-the-world
-      re-upload stall, and device-authoritative rows need no mirror
-      round-trip before growing;
-    - **fused donated ingest can grow**: new keys allocate pages inside
-      one extent (or a fresh extent when none fits the batch) and the
-      donated step scatters into that extent only;
-    - **frees return pages** to the allocator's free list for reuse —
-      ingest/delete churn keeps occupancy bounded;
-    - **search is per-extent + merge**: each established extent runs the
-      SAME shared kernel the slab uses; per-extent top-k candidates merge
-      on the host by (score desc, slot asc) — byte-identical results to
-      the slab path (single extent: literally the same kernel call);
-    - ``tenant`` / ``tenant_quotas`` tag this index's pages in the
-      allocator and cap them (PageQuotaExceeded past the cap) — the
-      accounting unit for many small indexes on one device.
-
-    The host mirror stays one contiguous array indexed by global slot
-    (mirror growth is a host-RAM memcpy; only DEVICE copies are the stall
-    this class deletes).
-    """
-
-    def _init_storage(self, reserved_space: int, device, *,
-                      page_rows: int | None = None, tenant: Any = None,
-                      tenant_quotas: dict[Any, int] | None = None) -> None:
-        from pathway_tpu.engine.paged_store import DevicePagePool
-
-        self._pool = DevicePagePool(
-            self.dim, reserved_space=reserved_space,
-            rows_per_page=page_rows, tenant_quotas=tenant_quotas,
-            lock=self._lock)
-        self._tenant = tenant
-        self._host_vectors = np.zeros((self._pool.capacity, self.dim),
-                                      dtype=self._np_dtype)
-        self._host_valid = np.zeros((self._pool.capacity,), dtype=bool)
-        self._free = None  # slot accounting lives in the page allocator
-        self._device = device
-
-    @property
-    def capacity(self) -> int:
-        return self._pool.capacity
-
-    def page_stats(self) -> dict:
-        with self._lock:
-            return self._pool.stats()
-
-    # -- slot allocation through the page table -------------------------
-    def _ensure_free(self, n: int) -> None:
-        self._pool.ensure_free(n, self._tenant)
-        self._extend_mirror()
-
-    def reserve_rows(self, n: int) -> None:
-        # single right-sized extent (paged_store.reserve_rows) instead of
-        # the doubling cascade — restore re-uploads into fewer extents
-        with self._lock:
-            self._pool.reserve_rows(n, self._tenant)
-            self._extend_mirror()
-
-    def _take_slot(self) -> int:
-        return self._pool.allocator.take_slot(self._tenant)
-
-    def _release_slot(self, slot: int) -> None:
-        self._pool.allocator.release_slot(slot)
-
-    def _grow(self) -> None:
-        self._pool.grow()
-        self._extend_mirror()
-
-    def _extend_mirror(self) -> None:
-        """Track pool capacity in the host mirror. Host-side only — the
-        device extents are untouched (no re-upload, dirty set unchanged,
-        device-authoritative rows stay put: no _sync_mirror needed)."""
-        cap = self._pool.capacity
-        old = self._host_vectors.shape[0]
-        if cap <= old:
-            return
-        new_vec = np.zeros((cap, self.dim), dtype=self._np_dtype)
-        new_vec[:old] = self._host_vectors
-        self._host_vectors = new_vec
-        new_valid = np.zeros((cap,), dtype=bool)
-        new_valid[:old] = self._host_valid
-        self._host_valid = new_valid
-
-    # -- device state per extent ----------------------------------------
-    def _establish_extent(self, ext) -> None:
-        """Zero device arrays for one extent (on-device allocation, no
-        host transfer) — rows arrive by scatter only, so establishment is
-        one-time and extents are never re-created."""
-        if ext.established:
-            return
-        import jax.numpy as jnp
-
-        if self._is_int8:
-            ext.vectors = jnp.zeros((ext.rows, self.dim), dtype=jnp.int8)
-            ext.scales = jnp.zeros((ext.rows,), jnp.float32)
-            ext.vsq = jnp.zeros((ext.rows,), jnp.float32)
-        else:
-            slab_dtype = (jnp.bfloat16 if self.dtype == "bfloat16"
-                          else jnp.float32)
-            ext.vectors = jnp.zeros((ext.rows, self.dim), dtype=slab_dtype)
-        ext.valid = jnp.zeros((ext.rows,), dtype=bool)
-
-    def _scatter(self, idxs, vals, valid_vals):
-        import jax.numpy as jnp
-
-        idxs_np = np.asarray(idxs)
-        self.upload_rows_total += len(idxs_np)
-        prof = current_profiler()
-        t0 = _time.perf_counter() if prof is not None else 0.0
-        groups = list(self._pool.split_by_extent(idxs_np))
-        for ext, local, pos in groups:
-            self._establish_extent(ext)
-            if len(groups) == 1:
-                vsub, valsub = vals, valid_vals
-            else:
-                vsub, valsub = vals[pos], valid_vals[pos]
-            if self._is_int8:
-                (ext.vectors, ext.scales, ext.vsq,
-                 ext.valid) = _shared_scatter_i8_fn()(
-                    ext.vectors, ext.scales, ext.vsq, ext.valid,
-                    jnp.asarray(local, dtype=jnp.int32), vsub, valsub)
-            else:
-                ext.vectors, ext.valid = _shared_scatter_fn()(
-                    ext.vectors, ext.valid,
-                    jnp.asarray(local, dtype=jnp.int32), vsub, valsub)
-        if prof is not None:
-            flops, nbytes = ingest_scatter_cost(
-                len(idxs_np), self.dim, itemsize=self._slab_itemsize())
-            prof.record_dispatch("ingest_scatter", flops, nbytes,
-                                 (_time.perf_counter() - t0) * 1e3)
-
-    def _flush_to_device(self):
-        import jax.numpy as jnp
-
-        if not self._dirty:
-            return
-        idxs = np.fromiter(self._dirty, dtype=np.int64)
-        self._dirty.clear()
-        scatter_rows: list[np.ndarray] = []
-        for ext, local, pos in self._pool.split_by_extent(idxs):
-            if not ext.established and not self._is_int8 \
-                    and len(pos) * 2 >= ext.rows:
-                # bulk load of a fresh extent: one dense upload of its
-                # mirror range (the slab's dense shortcut, per extent) —
-                # rows outside the dirty set are zeros with valid False
-                ext.vectors = jnp.asarray(
-                    self._host_vectors[ext.base:ext.base + ext.rows])
-                ext.valid = jnp.asarray(
-                    self._host_valid[ext.base:ext.base + ext.rows])
-                self.upload_rows_total += ext.rows
-            else:
-                scatter_rows.append(idxs[pos])
-        if scatter_rows:
-            rows = np.concatenate(scatter_rows)
-            self._scatter(rows, jnp.asarray(self._host_vectors[rows]),
-                          jnp.asarray(self._host_valid[rows]))
-
-    def _sync_mirror(self) -> None:
-        if not self._stale:
-            return
-        idxs = np.fromiter(self._stale, dtype=np.int64)
-        self._stale.clear()
-        for ext, local, pos in self._pool.split_by_extent(idxs):
-            if not ext.established:
-                continue
-            rows_global = idxs[pos]
-            local = local.astype(np.int32)
-            if self._is_int8:
-                rows = np.asarray(ext.vectors[local], dtype=np.float32)
-                scales = np.asarray(ext.scales[local], dtype=np.float32)
-                self._host_vectors[rows_global] = rows * scales[:, None]
-            else:
-                self._host_vectors[rows_global] = np.asarray(
-                    ext.vectors[local]).astype(self._np_dtype)
-
-    # -- search over the page table --------------------------------------
-    def _coverage_pages(self) -> frozenset:
-        # paged search scans established extents only — the pool reports
-        # exactly that set (the ISSUE-19 page-touch contract)
-        return self._pool.touched_page_ids()
-
-    def _extent_extras(self, ext) -> tuple:
-        if self._is_int8:
-            return (ext.scales, ext.vsq)
-        return ()
-
-    def _extent_fetch_cap(self, ext) -> int:
-        return min(ext.rows, _CHUNK_ROWS)
-
-    def _device_topk(self, qmat, fetch_k: int):
-        prof = current_profiler()
-        if prof is None:
-            return self._device_topk_parts(qmat, fetch_k)
-        t0 = _time.perf_counter()
-        out = self._device_topk_parts(qmat, fetch_k)
-        # the per-extent kernels scan exactly the established rows (each
-        # np.asarray in the parts loop materializes, so the wall is
-        # honest device time); cost the scan over those rows, not the
-        # slab capacity
-        rows = sum(e.rows for e in self._pool.extents if e.established)
-        if rows:
-            flops, nbytes = knn_search_cost(
-                int(qmat.shape[0]), rows, self.dim,
-                itemsize=self._slab_itemsize(),
-                extra_row_bytes=8 if self._is_int8 else 0)
-            prof.record_dispatch("knn_search", flops, nbytes,
-                                 (_time.perf_counter() - t0) * 1e3)
-        return out
-
-    def _device_topk_parts(self, qmat, fetch_k: int):
-        parts = []
-        for ext in self._pool.extents:
-            if not ext.established:
-                continue  # never written → no valid rows to score
-            k_e = min(fetch_k, self._extent_fetch_cap(ext))
-            fn = self._get_search_fn(k_e)
-            ts, ti = fn(qmat, ext.vectors, self._extent_extras(ext),
-                        ext.valid)
-            parts.append((np.asarray(ts), np.asarray(ti) + ext.base))
-        if not parts:
-            B = int(qmat.shape[0])
-            return (np.full((B, fetch_k), -np.inf, np.float32),
-                    np.zeros((B, fetch_k), np.int64))
-        if len(parts) == 1 and parts[0][0].shape[1] == fetch_k:
-            return parts[0]
-        # merge per-extent candidates: stable argsort on descending score
-        # reproduces top_k's tie order (candidates are laid out in global
-        # slot order: extents by base, top_k ties by ascending local slot)
-        cand_s = np.concatenate([p[0] for p in parts], axis=1)
-        cand_i = np.concatenate([p[1] for p in parts], axis=1)
-        order = np.argsort(-cand_s, axis=1, kind="stable")[:, :fetch_k]
-        top_s = np.take_along_axis(cand_s, order, axis=1)
-        top_i = np.take_along_axis(cand_i, order, axis=1)
-        if top_s.shape[1] < fetch_k:
-            # capacity counts not-yet-established extents, so the
-            # established candidates can undershoot an escalated fetch_k —
-            # pad to the contract width (-inf rows read as exhausted)
-            pad = fetch_k - top_s.shape[1]
-            top_s = np.pad(top_s, ((0, 0), (0, pad)),
-                           constant_values=-np.inf)
-            top_i = np.pad(top_i, ((0, 0), (0, pad)))
-        return top_s, top_i
-
-    def drain(self) -> None:
+        jit-traceable — the device side of one search over the extents as
+        they stand, so latency_probe measures the real storage layout."""
         import jax
-
-        with self._lock:
-            # pwt-ok: PWT402 — deliberate barrier, as the slab's drain()
-            jax.block_until_ready(
-                [(ext.vectors, ext.valid) for ext in self._pool.extents
-                 if ext.established])
-
-    def _probe_searcher(self, k: int):
         import jax.numpy as jnp
 
         exts = [e for e in self._pool.extents if e.established]
@@ -1374,7 +1140,7 @@ class PagedKnnIndex(BruteForceKnnIndex):
                for e in exts]
         bases = [e.base for e in exts]
         operands = tuple((e.vectors, self._extent_extras(e), e.valid)
-                        for e in exts)
+                         for e in exts)
 
         def run(q, operands):
             ts_all, ti_all = [], []
@@ -1385,8 +1151,6 @@ class PagedKnnIndex(BruteForceKnnIndex):
                 ti_all.append(ti + base)
             if len(ts_all) == 1:
                 return ts_all[0], ti_all[0]
-            import jax
-
             cand_s = jnp.concatenate(ts_all, axis=1)
             cand_i = jnp.concatenate(ti_all, axis=1)
             ms, pos = jax.lax.top_k(cand_s, min(k, cand_s.shape[1]))
@@ -1394,69 +1158,13 @@ class PagedKnnIndex(BruteForceKnnIndex):
 
         return run, operands
 
-    # -- fused ingest: grow by allocating pages/extents -------------------
-    def _fused_ingest(self, step, keys: list[Pointer], args,
-                      n_rows: int | None) -> list:
-        from pathway_tpu.engine.paged_store import PageQuotaExceeded
+    def _passes_filter(self, key: Pointer, filt: Any) -> bool:
+        return passes_filter(self._filter_data, key, filt)
 
-        alloc = self._pool.allocator
-        new_keys = [k for k in keys if k not in self._key_to_slot]
-        n_new = len(set(new_keys))
-        ext_ids = {self._pool.extent_index_of(self._key_to_slot[k])
-                   for k in keys if k in self._key_to_slot}
-        if len(ext_ids) > 1:
-            # one donated step scatters into ONE extent; a batch updating
-            # rows already spread across extents takes the two-dispatch
-            # fallback (DeviceEmbeddingKnnIndex catches this)
-            raise FusedIngestUnplaceable(
-                "fused ingest cannot update rows spanning multiple "
-                "extents in one donated step")
-        capped = alloc.quota_capped_slots(self._tenant)
-        if capped is not None and capped < n_new:
-            raise PageQuotaExceeded(
-                f"tenant {self._tenant!r} needs {n_new} slots but its "
-                f"page quota caps it at {capped} more")
-        if ext_ids:
-            eidx = next(iter(ext_ids))
-        else:
-            eidx = max(range(len(self._pool.extents)),
-                       key=lambda e: alloc.free_slots_available(
-                           self._tenant, regions=[e]))
-            if alloc.free_slots_available(
-                    self._tenant, regions=[eidx]) < n_new:
-                # ONLINE GROWTH under donation: a fresh extent sized for
-                # the batch — the previously donated extents are untouched
-                self._pool.grow(min_rows=n_new)
-                self._extend_mirror()
-                eidx = len(self._pool.extents) - 1
-        if alloc.free_slots_available(self._tenant, regions=[eidx]) < n_new:
-            # the one extent cannot hold the batch (updated rows pin it,
-            # or the tenant's quota caps it below the batch even after a
-            # grow): take the two-dispatch fallback, which allocates
-            # across extents — checked BEFORE any slot is assigned, so a
-            # failed fused attempt never leaks phantom key mappings
-            raise FusedIngestUnplaceable(
-                "fused ingest cannot place this batch in one extent")
-        self._flush_to_device()
-        ext = self._pool.extents[eidx]
-        self._establish_extent(ext)
-        slots = self._fused_take_slots(
-            keys, take=lambda: alloc.take_slot(self._tenant,
-                                               regions=[eidx]))
-        local = slots - ext.base
-        dev_slots = self._pad_slots(local, n_rows, ext.rows)
-        if self._is_int8:
-            (ext.vectors, ext.scales, ext.vsq, ext.valid, *aux) = step(
-                ext.vectors, ext.scales, ext.vsq, ext.valid,
-                dev_slots, *args)
-        else:
-            ext.vectors, ext.valid, *aux = step(
-                ext.vectors, ext.valid, dev_slots, *args)
-        self._host_valid[slots] = True
-        slot_list = slots.tolist()
-        self._stale.update(slot_list)
-        self._dirty.difference_update(slot_list)
-        return aux
+
+# one class, two names: scripts that import the index by its store's name
+# keep working
+PagedKnnIndex = BruteForceKnnIndex
 
 
 class DeviceEmbeddingKnnIndex:
@@ -1530,8 +1238,8 @@ class DeviceEmbeddingKnnIndex:
                 self.fused_batches += 1
                 return
             except FusedIngestUnplaceable:
-                # slab full / batch spans extents — fall through to the
-                # growable two-dispatch path (re-adds every key, so a
+                # batch spans extents / fits no single one — fall through
+                # to the two-dispatch path (re-adds every key, so a
                 # partially-fused ragged batch stays consistent)
                 self.fused_fallbacks += 1
         vecs = self.embedder.encode_batch_device(texts)

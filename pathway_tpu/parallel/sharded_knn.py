@@ -3,13 +3,13 @@
 Pod-scale variant of ops/knn.py (reference: BruteForceKNNIndex,
 src/external_integration/brute_force_knn_integration.rs:22,187-229 — which
 is per-worker: each timely worker owns the rows routed to it by key shard).
-Here the vector slab is one logical array of shape
+Here the vector slab is a list of extents, each one logical array of shape
 ``(n_shards, cap_per_shard, dim)`` sharded over the mesh ``data`` axis:
-each chip scores queries against its local shard (one MXU matmul), takes a
-local top-k, and the per-shard candidates are merged with a second top-k —
-the cross-chip traffic is only ``n_shards × B × k`` scores over ICI, never
-the slab itself. This is the distributed-KNN design for BASELINE.md
-config 5 (multi-worker KNN over a stream).
+each chip scores queries against its local block of every extent (one MXU
+matmul each), takes a local top-k, and the per-shard candidates are merged
+with a second top-k — the cross-chip traffic is only ``n_shards × B × k``
+scores over ICI, never the slab itself. This is the distributed-KNN design
+for BASELINE.md config 5 (multi-worker KNN over a stream).
 """
 
 from __future__ import annotations
@@ -27,39 +27,36 @@ from pathway_tpu.parallel.mesh import shard_map as _shard_map
 
 
 def slab_cap_per_shard(n_shards: int, reserved_space: int,
-                       page_rows: int | None = None) -> int:
+                       page_rows: int) -> int:
     """Per-shard slab capacity for a reservation of ``reserved_space`` rows.
 
     The ONE place the slab layout is decided: the index constructor sizes
-    its storage with it and the static shard checker
+    its first extent with it and the static shard checker
     (internals/static_check/shard_check.py, PWT102) predicts padding/skew
     from it — the two can never disagree about what a reservation costs.
-    Under the paged store (``page_rows`` set) each shard's slab is a whole
-    number of pages, so the per-shard capacity rounds up to the page size.
+    Each shard's slab is a whole number of pages, so the per-shard capacity
+    rounds up to the page size.
     """
     per = max(reserved_space // n_shards + 1, 1)
-    per = max(128, _round_up(per, 128))
-    if page_rows:
-        per = _round_up(per, page_rows)
-    return per
+    return _round_up(max(128, _round_up(per, 128)), page_rows)
 
 
 def pages_per_shard(n_shards: int, reserved_space: int,
                     page_rows: int) -> int:
-    """What a reservation costs in PAGES per shard — the paged-store unit
-    the allocator and the static checker (PWT111) both reason in."""
+    """What a reservation costs in PAGES per shard — the unit the allocator
+    and the static checker (PWT111) both reason in."""
     return slab_cap_per_shard(n_shards, reserved_space,
                               page_rows) // page_rows
 
 
 def search_operand_layout(dtype: str) -> tuple[tuple[tuple, int], ...]:
     """``((sharded_axes, rank), ...)`` per search-kernel operand, in call
-    order: queries, vectors, valid (+ scales, vsq for int8). ``sharded_axes``
-    is a tuple of mesh axis names, one per leading operand dim (empty =
-    replicated) — the symbolic twin of the ``in_specs`` handed to
-    ``shard_map``. Shared by ``_get_search_fn`` and the static shard checker
-    (PWT103), so the spec/rank contract is asserted against the layout the
-    kernel actually uses."""
+    order: queries, then one extent's vectors, valid (+ scales, vsq for
+    int8). ``sharded_axes`` is a tuple of mesh axis names, one per leading
+    operand dim (empty = replicated) — the symbolic twin of the
+    ``in_specs`` handed to ``shard_map``. Shared by ``_get_search_fn`` and
+    the static shard checker (PWT103), so the spec/rank contract is
+    asserted against the layout the kernel actually uses."""
     base = (
         ((), 2),            # queries (B, D): replicated
         ((DATA_AXIS,), 3),  # vectors (S, C, D): slab dim over the data axis
@@ -73,33 +70,46 @@ def search_operand_layout(dtype: str) -> tuple[tuple[tuple, int], ...]:
     return base
 
 
-class ShardedKnnIndex:
-    """Exact KNN over a mesh-sharded vector slab.
+class _ShardExtent:
+    """One sharded device allocation: ``cap_per_shard`` rows PER SHARD,
+    laid out as (n_shards, cap_per_shard, dim) over the mesh data axis.
+    Global slots [base + s*cap, base + (s+1)*cap) belong to shard s."""
 
-    Slots form one logical space of size ``n_shards * cap_per_shard``;
-    slot ``s`` lives on shard ``s // cap_per_shard``. Adds are balanced by
-    always allocating from the emptiest shard (the reference balances by
-    key-hash routing, src/engine/dataflow/shard.rs:6-20; explicit balancing
-    avoids hash skew in the slab).
-    """
+    __slots__ = ("base", "cap_per_shard", "vectors", "valid", "scales",
+                 "vsq")
+
+    def __init__(self, base: int, cap_per_shard: int):
+        self.base = base
+        self.cap_per_shard = cap_per_shard
+        self.vectors = None
+        self.valid = None
+        self.scales = None
+        self.vsq = None
+
+
+
+class ShardedKnnIndex:
+    """Exact KNN over mesh-sharded extents of vectors.
+
+    Each shard's slab is a whole number of pages (``slab_cap_per_shard``
+    page-aligned ⇒ ``pages_per_shard`` is the reservation unit) tracked by
+    ONE PageAllocator whose regions are (extent, shard) blocks. Adds are
+    balanced by always allocating from the emptiest shard (the reference
+    balances by key-hash routing, src/engine/dataflow/shard.rs:6-20;
+    explicit balancing avoids hash skew in the slab). Growth appends a
+    sharded extent — a fresh (S, C_new, D) device allocation — with no slot
+    remapping and no re-upload of existing extents. The search kernel
+    scores every extent shard-locally, merges the per-extent top-k on-chip,
+    and only then pays the cross-chip all-gather: ICI traffic stays
+    n_shards x B x k scores regardless of extent count."""
 
     device_bound = True  # pipeline through the device bridge (graph.py)
-
-    def __new__(cls, *args, **kwargs):
-        # paged per-shard storage is the default (PATHWAY_PAGED_STORE=0 /
-        # paged=False keeps this contiguous per-shard slab class)
-        if cls is ShardedKnnIndex:
-            from pathway_tpu.engine.paged_store import paged_store_enabled
-
-            if paged_store_enabled(kwargs.get("paged")):
-                cls = PagedShardedKnnIndex
-        return object.__new__(cls)
 
     def __init__(self, dimensions: int, *, mesh=None,
                  reserved_space: int = 0,
                  metric: KnnMetric | str = KnnMetric.L2SQ,
-                 dtype: str = "float32", paged: bool | None = None,
-                 page_rows: int | None = None, tenant: Any = None,
+                 dtype: str = "float32", page_rows: int | None = None,
+                 tenant: Any = None,
                  tenant_quotas: dict[Any, int] | None = None):
         if isinstance(metric, str):
             metric = KnnMetric(metric)
@@ -123,37 +133,35 @@ class ShardedKnnIndex:
         self._filter_data: dict[Pointer, Any] = {}
         self._dirty: set[int] = set()
         self._search_fn_cache: dict[tuple, Callable] = {}
-        self._init_storage(reserved_space, page_rows=page_rows,
-                           tenant=tenant, tenant_quotas=tenant_quotas)
+        self._tenant = tenant
+        self._init_storage(reserved_space, page_rows, tenant_quotas)
 
-    def _init_storage(self, reserved_space: int, *,
-                      page_rows: int | None = None, tenant: Any = None,
-                      tenant_quotas: dict[Any, int] | None = None) -> None:
-        if tenant_quotas:
-            # quota accounting lives in the page allocator — the
-            # contiguous per-shard slab has none. Loud, not silent: a
-            # quota the runtime will not enforce is a security config bug
-            import logging
+    def _init_storage(self, reserved_space: int, page_rows: int | None,
+                      tenant_quotas: dict[Any, int] | None) -> None:
+        from pathway_tpu.engine.paged_store import (PageAllocator,
+                                                    quota_pages,
+                                                    register_pool)
+        from pathway_tpu.engine.paged_store import page_rows as _page_rows
 
-            logging.getLogger("pathway_tpu.paged_store").warning(
-                "tenant_quotas are only enforced by the paged store — "
-                "the contiguous sharded slab (PATHWAY_PAGED_STORE=0) "
-                "ignores them")
-        self.cap_per_shard = slab_cap_per_shard(self.n_shards,
-                                                reserved_space)
-        cap = self.total_capacity
-        self._host_vectors = np.zeros((cap, self.dim), dtype=np.float32)
-        self._host_valid = np.zeros((cap,), dtype=bool)
-        # per-shard LIFO free lists
-        self._free: list[list[int]] = [
-            list(range((s + 1) * self.cap_per_shard - 1,
-                       s * self.cap_per_shard - 1, -1))
-            for s in range(self.n_shards)
-        ]
-        self._dev_vectors = None
-        self._dev_valid = None
-        self._dev_scales = None  # int8 only: per-row scale + INT-domain
-        self._dev_vsq = None     # squared norm, both (S, C) f32
+        self._page_rows = _page_rows(page_rows)
+        quota_p = ({t: quota_pages(rows, self._page_rows)
+                    for t, rows in tenant_quotas.items()}
+                   if tenant_quotas else None)
+        self.cap_per_shard = 0  # grows as extents are added
+        self._extents: list[_ShardExtent] = []
+        self._allocator = PageAllocator(self._page_rows, quota_p)
+        # per-shard free-row counters: the emptiest-shard choice runs per
+        # KEY on bulk ingest, and a full allocator scan there is O(S*E)
+        # dict work per row. The counters are exact without quotas; with
+        # quotas the allocator scan stays authoritative (quota headroom is
+        # global, a raw counter could overstate a shard's availability)
+        self._shard_free_rows = [0] * self.n_shards
+        self.grow_events = 0
+        self._host_vectors = np.zeros((0, self.dim), dtype=np.float32)
+        self._host_valid = np.zeros((0,), dtype=bool)
+        self._add_extent(slab_cap_per_shard(
+            self.n_shards, reserved_space, self._page_rows))
+        register_pool(self)
 
     @property
     def total_capacity(self) -> int:
@@ -162,33 +170,98 @@ class ShardedKnnIndex:
     def __len__(self) -> int:
         return len(self._key_to_slot)
 
-    # -- storage hooks (the paged subclass swaps these) -----------------
+    def stats(self) -> dict:
+        """Pool-stats shape for engine.paged_store.live_paged_stats."""
+        return self.page_stats()
+
+    def page_stats(self) -> dict:
+        with self._lock:
+            st = self._allocator.stats()
+            st.update({
+                "capacity_rows": self.total_capacity,
+                "extents": len(self._extents),
+                "grow_events": self.grow_events,
+                "shards": self.n_shards,
+            })
+            return st
+
+    # -- extents ---------------------------------------------------------
+    def _add_extent(self, cap_per_shard: int) -> None:
+        base = self.total_capacity
+        ext = _ShardExtent(base, cap_per_shard)
+        eidx = len(self._extents)
+        self._extents.append(ext)
+        for s in range(self.n_shards):
+            self._allocator.add_region(
+                (eidx, s), base + s * cap_per_shard,
+                cap_per_shard // self._page_rows)
+            self._shard_free_rows[s] += cap_per_shard
+        self.cap_per_shard += cap_per_shard
+        cap = self.total_capacity
+        new_vec = np.zeros((cap, self.dim), dtype=np.float32)
+        new_vec[:len(self._host_vectors)] = self._host_vectors
+        self._host_vectors = new_vec
+        new_valid = np.zeros((cap,), dtype=bool)
+        new_valid[:len(self._host_valid)] = self._host_valid
+        self._host_valid = new_valid
+
+    def _grow(self) -> None:
+        """Online growth: one more sharded extent (per-shard size doubles
+        the per-shard total so far) — existing extents, slot ids and the
+        dirty set are untouched."""
+        self.grow_events += 1
+        self._add_extent(_round_up(self.cap_per_shard, self._page_rows))
+
+    # -- slot allocation through per-shard page regions ------------------
+    def _shard_regions(self, shard: int) -> list:
+        return [(e, shard) for e in range(len(self._extents))]
+
+    def _shard_of(self, slot: int) -> int:
+        for ext in self._extents:
+            if slot < ext.base + self.n_shards * ext.cap_per_shard:
+                return (slot - ext.base) // ext.cap_per_shard
+        raise IndexError(slot)
+
+    def _shard_free(self, shard: int) -> int:
+        if self._allocator.tenant_quota_pages is None:
+            return self._shard_free_rows[shard]
+        return self._allocator.free_slots_available(
+            self._tenant, regions=self._shard_regions(shard))
+
     def _ensure_free(self, n: int) -> None:
-        while sum(len(f) for f in self._free) < n:
+        from pathway_tpu.engine.paged_store import PageQuotaExceeded
+
+        capped = self._allocator.quota_capped_slots(self._tenant)
+        if capped is not None and capped < n:
+            # growth cannot help: the tenant's quota, not the pool, is
+            # the limit (and an unguarded loop would grow forever)
+            raise PageQuotaExceeded(
+                f"tenant {self._tenant!r} needs {n} slots but its page "
+                f"quota caps it at {capped} more")
+        while self._allocator.free_slots_available(self._tenant) < n:
             self._grow()
 
     def _release_slot(self, slot: int) -> None:
-        self._free[slot // self.cap_per_shard].append(slot)
+        self._allocator.release_slot(slot)
+        self._shard_free_rows[self._shard_of(slot)] += 1
 
-    # ------------------------------------------------------------------
     def _alloc_slot(self, key: Pointer) -> int:
-        """Slot for ``key``, allocating from the emptiest shard (growing if
-        all shards are full). Lock held. Balances instead of key-hash routing
-        (reference routes by hash, src/engine/dataflow/shard.rs:6-20) to
-        avoid hash skew in the slab."""
+        """Slot for ``key``, allocating from the emptiest shard (growing
+        if all shards are full). Lock held."""
         slot = self._key_to_slot.get(key)
         if slot is None:
-            shard = max(range(self.n_shards),
-                        key=lambda s: len(self._free[s]))
-            if not self._free[shard]:
-                self._grow()
-                shard = max(range(self.n_shards),
-                            key=lambda s: len(self._free[s]))
-            slot = self._free[shard].pop()
+            shard = max(range(self.n_shards), key=self._shard_free)
+            if self._shard_free(shard) == 0:
+                self._ensure_free(1)
+                shard = max(range(self.n_shards), key=self._shard_free)
+            slot = self._allocator.take_slot(
+                self._tenant, regions=self._shard_regions(shard))
+            self._shard_free_rows[shard] -= 1
             self._key_to_slot[key] = slot
             self._slot_to_key[slot] = key
         return slot
 
+    # ------------------------------------------------------------------
     def add(self, key: Pointer, vector: Any,
             filter_data: Any | None = None) -> None:
         with self._lock:
@@ -206,8 +279,7 @@ class ShardedKnnIndex:
     def add_batch(self, keys: list[Pointer], vectors,
                   filter_data: list[Any] | None = None) -> None:
         """Vectorized add (same contract as ops.knn add_batch); rows go to
-        the emptiest shards. Capacity is ensured up front because _grow()
-        remaps slot ids — no grow may happen mid-allocation."""
+        the emptiest shards."""
         if len(keys) == 0:
             return
         vecs = np.asarray(vectors, dtype=np.float32)
@@ -243,107 +315,93 @@ class ShardedKnnIndex:
             self._release_slot(slot)
             self._dirty.add(slot)
 
-    def _grow(self) -> None:
-        """Double per-shard capacity; slot ids are remapped shard-locally."""
-        old_per = self.cap_per_shard
-        new_per = old_per * 2
-        cap = self.n_shards * new_per
-        new_vec = np.zeros((cap, self.dim), dtype=np.float32)
-        new_valid = np.zeros((cap,), dtype=bool)
-        remap: dict[int, int] = {}
-        for s in range(self.n_shards):
-            old_lo, new_lo = s * old_per, s * new_per
-            new_vec[new_lo:new_lo + old_per] = \
-                self._host_vectors[old_lo:old_lo + old_per]
-            new_valid[new_lo:new_lo + old_per] = \
-                self._host_valid[old_lo:old_lo + old_per]
-            for i in range(old_per):
-                remap[old_lo + i] = new_lo + i
-        self._host_vectors = new_vec
-        self._host_valid = new_valid
-        self._key_to_slot = {k: remap[v] for k, v in self._key_to_slot.items()}
-        self._slot_to_key = {remap[s]: k for s, k in self._slot_to_key.items()}
-        self._free = [
-            [remap[s] for s in shard_free] +
-            list(range((i + 1) * new_per - 1, i * new_per + old_per - 1, -1))
-            for i, shard_free in enumerate(self._free)
-        ]
-        self.cap_per_shard = new_per
-        self._dev_vectors = None
-        self._dev_valid = None
-        self._dev_scales = None
-        self._dev_vsq = None
-        self._search_fn_cache.clear()
-        self._dirty.clear()
-
-    # ------------------------------------------------------------------
+    # -- device sync per extent ------------------------------------------
     def flush_device(self) -> None:
-        """Push pending host-mirror changes to the sharded device slab now
-        (same contract as ops.knn.BruteForceKnnIndex.flush_device — the
+        """Push pending host-mirror changes to the sharded device extents
+        now (same contract as ops.knn.BruteForceKnnIndex.flush_device — the
         external-index operator calls this after ingest-only ticks so
         uploads ride the device leg instead of the next query)."""
         with self._lock:
             self._flush_to_device()
 
-    def _flush_to_device(self):
+    def _sharding(self):
+        import jax
+
+        return jax.sharding.NamedSharding(
+            self._mesh, jax.sharding.PartitionSpec(DATA_AXIS))
+
+    def _zeros_sharded(self, shape, dtype):
+        """Zero-establish a sharded array ON DEVICE when the runtime
+        supports out_shardings (no host transfer); host zeros upload as
+        the fallback."""
         import jax
         import jax.numpy as jnp
 
-        S, C, D = self.n_shards, self.cap_per_shard, self.dim
-        sharding = jax.sharding.NamedSharding(
-            self._mesh, jax.sharding.PartitionSpec(DATA_AXIS))
+        sharding = self._sharding()
+        try:
+            return jax.jit(lambda: jnp.zeros(shape, dtype),
+                           out_shardings=sharding)()
+        except TypeError:
+            return jax.device_put(np.zeros(shape, dtype), sharding)
 
-        def slab_rows(rows):
-            if self.dtype == "bfloat16":
-                return rows.astype(jnp.bfloat16) if hasattr(rows, "astype") \
-                    else rows
-            return rows
+    def _establish_extent(self, ext: _ShardExtent) -> None:
+        if ext.vectors is not None:
+            return
+        import jax.numpy as jnp
 
-        if self._dev_vectors is None:
+        S, C, D = self.n_shards, ext.cap_per_shard, self.dim
+        if self.dtype == "int8":
+            ext.vectors = self._zeros_sharded((S, C, D), jnp.int8)
+            # per-row scale + INT-domain squared norm, both (S, C) f32
+            ext.scales = self._zeros_sharded((S, C), jnp.float32)
+            ext.vsq = self._zeros_sharded((S, C), jnp.float32)
+        else:
+            dt = jnp.bfloat16 if self.dtype == "bfloat16" else jnp.float32
+            ext.vectors = self._zeros_sharded((S, C, D), dt)
+        ext.valid = self._zeros_sharded((S, C), jnp.bool_)
+
+    def _split_by_extent(self, idxs: np.ndarray):
+        for ext in self._extents:
+            span = self.n_shards * ext.cap_per_shard
+            in_ext = (idxs >= ext.base) & (idxs < ext.base + span)
+            if not in_ext.any():
+                continue
+            pos = np.flatnonzero(in_ext)
+            yield ext, idxs[pos] - ext.base, pos
+
+    def _flush_to_device(self):
+        import jax.numpy as jnp
+
+        for ext in self._extents:
+            self._establish_extent(ext)
+        if not self._dirty:
+            return
+        idxs = np.fromiter(self._dirty, dtype=np.int64)
+        self._dirty.clear()
+        for ext, local, pos in self._split_by_extent(idxs):
+            rows_global = idxs[pos]
+            sh, sl = local // ext.cap_per_shard, local % ext.cap_per_shard
             if self.dtype == "int8":
-                q, scale, vsq = _quantize_i8_np(self._host_vectors)
-                self._dev_vectors = jax.device_put(
-                    q.reshape(S, C, D), sharding)
-                self._dev_scales = jax.device_put(
-                    scale.reshape(S, C), sharding)
-                self._dev_vsq = jax.device_put(
-                    vsq.reshape(S, C), sharding)
+                q, scale, vsq = _quantize_i8_np(
+                    self._host_vectors[rows_global])
+                ext.vectors = ext.vectors.at[sh, sl].set(jnp.asarray(q))
+                ext.scales = ext.scales.at[sh, sl].set(jnp.asarray(scale))
+                ext.vsq = ext.vsq.at[sh, sl].set(jnp.asarray(vsq))
             else:
-                host = self._host_vectors
+                rows = self._host_vectors[rows_global]
                 if self.dtype == "bfloat16":
                     import ml_dtypes
 
-                    host = host.astype(ml_dtypes.bfloat16)
-                self._dev_vectors = jax.device_put(
-                    host.reshape(S, C, D), sharding)
-            self._dev_valid = jax.device_put(
-                self._host_valid.reshape(S, C), sharding)
-            self._dirty.clear()
-            return
-        if self._dirty:
-            idxs = np.fromiter(self._dirty, dtype=np.int32)
-            self._dirty.clear()
-            sh, sl = idxs // C, idxs % C
-            if self.dtype == "int8":
-                q, scale, vsq = _quantize_i8_np(self._host_vectors[idxs])
-                self._dev_vectors = self._dev_vectors.at[sh, sl].set(
-                    jnp.asarray(q))
-                self._dev_scales = self._dev_scales.at[sh, sl].set(
-                    jnp.asarray(scale))
-                self._dev_vsq = self._dev_vsq.at[sh, sl].set(
-                    jnp.asarray(vsq))
-            else:
-                self._dev_vectors = self._dev_vectors.at[sh, sl].set(
-                    slab_rows(jnp.asarray(self._host_vectors[idxs])))
-            self._dev_valid = self._dev_valid.at[sh, sl].set(
-                jnp.asarray(self._host_valid[idxs]))
+                    rows = rows.astype(ml_dtypes.bfloat16)
+                ext.vectors = ext.vectors.at[sh, sl].set(jnp.asarray(rows))
+            ext.valid = ext.valid.at[sh, sl].set(
+                jnp.asarray(self._host_valid[rows_global]))
 
+    # -- multi-extent search ---------------------------------------------
     @staticmethod
     def _local_scores(queries, vecs, valid_row, extras, metric, int8):
         """(B, C) scores of replicated queries vs one shard-local slab
-        block — the ONE scoring block both the contiguous and the paged
-        (multi-extent) sharded kernels trace, so their per-row arithmetic
-        can never diverge."""
+        block of one extent."""
         import jax
         import jax.numpy as jnp
 
@@ -380,7 +438,9 @@ class ShardedKnnIndex:
         return jnp.where(valid_row[None, :], scores, -jnp.inf)
 
     def _get_search_fn(self, k: int):
-        cache_key = (k, self.cap_per_shard, self.dtype)
+        caps = tuple(e.cap_per_shard for e in self._extents)
+        bases = tuple(e.base for e in self._extents)
+        cache_key = (k, caps, self.dtype)
         fn = self._search_fn_cache.get(cache_key)
         if fn is not None:
             return fn
@@ -389,31 +449,39 @@ class ShardedKnnIndex:
         from jax.sharding import PartitionSpec as P
 
         metric = self.metric
-        C = self.cap_per_shard
         int8 = self.dtype == "int8"
+        per_ext = 4 if int8 else 2
         score = self._local_scores
 
-        def local_search(queries, vectors, valid, *extras):
-            # queries (B, D) replicated; vectors (1, C, D), valid (1, C)
-            # local; extras = (scales, vsq) per-shard for int8
-            ex = (extras[0][0], extras[1][0]) if int8 else ()
-            scores = score(queries, vectors[0], valid[0], ex, metric, int8)
-            s, i = jax.lax.top_k(scores, min(k, C))  # (B, k) local
-            # globalize slot ids with this shard's offset
+        def local_search(queries, *ops):
+            # ops per extent: vectors (1,C,D), valid (1,C)[, scales, vsq]
             shard_id = jax.lax.axis_index(DATA_AXIS)
-            gi = i + shard_id * C
-            # gather candidates from every shard: (S, B, k) on each chip
+            cand_s, cand_i = [], []
+            for e, (C, base) in enumerate(zip(caps, bases)):
+                o = ops[e * per_ext:(e + 1) * per_ext]
+                ex = (o[2][0], o[3][0]) if int8 else ()
+                scores = score(queries, o[0][0], o[1][0], ex, metric, int8)
+                s, i = jax.lax.top_k(scores, min(k, C))
+                cand_s.append(s)
+                # slot ids: extent base + this shard's block + row
+                cand_i.append(base + shard_id * C + i)
+            s = jnp.concatenate(cand_s, axis=1)
+            gi = jnp.concatenate(cand_i, axis=1)
+            # local merge BEFORE the gather: cross-chip traffic stays
+            # n_shards x B x k however many extents exist
+            s, pos = jax.lax.top_k(s, min(k, s.shape[1]))
+            gi = jnp.take_along_axis(gi, pos, axis=1)
             all_s = jax.lax.all_gather(s, DATA_AXIS)
             all_i = jax.lax.all_gather(gi, DATA_AXIS)
             B = queries.shape[0]
-            cand_s = jnp.transpose(all_s, (1, 0, 2)).reshape(B, -1)
-            cand_i = jnp.transpose(all_i, (1, 0, 2)).reshape(B, -1)
-            ms, mpos = jax.lax.top_k(cand_s, min(k, cand_s.shape[1]))
-            mi = jnp.take_along_axis(cand_i, mpos, axis=1)
-            return ms, mi
+            cs = jnp.transpose(all_s, (1, 0, 2)).reshape(B, -1)
+            ci = jnp.transpose(all_i, (1, 0, 2)).reshape(B, -1)
+            ms, mpos = jax.lax.top_k(cs, min(k, cs.shape[1]))
+            return ms, jnp.take_along_axis(ci, mpos, axis=1)
 
-        in_specs = tuple(P(*axes)
-                         for axes, _rank in search_operand_layout(self.dtype))
+        ext_specs = tuple(P(*axes) for axes, _rank
+                          in search_operand_layout(self.dtype)[1:])
+        in_specs = (P(),) + ext_specs * len(caps)
         shard_fn = _shard_map(
             local_search, mesh=self._mesh,
             in_specs=in_specs,
@@ -428,10 +496,12 @@ class ShardedKnnIndex:
         """(scores, global slots) host arrays, best first. Lock held,
         device state flushed."""
         search_fn = self._get_search_fn(fetch_k)
-        extras = ((self._dev_scales, self._dev_vsq)
-                  if self.dtype == "int8" else ())
-        ts, ti = search_fn(qmat, self._dev_vectors, self._dev_valid,
-                           *extras)
+        ops = []
+        for ext in self._extents:
+            ops += [ext.vectors, ext.valid]
+            if self.dtype == "int8":
+                ops += [ext.scales, ext.vsq]
+        ts, ti = search_fn(qmat, *ops)
         return np.asarray(ts), np.asarray(ti)
 
     def search(self, queries: list[tuple]) -> list[tuple]:
@@ -482,299 +552,5 @@ class ShardedKnnIndex:
         return _passes(self._filter_data, key, filt)
 
 
-class _ShardExtent:
-    """One sharded device allocation: ``cap_per_shard`` rows PER SHARD,
-    laid out as (n_shards, cap_per_shard, dim) over the mesh data axis.
-    Global slots [base + s*cap, base + (s+1)*cap) belong to shard s."""
-
-    __slots__ = ("base", "cap_per_shard", "vectors", "valid", "scales",
-                 "vsq")
-
-    def __init__(self, base: int, cap_per_shard: int):
-        self.base = base
-        self.cap_per_shard = cap_per_shard
-        self.vectors = None
-        self.valid = None
-        self.scales = None
-        self.vsq = None
-
-
-class PagedShardedKnnIndex(ShardedKnnIndex):
-    """ShardedKnnIndex over per-shard page tables (the default —
-    ``ShardedKnnIndex(...)`` constructs this class unless
-    ``PATHWAY_PAGED_STORE=0`` / ``paged=False``).
-
-    Each shard's slab is a whole number of pages (``slab_cap_per_shard``
-    page-aligned ⇒ ``pages_per_shard`` is the reservation unit) tracked by
-    ONE PageAllocator whose regions are (extent, shard) blocks. Growth
-    appends a sharded extent — a fresh (S, C_new, D) device allocation —
-    with NO slot remapping and NO re-upload of existing extents (the
-    contiguous path remaps every slot and re-uploads the whole slab).
-    The search kernel scores every extent shard-locally, merges the
-    per-extent top-k on-chip, and only then pays the cross-chip
-    all-gather: ICI traffic stays n_shards x B x k scores regardless of
-    extent count."""
-
-    def _init_storage(self, reserved_space: int, *,
-                      page_rows: int | None = None, tenant: Any = None,
-                      tenant_quotas: dict[Any, int] | None = None) -> None:
-        from pathway_tpu.engine.paged_store import (PageAllocator,
-                                                    quota_pages)
-        from pathway_tpu.engine.paged_store import page_rows as _page_rows
-
-        self._page_rows = _page_rows(page_rows)
-        self._tenant = tenant
-        quota_p = ({t: quota_pages(rows, self._page_rows)
-                    for t, rows in tenant_quotas.items()}
-                   if tenant_quotas else None)
-        self.cap_per_shard = 0  # grows as extents are added
-        self._extents: list[_ShardExtent] = []
-        self._allocator = PageAllocator(self._page_rows, quota_p)
-        # per-shard free-row counters: the emptiest-shard choice runs per
-        # KEY on bulk ingest, and a full allocator scan there is O(S*E)
-        # dict work per row. The counters are exact without quotas; with
-        # quotas the allocator scan stays authoritative (quota headroom is
-        # global, a raw counter could overstate a shard's availability)
-        self._shard_free_rows = [0] * self.n_shards
-        self.grow_events = 0
-        self._host_vectors = np.zeros((0, self.dim), dtype=np.float32)
-        self._host_valid = np.zeros((0,), dtype=bool)
-        self._free = None  # slot accounting lives in the page allocator
-        self._dev_vectors = None   # unused in paged mode (per-extent state)
-        self._dev_valid = None
-        self._dev_scales = None
-        self._dev_vsq = None
-        self._add_extent(slab_cap_per_shard(
-            self.n_shards, reserved_space, self._page_rows))
-        from pathway_tpu.engine.paged_store import register_pool
-
-        register_pool(self)
-
-    def stats(self) -> dict:
-        """Pool-stats shape for engine.paged_store.live_paged_stats."""
-        return self.page_stats()
-
-    # -- extents ---------------------------------------------------------
-    def _add_extent(self, cap_per_shard: int) -> None:
-        base = self.total_capacity
-        ext = _ShardExtent(base, cap_per_shard)
-        eidx = len(self._extents)
-        self._extents.append(ext)
-        for s in range(self.n_shards):
-            self._allocator.add_region(
-                (eidx, s), base + s * cap_per_shard,
-                cap_per_shard // self._page_rows)
-            self._shard_free_rows[s] += cap_per_shard
-        self.cap_per_shard += cap_per_shard
-        cap = self.total_capacity
-        new_vec = np.zeros((cap, self.dim), dtype=np.float32)
-        new_vec[:len(self._host_vectors)] = self._host_vectors
-        self._host_vectors = new_vec
-        new_valid = np.zeros((cap,), dtype=bool)
-        new_valid[:len(self._host_valid)] = self._host_valid
-        self._host_valid = new_valid
-
-    def _grow(self) -> None:
-        """Online growth: one more sharded extent (per-shard size doubles
-        the per-shard total so far) — existing extents, slot ids and the
-        dirty set are untouched."""
-        self.grow_events += 1
-        self._add_extent(_round_up(self.cap_per_shard, self._page_rows))
-
-    def page_stats(self) -> dict:
-        with self._lock:
-            st = self._allocator.stats()
-            st.update({
-                "capacity_rows": self.total_capacity,
-                "extents": len(self._extents),
-                "grow_events": self.grow_events,
-                "shards": self.n_shards,
-            })
-            return st
-
-    # -- slot allocation through per-shard page regions ------------------
-    def _shard_regions(self, shard: int) -> list:
-        return [(e, shard) for e in range(len(self._extents))]
-
-    def _shard_of(self, slot: int) -> int:
-        for ext in self._extents:
-            if slot < ext.base + self.n_shards * ext.cap_per_shard:
-                return (slot - ext.base) // ext.cap_per_shard
-        raise IndexError(slot)
-
-    def _shard_free(self, shard: int) -> int:
-        if self._allocator.tenant_quota_pages is None:
-            return self._shard_free_rows[shard]
-        return self._allocator.free_slots_available(
-            self._tenant, regions=self._shard_regions(shard))
-
-    def _ensure_free(self, n: int) -> None:
-        from pathway_tpu.engine.paged_store import PageQuotaExceeded
-
-        capped = self._allocator.quota_capped_slots(self._tenant)
-        if capped is not None and capped < n:
-            # growth cannot help: the tenant's quota, not the pool, is
-            # the limit (and an unguarded loop would grow forever)
-            raise PageQuotaExceeded(
-                f"tenant {self._tenant!r} needs {n} slots but its page "
-                f"quota caps it at {capped} more")
-        while self._allocator.free_slots_available(self._tenant) < n:
-            self._grow()
-
-    def _release_slot(self, slot: int) -> None:
-        self._allocator.release_slot(slot)
-        self._shard_free_rows[self._shard_of(slot)] += 1
-
-    def _alloc_slot(self, key: Pointer) -> int:
-        slot = self._key_to_slot.get(key)
-        if slot is None:
-            # balance by emptiest shard, exactly like the slab path
-            shard = max(range(self.n_shards), key=self._shard_free)
-            if self._shard_free(shard) == 0:
-                self._ensure_free(1)
-                shard = max(range(self.n_shards), key=self._shard_free)
-            slot = self._allocator.take_slot(
-                self._tenant, regions=self._shard_regions(shard))
-            self._shard_free_rows[shard] -= 1
-            self._key_to_slot[key] = slot
-            self._slot_to_key[slot] = key
-        return slot
-
-    # -- device sync per extent ------------------------------------------
-    def _sharding(self):
-        import jax
-
-        return jax.sharding.NamedSharding(
-            self._mesh, jax.sharding.PartitionSpec(DATA_AXIS))
-
-    def _zeros_sharded(self, shape, dtype):
-        """Zero-establish a sharded array ON DEVICE when the runtime
-        supports out_shardings (no host transfer); host zeros upload as
-        the fallback."""
-        import jax
-        import jax.numpy as jnp
-
-        sharding = self._sharding()
-        try:
-            return jax.jit(lambda: jnp.zeros(shape, dtype),
-                           out_shardings=sharding)()
-        except TypeError:
-            return jax.device_put(np.zeros(shape, dtype), sharding)
-
-    def _establish_extent(self, ext: _ShardExtent) -> None:
-        if ext.vectors is not None:
-            return
-        import jax.numpy as jnp
-
-        S, C, D = self.n_shards, ext.cap_per_shard, self.dim
-        if self.dtype == "int8":
-            ext.vectors = self._zeros_sharded((S, C, D), jnp.int8)
-            ext.scales = self._zeros_sharded((S, C), jnp.float32)
-            ext.vsq = self._zeros_sharded((S, C), jnp.float32)
-        else:
-            dt = jnp.bfloat16 if self.dtype == "bfloat16" else jnp.float32
-            ext.vectors = self._zeros_sharded((S, C, D), dt)
-        ext.valid = self._zeros_sharded((S, C), jnp.bool_)
-
-    def _split_by_extent(self, idxs: np.ndarray):
-        for ext in self._extents:
-            span = self.n_shards * ext.cap_per_shard
-            in_ext = (idxs >= ext.base) & (idxs < ext.base + span)
-            if not in_ext.any():
-                continue
-            pos = np.flatnonzero(in_ext)
-            yield ext, idxs[pos] - ext.base, pos
-
-    def _flush_to_device(self):
-        import jax.numpy as jnp
-
-        for ext in self._extents:
-            self._establish_extent(ext)
-        if not self._dirty:
-            return
-        idxs = np.fromiter(self._dirty, dtype=np.int64)
-        self._dirty.clear()
-        for ext, local, pos in self._split_by_extent(idxs):
-            rows_global = idxs[pos]
-            sh, sl = local // ext.cap_per_shard, local % ext.cap_per_shard
-            if self.dtype == "int8":
-                q, scale, vsq = _quantize_i8_np(
-                    self._host_vectors[rows_global])
-                ext.vectors = ext.vectors.at[sh, sl].set(jnp.asarray(q))
-                ext.scales = ext.scales.at[sh, sl].set(jnp.asarray(scale))
-                ext.vsq = ext.vsq.at[sh, sl].set(jnp.asarray(vsq))
-            else:
-                rows = self._host_vectors[rows_global]
-                if self.dtype == "bfloat16":
-                    import ml_dtypes
-
-                    rows = rows.astype(ml_dtypes.bfloat16)
-                ext.vectors = ext.vectors.at[sh, sl].set(jnp.asarray(rows))
-            ext.valid = ext.valid.at[sh, sl].set(
-                jnp.asarray(self._host_valid[rows_global]))
-
-    # -- multi-extent search ---------------------------------------------
-    def _get_search_fn(self, k: int):
-        caps = tuple(e.cap_per_shard for e in self._extents)
-        bases = tuple(e.base for e in self._extents)
-        cache_key = (k, caps, self.dtype)
-        fn = self._search_fn_cache.get(cache_key)
-        if fn is not None:
-            return fn
-        import jax
-        import jax.numpy as jnp
-        from jax.sharding import PartitionSpec as P
-
-        metric = self.metric
-        int8 = self.dtype == "int8"
-        per_ext = 4 if int8 else 2
-        score = self._local_scores
-
-        def local_search(queries, *ops):
-            # ops per extent: vectors (1,C,D), valid (1,C)[, scales, vsq]
-            shard_id = jax.lax.axis_index(DATA_AXIS)
-            cand_s, cand_i = [], []
-            for e, (C, base) in enumerate(zip(caps, bases)):
-                o = ops[e * per_ext:(e + 1) * per_ext]
-                ex = (o[2][0], o[3][0]) if int8 else ()
-                scores = score(queries, o[0][0], o[1][0], ex, metric, int8)
-                s, i = jax.lax.top_k(scores, min(k, C))
-                cand_s.append(s)
-                # paged slot ids: extent base + this shard's block + row
-                cand_i.append(base + shard_id * C + i)
-            s = jnp.concatenate(cand_s, axis=1)
-            gi = jnp.concatenate(cand_i, axis=1)
-            # local merge BEFORE the gather: cross-chip traffic stays
-            # n_shards x B x k however many extents exist
-            s, pos = jax.lax.top_k(s, min(k, s.shape[1]))
-            gi = jnp.take_along_axis(gi, pos, axis=1)
-            all_s = jax.lax.all_gather(s, DATA_AXIS)
-            all_i = jax.lax.all_gather(gi, DATA_AXIS)
-            B = queries.shape[0]
-            cs = jnp.transpose(all_s, (1, 0, 2)).reshape(B, -1)
-            ci = jnp.transpose(all_i, (1, 0, 2)).reshape(B, -1)
-            ms, mpos = jax.lax.top_k(cs, min(k, cs.shape[1]))
-            return ms, jnp.take_along_axis(ci, mpos, axis=1)
-
-        ext_specs = tuple(P(*axes) for axes, _rank
-                          in search_operand_layout(self.dtype)[1:])
-        in_specs = (P(),) + ext_specs * len(caps)
-        shard_fn = _shard_map(
-            local_search, mesh=self._mesh,
-            in_specs=in_specs,
-            out_specs=(P(), P()),
-            check_vma=False,
-        )
-        fn = jax.jit(shard_fn)
-        self._search_fn_cache[cache_key] = fn
-        return fn
-
-    def _device_topk(self, qmat, fetch_k: int):
-        search_fn = self._get_search_fn(fetch_k)
-        ops = []
-        for ext in self._extents:
-            ops += [ext.vectors, ext.valid]
-            if self.dtype == "int8":
-                ops += [ext.scales, ext.vsq]
-        ts, ti = search_fn(qmat, *ops)
-        return np.asarray(ts), np.asarray(ti)
+# one class, two names (see ops/knn.py PagedKnnIndex)
+PagedShardedKnnIndex = ShardedKnnIndex

@@ -46,9 +46,9 @@ class BruteForceKnnFactory:
     # embedders (set by DataIndex when a query-embedder override is in
     # play — the fused text path could not honor it)
     fuse: bool = True
-    # paged store only: this index's page-allocator tenant tag + per-tenant
-    # row quotas (rounded UP to whole pages; PWT111 flags non-page-aligned
-    # quotas and quota sums past device HBM)
+    # this index's page-allocator tenant tag + per-tenant row quotas
+    # (rounded UP to whole pages; PWT111 flags non-page-aligned quotas and
+    # quota sums past device HBM)
     tenant: Any = None
     tenant_quotas: dict | None = None
 

@@ -1,7 +1,7 @@
 """Auto-jit canary: the framework-vs-raw throughput gate + the trace
 artifact (internals/autojit.py, round-5 verdict #5).
 
-One gate, evidence-first (same pattern as paging_canary.py):
+One gate, evidence-first:
 
 **bench autojit leg** (bench.bench_autojit): the SAME doc-scoring
 pipeline — traceable/vmappable scalar UDF chain + host-only formatter +

@@ -149,7 +149,7 @@ def check_rooflines() -> str | None:
         # knn_search + ingest_scatter
         rng = np.random.default_rng(11)
         vecs = rng.normal(size=(64, 16)).astype(np.float32)
-        idx = BruteForceKnnIndex(16, metric=KnnMetric.L2SQ, paged=False)
+        idx = BruteForceKnnIndex(16, metric=KnnMetric.L2SQ)
         idx.add_batch([Pointer(i) for i in range(64)], vecs)
         idx.search([(Pointer(900), vecs[3], 4, None)])
         # encoder_forward (packed) + segment_attention (ragged)

@@ -55,8 +55,8 @@ REQUIRE_SLO = os.environ.get("QOS_CANARY_REQUIRE_SLO", "") not in ("", "0")
 _BENCH_ENV = {
     "JAX_PLATFORMS": os.environ.get("JAX_PLATFORMS", "cpu"),
     "BENCH_SKIP": ",".join(sorted(
-        {"etl", "autojit", "scaleout", "paging", "durability", "recovery",
-         "replica", "embed", "framework", "knn", "serving"})),
+        {"etl", "autojit", "scaleout", "durability", "recovery", "replica",
+         "embed", "framework", "knn", "serving"})),
     "BENCH_QOS_N": os.environ.get("BENCH_QOS_N", "8000"),
     "BENCH_QOS_QUERIES": os.environ.get("BENCH_QOS_QUERIES", "16"),
     "BENCH_QOS_WARMUP": os.environ.get("BENCH_QOS_WARMUP", "4"),

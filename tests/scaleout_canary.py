@@ -1,8 +1,8 @@
 """Scale-out canary: the exchange plane must carry an honest multi-worker
 speedup, on both transports, without changing a single output byte.
 
-Two gates (same pattern as paging_canary.py — the gate is trusted because
-a seeded property is proven end to end):
+Two gates (the gate is trusted because a seeded property is proven end to
+end):
 
 1. **bench scaleout leg** (bench.bench_scaleout): the WordCount+join ETL
    pipeline at 1 process vs 4 SPMD processes over BOTH transports (shm

@@ -194,7 +194,7 @@ def gate_sanitized_serving() -> str | None:
             max_len=64, max_batch_size=1)
         idx = DeviceEmbeddingKnnIndex(
             emb, BruteForceKnnIndex(cfg.hidden, metric=KnnMetric.COS,
-                                    paged=True, page_rows=128))
+                                    page_rows=128))
         # population is pre-steady-state work: compiles here are warmup
         texts = [f"document number {i} with content {i * 7}"
                  for i in range(300)]  # 3 extents at page_rows=128

@@ -77,7 +77,7 @@ def test_bench_refuses_device_legs_off_the_chip():
 def test_bench_stamps_the_device_on_host_only_runs():
     """With every device leg skipped the host legs run anywhere — and the
     line still says which device JAX had."""
-    skip = ("embed,framework,knn,serving,autojit,scaleout,paging,"
+    skip = ("embed,framework,knn,serving,autojit,scaleout,"
             "durability,recovery,replica,qos,semantic_cache,etl")
     proc, out = _run_bench(JAX_PLATFORMS="cpu", BENCH_SKIP=skip)
     assert proc.returncode == 0, proc.stderr[-500:]

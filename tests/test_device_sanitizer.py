@@ -207,8 +207,7 @@ def test_paged_multi_extent_search_zero_compiles_in_steady_state(
     from pathway_tpu.ops.knn import BruteForceKnnIndex, KnnMetric
 
     monkeypatch.setenv("PATHWAY_DEVICE_SANITIZER", "1")
-    idx = BruteForceKnnIndex(8, metric=KnnMetric.COS, paged=True,
-                             page_rows=128)
+    idx = BruteForceKnnIndex(8, metric=KnnMetric.COS, page_rows=128)
     rng = np.random.default_rng(7)
     vecs = rng.normal(size=(300, 8)).astype(np.float32)  # 3 extents
     idx.add_batch([Pointer(i) for i in range(300)], vecs)

@@ -66,10 +66,10 @@ def test_monitoring_hammer_under_paged_ingest_and_pipelining():
     from pathway_tpu.ops.knn import BruteForceKnnIndex
 
     rng = np.random.default_rng(7)
-    # paged explicitly: the race class under test lives in the page
-    # allocator's dict iteration, regardless of the matrix's default
+    # the race class under test lives in the page allocator's dict
+    # iteration
     index = BruteForceKnnIndex(dimensions=DIM, reserved_space=256,
-                               paged=True, page_rows=128)
+                               page_rows=128)
     bridge = DeviceBridge(max_inflight=4, name="hammer-bridge")
     server = MonitoringHttpServer(_Runtime(bridge), port=0)
     server.start()

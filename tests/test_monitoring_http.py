@@ -252,7 +252,7 @@ def test_paged_store_metrics_exposed():
     from pathway_tpu.internals.keys import Pointer
     from pathway_tpu.ops.knn import BruteForceKnnIndex
 
-    idx = BruteForceKnnIndex(8, paged=True, tenant="acme")
+    idx = BruteForceKnnIndex(8, tenant="acme")
     idx.add_batch([Pointer(i) for i in range(10)],
                   np.zeros((10, 8), np.float32))
     lines = _metrics_lines(_FakeRuntime())

@@ -1,11 +1,15 @@
-"""Paged HBM vector store (engine/paged_store.py + ops/knn.py PagedKnnIndex
-+ parallel/sharded_knn.py PagedShardedKnnIndex) and ragged encoder batching.
+"""Paged HBM vector store (engine/paged_store.py + ops/knn.py
+BruteForceKnnIndex + parallel/sharded_knn.py ShardedKnnIndex) and ragged
+encoder batching.
 
-The load-bearing contract: the paged store is BYTE-IDENTICAL to the
-contiguous slab (PATHWAY_PAGED_STORE=0) across ingest/delete/grow/search
-churn — same keys, same distances, bit for bit — while growth allocates
-pages instead of re-uploading, fused donated ingest grows instead of
-raising, and freed pages are reused (occupancy bounded).
+The load-bearing contract: THE EXTENT LAYOUT DOES NOT CHANGE AN ANSWER. An
+index grown extent by extent answers BYTE-IDENTICALLY to one reserved in a
+single extent (the layout every benchmark cell runs: one kernel call, no
+merge) across ingest/delete/grow/search churn — same keys, same distances,
+bit for bit — and both agree with a numpy float32 exact search that
+shares no code with the index. Growth allocates pages instead of
+re-uploading, the fused donated ingest grows, and freed pages are reused
+(occupancy bounded).
 """
 
 from __future__ import annotations
@@ -15,18 +19,58 @@ import pytest
 
 from pathway_tpu.engine.paged_store import (DevicePagePool, PageAllocator,
                                             PageQuotaExceeded,
-                                            live_paged_stats, page_rows,
-                                            paged_store_enabled)
+                                            live_paged_stats, page_rows)
 from pathway_tpu.internals.keys import Pointer
-from pathway_tpu.ops.knn import BruteForceKnnIndex, KnnMetric, PagedKnnIndex
+from pathway_tpu.ops.knn import BruteForceKnnIndex, KnnMetric
 
 
 def _mk(n=None, **kw):
-    # paged pinned explicitly: this suite must test the paged path even
-    # on the CI matrix leg that flips the default to the slab
     kw.setdefault("metric", KnnMetric.L2SQ)
-    kw.setdefault("paged", True)
     return BruteForceKnnIndex(8, **kw)
+
+
+def _np_exact(metric, vecs, live, q, k):
+    """Exact float32 top-k in plain numpy over the live rows — the
+    reference that shares nothing with the index (no kernel, no mirror,
+    no ranking code). Returns [(row id, distance)], best first."""
+    live = np.asarray(sorted(live))
+    v = vecs[live].astype(np.float32)
+    q = np.asarray(q, dtype=np.float32)
+    if metric == KnnMetric.COS:
+        d = 1.0 - (v @ q) / (np.linalg.norm(v, axis=1) * np.linalg.norm(q))
+    else:
+        d = np.sum((v - q[None, :]) ** 2, axis=1)
+    order = np.argsort(d, kind="stable")[:k]
+    return [(int(live[i]), float(d[i])) for i in order]
+
+
+def _extents(idx) -> int:
+    return idx.page_stats()["extents"]
+
+
+def _rows_by_extent(idx) -> dict:
+    """Live row ids grouped by the extent their slot lives in."""
+    exts = idx._pool.extents if hasattr(idx, "_pool") else idx._extents
+    bases = [e.base for e in exts]
+    out: dict[int, list[int]] = {}
+    for key, slot in idx._key_to_slot.items():
+        e = int(np.searchsorted(bases, slot, side="right")) - 1
+        out.setdefault(e, []).append(int(key))
+    return out
+
+
+def _probe_rows(idx, per: int = 3) -> list:
+    """A few live rows of EVERY extent: queried with their own vectors,
+    each extent must put at least its self-matches into the answers, so
+    an extent dropped from the merge cannot go unnoticed."""
+    return [r for _e, rows in sorted(_rows_by_extent(idx).items())
+            for r in sorted(rows)[:per]]
+
+
+def _answers_span_every_extent(idx, results) -> bool:
+    by = _rows_by_extent(idx)
+    where = {r: e for e, rows in by.items() for r in rows}
+    return {where[int(k)] for res in results for k, _ in res} == set(by)
 
 
 # ---------------------------------------------------------------------------
@@ -43,15 +87,6 @@ def test_page_rows_validation(monkeypatch):
     monkeypatch.setenv("PATHWAY_PAGE_ROWS", "100")
     with pytest.raises(ValueError):
         page_rows()
-
-
-def test_paged_store_env_gate(monkeypatch):
-    monkeypatch.delenv("PATHWAY_PAGED_STORE", raising=False)
-    assert paged_store_enabled()          # default ON
-    assert not paged_store_enabled(False)  # explicit arg wins
-    monkeypatch.setenv("PATHWAY_PAGED_STORE", "0")
-    assert not paged_store_enabled()
-    assert paged_store_enabled(True)
 
 
 def test_allocator_alloc_free_reuse():
@@ -114,28 +149,19 @@ def test_pool_grow_appends_extent_without_touching_old():
 
 
 # ---------------------------------------------------------------------------
-# paged index vs slab: byte-identical across churn
+# grown extent by extent vs reserved in one extent: byte-identical across
+# churn ("slab" in the names below: the one-extent instance)
 # ---------------------------------------------------------------------------
-
-def test_default_is_paged_and_opt_out_works(monkeypatch):
-    monkeypatch.delenv("PATHWAY_PAGED_STORE", raising=False)
-    idx = BruteForceKnnIndex(8)
-    assert isinstance(idx, PagedKnnIndex)
-    slab = BruteForceKnnIndex(8, paged=False)
-    assert type(slab) is BruteForceKnnIndex
-    monkeypatch.setenv("PATHWAY_PAGED_STORE", "0")
-    assert type(BruteForceKnnIndex(8)) is BruteForceKnnIndex
-
 
 @pytest.mark.parametrize("metric", [KnnMetric.L2SQ, KnnMetric.COS])
 def test_churn_byte_identical_vs_slab(metric):
     """The acceptance-pinned property: interleaved ingest/delete/grow/
-    search — paged top-k == slab top-k, keys AND distances, byte for
-    byte."""
+    search — the grown index's top-k == the one-extent index's top-k,
+    keys AND distances, byte for byte."""
     rng = np.random.default_rng(11)
-    paged = BruteForceKnnIndex(16, metric=metric, paged=True)
-    slab = BruteForceKnnIndex(16, metric=metric, paged=False)
-    assert isinstance(paged, PagedKnnIndex)
+    paged = BruteForceKnnIndex(16, metric=metric)
+    # 30 steps of at most 400 new rows: never leaves its first extent
+    slab = BruteForceKnnIndex(16, metric=metric, reserved_space=30 * 400)
     live: list[int] = []
     next_key = 0
 
@@ -170,17 +196,17 @@ def test_churn_byte_identical_vs_slab(metric):
                    rng.normal(size=16).astype(np.float32),
                    int(rng.integers(1, 12)), None) for i in range(4)]
             assert paged.search(qs) == slab.search(qs)
-    assert paged.capacity > 1024, "churn never grew the store"
+    assert _extents(paged) >= 2, "churn never grew the store"
+    assert _extents(slab) == 1
     assert len(paged) == len(slab) == len(live)
 
 
 @pytest.mark.parametrize("dtype", ["bfloat16", "int8"])
 def test_churn_low_precision_paged_matches_slab(dtype):
     rng = np.random.default_rng(3)
-    paged = BruteForceKnnIndex(16, metric=KnnMetric.COS, dtype=dtype,
-                               paged=True)
+    paged = BruteForceKnnIndex(16, metric=KnnMetric.COS, dtype=dtype)
     slab = BruteForceKnnIndex(16, metric=KnnMetric.COS, dtype=dtype,
-                              paged=False)
+                              reserved_space=1500)
     keys = [Pointer(i) for i in range(1500)]  # grows past 1024
     vecs = rng.normal(size=(1500, 16)).astype(np.float32)
     paged.add_batch(keys, vecs)
@@ -191,9 +217,40 @@ def test_churn_low_precision_paged_matches_slab(dtype):
     qs = [(Pointer(10**9 + i), vecs[700 + 13 * i], 10, None)
           for i in range(4)]
     rp, rs = paged.search(qs), slab.search(qs)
+    assert _extents(paged) >= 2 and _extents(slab) == 1
     for a, b in zip(rp, rs):
         assert [k for k, _ in a] == [k for k, _ in b]
         np.testing.assert_allclose([d for _, d in a], [d for _, d in b],
+                                   rtol=1e-5, atol=1e-5)
+
+
+# ---------------------------------------------------------------------------
+# the independent reference: numpy float32 exact search over a grown index
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("metric", [KnnMetric.L2SQ, KnnMetric.COS])
+def test_grown_index_matches_numpy_oracle(metric):
+    rng = np.random.default_rng(21)
+    n, dim, k = 2600, 16, 10
+    vecs = rng.normal(size=(n, dim)).astype(np.float32)
+    idx = BruteForceKnnIndex(dim, metric=metric)
+    for lo in range(0, n, 650):  # 1024 + 1024 + 2048 rows: three extents
+        idx.add_batch([Pointer(i) for i in range(lo, lo + 650)],
+                      vecs[lo:lo + 650])
+    removed = set(rng.choice(n, size=900, replace=False).tolist())
+    for i in sorted(removed):
+        idx.remove(Pointer(i))
+    live = set(range(n)) - removed
+    assert _extents(idx) >= 3 and len(idx) == len(live)
+    qvecs = vecs[_probe_rows(idx)] + np.float32(0.05)
+    got = idx.search([(Pointer(10**9 + i), q, k, None)
+                      for i, q in enumerate(qvecs)])
+    assert _answers_span_every_extent(idx, got)
+    for q, res in zip(qvecs, got):
+        want = _np_exact(metric, vecs, live, q, k)
+        assert [int(key) for key, _ in res] == [i for i, _ in want]
+        np.testing.assert_allclose([d for _, d in res],
+                                   [d for _, d in want],
                                    rtol=1e-5, atol=1e-5)
 
 
@@ -216,7 +273,7 @@ def test_filtered_search_and_exhaustive_fallback_paged(monkeypatch):
 
 
 # ---------------------------------------------------------------------------
-# fused donated ingest: paged grows, slab still errors (regression)
+# fused donated ingest grows
 # ---------------------------------------------------------------------------
 
 def test_fused_ingest_grows_by_allocating_extent():
@@ -235,14 +292,36 @@ def test_fused_ingest_grows_by_allocating_extent():
     assert res[0][0][0] == Pointer(2500 + 17)
 
 
-def test_fused_ingest_slab_still_errors_clearly():
+@pytest.mark.parametrize("dtype", ["bfloat16", "int8"])
+def test_fused_ingest_grown_matches_one_extent(dtype):
+    """The donated one-dispatch ingest growing into fresh extents answers
+    byte for byte as the same ingest into one reserved extent."""
     import jax.numpy as jnp
 
-    slab = _mk(paged=False)
-    ingest = slab.make_fused_ingest(lambda x: x)
-    with pytest.raises(ValueError, match="cannot grow the slab"):
-        ingest([Pointer(i) for i in range(2000)],
-               jnp.zeros((2000, 8), jnp.float32))
+    rng = np.random.default_rng(22)
+    n = 3000
+    vecs = rng.normal(size=(n, 8)).astype(np.float32)
+    grown = _mk(metric=KnnMetric.COS, dtype=dtype)
+    one = _mk(metric=KnnMetric.COS, dtype=dtype, reserved_space=n)
+    for idx in (grown, one):
+        ingest = idx.make_fused_ingest(lambda x: x)
+        for lo in range(0, n, 500):
+            ingest([Pointer(lo + i) for i in range(500)],
+                   jnp.asarray(vecs[lo:lo + 500]))
+        # every batch took the donated dispatch: nothing went through
+        # the two-dispatch scatter
+        assert idx.upload_rows_total == 0
+    assert _extents(grown) >= 2 and _extents(one) == 1
+    assert grown.page_stats()["grow_events"] >= 1
+    for i in range(0, n, 3):
+        grown.remove(Pointer(i))
+        one.remove(Pointer(i))
+    qs = [(Pointer(10**9 + i), vecs[r], 12, None)
+          for i, r in enumerate(_probe_rows(grown))]
+    rg, ro = grown.search(qs), one.search(qs)
+    assert _answers_span_every_extent(grown, rg)
+    assert rg == ro
+    assert all(len(r) == 12 and int(k) % 3 for r in rg for k, _ in r)
 
 
 def test_fused_ingest_quota_exceeded_is_not_swallowed():
@@ -353,12 +432,9 @@ def mesh4():
 
 
 def test_sharded_paged_grow_without_remap(mesh4):
-    from pathway_tpu.parallel.sharded_knn import (PagedShardedKnnIndex,
-                                                  ShardedKnnIndex)
+    from pathway_tpu.parallel.sharded_knn import ShardedKnnIndex
 
-    idx = ShardedKnnIndex(8, mesh=mesh4, reserved_space=8, page_rows=128,
-                          paged=True)
-    assert isinstance(idx, PagedShardedKnnIndex)
+    idx = ShardedKnnIndex(8, mesh=mesh4, reserved_space=8, page_rows=128)
     assert idx.cap_per_shard == 128  # page-aligned minimum
     rng = np.random.default_rng(10)
     n = idx.total_capacity + 200
@@ -368,7 +444,7 @@ def test_sharded_paged_grow_without_remap(mesh4):
     slot_snapshot = dict(idx._key_to_slot)
     idx.add_batch([Pointer(n)],
                   rng.normal(size=(1, 8)).astype(np.float32))
-    # online growth: NO slot was remapped (the slab path remaps them all)
+    # online growth: NO slot was remapped
     assert all(idx._key_to_slot[k] == s for k, s in slot_snapshot.items())
     for probe in (0, n // 2, n - 1):
         res = idx.search([(Pointer(10**6), vecs[probe], 1, None)])
@@ -380,7 +456,7 @@ def test_sharded_paged_tenant_quota_enforced(mesh4):
     from pathway_tpu.parallel.sharded_knn import ShardedKnnIndex
 
     idx = ShardedKnnIndex(8, mesh=mesh4, reserved_space=8, page_rows=128,
-                          paged=True, tenant="acme",
+                          tenant="acme",
                           tenant_quotas={"acme": 512})  # 4 pages
     rng = np.random.default_rng(13)
     idx.add_batch([Pointer(i) for i in range(512)],
@@ -401,11 +477,11 @@ def test_sharded_paged_matches_contiguous(mesh4):
     rng = np.random.default_rng(12)
     vecs = rng.normal(size=(700, 8)).astype(np.float32)
     keys = [Pointer(i) for i in range(700)]
-    paged = ShardedKnnIndex(8, mesh=mesh4, reserved_space=8, page_rows=128,
-                            paged=True)
-    flat = ShardedKnnIndex(8, mesh=mesh4, reserved_space=700, paged=False)
+    paged = ShardedKnnIndex(8, mesh=mesh4, reserved_space=8, page_rows=128)
+    flat = ShardedKnnIndex(8, mesh=mesh4, reserved_space=700)
     paged.add_batch(keys, vecs)
     flat.add_batch(keys, vecs)
+    assert _extents(paged) >= 2 and _extents(flat) == 1
     for i in range(0, 700, 2):
         paged.remove(Pointer(i))
         flat.remove(Pointer(i))
@@ -415,6 +491,59 @@ def test_sharded_paged_matches_contiguous(mesh4):
     for a, b in zip(rp, rf):
         assert [k for k, _ in a] == [k for k, _ in b]
         np.testing.assert_allclose([d for _, d in a], [d for _, d in b],
+                                   rtol=1e-5, atol=1e-5)
+
+
+def _sharded_churn(mesh4, n, rng, **kw):
+    """A sharded index grown across extents by add/remove/add, and its
+    twin reserved in one extent; returns (grown, one, vecs, live)."""
+    from pathway_tpu.parallel.sharded_knn import ShardedKnnIndex
+
+    vecs = rng.normal(size=(n, 8)).astype(np.float32)
+    grown = ShardedKnnIndex(8, mesh=mesh4, reserved_space=8, page_rows=128,
+                            **kw)
+    one = ShardedKnnIndex(8, mesh=mesh4, reserved_space=n, page_rows=128,
+                          **kw)
+    half = n // 2
+    for idx in (grown, one):
+        idx.add_batch([Pointer(i) for i in range(half)], vecs[:half])
+        idx.search([(Pointer(10**6), vecs[0], 1, None)])  # flush, then churn
+        for i in range(0, half, 3):
+            idx.remove(Pointer(i))
+        idx.add_batch([Pointer(i) for i in range(half, n)], vecs[half:])
+    live = {i for i in range(half) if i % 3} | set(range(half, n))
+    assert _extents(grown) >= 3 and _extents(one) == 1
+    assert len(grown) == len(one) == len(live)
+    return grown, one, vecs, live
+
+
+@pytest.mark.parametrize("metric", [KnnMetric.L2SQ, KnnMetric.COS])
+def test_sharded_int8_grown_matches_one_extent(mesh4, metric):
+    rng = np.random.default_rng(23)
+    grown, one, vecs, live = _sharded_churn(mesh4, 1300, rng, metric=metric,
+                                            dtype="int8")
+    qs = [(Pointer(10**6 + i), vecs[r], 8, None)
+          for i, r in enumerate(_probe_rows(grown))]
+    rg, ro = grown.search(qs), one.search(qs)
+    assert _answers_span_every_extent(grown, rg)
+    assert rg == ro
+    assert all(int(k) in live for r in rg for k, _ in r)
+
+
+@pytest.mark.parametrize("metric", [KnnMetric.L2SQ, KnnMetric.COS])
+def test_sharded_grown_matches_numpy_oracle(mesh4, metric):
+    rng = np.random.default_rng(24)
+    grown, _one, vecs, live = _sharded_churn(mesh4, 1300, rng,
+                                             metric=metric)
+    qvecs = vecs[_probe_rows(grown)] + np.float32(0.05)
+    got = grown.search([(Pointer(10**6 + i), q, 8, None)
+                        for i, q in enumerate(qvecs)])
+    assert _answers_span_every_extent(grown, got)
+    for q, res in zip(qvecs, got):
+        want = _np_exact(metric, vecs, live, q, 8)
+        assert [int(key) for key, _ in res] == [i for i, _ in want]
+        np.testing.assert_allclose([d for _, d in res],
+                                   [d for _, d in want],
                                    rtol=1e-5, atol=1e-5)
 
 
@@ -469,7 +598,7 @@ def test_ragged_fused_ingest_end_to_end():
     cfg = EncoderConfig.tiny()
     emb = JaxEncoderEmbedder(config=cfg, ragged=True, max_len=64)
     inner = BruteForceKnnIndex(cfg.hidden, metric=KnnMetric.COS,
-                               dtype="bfloat16", paged=True)
+                               dtype="bfloat16")
     idx = DeviceEmbeddingKnnIndex(emb, inner)
     texts = [f"document number {i} with content {i * 7}" for i in range(150)]
     idx.add_batch([Pointer(i) for i in range(150)], texts)
@@ -487,8 +616,7 @@ def test_ragged_warmup_compile_count_under_six():
     cfg = EncoderConfig.tiny(max_len=512)
     emb = JaxEncoderEmbedder(config=cfg, ragged=True, max_len=512)
     idx = DeviceEmbeddingKnnIndex(
-        emb, BruteForceKnnIndex(cfg.hidden, metric=KnnMetric.COS,
-                                paged=True))
+        emb, BruteForceKnnIndex(cfg.hidden, metric=KnnMetric.COS))
     out = pw.warmup(emb, index=idx)
     # leaked gc-pending fused programs from other tests may add autojit
     # entries — the ragged ladder is what this pin counts

@@ -376,7 +376,7 @@ def _knn_roundtrip(n=48, dim=8, q=3):
 
     rng = np.random.default_rng(7)
     vecs = rng.normal(size=(n, dim)).astype(np.float32)
-    idx = BruteForceKnnIndex(dim, metric=KnnMetric.L2SQ, paged=False)
+    idx = BruteForceKnnIndex(dim, metric=KnnMetric.L2SQ)
     idx.add_batch([Pointer(i) for i in range(n)], vecs)
     queries = [(Pointer(1000 + i), vecs[i * 5], 4, None) for i in range(q)]
     return idx.search(queries)
@@ -393,38 +393,15 @@ def test_knn_outputs_identical_with_profiler_on_and_off():
     assert fams["ingest_scatter"]["dispatches"] >= 1
     assert fams["knn_search"]["dispatches"] >= 1
     assert fams["knn_search"]["roofline"]["bound_by"] == "bandwidth"
-    # search bytes follow the slab-scan model exactly: N*D*4 + Q*D*4
-    # per dispatch, with N the (power-of-two) device capacity
+    assert fams["knn_search"]["device_ms_total"] > 0.0
+    # search bytes follow the scan model exactly: N*D*4 + Q*D*4 per
+    # dispatch, with N the (power-of-two) rows of the established extents
     from pathway_tpu.engine.profiler import knn_search_cost as cost
 
     per = fams["knn_search"]["bytes_total"] / \
         fams["knn_search"]["dispatches"]
     caps = [cost(3, 1 << p, 8)[1] for p in range(4, 12)]
     assert per in caps
-
-
-@pytest.mark.slow
-def test_paged_knn_records_families_too():
-    # the paged store (default since PR 7) overrides _scatter and
-    # _device_topk — the production serving path must feed the cost
-    # model like the legacy slab does (regression: a live server on
-    # paged storage exported zero kernel families)
-    from pathway_tpu.internals.keys import Pointer
-    from pathway_tpu.ops.knn import BruteForceKnnIndex, KnnMetric
-
-    prof = Profiler(sample_interval_ms=1e6, machine=V5E)
-    install_profiler(prof)
-    rng = np.random.default_rng(7)
-    vecs = rng.normal(size=(48, 8)).astype(np.float32)
-    idx = BruteForceKnnIndex(8, metric=KnnMetric.L2SQ, paged=True)
-    idx.add_batch([Pointer(i) for i in range(48)], vecs)
-    out = idx.search([(Pointer(1000), vecs[5], 4, None)])
-    assert out and out[0]
-    fams = prof.family_stats()
-    assert fams["ingest_scatter"]["dispatches"] >= 1
-    assert fams["knn_search"]["dispatches"] >= 1
-    assert fams["knn_search"]["roofline"]["bound_by"] == "bandwidth"
-    assert fams["knn_search"]["device_ms_total"] > 0.0
 
 
 # ---------------------------------------------------------------------------
@@ -488,8 +465,7 @@ def test_knn_search_attributes_tenant_to_live_trackers():
     span = tr.start("r1", "/q", t_ingress=0.0)
     span.key = qkey
     tr._by_key[qkey] = span
-    idx = BruteForceKnnIndex(4, metric=KnnMetric.L2SQ, paged=False)
-    idx._tenant = "acme"
+    idx = BruteForceKnnIndex(4, metric=KnnMetric.L2SQ, tenant="acme")
     idx.add_batch([Pointer(0)], np.ones((1, 4), np.float32))
     idx.search([(qkey, np.ones(4, np.float32), 1, None)])
     assert span.tenant == "acme"

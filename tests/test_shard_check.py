@@ -343,56 +343,10 @@ def test_pwt107_negative_model_1_or_device_embedder(tmp_path):
 
 
 # ---------------------------------------------------------------------------
-# PWT108 — fused donated slab with no reserved capacity
-# ---------------------------------------------------------------------------
-
-class _DeviceEmbedder:
-    def encode_batch_device(self, texts):
-        raise NotImplementedError
-
-    def get_embedding_dimension(self):
-        return 16
-
-
-def test_pwt108_fused_ingest_without_reservation(tmp_path, monkeypatch):
-    # the fused-path cliff only exists on the contiguous slab — the paged
-    # store grows the fused path by allocating pages
-    monkeypatch.setenv("PATHWAY_PAGED_STORE", "0")
-    _knn_pipeline(tmp_path, mesh=None, embedder=_DeviceEmbedder(),
-                  reserved_space=0)
-    diags = pw.static_check()
-    pwt108 = [d for d in diags if d.code == "PWT108"]
-    assert len(pwt108) == 1
-    assert pwt108[0].severity is Severity.WARNING
-    assert "1024" in pwt108[0].message  # names the pinned minimum capacity
-
-
-def test_pwt108_negative_reserved_or_unfused(tmp_path, monkeypatch):
-    monkeypatch.setenv("PATHWAY_PAGED_STORE", "0")
-    _knn_pipeline(tmp_path, mesh=None, embedder=_DeviceEmbedder(),
-                  reserved_space=4096)
-    assert "PWT108" not in codes(pw.static_check())
-    G.clear()
-    # a plain UDF embedder has no fused device path to lose
-    _knn_pipeline(tmp_path, mesh=None, reserved_space=0)
-    assert "PWT108" not in codes(pw.static_check())
-
-
-def test_pwt108_suppressed_under_paged_store(tmp_path, monkeypatch):
-    # default (paged) storage: fused ingest grows by allocating a page,
-    # so the unreserved-slab cliff PWT108 warns about does not exist
-    monkeypatch.delenv("PATHWAY_PAGED_STORE", raising=False)
-    _knn_pipeline(tmp_path, mesh=None, embedder=_DeviceEmbedder(),
-                  reserved_space=0)
-    assert "PWT108" not in codes(pw.static_check())
-
-
-# ---------------------------------------------------------------------------
 # PWT111 — paged-store reservation / tenant quota layout
 # ---------------------------------------------------------------------------
 
 def test_pwt111_unaligned_reservation(tmp_path, monkeypatch):
-    monkeypatch.delenv("PATHWAY_PAGED_STORE", raising=False)
     monkeypatch.delenv("PATHWAY_PAGE_ROWS", raising=False)
     _knn_pipeline(tmp_path, mesh=None, reserved_space=1500)
     diags = pw.static_check()
@@ -402,8 +356,7 @@ def test_pwt111_unaligned_reservation(tmp_path, monkeypatch):
     assert "1500" in pwt[0].message and "2048" in pwt[0].message
 
 
-def test_pwt111_unaligned_tenant_quota(tmp_path, monkeypatch):
-    monkeypatch.delenv("PATHWAY_PAGED_STORE", raising=False)
+def test_pwt111_unaligned_tenant_quota(tmp_path):
     _knn_pipeline(tmp_path, mesh=None, reserved_space=1024,
                   tenant_quotas={"acme": 1500, "globex": 2048})
     diags = pw.static_check()
@@ -413,7 +366,6 @@ def test_pwt111_unaligned_tenant_quota(tmp_path, monkeypatch):
 
 
 def test_pwt111_quotas_past_device_hbm(tmp_path, monkeypatch):
-    monkeypatch.delenv("PATHWAY_PAGED_STORE", raising=False)
     monkeypatch.setenv("PATHWAY_DEVICE_HBM_GB", "1")
     # 16 B/row f32 rows: 2^27 rows/tenant x 4 tenants = 8 GiB >> 1 GiB
     quotas = {f"t{i}": (1 << 27) for i in range(4)}
@@ -425,16 +377,10 @@ def test_pwt111_quotas_past_device_hbm(tmp_path, monkeypatch):
     assert "HBM" in over[0].message
 
 
-def test_pwt111_negative_cases(tmp_path, monkeypatch):
+def test_pwt111_negative_cases(tmp_path):
     # page-aligned reservation + aligned, HBM-fitting quotas: clean
-    monkeypatch.delenv("PATHWAY_PAGED_STORE", raising=False)
     _knn_pipeline(tmp_path, mesh=None, reserved_space=2048,
                   tenant_quotas={"acme": 4096})
-    assert "PWT111" not in codes(pw.static_check())
-    G.clear()
-    # slab mode: the paged layout rules do not apply
-    monkeypatch.setenv("PATHWAY_PAGED_STORE", "0")
-    _knn_pipeline(tmp_path, mesh=None, reserved_space=1500)
     assert "PWT111" not in codes(pw.static_check())
 
 
