@@ -11,6 +11,7 @@ from __future__ import annotations
 
 import json
 import os
+import sys
 import threading
 from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 
@@ -42,6 +43,15 @@ def _expert_load() -> dict | None:
         return expert_load_stats()
     except Exception:
         return None
+
+
+def _scan_lowerings() -> dict | None:
+    """Delta-rule scans lowered in this process by the lowering they took
+    (ops/deltanet.py), or None where no program holds one (a process
+    that never loaded the module does not load it for this)."""
+    module = sys.modules.get("pathway_tpu.ops.deltanet")
+    took = module.scan_lowerings() if module is not None else {}
+    return took if any(took.values()) else None
 
 
 def _cache_stats() -> dict | None:
@@ -681,6 +691,15 @@ class MonitoringHttpServer:
             lines.append("# TYPE pathway_tpu_moe_buffer_rows_mean gauge")
             lines.append(f"pathway_tpu_moe_buffer_rows_mean "
                          f"{experts['buffer_rows_mean']}")
+        scans = _scan_lowerings()
+        if scans is not None:
+            # which lowering the delta-rule scans of the compiled programs
+            # took: the fused TPU kernel, or the reference (every other
+            # backend, and the shapes the kernel does not tile)
+            lines.append("# TYPE pathway_tpu_deltanet_scan_programs counter")
+            for lowering, count in sorted(scans.items()):
+                lines.append(f'pathway_tpu_deltanet_scan_programs'
+                             f'{{lowering="{lowering}"}} {count}')
         paged = _paged_stats()
         if paged is not None:
             # paged vector store occupancy (engine/paged_store.py): pool

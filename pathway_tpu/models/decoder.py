@@ -198,9 +198,10 @@ def _l2_normalise(x, eps: float = 1e-6):
     return x * jax.lax.rsqrt(jnp.sum(x * x, axis=-1, keepdims=True) + eps)
 
 
-def deltanet_layer(x, p, pos, config: DecoderConfig):
+def deltanet_layer(x, p, pos, config: DecoderConfig, valid=None):
     """Gated DeltaNet mixer. x (B, T, H) normed input; pos (B, T) a
-    token's position in its document (0 resets state and convolution)."""
+    token's position in its document (0 resets state and convolution);
+    valid (B, T) False at padding, whose output nobody reads."""
     c = config
     b, t, _ = x.shape
     nk, nv = c.linear_num_key_heads, c.linear_num_value_heads
@@ -218,10 +219,9 @@ def deltanet_layer(x, p, pos, config: DecoderConfig):
         ba[..., nv:] + p["dt_bias"].astype(jnp.float32))
     q = _l2_normalise(q) * dk ** -0.5
     k = _l2_normalise(k)
-    # each key head serves nv / nk value heads
-    q, k = (jnp.repeat(a, nv // nk, axis=2) for a in (q, k))
+    # q and k stay with the key heads: each serves nv / nk value heads
     with jax.named_scope("decoder.deltanet.scan"):
-        o = deltanet.gated_delta_rule(q, k, v, g, beta, pos == 0)
+        o = deltanet.gated_delta_rule(q, k, v, g, beta, pos == 0, valid)
     o = _rms_norm(o, p["norm"], c.rms_norm_eps, zero_centred=False)
     o = o * jax.nn.silu(z.reshape(b, t, nv, dv))
     return _proj(o.reshape(b, t, vd), p["out_proj"], c)
@@ -316,7 +316,8 @@ def _forward(params, token_ids, pos, seg, config: DecoderConfig):
                 x = x + attention_layer(normed, layer["mixer"], pos, seg, c)
         else:
             with jax.named_scope("decoder.deltanet"):
-                x = x + deltanet_layer(normed, layer["mixer"], pos, c)
+                x = x + deltanet_layer(normed, layer["mixer"], pos, c,
+                                       valid)
         y, took, used = moe_layer(
             _rms_norm(x, layer["norm2"], c.rms_norm_eps), layer["moe"],
             valid, c)

@@ -364,20 +364,32 @@ def _recurrence(q, k, v, g, beta, starts):
     return out
 
 
+def _scan_operands(rng, b, t, nk, nv, dk, dv, starts):
+    q, k = (rng.standard_normal((b, t, nk, dk)) for _ in range(2))
+    q, k = (a / np.linalg.norm(a, axis=-1, keepdims=True) for a in (q, k))
+    v = rng.standard_normal((b, t, nv, dv))
+    g = -rng.uniform(0.0, 2.0, (b, t, nv))
+    beta = rng.uniform(0.0, 1.0, (b, t, nv))
+    return q, k, v, g, beta, starts
+
+
 @pytest.mark.parametrize("length", [1, 63, 64, 65, 200])
 def test_chunked_scan_equals_the_recurrence(length):
+    """Heads of 8 features: no shape of the kernel's, so the reference
+    lowering, which the counter says."""
     rng = np.random.default_rng(length)
     b, h, dk, dv = 2, 3, 8, 8
-    q, k = (rng.standard_normal((b, length, h, dk)) for _ in range(2))
-    q, k = (a / np.linalg.norm(a, axis=-1, keepdims=True) for a in (q, k))
-    v = rng.standard_normal((b, length, h, dv))
-    g = -rng.uniform(0.0, 2.0, (b, length, h))
-    beta = rng.uniform(0.0, 1.0, (b, length, h))
     starts = rng.random((b, length)) < 0.03
     starts[:, 0] = True
+    q, k, v, g, beta, _ = _scan_operands(rng, b, length, h, h, dk, dv,
+                                         starts)
+    before = deltanet.scan_lowerings()
     got = np.asarray(deltanet.gated_delta_rule(
         *(jnp.asarray(a, jnp.float32) for a in (q, k, v, g, beta)),
         jnp.asarray(starts)))
+    after = deltanet.scan_lowerings()
+    assert (after["reference"] - before["reference"],
+            after["kernel"] - before["kernel"]) == (1, 0)
     assert got.shape == (b, length, h, dv)
     for row in range(b):
         want = _recurrence(q[row], k[row], v[row], g[row], beta[row],
@@ -458,3 +470,122 @@ def test_a_fused_dispatch_writes_an_embedder_dispatch_span(weights,
     # the rows are the embedder's: a document finds itself first
     ((key, _score),), = index.search([(Pointer(99), texts[3], 1, None)])
     assert key == Pointer(13)
+
+
+def _documents(t, *firsts):
+    starts = np.zeros(t, bool)
+    starts[list(firsts)] = True
+    return starts
+
+
+#: name: (tokens a row, a document's first tokens row by row, real slots a
+#: row or None for all, key heads). Two value heads a key head, 128
+#: features a head.
+KERNEL_CASES = {
+    "length_1": (1, [(0,)], None, 1),
+    "length_63": (63, [(0, 20)], None, 1),
+    "length_64": (64, [(0, 63)], None, 1),
+    "length_65": (65, [(0, 64)], None, 1),
+    "length_200": (200, [(0, 70, 71)], None, 1),
+    # two rows, two key heads: a value head finds its key head by index
+    "length_512": (512, [(0, 100, 300), (0, 256, 448)], None, 2),
+    # a document from slot 40 to slot 239 lies in chunks 0 to 3 and starts
+    # inside chunk 0; the next one starts on the edge of chunk 4
+    "starts_mid_chunk_and_spans_three_chunks": (
+        320, [(0, 40, 240)], None, 1),
+    # row 0's last three chunks and row 1's last chunk hold padding alone:
+    # every padded slot its own document, as the packer marks it
+    "last_chunks_hold_no_real_token": (
+        320, [(0, 90) + tuple(range(130, 320)),
+              (0,) + tuple(range(250, 320))], [130, 250], 1),
+}
+
+
+@pytest.mark.parametrize("case", KERNEL_CASES)
+def test_scan_kernel_equals_the_reference_lowering_and_the_recurrence(case):
+    """The fused kernel through the interpreter: q and k come with the key
+    heads and are read by index, never repeated; against
+    ``_gated_delta_rule`` to 1e-5 and against the token-by-token definition
+    to 2e-4, at the real slots; past a row's last real token it writes
+    zeros."""
+    t, firsts, real_upto, nk = KERNEL_CASES[case]
+    rng = np.random.default_rng(t)
+    b, nv, d = len(firsts), 2 * nk, 128
+    starts = np.stack([_documents(t, *row) for row in firsts])
+    q, k, v, g, beta, _ = _scan_operands(rng, b, t, nk, nv, d, d, starts)
+    real = None if real_upto is None else \
+        np.arange(t)[None] < np.asarray(real_upto)[:, None]
+    operands = [jnp.asarray(a, jnp.float32) for a in (q, k, v, g, beta)] \
+        + [jnp.asarray(starts)]
+    # jitted: op by op each lowering would compile some sixty small programs
+    got = np.asarray(jax.jit(
+        lambda *a: deltanet._scan_kernel(*a, interpret=True))(
+            *operands, None if real is None else jnp.asarray(real)))
+    assert got.shape == (b, t, nv, d)
+    repeated = [jnp.repeat(a, nv // nk, axis=2) for a in operands[:2]]
+    reference = np.asarray(jax.jit(
+        deltanet._gated_delta_rule, static_argnums=6)(
+            *repeated, *operands[2:], deltanet.CHUNK))
+    read = np.ones((b, t), bool) if real is None else real
+    assert np.abs(got - reference)[read].max() < 1e-5
+    for row in range(b):
+        want = _recurrence(*(np.repeat(a[row], nv // nk, axis=1)
+                             for a in (q, k)), v[row], g[row], beta[row],
+                           starts[row])
+        assert np.allclose(got[row][read[row]], want[read[row]], atol=2e-4)
+    if real is not None:
+        # whole chunks past the last real token: written, as zeros
+        dead = np.arange(t)[None] >= -(-np.asarray(real_upto)[:, None]
+                                       // deltanet.CHUNK) * deltanet.CHUNK
+        assert dead.any() and (got[dead] == 0.0).all()
+        assert np.abs(reference[dead]).max() > 0.0
+
+
+def test_the_kernel_is_taken_for_the_chip_at_its_shapes_alone():
+    """Lowered for the TPU at the published head sizes (16 key heads
+    serving 32 value heads of 128 features), ``deltanet_layer`` carries the
+    kernel's call under ``decoder.deltanet.scan`` and no (.., 64, 64)
+    float32 result outside it; lowered for the CPU, or with heads of 8
+    features, it carries none. The counter says which lowering a program
+    took, as ``/metrics`` shows it."""
+    import re
+
+    from test_monitoring_http import (_FakeRuntime, _metrics_lines,
+                                      _parse_samples)
+
+    def lowered(config, platform):
+        p = jax.eval_shape(lambda key: decoder.init_params(key, config),
+                           jax.random.PRNGKey(0))["layers"][0]["mixer"]
+        x = jax.ShapeDtypeStruct((2, 128, config.hidden_size), jnp.float32)
+        pos = jax.ShapeDtypeStruct((2, 128), jnp.int32)
+        before = deltanet.scan_lowerings()
+        text = jax.jit(lambda x, p, pos: decoder.deltanet_layer(
+            x, p, pos, config, pos >= 0)).trace(x, p, pos).lower(
+                lowering_platforms=(platform,)).as_text(debug_info=True)
+        after = deltanet.scan_lowerings()
+        return text, {name: after[name] - before[name] for name in after}
+
+    wide = decoder.DecoderConfig.tiny(
+        linear_num_key_heads=2, linear_num_value_heads=4,
+        linear_key_head_dim=128, linear_value_head_dim=128)
+    text, took = lowered(wide, "tpu")
+    assert took == {"kernel": 1, "reference": 0}
+    calls = [line for line in text.splitlines() if "tpu_custom_call" in line]
+    assert len(calls) == 1
+    # the call's location is the scope's; the kernel is named for the trace
+    assert "decoder.deltanet.scan" in text and "_scan_body" in calls[0]
+    chunk_squares = re.compile(r"tensor<(\d+x)*64x64xf32>")
+    assert not chunk_squares.search(text)
+    text, took = lowered(wide, "cpu")
+    assert took == {"kernel": 0, "reference": 1}
+    assert "tpu_custom_call" not in text and chunk_squares.search(text)
+    text, took = lowered(decoder.DecoderConfig.tiny(), "tpu")
+    assert took == {"kernel": 0, "reference": 1}
+    assert "tpu_custom_call" not in text and chunk_squares.search(text)
+    samples = {(f, labels.get("lowering")): v for f, labels, v in
+               _parse_samples(_metrics_lines(_FakeRuntime()))}
+    counted = deltanet.scan_lowerings()
+    assert counted["kernel"] >= 1 and counted["reference"] >= 2
+    for name in ("kernel", "reference"):
+        assert samples["pathway_tpu_deltanet_scan_programs", name] \
+            == counted[name]
