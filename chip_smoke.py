@@ -169,6 +169,69 @@ def _chunked_scan_check(rows: int, dim: int) -> None:
     del index
 
 
+def _windowed_decoder_check(cfg, row: int, seed: int) -> dict:
+    """One forward of the decoder with window and full attention mixed
+    (models/decoder.py, ops/attention.py ``segment_attention``) at ``cfg``'s
+    widths on packed rows of ``row`` slots, weights drawn on the device in
+    the compute dtype: a document longer than the window alone in a row,
+    then behind two others. Finite unit embeddings, and the document's
+    embedding does not depend on where it lies; on a TPU with heads of
+    whole lanes the attention took the kernel."""
+    import jax
+    import jax.numpy as jnp
+
+    from pathway_tpu.models import decoder
+    from pathway_tpu.ops import attention
+
+    params = jax.jit(lambda key: decoder.init_params(
+        key, cfg, cfg.compute_dtype))(jax.random.PRNGKey(seed))
+    rng = np.random.default_rng(seed)
+    long_doc = rng.integers(0, cfg.vocab_size, row // 2 + row // 8)
+    others = [rng.integers(0, cfg.vocab_size, n)
+              for n in (row // 16, row // 4)]
+
+    def packed(docs):
+        ids = np.zeros((1, row), np.int32)
+        seg = np.full((1, row), -1, np.int32)
+        pos = np.zeros((1, row), np.int32)
+        last, at = [], 0
+        for j, doc in enumerate(docs):
+            n = len(doc)
+            ids[0, at:at + n], seg[0, at:at + n] = doc, j
+            pos[0, at:at + n] = np.arange(n)
+            at += n
+            last.append(at - 1)
+        return ids, seg, pos, np.zeros(len(docs), np.int32), \
+            np.asarray(last, np.int32)
+
+    forward = jax.jit(cfg.encode_ragged)
+    before = attention.attention_lowerings()
+    alone, _ = forward(params, *map(jnp.asarray, packed([long_doc])))
+    behind, _ = forward(params, *map(jnp.asarray,
+                                     packed(others + [long_doc])))
+    took = {k: v - before[k] for k, v in attention.attention_lowerings(
+        ).items()}
+    alone, behind = np.asarray(alone, np.float32), np.asarray(
+        behind, np.float32)
+    _check(np.isfinite(alone).all() and np.isfinite(behind).all()
+           and np.allclose(np.linalg.norm(behind, axis=1), 1.0, atol=1e-3),
+           f"the windowed decoder's embeddings of a {row}-slot row are "
+           f"finite unit vectors")
+    cos = float(alone[0] @ behind[2])
+    _check(cos >= 0.98, f"a document of {len(long_doc)} tokens (window "
+           f"{cfg.sliding_window_size}) embeds alike alone and behind two "
+           f"others in its row: cos {cos:.5f} >= 0.98")
+    kernel = jax.devices()[0].platform == "tpu" and cfg.head_dim % 128 == 0
+    _check(took["blockwise" if kernel else "kernel"] == 0
+           and took["kernel" if kernel else "blockwise"] > 0,
+           f"attention lowered as the "
+           f"{'kernel' if kernel else 'blockwise loop'}: {took}")
+    del params
+    return {"row": row, "layers": cfg.num_hidden_layers,
+            "hidden": cfg.hidden_size, "experts": cfg.num_experts,
+            "alone_vs_packed_cos": round(cos, 6), "lowerings": took}
+
+
 def _device_memory(devices) -> list[dict]:
     out = []
     for d in devices:
@@ -358,7 +421,8 @@ def run_smoke(*, expected_platform: str, config, n_docs: int,
               max_words: int, max_len: int, scan_rows: int,
               mesh: str | None = None, seed: int = 0,
               request_timeout_s: int = 600,
-              out_path: str | None = None) -> dict:
+              out_path: str | None = None, decoder_config=None,
+              decoder_row: int = 0) -> dict:
     """Drive the main path once and check it; returns the summary dict
     (also printed). Raises on the first failed check.
 
@@ -369,7 +433,10 @@ def run_smoke(*, expected_platform: str, config, n_docs: int,
     fused index, "auto" to shard the index over every visible device.
     ``scan_rows``: size of the device-built slab of the scan check.
     ``out_path``: where to write the top-k answers as JSON (to compare a
-    one-chip with a four-chip run)."""
+    one-chip with a four-chip run). ``decoder_config``: a callable
+    returning a ``DecoderConfig`` of the windowed pattern, forwarded once
+    on rows of ``decoder_row`` slots after the server is down (None: no
+    such check)."""
     t_start = time.perf_counter()
     import jax
 
@@ -425,6 +492,8 @@ def run_smoke(*, expected_platform: str, config, n_docs: int,
                f"bf16 encoder agrees with float32/highest: min cos "
                f"{min_cos:.6f} >= {BF16_VS_F32_MIN_COS}")
         _chunked_scan_check(scan_rows, cfg.hidden)
+        windowed = _windowed_decoder_check(
+            decoder_config(), decoder_row, seed) if decoder_config else None
     finally:
         jax.monitoring.unregister_event_duration_listener(on_jit_event)
 
@@ -450,6 +519,7 @@ def run_smoke(*, expected_platform: str, config, n_docs: int,
         "requests": served["requests"],
         "bridge_legs_resolved": served["bridge_legs_resolved"],
         "bf16_vs_f32_min_cos": round(min_cos, 6),
+        "windowed_decoder": windowed,
         "topk_digest": hashlib.sha256(json.dumps(sorted(
             (q, [name for name, _dist in hits])
             for q, hits in answers.items())).encode()).hexdigest()[:16],
@@ -473,12 +543,33 @@ def main() -> int:
 
             return EncoderConfig.bge_small()  # 12 x 384, 12 heads, bf16
 
+        def smallthinker_period():
+            """One period of SmallThinker-21BA3B at its published widths:
+            benchmark/configs/smallthinker-21b-embed.json."""
+            import jax.numpy as jnp
+
+            from pathway_tpu.models.decoder import DecoderConfig
+
+            return DecoderConfig(
+                vocab_size=151936, hidden_size=2560, num_hidden_layers=4,
+                zero_centred_norm=False, num_attention_heads=28,
+                num_key_value_heads=4, head_dim=128,
+                partial_rotary_factor=1.0, rope_theta=1.5e6,
+                attention_gate=False, qk_norm=False,
+                sliding_window_layout=(0, 1, 1, 1), rope_layout=(0, 1, 1, 1),
+                sliding_window_size=4096, num_experts=64,
+                num_experts_per_tok=6, moe_intermediate_size=768,
+                shared_expert_intermediate_size=None, hidden_act="relu",
+                router_input="mixer_input", max_len=16384,
+                compute_dtype=jnp.bfloat16)
+
         summary = run_smoke(
             expected_platform="tpu", config=bge_small, n_docs=3000,
             max_words=120, max_len=128, scan_rows=1 << 20,
             mesh="auto" if n_devices > 1 else None,
             out_path=os.path.join(out_dir,
-                                  f"chip_smoke_topk_{n_devices}chip.json"))
+                                  f"chip_smoke_topk_{n_devices}chip.json"),
+            decoder_config=smallthinker_period, decoder_row=16384)
     except Exception:  # any failed check or error: exit != 0, no JSON line
         import traceback
 

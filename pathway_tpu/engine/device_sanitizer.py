@@ -47,6 +47,7 @@ from __future__ import annotations
 import contextlib
 import logging
 import os
+import threading
 
 from pathway_tpu.engine.locking import create_lock
 
@@ -97,6 +98,8 @@ class _SanitizerState:
         self.post_warmup_compiles = 0
         self.total_compiles = 0
         self.total_compile_s = 0.0
+        # the same by the name of the thread that compiled
+        self.compile_s_by_thread: dict[str, float] = {}
         self.violation_log: list[dict] = []
 
 
@@ -171,6 +174,9 @@ def _on_compile_event(event: str, duration: float, **_kw) -> None:
     with _STATE.mutex:
         _STATE.total_compiles += 1
         _STATE.total_compile_s += duration
+        thread = threading.current_thread().name
+        _STATE.compile_s_by_thread[thread] = \
+            _STATE.compile_s_by_thread.get(thread, 0.0) + duration
         if not _STATE.armed:
             return
         if not _STATE.steady:
@@ -206,12 +212,23 @@ def install_compile_counter():
     return lambda: _STATE.total_compiles
 
 
-def install_compile_clock():
+def install_compile_clock(thread: str | None = None):
     """As :func:`install_compile_counter`, but the callable yields the
-    seconds the process has spent in backend compiles so far: a device
-    leg's cost model (engine/qos.py) takes them out of the leg's time."""
+    seconds spent in backend compiles so far: by the whole process, or
+    with ``thread`` by the threads whose name holds it. A device leg's
+    cost model (engine/qos.py) takes the bridge worker's out of the leg's
+    time: what another thread compiled meanwhile (a warm-up of the query
+    path beside the first ingest legs) is no part of a leg."""
     _install_listener()
-    return lambda: _STATE.total_compile_s
+    if thread is None:
+        return lambda: _STATE.total_compile_s
+
+    def on_thread() -> float:
+        with _STATE.mutex:
+            return sum(s for name, s in _STATE.compile_s_by_thread.items()
+                       if thread in name)
+
+    return on_thread
 
 
 def _set_transfer_guard(mode: str) -> None:

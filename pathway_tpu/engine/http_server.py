@@ -54,6 +54,20 @@ def _scan_lowerings() -> dict | None:
     return took if any(took.values()) else None
 
 
+def _attention_stats() -> tuple[dict | None, dict | None]:
+    """(attention cores lowered in this process by the lowering they took,
+    key blocks the live embedders' attention ran of all up to the
+    diagonal): ops/attention.py, xpacks/llm/embedders.py; each None where
+    there is nothing (a process that never loaded a module does not load
+    it for this)."""
+    module = sys.modules.get("pathway_tpu.ops.attention")
+    took = module.attention_lowerings() if module is not None else {}
+    embedders = sys.modules.get("pathway_tpu.xpacks.llm.embedders")
+    tiles = embedders.attention_tile_stats() if embedders is not None \
+        else None
+    return (took if any(took.values()) else None), tiles
+
+
 def _cache_stats() -> dict | None:
     """Aggregate semantic-result-cache stats (engine/result_cache.py),
     or None when no cache is live in this process."""
@@ -700,6 +714,23 @@ class MonitoringHttpServer:
             for lowering, count in sorted(scans.items()):
                 lines.append(f'pathway_tpu_deltanet_scan_programs'
                              f'{{lowering="{lowering}"}} {count}')
+        attention, tiles = _attention_stats()
+        if attention is not None:
+            # which lowering the decoder's blocked attention took in the
+            # compiled programs: the Pallas TPU kernel, or plain JAX
+            lines.append("# TYPE pathway_tpu_attention_programs counter")
+            for lowering, count in sorted(attention.items()):
+                lines.append(f'pathway_tpu_attention_programs'
+                             f'{{lowering="{lowering}"}} {count}')
+        if tiles is not None:
+            # key blocks the attention layers ran, of all up to the
+            # diagonal: what windows and documents' edges spare
+            lines.append("# TYPE pathway_tpu_attention_tiles_run counter")
+            lines.append(
+                f"pathway_tpu_attention_tiles_run {tiles['tiles_run']}")
+            lines.append("# TYPE pathway_tpu_attention_tiles_all counter")
+            lines.append(
+                f"pathway_tpu_attention_tiles_all {tiles['tiles_all']}")
         paged = _paged_stats()
         if paged is not None:
             # paged vector store occupancy (engine/paged_store.py): pool

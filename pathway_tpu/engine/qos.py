@@ -246,6 +246,21 @@ class IngestCost:
 #: with a leg's p95 at 0.94 s; under a dispatch a leg the cost of a row
 #: is all fixed cost and the bound falls to one row
 LEG_TICKS = 8
+#: rows a drain takes at most before the device has retired its first
+#: ingest leg: nothing is known of its pace yet, and the window lets two
+#: legs pile up behind the first before a submit waits. Behind an embedder
+#: of 135 ms a dispatch of three documents the three unbounded ticks after
+#: a release held 130 documents, six seconds of legs with `/v1/statistics`
+#: that far ahead of the index (my chip run, PR 33); a device that keeps up
+#: retires its first leg within a tick or two and is held to this for as
+#: long
+FIRST_LEG_ROWS = 8
+#: readings of a row's cost of which the dearest stands, before smoothing
+EARLY_READINGS = 4
+
+
+def _half_more(rows: int) -> int:
+    return rows + (rows + 1) // 2
 
 
 class DeviceBackpressure:
@@ -261,11 +276,15 @@ class DeviceBackpressure:
     ``LEG_TICKS`` commit intervals, by the cost of the legs that have
     retired (:class:`IngestCost`; the first leg has retired by the first
     submit that waits, so the bound stands from the third tick after a
-    release). The rest stays in its session and rides later ticks, as
-    under the controller's budget. A submit that did not wait raises the
-    bound by half while rows are held back and lifts it once a drain
-    left nothing behind: a device that keeps up is never held back, and
-    what a bound held back does not arrive as one tick.
+    release; until the first ingest leg of the runtime's life has retired
+    a drain takes ``FIRST_LEG_ROWS`` at most, and that leg's cost is the
+    first bound). The rest stays in its session and rides later ticks, as
+    under the controller's budget. A submit that found the device idle
+    (no leg in flight but its own) raises the bound by half while rows
+    are held back, up to a leg of ``LEG_TICKS`` intervals by the readings,
+    and lifts it once a drain left nothing behind: a device that keeps up
+    is never held back, and what a bound held back does not arrive as one
+    tick.
 
     The time a leg spent in XLA's compiler is taken out of its cost, and
     a submit that waited for legs which spent most of their time there
@@ -281,16 +300,22 @@ class DeviceBackpressure:
         self.tick_interval_ms = max(1.0, tick_interval_s * 1e3)
         self._cost = IngestCost()
         self._rows: int | None = None
+        # whether an ingest leg has retired yet: before, nothing is known
+        self._paced = False
+        self._readings = 0
         # (tick, ingest rows, query rows) of the ticks whose legs have
         # not retired, and what the last look saw of the bridge
         self._unretired: collections.deque = collections.deque()
         self._exec_ms_seen = 0.0
         self._blocked_seen = 0
-        self._compile_s = install_compile_clock()
+        # the bridge worker's compiles: the legs' own
+        self._compile_s = install_compile_clock("device-bridge")
         self._compile_s_seen = self._compile_s()
 
     def ingest_row_budget(self) -> int | None:
         """Max ingest rows the next drain may take (None: all)."""
+        if self._rows is None and not self._paced:
+            return FIRST_LEG_ROWS
         return self._rows
 
     def note_deferral(self, n_rows: int) -> None:
@@ -308,25 +333,62 @@ class DeviceBackpressure:
             _tick, rows, queries = self._unretired.popleft()
             retired += rows
             clean = clean and not queries
+        self._paced = self._paced or retired > 0
         compile_ms = (self._compile_s() - self._compile_s_seen) * 1e3
         exec_ms = bridge["exec_ms"] - self._exec_ms_seen
-        # a submit that waited for a leg which spent most of its time in
-        # XLA's compiler waited for the compiler, not for the device
-        waited = bridge["submits_blocked"] != self._blocked_seen \
-            and compile_ms <= 0.5 * exec_ms
+        # a leg which spent most of its time in XLA's compiler says nothing
+        # of the device's pace: a submit that waited for it waited for the
+        # compiler, and it is no reading of a row's cost
+        steady = compile_ms <= 0.5 * exec_ms
+        waited = bridge["submits_blocked"] != self._blocked_seen and steady
         self._exec_ms_seen += exec_ms
         self._blocked_seen = bridge["submits_blocked"]
         self._compile_s_seen += compile_ms / 1e3
-        if not waited:
-            if self._rows is not None:
-                self._rows = (self._rows + (self._rows + 1) // 2
-                              if deferred else None)
-            return
-        if retired and clean and exec_ms > compile_ms:
+        first = self._cost.ms_per_row is None
+        if retired and clean and steady and exec_ms > compile_ms:
+            # the first readings are of a few rows each, and rows differ
+            # (documents of 50 to 16,382 tokens): the dearest of them
+            # stands until there are enough to smooth, so that the first
+            # legs come out short rather than long
+            dearest = max(self._cost.ms_per_row or 0.0,
+                          (exec_ms - compile_ms) / retired)
             self._cost.sample(retired, exec_ms - compile_ms)
+            self._readings += 1
+            if self._readings <= EARLY_READINGS:
+                self._cost.ms_per_row = dearest
         rows = self._cost.rows_in(LEG_TICKS * self.tick_interval_ms)
-        if rows is not None:
-            self._rows = max(1, rows)
+        if waited:
+            if rows is not None:
+                # down at once, up by half at most: a reading can flatter
+                # (short legs are cheap a row where a leg's host work
+                # overlaps the next tick's), and a bound that jumped to it
+                # made legs of three times the rows
+                self._rows = max(1, rows) if self._rows is None else max(
+                    1, min(rows, _half_more(self._rows)))
+        elif first and self._rows is None:
+            # the first reading of the device's pace, from a leg held to
+            # ``FIRST_LEG_ROWS``: a leg of ``LEG_TICKS`` intervals by it,
+            # but at most twice what that leg held
+            if rows is not None:
+                self._rows = max(1, min(rows, 2 * FIRST_LEG_ROWS))
+        elif bridge.get("depth", 0) > 1:
+            # the leg before this one had not retired when this one was
+            # submitted: the device is not ahead of the ticks, though the
+            # window (ticks that are slow on the host fill it late) has
+            # room. The bound holds
+            pass
+        elif self._rows is not None:
+            if not deferred:
+                self._rows = None
+            else:
+                # half as much again, but no leg longer than ``LEG_TICKS``
+                # intervals by the readings: where ticks are as slow on the
+                # host as legs on the device the device is idle at many a
+                # submit, and a bound that grew on that alone swung between
+                # one leg's rows and three legs'
+                grown = _half_more(self._rows)
+                self._rows = grown if rows is None \
+                    else max(self._rows, min(grown, rows))
 
 
 class QosController:

@@ -2,14 +2,23 @@
 with last-token pooling, the way causal language models are used for
 retrieval.
 
-The shape is the one published for Qwen3-Next (``config.json`` of
-``Qwen/Qwen3-Next-80B-A3B-Instruct``; :class:`DecoderConfig` takes the
-published keys): pre-norm blocks ``x = x + mixer(norm1(x)); x = x +
-moe(norm2(x))`` with zero-centred RMSNorm (``x / rms(x) * (1 + w)``), no
-biases. Layer ``i`` is gated softmax attention where ``(i + 1) %
-full_attention_interval == 0`` and a Gated DeltaNet otherwise; every layer's
-feed-forward is routed experts (top-k of many, weights renormalised over the
-k) plus one shared expert behind a sigmoid gate.
+Pre-norm blocks ``x = x + mixer(norm1(x)); x = x + moe(norm2(x))`` with
+RMSNorm and no biases; every layer's feed-forward is routed experts (top-k
+of many, weights renormalised over the k). What a layer does follows from
+:class:`DecoderConfig`, which takes the published keys of two families:
+
+- Qwen3-Next (``config.json`` of ``Qwen/Qwen3-Next-80B-A3B-Instruct``; the
+  defaults): zero-centred norms (``x / rms(x) * (1 + w)``); layer ``i`` is
+  gated softmax attention with q/k norms and partial rotary positions where
+  ``(i + 1) % full_attention_interval == 0`` and a Gated DeltaNet
+  otherwise; SiLU experts plus one shared expert behind a sigmoid gate;
+- SmallThinker (``PowerInfer/SmallThinker-21BA3B-Instruct``): plain norms
+  (``x / rms(x) * w``); every layer attention with no gate and no q/k norm,
+  a window of ``sliding_window_size`` keys where ``sliding_window_layout[i]``
+  is 1 and full otherwise, rotary over the whole head where
+  ``rope_layout[i]`` is 1 and no positions at all otherwise; ReGLU experts,
+  no shared one, the router reading the mixer's input
+  (``router_input="mixer_input"``).
 
 - one layer function per kind: :func:`deltanet_layer`,
   :func:`attention_layer`, :func:`moe_layer`;
@@ -19,7 +28,9 @@ k) plus one shared expert behind a sigmoid gate.
   expert counted once, sum to the whole layer. On one chip it runs without
   the exchange, and nothing stands in for the absent chips;
 - padded batches and ragged-packed rows (several documents back to back in
-  a row) run the same forward: attention is causal within a document with
+  a row) run the same forward: attention is causal within a document
+  (``ops/attention.py`` ``segment_attention``, blocked: no score tensor of
+  a row's length squared) with
   rotary positions restarting at each, the recurrent state and the
   convolution of a DeltaNet layer are reset at each document's first token
   (``position_ids == 0``);
@@ -40,7 +51,17 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
-from pathway_tpu.ops import deltanet, moe
+from pathway_tpu.ops import attention, deltanet, moe
+
+
+@dataclasses.dataclass(frozen=True)
+class LayerKind:
+    """What the mixer of one layer is: ``"deltanet"``, or ``"attention"``
+    with its window (None: full) and whether it rotates q and k."""
+
+    mixer: str
+    window: int | None = None
+    rotary: bool = True
 
 
 @dataclasses.dataclass(frozen=True)
@@ -50,12 +71,24 @@ class DecoderConfig:
     num_hidden_layers: int = 48
     full_attention_interval: int = 4
     rms_norm_eps: float = 1e-6
-    # gated attention
+    #: the norms' form: ``x / rms(x) * (1 + w)``, or ``x / rms(x) * w``
+    zero_centred_norm: bool = True
+    # attention
     num_attention_heads: int = 16
     num_key_value_heads: int = 2
     head_dim: int = 256
     partial_rotary_factor: float = 0.25
     rope_theta: float = 1e7
+    #: a sigmoid gate on the heads' output, from ``q_proj``'s second half
+    attention_gate: bool = True
+    #: RMSNorm over the head on q and k
+    qk_norm: bool = True
+    #: given, every layer is attention: layer ``i`` keeps a window of
+    #: ``sliding_window_size`` keys where ``sliding_window_layout[i]`` is 1
+    #: and rotates q and k where ``rope_layout[i]`` is 1
+    sliding_window_layout: tuple[int, ...] | None = None
+    rope_layout: tuple[int, ...] | None = None
+    sliding_window_size: int | None = None
     # Gated DeltaNet
     linear_num_key_heads: int = 16
     linear_num_value_heads: int = 32
@@ -66,8 +99,15 @@ class DecoderConfig:
     num_experts: int = 512
     num_experts_per_tok: int = 10
     moe_intermediate_size: int = 512
-    shared_expert_intermediate_size: int = 512
+    #: None: no shared expert
+    shared_expert_intermediate_size: int | None = 512
     norm_topk_prob: bool = True
+    #: the experts' activation: ``"silu"``, ``"relu"``
+    hidden_act: str = "silu"
+    #: what the router reads: the expert layer's own normed input
+    #: (``"moe_input"``) or the mixer's (``"mixer_input"``: the router
+    #: placed before attention)
+    router_input: str = "moe_input"
     #: the range [lo, hi) of routed experts this process holds (None: all)
     experts_held: tuple[int, int] | None = None
     max_len: int = 512
@@ -88,11 +128,29 @@ class DecoderConfig:
         return self.experts_held or (0, self.num_experts)
 
     def is_attention(self, layer: int) -> bool:
-        return (layer + 1) % self.full_attention_interval == 0
+        return self.layer_kind(layer).mixer == "attention"
+
+    def layer_kind(self, layer: int) -> LayerKind:
+        if self.sliding_window_layout is not None:
+            rotary = self.rope_layout is None or bool(self.rope_layout[layer])
+            window = self.sliding_window_size \
+                if self.sliding_window_layout[layer] else None
+            return LayerKind("attention", window, rotary)
+        if (layer + 1) % self.full_attention_interval == 0:
+            return LayerKind("attention")
+        return LayerKind("deltanet")
+
+    @property
+    def attention_windows(self) -> tuple:
+        """Each attention layer's window, None for full attention (the
+        packer counts a dispatch's attention work from it)."""
+        kinds = map(self.layer_kind, range(self.num_hidden_layers))
+        return tuple(k.window for k in kinds if k.mixer == "attention")
 
     @staticmethod
     def tiny(**kw) -> "DecoderConfig":
-        """Small config for tests: one period, 8 experts top-2."""
+        """Small config for tests: one period, 8 experts top-2 (the
+        Qwen3-Next pattern; :meth:`tiny_windowed` is the other)."""
         base = dict(vocab_size=2048, hidden_size=64, num_hidden_layers=4,
                     num_attention_heads=4, num_key_value_heads=2,
                     head_dim=32, linear_num_key_heads=2,
@@ -102,6 +160,21 @@ class DecoderConfig:
                     shared_expert_intermediate_size=32, max_len=128)
         base.update(kw)
         return DecoderConfig(**base)
+
+    @staticmethod
+    def tiny_windowed(**kw) -> "DecoderConfig":
+        """Small config of the SmallThinker pattern: one period of a full
+        layer without positions and three window layers with rotary, plain
+        norms, 8 ReGLU experts top-2, no shared expert, the router on the
+        mixer's input."""
+        base = dict(zero_centred_norm=False, attention_gate=False,
+                    qk_norm=False, partial_rotary_factor=1.0,
+                    rope_theta=1.5e6, sliding_window_layout=(0, 1, 1, 1),
+                    rope_layout=(0, 1, 1, 1), sliding_window_size=24,
+                    shared_expert_intermediate_size=None, hidden_act="relu",
+                    router_input="mixer_input")
+        base.update(kw)
+        return DecoderConfig.tiny(**base)
 
     # the embedder protocol: what JaxEncoderEmbedder calls on a config
     def encode(self, params, token_ids, attention_mask):
@@ -128,9 +201,11 @@ class DecoderConfig:
 
 def init_params(key, config: DecoderConfig, dtype=jnp.float32) -> dict:
     """Seeded random weights in the program's tree (normal of deviation
-    0.02; zero-centred norm weights zero; the DeltaNet's ``A_log`` the log
-    of a uniform draw from (0, 16) and ``dt_bias`` ones, as the published
-    code initialises them)."""
+    0.02; zero-centred norm weights zero, plain ones one; the DeltaNet's
+    ``A_log`` the log of a uniform draw from (0, 16) and ``dt_bias`` ones,
+    as the published code initialises them). A tree holds what the
+    configuration's layers use: no gate's half of ``q_proj``, no q/k norm
+    and no shared expert where the configuration has none."""
     c = config
     keys = iter(jax.random.split(key, 16 * c.num_hidden_layers + 2))
 
@@ -141,16 +216,22 @@ def init_params(key, config: DecoderConfig, dtype=jnp.float32) -> dict:
     h, nv, nk = c.hidden_size, c.linear_num_value_heads, c.linear_num_key_heads
     kd, vd = nk * c.linear_key_head_dim, nv * c.linear_value_head_dim
     lo, hi = c.held
+
+    def norm(n):
+        return (jnp.zeros if c.zero_centred_norm else jnp.ones)(
+            (n,), jnp.float32)
+
     layers = []
     for i in range(c.num_hidden_layers):
         if c.is_attention(i):
             mixer = {
-                "q_proj": dense(h, c.num_attention_heads * 2 * c.head_dim),
+                "q_proj": dense(h, c.num_attention_heads * c.head_dim
+                                * (2 if c.attention_gate else 1)),
                 "k_proj": dense(h, c.num_key_value_heads * c.head_dim),
                 "v_proj": dense(h, c.num_key_value_heads * c.head_dim),
-                "q_norm": jnp.zeros((c.head_dim,), jnp.float32),
-                "k_norm": jnp.zeros((c.head_dim,), jnp.float32),
                 "o_proj": dense(c.num_attention_heads * c.head_dim, h)}
+            if c.qk_norm:
+                mixer.update(q_norm=norm(c.head_dim), k_norm=norm(c.head_dim))
         else:
             mixer = {
                 "in_proj_qkvz": dense(h, 2 * kd + 2 * vd),
@@ -162,18 +243,17 @@ def init_params(key, config: DecoderConfig, dtype=jnp.float32) -> dict:
                 "norm": jnp.ones((c.linear_value_head_dim,), jnp.float32),
                 "out_proj": dense(vd, h)}
         f, fs = c.moe_intermediate_size, c.shared_expert_intermediate_size
-        layers.append({
-            "norm1": jnp.zeros((h,), jnp.float32),
-            "norm2": jnp.zeros((h,), jnp.float32),
-            "mixer": mixer,
-            "moe": {"router": dense(h, c.num_experts),
-                    "gate": dense(hi - lo, h, f), "up": dense(hi - lo, h, f),
-                    "down": dense(hi - lo, f, h),
-                    "shared_gate": dense(h, fs), "shared_up": dense(h, fs),
-                    "shared_down": dense(fs, h),
-                    "shared_router": dense(h, 1)}})
+        experts = {"router": dense(h, c.num_experts),
+                   "gate": dense(hi - lo, h, f), "up": dense(hi - lo, h, f),
+                   "down": dense(hi - lo, f, h)}
+        if fs is not None:
+            experts.update(shared_gate=dense(h, fs), shared_up=dense(h, fs),
+                           shared_down=dense(fs, h),
+                           shared_router=dense(h, 1))
+        layers.append({"norm1": norm(h), "norm2": norm(h), "mixer": mixer,
+                       "moe": experts})
     return {"embed": dense(c.vocab_size, h), "layers": layers,
-            "final_norm": jnp.zeros((h,), jnp.float32)}
+            "final_norm": norm(h)}
 
 
 # ---------------------------------------------------------------------------
@@ -239,40 +319,46 @@ def _rotary(x, pos, rotary_dim: int, theta: float):
         [x1 * cos - x2 * sin, x2 * cos + x1 * sin, rest], axis=-1)
 
 
-def attention_layer(x, p, pos, seg, config: DecoderConfig):
-    """Gated softmax attention, causal within a document, grouped heads.
+def attention_layer(x, p, pos, seg, config: DecoderConfig,
+                    kind: LayerKind = LayerKind("attention")):
+    """Softmax attention, causal within a document, grouped heads; by the
+    configuration with a sigmoid gate on the heads' output and RMSNorm on q
+    and k, by the layer's ``kind`` with rotary positions and a window.
     seg (B, T): a token's document (-1 = padding)."""
     c = config
     b, t, _ = x.shape
     nh, nkv, hd = c.num_attention_heads, c.num_key_value_heads, c.head_dim
     cd = c.compute_dtype
-    qg = _proj(x, p["q_proj"], c).reshape(b, t, nh, 2 * hd)
-    q, gate = qg[..., :hd], qg[..., hd:]
+    q = _proj(x, p["q_proj"], c)
+    gate = None
+    if c.attention_gate:
+        q = q.reshape(b, t, nh, 2 * hd)
+        q, gate = q[..., :hd], q[..., hd:]
+    q = q.reshape(b, t, nh, hd)
     k = _proj(x, p["k_proj"], c).reshape(b, t, nkv, hd)
     v = _proj(x, p["v_proj"], c).reshape(b, t, nkv, hd)
-    rot = int(hd * c.partial_rotary_factor)
-    q = _rotary(_rms_norm(q, p["q_norm"], c.rms_norm_eps), pos, rot,
-                c.rope_theta)
-    k = _rotary(_rms_norm(k, p["k_norm"], c.rms_norm_eps), pos, rot,
-                c.rope_theta)
-    q = q.reshape(b, t, nkv, nh // nkv, hd)
-    scores = jnp.einsum("bqgrd,bkgd->bgrqk", q.astype(cd), k.astype(cd),
-                        preferred_element_type=jnp.float32) * hd ** -0.5
-    at = jnp.arange(t)
-    see = (seg[:, :, None] == seg[:, None, :]) & (seg >= 0)[:, None, :] \
-        & (at[None, :, None] >= at[None, None, :])
-    scores = jnp.where(see[:, None, None], scores, -1e30)
-    probs = jax.nn.softmax(scores, axis=-1)
-    o = jnp.einsum("bgrqk,bkgd->bqgrd", probs.astype(cd), v.astype(cd),
-                   preferred_element_type=jnp.float32)
-    o = o.reshape(b, t, nh, hd) * jax.nn.sigmoid(gate)
+    if c.qk_norm:
+        q = _rms_norm(q, p["q_norm"], c.rms_norm_eps, c.zero_centred_norm)
+        k = _rms_norm(k, p["k_norm"], c.rms_norm_eps, c.zero_centred_norm)
+    if kind.rotary:
+        rot = int(hd * c.partial_rotary_factor)
+        q, k = (_rotary(a, pos, rot, c.rope_theta) for a in (q, k))
+    scope = "decoder.attention.full" if kind.window is None \
+        else "decoder.attention.window"
+    with jax.named_scope(scope):
+        o = attention.segment_attention(
+            q.astype(cd), k.astype(cd), v.astype(cd), seg, pos,
+            window=kind.window)
+    if gate is not None:
+        o = o * jax.nn.sigmoid(gate)
     return _proj(o.reshape(b, t, nh * hd), p["o_proj"], c)
 
 
-def moe_layer(x, p, valid, config: DecoderConfig):
-    """Routed experts (the held range's part) plus the shared expert.
-    x (B, T, H) normed input; valid (B, T) False at padding. Returns
-    (y (B, T, H) float32, tokens each held expert took, what this
+def moe_layer(x, p, valid, config: DecoderConfig, router_x=None):
+    """Routed experts (the held range's part) plus the shared expert, where
+    the configuration has one. x (B, T, H) normed input; valid (B, T) False
+    at padding; router_x (B, T, H): what the router reads (None: ``x``).
+    Returns (y (B, T, H) float32, tokens each held expert took, what this
     execution adds to the pair buffer's counters: ``moe.buffer_use``)."""
     c = config
     b, t, h = x.shape
@@ -281,19 +367,22 @@ def moe_layer(x, p, valid, config: DecoderConfig):
     lengths = moe.buffer_lengths(b * t * c.num_experts_per_tok,
                                  (hi - lo) / c.num_experts)
     with jax.named_scope("decoder.moe.route"):
-        weights, experts = moe.route(flat, p["router"],
-                                     c.num_experts_per_tok, c.norm_topk_prob)
+        weights, experts = moe.route(
+            flat if router_x is None else router_x.reshape(b * t, h),
+            p["router"], c.num_experts_per_tok, c.norm_topk_prob)
     with jax.named_scope("decoder.moe.experts"):
-        routed, load = moe.grouped_experts(
+        y, load = moe.grouped_experts(
             flat, weights, experts, p["gate"], p["up"], p["down"], c.held,
-            valid.reshape(b * t), lengths)
+            valid.reshape(b * t), lengths, c.hidden_act)
         buffer = moe.buffer_use(load, lengths)
-    with jax.named_scope("decoder.moe.shared"):
-        hidden = jax.nn.silu(_proj(flat, p["shared_gate"], c)) \
-            * _proj(flat, p["shared_up"], c)
-        shared = _proj(hidden, p["shared_down"], c) * jax.nn.sigmoid(
-            _proj(flat, p["shared_router"], c))
-    return (routed + shared).reshape(b, t, h), load, buffer
+    if c.shared_expert_intermediate_size is not None:
+        with jax.named_scope("decoder.moe.shared"):
+            hidden = moe.ACTIVATIONS[c.hidden_act](
+                _proj(flat, p["shared_gate"], c)) \
+                * _proj(flat, p["shared_up"], c)
+            y = y + _proj(hidden, p["shared_down"], c) * jax.nn.sigmoid(
+                _proj(flat, p["shared_router"], c))
+    return y.reshape(b, t, h), load, buffer
 
 
 def _forward(params, token_ids, pos, seg, config: DecoderConfig):
@@ -303,6 +392,7 @@ def _forward(params, token_ids, pos, seg, config: DecoderConfig):
     [executions, those at the full length, pair-buffer rows]}``, which an
     embedder sums as its ``aux``)."""
     c = config
+    norm = lambda x, w: _rms_norm(x, w, c.rms_norm_eps, c.zero_centred_norm)
     with jax.named_scope("decoder.embed"):
         x = params["embed"][token_ids].astype(jnp.float32)
     valid = seg >= 0
@@ -310,19 +400,20 @@ def _forward(params, token_ids, pos, seg, config: DecoderConfig):
     load = jnp.zeros((hi - lo,), jnp.int32)
     buffer = jnp.zeros((3,), jnp.float32)
     for i, layer in enumerate(params["layers"]):
-        normed = _rms_norm(x, layer["norm1"], c.rms_norm_eps)
-        if c.is_attention(i):
+        normed, kind = norm(x, layer["norm1"]), c.layer_kind(i)
+        if kind.mixer == "attention":
             with jax.named_scope("decoder.attention"):
-                x = x + attention_layer(normed, layer["mixer"], pos, seg, c)
+                x = x + attention_layer(normed, layer["mixer"], pos, seg, c,
+                                        kind)
         else:
             with jax.named_scope("decoder.deltanet"):
                 x = x + deltanet_layer(normed, layer["mixer"], pos, c,
                                        valid)
         y, took, used = moe_layer(
-            _rms_norm(x, layer["norm2"], c.rms_norm_eps), layer["moe"],
-            valid, c)
+            norm(x, layer["norm2"]), layer["moe"], valid, c,
+            normed if c.router_input == "mixer_input" else None)
         x, load, buffer = x + y, load + took, buffer + used
-    return (_rms_norm(x, params["final_norm"], c.rms_norm_eps),
+    return (norm(x, params["final_norm"]),
             {"tokens_per_expert": load, "buffer": buffer})
 
 

@@ -1,4 +1,8 @@
-"""Fused (flash-style) attention Pallas kernel for the encoder.
+"""Attention kernels: the encoder's whole-sequence fused kernel
+(:func:`flash_attention`, below) and the decoder's blocked causal attention
+over packed rows (:func:`segment_attention`, at the end of the file).
+
+**The encoder's.**
 
 The XLA fallback (models/encoder.py _dense_attention) materializes the
 (B, H, S, S) float32 score tensor in HBM — at encoder bench shapes
@@ -26,6 +30,9 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
+
+from pathway_tpu.ops import lowering_count
 
 _NEG_INF = float(jnp.finfo(jnp.float32).min)
 
@@ -94,3 +101,386 @@ def flash_attention(q, k, v, mask, *, interpret: bool = False):
         interpret=interpret,
     )(q, k, v, mask_i)
     return out
+
+
+# ---------------------------------------------------------------------------
+# blocked causal attention over packed rows (the decoder's)
+# ---------------------------------------------------------------------------
+#
+# A row holds several documents back to back (``seg``: a slot's document,
+# -1 padding; ``pos``: its position in its document; a document's tokens
+# lie in consecutive slots in order of position, as the packer lays them).
+# A query sees the keys of its own document at or before its own slot and,
+# under a window, at most ``window - 1`` positions back. Rows are cut into
+# query blocks and key blocks; the softmax is accumulated online over the
+# key blocks a query block can see, so nothing of shape T x T is ever
+# written, and key blocks that lie wholly in the future, in other documents
+# or behind the window are neither fetched nor multiplied: the first key
+# block a query block sees and how many follow are computed on the device
+# from ``seg``, ``pos`` and ``window`` before the loop (:func:`_block_ranges`).
+#
+# One algorithm, two lowerings, chosen by what the function observes
+# (platform, head size, row length): on a TPU with heads of whole lanes it
+# is one Pallas kernel (:func:`_segment_kernel`), everywhere else the same
+# loop in plain ``jax.numpy`` (:func:`_blockwise`).
+
+#: the running max a row starts from, and a masked score: both large and
+#: finite, so that nothing makes a NaN, and the masked one the lower, so
+#: that a row that has seen no key yet gives a masked score the weight
+#: ``exp(_MASKED - _UNSEEN) = 0`` with no second mask after the exponential
+_UNSEEN = -1e30
+_MASKED = -2e30
+
+
+def block_sizes(t: int) -> tuple[int, int, int]:
+    """(query block, key block, padded row length) for rows of ``t``
+    slots: blocks of 256 queries and 1,024 keys, fewer keys where a row
+    is shorter, one block where it is shorter than 256. A query block's
+    heads are stacked as rows of one product (1,792 rows for 7 heads a
+    key head), so a key block read once serves them all. On the chip a
+    row of 16,384 slots that is one document takes a full layer 27.8 ms
+    with key blocks of 512, 18.4 ms with 1,024 (51.7 with 256; query
+    blocks of 128 read 32.5): a step's fixed work, the running max, sum
+    and accumulator read and written, is spread over more keys (my chip
+    run, PR 33)."""
+    if t <= 256:
+        return t, t, t
+    bk = next(size for size in (1024, 512, 256) if t >= size)
+    return 256, bk, -(-t // bk) * bk
+
+
+def _block_ranges(xp, seg, pos, window, bq: int, bk: int):
+    """For every query block of every row: (the first key block it can
+    see, how many it sees from there on; 0 for a block of padding), each
+    (B, T / bq) int32. ``xp`` is ``jax.numpy`` or ``numpy``: the host
+    counts with the same lines (:func:`attention_work`)."""
+    b, t = seg.shape
+    slot = xp.arange(t, dtype=xp.int32)[None, :]
+    real = seg >= 0
+    back = pos if window is None else xp.minimum(pos, window - 1)
+    first_key = xp.where(real, slot - back, t).reshape(b, t // bq, bq)
+    last_key = xp.where(real, slot, -1).reshape(b, t // bq, bq)
+    lo, hi = first_key.min(axis=-1) // bk, last_key.max(axis=-1) // bk
+    count = xp.where(hi >= 0, hi - lo + 1, 0)
+    return (xp.where(count > 0, lo, 0).astype(xp.int32),
+            count.astype(xp.int32))
+
+
+def _max_steps(t: int, window, bq: int, bk: int) -> int:
+    """The most key blocks one query block can see."""
+    if window is None:
+        return t // bk
+    return min(t // bk, -(-(window - 1 + bq) // bk) + 1)
+
+
+def attention_work(seg: np.ndarray, pos: np.ndarray,
+                   windows: tuple) -> dict:
+    """What the attention layers of one dispatch have to do, counted on
+    the host from the packed rows (``seg``, ``pos`` (B, T) as the packer
+    made them; ``windows``: each attention layer's window, None for full
+    attention): ``attn_pairs_full`` the visible (query, key) pairs of a
+    full layer (a document of n tokens has n (n + 1) / 2),
+    ``attn_pairs_window`` of a window layer (where the model has one),
+    ``attn_tiles_run`` the key blocks the kernel's ranges admit and
+    ``attn_tiles_all`` all key blocks up to the diagonal, both summed over
+    query blocks and attention layers."""
+    t = seg.shape[1]
+    bq, bk, padded = block_sizes(t)
+    if padded != t:
+        seg = np.pad(seg, ((0, 0), (0, padded - t)), constant_values=-1)
+        pos = np.pad(pos, ((0, 0), (0, padded - t)))
+    real = seg >= 0
+    reach = (pos.astype(np.int64) + 1)[real]
+    out = {"attn_pairs_full": int(reach.sum())}
+    to_diagonal = seg.shape[0] * int(
+        ((np.arange(1, padded // bq + 1) * bq - 1) // bk + 1).sum())
+    out["attn_tiles_run"], out["attn_tiles_all"] = 0, 0
+    for window in sorted(set(windows), key=lambda w: w or 0):
+        layers = windows.count(window)
+        if window is not None:
+            out["attn_pairs_window"] = int(np.minimum(reach, window).sum())
+        _lo, count = _block_ranges(np, seg, pos, window, bq, bk)
+        out["attn_tiles_run"] += layers * int(count.sum())
+        out["attn_tiles_all"] += layers * to_diagonal
+    return out
+
+
+def attention_lowerings() -> dict:
+    """Attention cores lowered in this process by the lowering they took:
+    ``kernel`` (the Pallas TPU kernel) or ``blockwise`` (plain JAX), one
+    count a call of a compiled program (``ops/lowering_count.py``).
+    ``/metrics`` shows it as ``pathway_tpu_attention_programs``."""
+    return lowering_count.counts("attention", ("kernel", "blockwise"))
+
+
+def _kernel_tiles(q_shape: tuple, padded: int) -> bool:
+    """The shapes the kernel tiles: a head fills whole lanes of the chip's
+    vector registers and a row whole blocks of 128 slots."""
+    return q_shape[3] % 128 == 0 and padded % 128 == 0
+
+
+@functools.partial(jax.jit, static_argnames=("window",))
+def segment_attention(q, k, v, seg, pos, *, window: int | None = None):
+    """Causal softmax attention inside each document of packed rows, with
+    grouped heads and an optional window.
+
+    q (B, T, nh, d); k, v (B, T, nkv, d), ``nh // nkv`` query heads a key
+    head, in the dtype the products run in (sums are float32); seg (B, T)
+    int32 a slot's document, -1 padding; pos (B, T) int32 its position in
+    its document (a document's tokens lie in consecutive slots, in
+    order). ``visible(t, s) = seg[t] == seg[s] >= 0 and s <= t and
+    (window is None or pos[t] - pos[s] < window)``; scores are scaled by
+    ``d ** -0.5``. Returns (B, T, nh, d) float32, defined at the real
+    slots (padding reads zeros).
+
+    One algorithm, two lowerings: lowered for a TPU at the shapes of
+    :func:`_kernel_tiles` it is :func:`_segment_kernel`, on every other
+    platform and at every other shape :func:`_blockwise`.
+    :func:`attention_lowerings` counts which one each compiled program
+    took. Jitted, so that the layers of one program that share a window
+    share one trace and one lowering."""
+    b, t, nh, d = q.shape
+    bq, bk, padded = block_sizes(t)
+    if padded != t:
+        grow = ((0, 0), (0, padded - t))
+        q, k, v = (jnp.pad(a, grow + ((0, 0), (0, 0))) for a in (q, k, v))
+        seg = jnp.pad(seg, grow, constant_values=-1)
+        pos = jnp.pad(pos, grow)
+    seg, pos = seg.astype(jnp.int32), pos.astype(jnp.int32)
+    lo, count = _block_ranges(jnp, seg, pos, window, bq, bk)
+    sizes = dict(window=window, bq=bq, bk=bk)
+
+    def blockwise(q, k, v, seg, pos, lo, count):
+        return lowering_count.took(
+            _blockwise(q, k, v, seg, pos, lo, count, **sizes),
+            "attention", "blockwise")
+
+    if not _kernel_tiles(q.shape, padded):
+        out = blockwise(q, k, v, seg, pos, lo, count)
+    else:
+        def kernel(q, k, v, seg, pos, lo, count):
+            return lowering_count.took(
+                _segment_kernel(q, k, v, seg, pos, lo, count, **sizes),
+                "attention", "kernel")
+
+        out = jax.lax.platform_dependent(q, k, v, seg, pos, lo, count,
+                                         tpu=kernel, default=blockwise)
+    return out[:, :t]
+
+
+def _visible(seg_q, pos_q, slot_q, seg_k, pos_k, slot_k, window):
+    """The mask of one block from its queries' (.., bq, 1) and its keys'
+    (.., 1, bk) document, position and slot."""
+    see = (seg_q == seg_k) & (seg_k >= 0) & (slot_k <= slot_q)
+    if window is not None:
+        see = see & (pos_q - pos_k < window)
+    return see
+
+
+def _blockwise(q, k, v, seg, pos, lo, count, *, window, bq: int, bk: int):
+    """:func:`segment_attention` in plain JAX: a ``lax.map`` over the
+    query blocks, inside it a loop over the key blocks that block can see
+    (of any row: the rows of a dispatch walk the union of their ranges),
+    the online softmax of ``parallel/ring_attention.py``. The largest
+    array is one block's scores, (B, nh, bq, bk)."""
+    b, t, nh, d = q.shape
+    nkv = k.shape[2]
+    nq, nk = t // bq, t // bk
+    q = q.reshape(b, nq, bq, nkv, nh // nkv, d)
+    scale = d ** -0.5
+    first = jnp.min(jnp.where(count > 0, lo, nk), axis=0)         # (nq,)
+    steps = jnp.maximum(
+        jnp.max(jnp.where(count > 0, lo + count, 0), axis=0) - first, 0)
+    f32 = jnp.float32
+
+    def query_block(i):
+        qb = q[:, i]                                      # (B, bq, g, r, d)
+        cut = lambda a, at, n: jax.lax.dynamic_slice_in_dim(a, at, n, axis=1)
+        seg_q, pos_q = (cut(a, i * bq, bq)[:, :, None] for a in (seg, pos))
+        slot_q = (i * bq + jnp.arange(bq))[None, :, None]
+
+        def key_block(j, carry):
+            m, l, acc = carry
+            at = (first[i] + j) * bk
+            kb, vb = cut(k, at, bk), cut(v, at, bk)       # (B, bk, g, d)
+            seg_k, pos_k = (cut(a, at, bk)[:, None, :] for a in (seg, pos))
+            see = _visible(seg_q, pos_q, slot_q, seg_k, pos_k,
+                           (at + jnp.arange(bk))[None, None, :],
+                           window)[:, None, None]         # (B,1,1,bq,bk)
+            s = jnp.einsum("bqgrd,bkgd->bgrqk", qb, kb,
+                           preferred_element_type=f32) * scale
+            s = jnp.where(see, s, _MASKED)
+            m_new = jnp.maximum(m, jnp.max(s, axis=-1))
+            p = jnp.exp(s - m_new[..., None])
+            alpha = jnp.exp(m - m_new)
+            acc = acc * alpha[..., None] + jnp.einsum(
+                "bgrqk,bkgd->bgrqd", p.astype(vb.dtype), vb,
+                preferred_element_type=f32)
+            return m_new, alpha * l + jnp.sum(p, axis=-1), acc
+
+        stats = (b, nkv, nh // nkv, bq)
+        _m, l, acc = jax.lax.fori_loop(
+            0, steps[i], key_block,
+            (jnp.full(stats, _UNSEEN, f32), jnp.zeros(stats, f32),
+             jnp.zeros(stats + (d,), f32)))
+        return acc * jnp.where(l > 0, 1.0 / l, 0.0)[..., None]
+
+    out = jax.lax.map(query_block, jnp.arange(nq))      # (nq,B,g,r,bq,d)
+    return jnp.transpose(out, (1, 0, 4, 2, 3, 5)).reshape(b, t, nh, d)
+
+
+def _segment_body(lo_ref, count_ref, qdoc_ref, kdoc_ref, q_ref, k_ref,
+                  v_ref, qcols_ref, krows_ref, o_ref, qs_ref, m_ref, l_ref,
+                  acc_ref, *, rep: int, d: int, bq: int, bk: int, nk: int,
+                  window):
+    """One key block of one query block of one key head's ``rep`` query
+    heads. Blocks: q, o (bq, rep * d); k, v (bk, d); qcols (bq, 128): the
+    queries' document in lane 0 and position in lane 1; krows (8, bk): the
+    keys' in sublanes 0 and 1. Scalar memory: the query block's first key
+    block and its count, and for every query block and key block the
+    document all its slots belong to (or a negative number where they do
+    not share one). Vector memory, for the length of a query block: the
+    heads' queries stacked as rows (rep * bq, d), the running max and sum
+    (rep * bq, 1) and the accumulator (rep * bq, d), float32."""
+    from jax.experimental import pallas as pl
+
+    row, qi, j = pl.program_id(0), pl.program_id(2), pl.program_id(3)
+    at = row * pl.num_programs(2) + qi
+    count = count_ref[at]
+    # past the block's last step the index stays where it was: as the
+    # blocks' index maps have it (``key_block`` of :func:`_segment_kernel`)
+    kb = lo_ref[at] + jnp.minimum(j, jnp.maximum(count, 1) - 1)
+    f32 = jnp.float32
+
+    @pl.when(j == 0)
+    def _():
+        for r in range(rep):
+            qs_ref[r * bq:(r + 1) * bq, :] = q_ref[:, r * d:(r + 1) * d]
+        m_ref[...] = jnp.full(m_ref.shape, _UNSEEN, f32)
+        l_ref[...] = jnp.zeros(l_ref.shape, f32)
+        acc_ref[...] = jnp.zeros(acc_ref.shape, f32)
+
+    def accumulate(see):
+        s = jax.lax.dot_general(
+            qs_ref[...], k_ref[...], (((1,), (1,)), ((), ())),
+            preferred_element_type=f32) * (d ** -0.5)          # (R, bk)
+        if see is not None:
+            # one mask for the block, added to every head's rows
+            hide = jax.lax.select(see, jnp.zeros((bq, bk), f32),
+                                  jnp.full((bq, bk), _MASKED, f32))
+            s = (s.reshape(rep, bq, bk) + hide[None]).reshape(rep * bq, bk)
+        m = m_ref[...]
+        m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
+        p = jnp.exp(s - m_new)
+        alpha = jnp.exp(m - m_new)
+        l_ref[...] = alpha * l_ref[...] + jnp.sum(p, axis=1, keepdims=True)
+        acc_ref[...] = alpha * acc_ref[...] + jax.lax.dot_general(
+            p.astype(v_ref.dtype), v_ref[...], (((1,), (0,)), ((), ())),
+            preferred_element_type=f32)
+        m_ref[...] = m_new
+
+    # every query of the block sees every key of the block: both lie in
+    # one document, the keys wholly before the queries and inside the
+    # window of the last of them. Only the other blocks build a mask
+    inside = (qdoc_ref[at] == kdoc_ref[row * nk + kb]) \
+        & ((kb + 1) * bk - 1 <= qi * bq)
+    if window is not None:
+        inside = inside & ((qi + 1) * bq - 1 - kb * bk < window)
+    live = j < count
+
+    @pl.when(live & inside)
+    def _():
+        accumulate(None)
+
+    @pl.when(live & jnp.logical_not(inside))
+    def _():
+        shape = (bq, bk)
+        spread = lambda a: jnp.broadcast_to(a, shape)
+        see = _visible(
+            spread(qcols_ref[:, 0:1]), spread(qcols_ref[:, 1:2]),
+            qi * bq + jax.lax.broadcasted_iota(jnp.int32, shape, 0),
+            spread(krows_ref[0:1, :]), spread(krows_ref[1:2, :]),
+            kb * bk + jax.lax.broadcasted_iota(jnp.int32, shape, 1), window)
+        accumulate(see)
+
+    @pl.when(j == jnp.maximum(count, 1) - 1)
+    def _():
+        l = l_ref[...]
+        out = acc_ref[...] * jnp.where(l > 0, 1.0 / l, 0.0)
+        for r in range(rep):
+            o_ref[:, r * d:(r + 1) * d] = out[r * bq:(r + 1) * bq]
+
+
+def _segment_kernel(q, k, v, seg, pos, lo, count, *, window, bq: int,
+                    bk: int, interpret: bool = False):
+    """:func:`segment_attention` as one Pallas kernel. Grid (row, key
+    head, query block, key-block step), the steps innermost: step ``j`` of
+    a query block reads key block ``lo + j`` while ``j < count`` and stays
+    on the last one after that, so that blocks outside a query block's
+    range are neither fetched nor multiplied (``lo``, ``count``: scalar
+    prefetch, :func:`_block_ranges`). The ``nh // nkv`` query heads of a
+    key head are stacked as rows of one product, so a key block is read
+    once for all of them; operands in the dtype given (bfloat16 on the
+    chip), the running max, sum and accumulator float32 in vector memory;
+    the mask is built only in blocks on an edge (the diagonal, the
+    window's edge, a document's edge, padding)."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    b, t, nh, d = q.shape
+    nkv = k.shape[2]
+    rep = nh // nkv
+    nq, nk = t // bq, t // bk
+    steps = _max_steps(t, window, bq, bk)
+
+    def one_document(a, size, other):
+        """(B, T / size): the document all slots of a block share, or
+        ``other`` (negative) where they share none or it is padding."""
+        blocks = a.reshape(b, t // size, size)
+        first = blocks[..., 0]
+        same = jnp.all(blocks == first[..., None], axis=-1) & (first >= 0)
+        return jnp.where(same, first, other).astype(jnp.int32).reshape(-1)
+
+    qcols = jnp.pad(jnp.stack([seg, pos], axis=-1),
+                    ((0, 0), (0, 0), (0, 126)))              # (B, T, 128)
+    krows = jnp.pad(jnp.stack([seg, pos], axis=1),
+                    ((0, 0), (0, 6), (0, 0)))                # (B, 8, T)
+
+    def key_block(r, g, i, j, lo_ref, count_ref, *_):
+        at = r * nq + i
+        return lo_ref[at] + jnp.minimum(
+            j, jnp.maximum(count_ref[at], 1) - 1)
+
+    queries = lambda r, g, i, j, *_: (r, i, g)
+    keys = lambda r, g, i, j, *s: (r, key_block(r, g, i, j, *s), g)
+    rows = rep * bq
+    out = pl.pallas_call(
+        functools.partial(_segment_body, rep=rep, d=d, bq=bq, bk=bk,
+                          nk=nk, window=window),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=4,
+            grid=(b, nkv, nq, steps),
+            in_specs=[
+                pl.BlockSpec((None, bq, rep * d), queries),
+                pl.BlockSpec((None, bk, d), keys),
+                pl.BlockSpec((None, bk, d), keys),
+                pl.BlockSpec((None, bq, 128),
+                             lambda r, g, i, j, *_: (r, i, 0)),
+                pl.BlockSpec((None, 8, bk), lambda r, g, i, j, *s: (
+                    r, 0, key_block(r, g, i, j, *s))),
+            ],
+            out_specs=pl.BlockSpec((None, bq, rep * d), queries),
+            scratch_shapes=[pltpu.VMEM((rows, d), q.dtype),
+                            pltpu.VMEM((rows, 1), jnp.float32),
+                            pltpu.VMEM((rows, 1), jnp.float32),
+                            pltpu.VMEM((rows, d), jnp.float32)]),
+        out_shape=jax.ShapeDtypeStruct((b, t, nh * d), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "parallel",
+                                 "arbitrary"),
+            vmem_limit_bytes=64 * 1024 * 1024),
+        interpret=interpret,
+    )(lo.reshape(-1), count.reshape(-1), one_document(seg, bq, -2),
+      one_document(seg, bk, -3), q.reshape(b, t, nh * d),
+      k.reshape(b, t, nkv * d), v.reshape(b, t, nkv * d), qcols, krows)
+    return out.reshape(b, t, nh, d)

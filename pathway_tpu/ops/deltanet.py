@@ -44,11 +44,11 @@ from __future__ import annotations
 import functools
 
 import jax
-import jax.extend
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-from jax.interpreters import ad, batching, mlir
+
+from pathway_tpu.ops import lowering_count
 
 CHUNK = 64
 
@@ -213,40 +213,17 @@ def _gated_delta_rule(q, k, v, g, beta, starts, chunk: int):
 # which lowering a compiled program took
 # ---------------------------------------------------------------------------
 
-_LOWERINGS = {"kernel": 0, "reference": 0}
-
-
 def scan_lowerings() -> dict:
     """Scans lowered in this process by the lowering they took: ``kernel``
     (the fused TPU kernel) or ``reference`` (:func:`_gated_delta_rule`),
-    one count a scan of a compiled program (an eager call counts as one).
-    ``/metrics`` shows it as ``pathway_tpu_deltanet_scan_programs``."""
-    return dict(_LOWERINGS)
-
-
-# ``platform_dependent`` traces both lowerings and picks one when the
-# program is lowered, so the count is made there: an identity on the
-# scan's result whose lowering rule (or eager evaluation) notes the name
-_took_p = jax.extend.core.Primitive("deltanet_scan_took")
-_took_p.def_abstract_eval(lambda x, *, lowering: x)
-
-
-@_took_p.def_impl
-def _note(x, *, lowering: str):
-    _LOWERINGS[lowering] += 1
-    return x
-
-
-mlir.register_lowering(
-    _took_p, lambda ctx, x, *, lowering: [_note(x, lowering=lowering)],
-    cacheable=False)
-# the reference stays what it was under ``grad`` and ``vmap``
-ad.deflinear2(_took_p, lambda ct, x, *, lowering: [ct])
-batching.defvectorized(_took_p)
+    one count a scan of a compiled program (an eager call counts as one:
+    ``ops/lowering_count.py``). ``/metrics`` shows it as
+    ``pathway_tpu_deltanet_scan_programs``."""
+    return lowering_count.counts("deltanet_scan", ("kernel", "reference"))
 
 
 def _took(o, lowering: str):
-    return _took_p.bind(o, lowering=lowering)
+    return lowering_count.took(o, "deltanet_scan", lowering)
 
 
 # ---------------------------------------------------------------------------

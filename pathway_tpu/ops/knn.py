@@ -1217,19 +1217,23 @@ class DeviceEmbeddingKnnIndex:
                     # fixed-shape chunk; padded doc rows scatter-drop
                     d0 = 0
                     spans = _fr.recording()
+                    work = getattr(self.embedder, "dispatch_work", None)
                     for args, n_docs, n_pad in \
                             self.embedder.pack_ragged(texts):
                         t0 = _time.perf_counter()
                         self._fused(keys[d0:d0 + n_docs],
                                     self.embedder.params, *args,
                                     n_rows=n_pad)
+                        t1 = _time.perf_counter()
+                        # what attention had to do there, where the model
+                        # has such layers (summed for /metrics)
+                        attn = work(args) if work is not None else {}
                         if spans:
                             # args[1] is the packed rows' document map
                             _fr.live_span(
-                                "embedder.dispatch", t0,
-                                _time.perf_counter(), docs=n_docs,
+                                "embedder.dispatch", t0, t1, docs=n_docs,
                                 rows=int(args[0].shape[0]),
-                                tokens=int((args[1] >= 0).sum()))
+                                tokens=int((args[1] >= 0).sum()), **attn)
                         d0 += n_docs
                 else:
                     ids, lens = self.embedder.pack_tokens(texts)

@@ -42,6 +42,9 @@ ROW_TILE = 256
 #: packer left a fifth or more empty; the second every full dispatch, with
 #: an eighth of room for a router that leans towards the held range
 ROOM = (0.8, 1.125)
+#: an expert's activation by its published name (``hidden_act``): the
+#: gated unit is ``act(W_gate x) * (W_up x)``, SwiGLU or ReGLU
+ACTIVATIONS = {"silu": jax.nn.silu, "relu": jax.nn.relu}
 
 
 def route(x, router, k: int, renormalise: bool):
@@ -114,7 +117,7 @@ def buffer_use(sizes, lengths: tuple[int, ...]):
 
 
 def _held_pairs(rows: int, x, weights, order, back, sizes, w_gate, w_up,
-                w_down):
+                w_down, act: str = "silu"):
     """The held experts' part over the first ``rows`` sorted places, which
     hold every held pair: (N, H) float32. ``back`` (N*k,): the sorted
     place of each pair."""
@@ -128,7 +131,7 @@ def _held_pairs(rows: int, x, weights, order, back, sizes, w_gate, w_up,
     # the router's weight goes in before the last product, where a row is
     # F wide and not H
     scale = weights.reshape(-1)[place][:, None]
-    hidden = (jax.nn.silu(gate) * up * scale).astype(x.dtype)
+    hidden = (ACTIVATIONS[act](gate) * up * scale).astype(x.dtype)
     out = jax.lax.ragged_dot(hidden, w_down, sizes,
                              preferred_element_type=x.dtype)
     # back to the pairs' own order. A pair that is not held sorted behind
@@ -143,10 +146,12 @@ def _held_pairs(rows: int, x, weights, order, back, sizes, w_gate, w_up,
 
 def grouped_experts(x, weights, experts, w_gate, w_up, w_down,
                     held: tuple[int, int], valid=None,
-                    lengths: tuple[int, ...] | None = None):
+                    lengths: tuple[int, ...] | None = None,
+                    act: str = "silu"):
     """What the held experts add for every token:
-    ``sum_e p_e W_down[e] (silu(W_gate[e] x) * (W_up[e] x))`` over the
-    token's chosen experts that live here.
+    ``sum_e p_e W_down[e] (act(W_gate[e] x) * (W_up[e] x))`` over the
+    token's chosen experts that live here (``act``: a name of
+    :data:`ACTIVATIONS`).
 
     x (N, H); weights, experts (N, k) from :func:`route`; w_gate, w_up
     (hi - lo, H, F); w_down (hi - lo, F, H); ``lengths``: the pair buffer's
@@ -161,6 +166,6 @@ def grouped_experts(x, weights, experts, w_gate, w_up, w_down,
     order, sizes = group_by_expert(experts, held, valid)
     y = jax.lax.switch(
         buffer_branch(sizes, lengths),
-        [functools.partial(_held_pairs, rows) for rows in lengths],
+        [functools.partial(_held_pairs, rows, act=act) for rows in lengths],
         x, weights, order, jnp.argsort(order), sizes, w_gate, w_up, w_down)
     return y, sizes

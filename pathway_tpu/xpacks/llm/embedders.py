@@ -11,6 +11,7 @@ tokenized and encoded in one device call, never per row.
 from __future__ import annotations
 
 import asyncio
+import collections
 import operator
 import threading
 import weakref
@@ -37,6 +38,17 @@ class BaseEmbedder(udfs.UDF):
         return int(arr.shape[0])
 
 
+#: forwards of a model that returns ``aux`` which the device may hold
+#: beside the one just dispatched before the host waits: one runs, one is
+#: queued, and the host packs the next. The runtime queues dozens without
+#: a wait, so the first legs behind a slow model retired in a tenth of
+#: their device time, ``DeviceBackpressure`` read 5 ms a document for 50,
+#: and the ticks after a release held 8, 8, 8, 16, 24, 36, 36 documents:
+#: seven seconds of work for a budget of 0.4 s a leg, then legs of two
+#: documents for ten seconds (my chip runs, PR 33). Waiting here makes a
+#: leg's host time its device time to within these dispatches
+DISPATCHES_AHEAD = 2
+
 # live embedders that hold an expert-load sum (each joins at its first
 # ``note_producer_aux``): /metrics reads them (engine/http_server.py)
 # without a reference plumbed through the graph
@@ -62,6 +74,23 @@ def expert_load_stats() -> dict | None:
                                       for ld in loads),
             "buffer_rows_mean": sum(ld["buffer_rows"] for ld in loads)
             / layers if layers else 0.0}
+
+
+# live embedders whose model has attention layers the packer counts the
+# work of (each joins at its first ``dispatch_work``)
+_ATTENTION_EMBEDDERS: "weakref.WeakSet" = weakref.WeakSet()
+
+
+def attention_tile_stats() -> dict | None:
+    """Key blocks the blocked attention of the live embedders ran
+    (``tiles_run``) of all up to the diagonal (``tiles_all``), summed over
+    every fused dispatch so far (ops/attention.py ``attention_work``); None
+    where no embedder counts them."""
+    totals = [e.attention_tiles() for e in list(_ATTENTION_EMBEDDERS)]
+    if not totals:
+        return None
+    return {"tiles_run": sum(t[0] for t in totals),
+            "tiles_all": sum(t[1] for t in totals)}
 
 
 class JaxEncoderEmbedder(BaseEmbedder):
@@ -130,6 +159,10 @@ class JaxEncoderEmbedder(BaseEmbedder):
         self._aux_lock = threading.Lock()
         self._aux_sum = None
         self._aux_dispatches = 0
+        # what the last ``DISPATCHES_AHEAD`` forwards returned
+        self._aux_ahead: collections.deque = collections.deque()
+        # key blocks the attention layers ran, of all up to the diagonal
+        self._attention_tiles = [0, 0]
 
         def add_aux(total, aux):
             return jax.tree.map(operator.add, total, aux)
@@ -227,7 +260,11 @@ class JaxEncoderEmbedder(BaseEmbedder):
     def note_producer_aux(self, aux) -> None:
         """Sum what a forward returned beside its embeddings into the
         device arrays this embedder keeps (one small asynchronous
-        dispatch: no transfer, no wait)."""
+        dispatch, no transfer), and wait for the forward
+        ``DISPATCHES_AHEAD`` before this one: ``aux`` is ready when its
+        forward is done."""
+        import jax
+
         with self._aux_lock:
             if self._aux_sum is None:
                 _AUX_EMBEDDERS.add(self)
@@ -235,6 +272,11 @@ class JaxEncoderEmbedder(BaseEmbedder):
             else:
                 self._aux_sum = self._add_aux(self._aux_sum, aux)
             self._aux_dispatches += 1
+            self._aux_ahead.append(aux)
+            done = self._aux_ahead.popleft() \
+                if len(self._aux_ahead) > DISPATCHES_AHEAD else None
+        if done is not None:
+            jax.block_until_ready(done)
 
     def _embeddings(self, out):
         """A forward's embeddings, its ``aux`` (if any) noted."""
@@ -258,6 +300,32 @@ class JaxEncoderEmbedder(BaseEmbedder):
         return {"tokens_per_expert": np.asarray(total["tokens_per_expert"]),
                 "dispatches": dispatches, "expert_layers": int(layers),
                 "full_buffer_layers": int(full), "buffer_rows": rows}
+
+    def dispatch_work(self, args: tuple) -> dict:
+        """What the attention layers have to do in the ragged dispatch of
+        ``args`` (a chunk of :meth:`pack_ragged`), counted on the host from
+        its documents' places: ``attn_pairs_full``, ``attn_pairs_window``,
+        ``attn_tiles_run``, ``attn_tiles_all`` (ops/attention.py
+        ``attention_work``), which the ``embedder.dispatch`` span carries
+        and :meth:`attention_tiles` sums. Empty where the model's config
+        names no attention layers."""
+        windows = getattr(self.config, "attention_windows", None)
+        if not windows:
+            return {}
+        from pathway_tpu.ops.attention import attention_work
+
+        work = attention_work(args[1], args[2], windows)
+        with self._aux_lock:
+            _ATTENTION_EMBEDDERS.add(self)
+            self._attention_tiles[0] += work["attn_tiles_run"]
+            self._attention_tiles[1] += work["attn_tiles_all"]
+        return work
+
+    def attention_tiles(self) -> tuple[int, int]:
+        """(key blocks run, all up to the diagonal) over every fused
+        dispatch so far."""
+        with self._aux_lock:
+            return tuple(self._attention_tiles)
 
     def ragged_buckets(self) -> list[int]:
         """Sequence-count buckets the ragged path can dispatch: powers of

@@ -106,29 +106,38 @@ def test_bridge_counts_the_submits_that_waited():
 
 
 def _look(limiter, tick, rows, *, watermark, exec_ms, blocked,
-          deferred=True, queries=0):
+          deferred=True, queries=0, depth=None):
+    bridge = {"resolved_watermark": watermark, "exec_ms": exec_ms,
+              "submits_blocked": blocked}
+    if depth is not None:
+        bridge["depth"] = depth
     limiter.on_tick(tick, ingest_rows=rows, query_rows=queries,
-                    deferred=deferred,
-                    bridge={"resolved_watermark": watermark,
-                            "exec_ms": exec_ms, "submits_blocked": blocked})
+                    deferred=deferred, bridge=bridge)
     return limiter.ingest_row_budget()
 
 
 def test_the_bound_stands_from_the_first_submit_that_waits():
     """Three ticks after a release: two find room in the window, the third
-    waits for the first leg, and that leg's cost sets the bound at once."""
+    waits for the first leg, and that leg's cost sets the bound at once.
+    Until the runtime's first ingest leg has retired nothing is known of
+    the device's pace, and a drain takes ``FIRST_LEG_ROWS`` at most."""
     limiter = qos.DeviceBackpressure(0.05)
     leg_ms = qos.LEG_TICKS * 50.0
-    assert _look(limiter, 1, 60, watermark=0, exec_ms=0.0, blocked=0) is None
-    assert _look(limiter, 2, 60, watermark=0, exec_ms=0.0, blocked=0) is None
+    assert limiter.ingest_row_budget() == qos.FIRST_LEG_ROWS
+    assert _look(limiter, 1, 60, watermark=0, exec_ms=0.0,
+                 blocked=0) == qos.FIRST_LEG_ROWS
+    assert _look(limiter, 2, 60, watermark=0, exec_ms=0.0,
+                 blocked=0) == qos.FIRST_LEG_ROWS
     # 60 rows took 420 ms: 7 ms a row
     assert _look(limiter, 3, 60, watermark=1, exec_ms=420.0,
                  blocked=1) == int(leg_ms / 7.0)
     # a submit that did not wait raises the bound by half while rows are
-    # held back, and a drain that left nothing behind lifts it
+    # held back, but no further than a leg of LEG_TICKS intervals by the
+    # readings (7 ms a row still), and a drain that left nothing behind
+    # lifts it
     bound = limiter.ingest_row_budget()
     assert _look(limiter, 4, bound, watermark=3, exec_ms=1260.0,
-                 blocked=1) == bound + (bound + 1) // 2
+                 blocked=1) == bound
     assert _look(limiter, 5, 10, watermark=4, exec_ms=1660.0, blocked=1,
                  deferred=False) is None
 
@@ -157,3 +166,41 @@ def test_a_leg_that_compiled_or_served_queries_is_no_reading():
     compile_s[0] += 0.02
     assert _look(limiter, 5, 50, watermark=3, exec_ms=1020.0,
                  blocked=3) == int(qos.LEG_TICKS * 50.0 / 2.0)
+
+
+@pytest.mark.parametrize("leg_ms, first_bound", [
+    (400.0, 8),     # 50 ms a row: eight rows fill a leg of 400 ms
+    (16.0, 16),     # 2 ms a row would allow 200: twice the first leg's rows
+])
+def test_the_first_leg_s_cost_is_the_first_bound(leg_ms, first_bound):
+    """Ticks that are slow on the host fill the window late: behind a
+    device of 50 ms a row no submit waits for seconds, and unbounded ticks
+    meanwhile pile up legs of a hundred rows (my chip run, PR 33). The first
+    leg, held to ``FIRST_LEG_ROWS``, is a reading of the device's pace
+    whether or not a submit waited for it; the bound then holds while a leg
+    is still in flight behind the one submitted, grows by half when the
+    device was idle, and is lifted once a drain leaves nothing behind."""
+    limiter = qos.DeviceBackpressure(0.05)
+    rows = qos.FIRST_LEG_ROWS
+    assert _look(limiter, 1, rows, watermark=0, exec_ms=0.0, blocked=0,
+                 depth=1) == rows
+    assert _look(limiter, 2, rows, watermark=0, exec_ms=0.0, blocked=0,
+                 depth=2) == rows
+    # the first leg retired: 8 rows in ``leg_ms``
+    assert _look(limiter, 3, rows, watermark=1, exec_ms=leg_ms, blocked=0,
+                 depth=2) == first_bound
+    # a leg still runs behind the one just submitted: the bound holds
+    assert _look(limiter, 4, first_bound, watermark=2, exec_ms=2 * leg_ms,
+                 blocked=0, depth=2) == first_bound
+    # the device was idle when this leg was submitted: half as much again,
+    # but no leg longer than LEG_TICKS intervals by the readings
+    grown = min(first_bound + (first_bound + 1) // 2,
+                int(qos.LEG_TICKS * 50.0 / (leg_ms / rows)))
+    assert _look(limiter, 5, first_bound, watermark=4, exec_ms=4 * leg_ms,
+                 blocked=0, depth=1) == grown
+    # and nothing was left behind: no bound
+    assert _look(limiter, 6, 3, watermark=5, exec_ms=5 * leg_ms, blocked=0,
+                 depth=1, deferred=False) is None
+    # a later release, the pace known: no first-leg rule again
+    assert _look(limiter, 7, 500, watermark=6, exec_ms=6 * leg_ms,
+                 blocked=0, depth=1) is None

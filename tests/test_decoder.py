@@ -589,3 +589,48 @@ def test_the_kernel_is_taken_for_the_chip_at_its_shapes_alone():
     for name in ("kernel", "reference"):
         assert samples["pathway_tpu_deltanet_scan_programs", name] \
             == counted[name]
+
+
+def test_the_host_waits_for_the_forward_two_before(weights, monkeypatch):
+    """The runtime queues dozens of dispatches without a wait: behind a
+    slow model the first legs after a release then retire in a tenth of
+    their device time and ``DeviceBackpressure`` reads a pace ten times the
+    device's (my chip runs, PR 33). A forward's ``aux`` is ready when the
+    forward is done, so the embedder waits, at each dispatch, for the one
+    ``DISPATCHES_AHEAD`` before it: one runs, one is queued, the host packs
+    the next."""
+    from pathway_tpu.internals.keys import Pointer
+    from pathway_tpu.ops.knn import (BruteForceKnnIndex,
+                                     DeviceEmbeddingKnnIndex)
+    from pathway_tpu.xpacks.llm import embedders
+
+    assert embedders.DISPATCHES_AHEAD == 2
+    emb = _embedder(weights, ragged=True, ragged_max_seqs=2)
+    noted, waited = [], []
+    note, ready = emb.note_producer_aux, jax.block_until_ready
+
+    def noting(aux):
+        noted.append(aux)
+        note(aux)
+
+    def waiting(x):
+        # what it is asked to wait for, and how many had been noted by then
+        waited.append((x, len(noted)))
+        return ready(x)
+
+    monkeypatch.setattr(emb, "note_producer_aux", noting)
+    monkeypatch.setattr(jax, "block_until_ready", waiting)
+    index = DeviceEmbeddingKnnIndex(
+        emb, BruteForceKnnIndex(64, reserved_space=256, metric="cos"))
+    # rows of 128 slots, two a dispatch: five dispatches in one leg
+    texts = _texts((100, 100, 100, 100, 100, 100, 100, 100, 100))
+    assert len(emb.pack_ragged(texts)) == 5
+    index.add_batch([Pointer(i) for i in range(len(texts))], texts)
+    monkeypatch.undo()
+    assert len(noted) == 5
+    # the third dispatch waited for the first, the fourth for the second...
+    assert [(x is noted[n - 3], n) for x, n in waited] \
+        == [(True, 3), (True, 4), (True, 5)]
+    assert len(emb._aux_ahead) == 2
+    # and the sum is what it was: every dispatch counted once
+    assert emb.expert_load()["dispatches"] == 5
