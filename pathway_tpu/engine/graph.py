@@ -394,6 +394,10 @@ class Scheduler:
             return self._bridge.inflight()
         return None
 
+    def bridge_depth(self) -> int:
+        """Device legs queued or running (0 when pipelining is off)."""
+        return self._bridge.depth() if self._bridge is not None else 0
+
     def bridge_stats(self) -> dict | None:
         """Device-bridge instrumentation (None when pipelining is off)."""
         if self._bridge is not None:

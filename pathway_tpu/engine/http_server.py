@@ -529,6 +529,15 @@ class MonitoringHttpServer:
             lines.append("# TYPE pathway_tpu_device_exec_ms_total counter")
             lines.append(
                 f"pathway_tpu_device_exec_ms_total {bridge['exec_ms']}")
+        woken = getattr(self.runtime, "ticks_woken_by", None)
+        if woken is not None:
+            # commit ticks by what ended the loop's wait
+            # (engine/streaming.py): the autocommit period, or a request
+            # pushed into a serving source before it ran out
+            lines.append("# TYPE pathway_tpu_ticks_total counter")
+            for cause, n in woken.items():
+                lines.append(
+                    f'pathway_tpu_ticks_total{{woken_by="{cause}"}} {n}')
         prof = _profiler_stats()
         if prof is not None:
             # continuous profiling plane (engine/profiler.py): rolling

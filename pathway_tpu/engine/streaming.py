@@ -45,6 +45,13 @@ def stop_all(join_timeout: float = 5.0) -> None:
         rt.join_readers(join_timeout)
 
 
+def _is_serving(datasource) -> bool:
+    """A source of requests (``rest_connector``): it declares the
+    ``request_tracker`` slot. Its rows are never clipped by an ingest
+    budget, and a request pushed into its session wakes the commit loop."""
+    return hasattr(datasource, "request_tracker")
+
+
 class StreamingRuntime:
     def __init__(self, runner, *, monitoring_level=None, with_http_server=False,
                  persistence_config=None, terminate_on_error=True,
@@ -86,6 +93,18 @@ class StreamingRuntime:
         self.default_commit_ms = default_commit_ms
         self.terminate_on_error = terminate_on_error
         self._stop = threading.Event()
+        # what the commit loop sleeps on between ticks: set by a stop
+        # request and by a request pushed into a serving source's session
+        # (wired below), so the autocommit period is the longest a
+        # request waits for a tick and not what it waits on average
+        self._wake = threading.Event()
+        # ticks run, by what ended the loop's wait (/metrics
+        # pathway_tpu_ticks_total; the ``tick`` span's ``woken_by``)
+        self.ticks_woken_by = {"period": 0, "request": 0}
+        # a request is waiting for the leg in flight to retire: the
+        # bridge's worker ends the loop's wait then (a bare bool store,
+        # atomic under the GIL, as ``last_tick_at``)
+        self._wake_on_retire = False
         # last tick run_time RETURNED for (pipelined: its device leg may
         # still be in flight — the bridge watermark, not this counter, is
         # the durability frontier)
@@ -228,6 +247,15 @@ class StreamingRuntime:
             # classify sources: WAL-backed feeds are tailed (no reader
             # thread), serving sources run live
             self.replica.bind(self.sessions)
+        if cluster is None:
+            # a request wakes the loop; a row of an ingest source never
+            # does. Under a cluster every process keeps the period: the
+            # tick is a lock-step exchange (_tick_sync), and a process
+            # woken alone would only block in it until its peers' period
+            # ends
+            for _node, session, ds in self.sessions:
+                if _is_serving(ds):
+                    session.wake = self._wake.set
         # fleet control channel (engine/replica.py): when a router's
         # control address is configured, this process — replica OR a
         # read-serving primary — registers and heartbeats its applied
@@ -250,6 +278,9 @@ class StreamingRuntime:
         # (ingest_rows, query_rows, deferred) of the latest drain — the
         # QoS feedback loop's per-tick input
         self._last_drain: tuple[int, int, bool] = (0, 0, False)
+        # query rows of the request-woken ticks since the last period
+        # tick: either ingest budget is fed once an interval, as before
+        self._queries_between_periods = 0
         # the ingest budget with no QoS armed (engine/qos.py
         # DeviceBackpressure): made by the first tick that has a bridge
         self._backpressure = None
@@ -287,7 +318,7 @@ class StreamingRuntime:
             self.recorder.requests if self.recorder is not None else None)
         if self._request_tracker is not None:
             for _node, _session, ds in self.sessions:
-                if hasattr(ds, "request_tracker"):
+                if _is_serving(ds):
                     ds.request_tracker = self._request_tracker
         # a polling source writes its ``connector.pass`` spans through the
         # recorder slot of its session (io/_datasource.py; None = off)
@@ -323,6 +354,7 @@ class StreamingRuntime:
 
     def stop(self) -> None:
         self._stop.set()
+        self._wake.set()
         self.supervisor.request_stop()
         for _node, session, _ds in self.sessions:
             session.stopping.set()
@@ -417,6 +449,10 @@ class StreamingRuntime:
     def _on_watermark_advance(self, tick: int) -> None:
         # bridge-worker thread; a bare float store is atomic under the GIL
         self.last_tick_at = _time.monotonic()
+        if self._wake_on_retire:
+            # a request waits for the bridge to be free (_wait_for_tick)
+            self._wake_on_retire = False
+            self._wake.set()
 
     def _handle_engine_failure(self, error: BaseException) -> bool:
         """A failure escaped the commit loop: a poisoned device leg, a
@@ -479,15 +515,28 @@ class StreamingRuntime:
                 release(watermark)
 
     def _tick_feedback(self, tick: int, tick_ms: float,
-                       commit_s: float) -> None:
+                       commit_s: float, by_request: bool = False) -> None:
         """Close the loop for one tick: feed the ingest budget what the
         tick actually did (rows drained, host wall time, what retired on
         the bridge since the last tick). With QoS armed the budget is the
         controller's, and its deferral backpressure goes on to the
         connector readers; without, it is ``DeviceBackpressure``'s, which
         holds nothing back until a submit finds the bridge's window
-        full."""
+        full.
+
+        Both budgets are reckoned in commit intervals, so both are fed by
+        the period's ticks alone. A tick that a request woke
+        (``by_request``) looked at no ingest source and moves no bound:
+        its queries are counted into the next period tick's look, which
+        so sees one interval's rows, queries and device time, as when
+        that tick drained them itself (an interval that served queries
+        is no reading of an ingest row's cost)."""
         ingest_rows, query_rows, deferred = self._last_drain
+        if by_request:
+            self._queries_between_periods += query_rows
+            return
+        query_rows += self._queries_between_periods
+        self._queries_between_periods = 0
         bridge = self.scheduler.bridge_stats()
         if self.qos is None:
             if bridge is not None:
@@ -612,7 +661,48 @@ class StreamingRuntime:
         self.scheduler.emit_restored_outputs(snap["tick"])
         return snap["tick"]
 
-    def _drain_and_forward(self, tick: int, budgeted: bool = True):
+    def _wait_for_tick(self, period_due: float) -> str:
+        """Sleep until the next commit tick and say what ended the wait:
+        ``"stop"`` (a stop request: the loop ends), ``"period"`` (the
+        autocommit interval ran out at ``period_due``, on ``monotonic``:
+        a tick over every source) or ``"request"`` (a serving source
+        pushed a request before that: a tick over the serving sources
+        alone, at once, or as soon as the device leg in flight has
+        retired)."""
+        while not self._stop.is_set():
+            timeout = period_due - _time.monotonic()
+            if timeout <= 0:
+                # the period's tick drains the serving sources too
+                self._wake.clear()
+                return "period"
+            if self._wake.wait(timeout) and not self._stop.is_set():
+                # cleared BEFORE the drain: a request pushed from here on
+                # either rides this tick or finds the flag down and wakes
+                # the next
+                self._wake.clear()
+                if not self._held_behind_a_leg():
+                    return "request"
+        return "stop"
+
+    def _held_behind_a_leg(self) -> bool:
+        """Whether a waiting request's tick has to wait for the device
+        bridge: a leg submitted now would only queue behind the one in
+        flight, so the loop sleeps on until that leg retires
+        (:meth:`_on_watermark_advance` ends the wait) and the requests
+        that arrive meanwhile ride one tick, one batch of the scan. On
+        the chip, against a tick at every arrival (PERF.md, PR 34): the
+        same median at 20 queries/s, and at 30/s p50 30.7 ms for 37.2,
+        p95 58.7 for 82.7. The period still ends the wait."""
+        # raised BEFORE the look: a leg that retires after it finds the
+        # flag up and wakes the loop, which then looks again
+        self._wake_on_retire = True
+        if self.scheduler.bridge_depth() > 0:
+            return True
+        self._wake_on_retire = False
+        return False
+
+    def _drain_and_forward(self, tick: int, budgeted: bool = True,
+                           serving_only: bool = False):
         """Drain local sessions; under a cluster split each source's rows
         by owning process (single reader on process 0 forwards shards —
         reference: 'single reader forwards for non-partitioned sources').
@@ -628,7 +718,13 @@ class StreamingRuntime:
         or content.
         Serving sources (request-tracking) are never clipped; the
         end-of-stream re-drain passes ``budgeted=False`` (latency has no
-        meaning once every source closed — finish at full throughput)."""
+        meaning once every source closed — finish at full throughput).
+
+        ``serving_only``: a tick that a request woke drains the serving
+        sources alone. Ingest sources are not looked at, so their rows,
+        their seals and their budget keep the period's cadence: what an
+        ingest source hands over per interval and per device leg is what
+        it hands over with no request at all."""
         any_data = False
         all_closed = True
         tracker = self._request_tracker
@@ -651,7 +747,11 @@ class StreamingRuntime:
             order = order[r:] + order[:r]
         for i in order:
             node, session, datasource = self.sessions[i]
-            serving = hasattr(datasource, "request_tracker")
+            serving = _is_serving(datasource)
+            if serving_only and not serving:
+                if not session.closed.is_set():
+                    all_closed = False
+                continue
             limit = None
             if budget is not None and not serving:
                 limit = budget - ingest_rows
@@ -721,19 +821,22 @@ class StreamingRuntime:
             all_closed = all_closed and payload["closed"]
         return any_data, all_closed
 
-    def _record_tick(self, rec, tick: int, any_data: bool, t_wake: float,
-                     t_drain: float, t_host: float, t_end: float) -> None:
+    def _record_tick(self, rec, tick: int, woken_by: str, any_data: bool,
+                     t_wake: float, t_drain: float, t_host: float,
+                     t_end: float) -> None:
         """The spans of one commit tick, sharing ``("tick", tick)`` with
         the leg the tick submitted and, through ``RequestSpan.tick``, with
         the requests it picked up: ``tick`` from the loop's wake-up to
-        ``run_time``'s return (every tick), and on a tick that carried
-        rows ``tick.drain`` around the drain and the cluster exchange and
+        ``run_time``'s return (every tick; ``woken_by`` says what ended
+        the loop's wait, ``"period"`` or ``"request"``), and on a tick
+        that carried rows ``tick.drain`` around the drain and the cluster exchange and
         ``tick.host`` around ``run_time``, which returns with the device
         leg submitted (``t_host == t_end``: the tick skipped it)."""
         cause = ("tick", tick)
         by_source, requests = self._last_drain_counts
         rec.span("tick", t_wake, t_end, cause,
-                 rows=sum(by_source.values()), requests=requests)
+                 rows=sum(by_source.values()), requests=requests,
+                 woken_by=woken_by)
         if any_data:
             rec.span("tick.drain", t_drain, t_host, cause, **by_source)
             if t_end > t_host:
@@ -857,11 +960,20 @@ class StreamingRuntime:
         # count). The flag flips only when the while-loop exits normally.
         loop_clean = False
         try:
-            # Event wait, not time.sleep: a stop request wakes the loop
-            # immediately instead of out-waiting the commit interval
-            # (the PWT206 sleep-polling pattern this checker family bans)
+            # Event wait, not time.sleep: a stop request ends the wait at
+            # once, and so does a request pushed into a serving source
+            # (_wait_for_tick). The commit interval is the longest a
+            # request, or a row, waits for a tick; a row of an ingest
+            # source waits for the period's tick, whatever woke the ticks
+            # between (the PWT206 sleep-polling pattern this checker
+            # family bans)
             rec = self.recorder
-            while not self._stop.wait(commit_s):
+            period_due = _time.monotonic() + commit_s
+            while True:
+                woken_by = self._wait_for_tick(period_due)
+                if woken_by == "stop":
+                    break
+                by_request = woken_by == "request"
                 # the tick's spans (engine/flight_recorder.py): four clock
                 # reads and up to three tuples a tick while recording
                 t_wake = (_time.perf_counter()
@@ -897,16 +1009,25 @@ class StreamingRuntime:
                 # required by operator-state snapshots (a seal taken before
                 # the drain would let gap entries be processed at t but
                 # recorded at t+1, double-counting them after a restore)
-                if self.replica is not None:
+                if self.replica is not None and not by_request:
                     # tail the primary's WAL: every complete new primary
                     # commit tick is applied, coalesced per round into
                     # one local scheduler tick (engine/replica.py pump —
-                    # advances applied_tick)
+                    # advances applied_tick). On the period's ticks alone:
+                    # the tailer counts quiet POLLS before it trusts the
+                    # newest tick, and polls that follow requests would
+                    # shorten that hold-back
                     time_counter = self.replica.pump(self, time_counter)
                 if t_wake is not None:
                     t_drain = _time.perf_counter()
                 any_data, all_closed, pushes = self._drain_and_forward(
-                    time_counter)
+                    time_counter, serving_only=by_request)
+                if by_request and not any_data:
+                    # the request that set the wake-up rode the tick
+                    # before (it was pushed between that tick's wake-up
+                    # and its drain): no tick to run, no span, no count
+                    continue
+                self.ticks_woken_by[woken_by] += 1
                 any_data, all_closed = self._tick_sync(
                     time_counter, any_data, all_closed, pushes)
                 if t_wake is not None:
@@ -933,7 +1054,8 @@ class StreamingRuntime:
                     self._last_completed_tick = time_counter
                     self._tick_feedback(
                         time_counter,
-                        (_time.perf_counter() - t_tick0) * 1e3, commit_s)
+                        (_time.perf_counter() - t_tick0) * 1e3, commit_s,
+                        by_request)
                     # close every live semantic result cache's
                     # invalidations/tick window (engine/result_cache.py)
                     # — the basis of the exported invalidations-per-tick
@@ -952,15 +1074,18 @@ class StreamingRuntime:
                         # could fail, but checkpoint cadence no longer
                         # prices pipelining at effective depth 1
                         self._commit_watermark_tick(time_counter)
-                        if self._snapshot_due(time_counter):
+                        if not by_request \
+                                and self._snapshot_due(time_counter):
                             # bounded-time recovery: operator-state
                             # snapshot anchored to the watermark + WAL
                             # compaction (engine/persistence.py)
                             self._snapshot_pass(time_counter)
                 if t_wake is not None:
-                    self._record_tick(rec, time_counter, any_data, t_wake,
-                                      t_drain, t_host, t_end)
+                    self._record_tick(rec, time_counter, woken_by, any_data,
+                                      t_wake, t_drain, t_host, t_end)
                 time_counter += 1
+                if not by_request:
+                    period_due = _time.monotonic() + commit_s
                 if all_closed and not any_data:
                     # re-drain: a source may have pushed between its drain()
                     # and closing — loop until truly empty, then final tick

@@ -19,7 +19,11 @@ def run(*, debug: bool = False, monitoring_level=None, with_http_server: bool = 
 
     Static-only graphs run in batch mode to completion; graphs with streaming
     sources enter the realtime microbatch loop (pathway_tpu/engine/streaming.py)
-    until all sources finish or the process is stopped.
+    until all sources finish or the process is stopped. The loop ticks at
+    the smallest ``autocommit_duration_ms`` of its sources (100 ms at
+    most): that period is the cadence of ingest and the longest a request
+    of a ``rest_connector`` waits for a tick, since a waiting request
+    wakes the loop before the period runs out.
 
     ``trace_path`` (or ``PATHWAY_TRACE_PATH``) turns on the flight
     recorder (engine/flight_recorder.py) and writes the run's span buffer

@@ -49,6 +49,13 @@ class Session:
         # the streaming runtime while one records: a polling source writes
         # one ``connector.pass`` span per pass through it. None = off
         self.recorder = None
+        # filled by the streaming runtime for the session of a serving
+        # source (rest_connector) alone: called after every pushed
+        # insertion, it ends the commit loop's wait, so a request is
+        # drained by a tick that starts at once and the autocommit period
+        # is the longest it can wait. None (every ingest source): a push
+        # wakes nobody and rides the period's tick
+        self.wake: Callable[[], None] | None = None
 
     @property
     def stop_requested(self) -> bool:
@@ -69,6 +76,10 @@ class Session:
         # consumed by the persistence layer's RecordingSession proxy
         # (engine/persistence.py) and ignored on the plain live path.
         self._q.put((key, row, diff))
+        if self.wake is not None and diff > 0:
+            # a request; its retraction, once answered (diff < 0), needs
+            # no tick of its own and rides the next one
+            self.wake()
 
     def drain(self, limit: int | None = None) -> list[tuple]:
         """Pop buffered entries (all of them, or at most ``limit`` when

@@ -321,6 +321,9 @@ class RestSource(DataSource):
                  delete_completed_queries: bool,
                  autocommit_duration_ms=50, request_validator=None,
                  format: str = "custom", durable_ack: bool = False):
+        # the period bounds a request's wait for a tick from above: its
+        # push wakes the commit loop (the runtime fills ``Session.wake``
+        # for a source that declares ``request_tracker``)
         super().__init__(schema, autocommit_duration_ms)
         self.webserver = webserver
         self.route = route
@@ -497,6 +500,16 @@ def rest_connector(host: str | None = None, port: int | None = None, *,
     schema-ful one infers ``custom``
     (reference: _server.py:50,525-535,733-736).
 
+    ``autocommit_duration_ms`` is the longest a request waits for a
+    commit tick, not what it waits on average: a request pushed here
+    wakes the commit loop (engine/streaming.py), and the tick it wakes
+    drains the serving sources alone, at once or, behind a device leg in
+    flight, when that leg retires. The period's own ticks
+    keep the cadence of ingest (the rows of every other source are
+    drained, sealed and budgeted by them alone), and under a
+    multi-process cluster, whose ticks are a lock-step exchange, a
+    request rides them too.
+
     ``persistent_id`` records the route's rows in the WAL like any other
     persisted source — required for write routes whose state must
     survive restarts and be tailed by replicas. ``durable_ack`` holds
@@ -580,6 +593,10 @@ def _jsonable(value):
 def read(url: str, *, schema=None, format: str = "json",
          autocommit_duration_ms: int | None = 1500, name=None,
          **kwargs) -> Table:
+    """Stream the lines of ``url`` as rows. An ingest source:
+    ``autocommit_duration_ms`` is the cadence at which its rows are
+    committed (the runtime ticks at the smallest period of its sources);
+    a row wakes no tick, unlike a ``rest_connector``'s request."""
     import urllib.request
 
     from pathway_tpu.io._datasource import CallbackSource

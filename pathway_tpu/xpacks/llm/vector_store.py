@@ -317,7 +317,10 @@ class VectorStoreServer:
                    cache_backend=None, **run_kwargs):
         """Expose /v1/retrieve, /v1/statistics, /v1/inputs and run
         (reference vector_store.py:461-566). with_cache memoizes UDF calls
-        without an explicit cache_strategy (DiskCache by default)."""
+        without an explicit cache_strategy (DiskCache by default). A
+        request to any of the routes wakes the commit loop: the routes'
+        ``autocommit_duration_ms`` (``rest_connector``'s 50) is the longest
+        it waits for a tick and the cadence at which documents are taken."""
         from pathway_tpu.internals import udfs
 
         if with_cache:

@@ -540,6 +540,11 @@ def test_streaming_run_records_one_chain_of_spans(live_rag):
         if hits and hits[0]["metadata"]["path"].endswith("new.txt"):
             first_seen = time.perf_counter()
         assert time.monotonic() < deadline, "the new file never surfaced"
+    # the answer leaves from inside the leg; its spans are written when the
+    # leg has retired
+    while runtime.scheduler.bridge_depth():
+        assert time.monotonic() < deadline, "the leg never retired"
+        time.sleep(0.005)
     spans = rec.spans()
     by_name: dict = {}
     for sp in spans:
@@ -587,13 +592,17 @@ def test_streaming_run_records_one_chain_of_spans(live_rag):
     assert found[1] <= push <= found[2]
     push_wall = push + rec._wall_ns_offset / 1e9
     assert push_wall >= mtime
-    # the commit stamp: the end of the leg of the first tick whose drain
-    # started at or after the push
+    # the commit stamp: the end of the leg of the first tick that drained
+    # the file's source at or after the push (a tick that a request woke
+    # drains the serving sources alone: its ``tick.drain`` names no other)
     drains = sorted((sp for sp in spans if sp[0] == "tick.drain"
-                     and sp[1] >= push), key=lambda sp: sp[1])
+                     and sp[1] >= push
+                     and any(k.startswith("fs-") for k in sp[5])),
+                    key=lambda sp: sp[1])
     commit = by_name["bridge.leg"][drains[0][3]][2]
     assert push <= commit <= first_seen
-    assert any(k.startswith("fs-") for k in drains[0][5])
+    assert by_name["tick"][drains[0][3]][5]["woken_by"] == "period"
+    assert tick[5]["woken_by"] in ("request", "period")
 
     # -- the surfaces ----------------------------------------------------------
     payload = rec.trace_payload()
