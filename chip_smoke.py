@@ -169,14 +169,15 @@ def _chunked_scan_check(rows: int, dim: int) -> None:
     del index
 
 
-def _windowed_decoder_check(cfg, row: int, seed: int) -> dict:
-    """One forward of the decoder with window and full attention mixed
-    (models/decoder.py, ops/attention.py ``segment_attention``) at ``cfg``'s
+def _decoder_check(cfg, row: int, seed: int, what: str) -> dict:
+    """One forward of a decoder whose attention is the blocked core over
+    packed rows (models/decoder.py, ops/attention.py ``segment_attention``:
+    window and full attention mixed, or latent attention) at ``cfg``'s
     widths on packed rows of ``row`` slots, weights drawn on the device in
-    the compute dtype: a document longer than the window alone in a row,
-    then behind two others. Finite unit embeddings, and the document's
-    embedding does not depend on where it lies; on a TPU with heads of
-    whole lanes the attention took the kernel."""
+    the compute dtype: a long document (longer than the window, where there
+    is one) alone in a row, then behind two others. Finite unit embeddings,
+    and the document's embedding does not depend on where it lies; on a TPU
+    with values of whole lanes the attention took the kernel."""
     import jax
     import jax.numpy as jnp
 
@@ -215,13 +216,14 @@ def _windowed_decoder_check(cfg, row: int, seed: int) -> dict:
         behind, np.float32)
     _check(np.isfinite(alone).all() and np.isfinite(behind).all()
            and np.allclose(np.linalg.norm(behind, axis=1), 1.0, atol=1e-3),
-           f"the windowed decoder's embeddings of a {row}-slot row are "
+           f"the {what} decoder's embeddings of a {row}-slot row are "
            f"finite unit vectors")
     cos = float(alone[0] @ behind[2])
     _check(cos >= 0.98, f"a document of {len(long_doc)} tokens (window "
            f"{cfg.sliding_window_size}) embeds alike alone and behind two "
            f"others in its row: cos {cos:.5f} >= 0.98")
-    kernel = jax.devices()[0].platform == "tpu" and cfg.head_dim % 128 == 0
+    lanes = cfg.v_head_dim if cfg.attention_method else cfg.head_dim
+    kernel = jax.devices()[0].platform == "tpu" and lanes % 128 == 0
     _check(took["blockwise" if kernel else "kernel"] == 0
            and took["kernel" if kernel else "blockwise"] > 0,
            f"attention lowered as the "
@@ -422,7 +424,8 @@ def run_smoke(*, expected_platform: str, config, n_docs: int,
               mesh: str | None = None, seed: int = 0,
               request_timeout_s: int = 600,
               out_path: str | None = None, decoder_config=None,
-              decoder_row: int = 0) -> dict:
+              decoder_row: int = 0, latent_config=None,
+              latent_row: int = 0) -> dict:
     """Drive the main path once and check it; returns the summary dict
     (also printed). Raises on the first failed check.
 
@@ -436,7 +439,8 @@ def run_smoke(*, expected_platform: str, config, n_docs: int,
     one-chip with a four-chip run). ``decoder_config``: a callable
     returning a ``DecoderConfig`` of the windowed pattern, forwarded once
     on rows of ``decoder_row`` slots after the server is down (None: no
-    such check)."""
+    such check); ``latent_config``, ``latent_row``: the same for a
+    ``DecoderConfig`` of the latent attention pattern."""
     t_start = time.perf_counter()
     import jax
 
@@ -492,8 +496,11 @@ def run_smoke(*, expected_platform: str, config, n_docs: int,
                f"bf16 encoder agrees with float32/highest: min cos "
                f"{min_cos:.6f} >= {BF16_VS_F32_MIN_COS}")
         _chunked_scan_check(scan_rows, cfg.hidden)
-        windowed = _windowed_decoder_check(
-            decoder_config(), decoder_row, seed) if decoder_config else None
+        windowed = _decoder_check(
+            decoder_config(), decoder_row, seed, "windowed") \
+            if decoder_config else None
+        latent = _decoder_check(latent_config(), latent_row, seed,
+                                "latent") if latent_config else None
     finally:
         jax.monitoring.unregister_event_duration_listener(on_jit_event)
 
@@ -520,6 +527,7 @@ def run_smoke(*, expected_platform: str, config, n_docs: int,
         "bridge_legs_resolved": served["bridge_legs_resolved"],
         "bf16_vs_f32_min_cos": round(min_cos, 6),
         "windowed_decoder": windowed,
+        "latent_decoder": latent,
         "topk_digest": hashlib.sha256(json.dumps(sorted(
             (q, [name for name, _dist in hits])
             for q, hits in answers.items())).encode()).hexdigest()[:16],
@@ -563,13 +571,36 @@ def main() -> int:
                 router_input="mixer_input", max_len=16384,
                 compute_dtype=jnp.bfloat16)
 
+        def longcat_layer():
+            """One layer of LongCat-Flash's language model at its published
+            widths, 16 of its 512 experts held:
+            benchmark/configs/longcat-flash-embed.json (1.24 billion
+            parameters a layer, 2.5 GB in bfloat16)."""
+            import jax.numpy as jnp
+
+            from pathway_tpu.models.decoder import DecoderConfig
+
+            return DecoderConfig(
+                vocab_size=16384, hidden_size=6144, num_hidden_layers=1,
+                rms_norm_eps=1e-5, zero_centred_norm=False,
+                num_attention_heads=64, rope_theta=1e7,
+                attention_method="MLA", q_lora_rank=1536, kv_lora_rank=512,
+                qk_nope_head_dim=128, qk_rope_head_dim=64, v_head_dim=128,
+                ffn_hidden_size=12288, num_experts=512,
+                num_experts_per_tok=12, moe_intermediate_size=2048,
+                shared_expert_intermediate_size=None, norm_topk_prob=False,
+                zero_expert_num=256, routed_scaling_factor=6.0,
+                experts_held=(0, 16), max_len=8192,
+                compute_dtype=jnp.bfloat16)
+
         summary = run_smoke(
             expected_platform="tpu", config=bge_small, n_docs=3000,
             max_words=120, max_len=128, scan_rows=1 << 20,
             mesh="auto" if n_devices > 1 else None,
             out_path=os.path.join(out_dir,
                                   f"chip_smoke_topk_{n_devices}chip.json"),
-            decoder_config=smallthinker_period, decoder_row=16384)
+            decoder_config=smallthinker_period, decoder_row=16384,
+            latent_config=longcat_layer, latent_row=8192)
     except Exception:  # any failed check or error: exit != 0, no JSON line
         import traceback
 

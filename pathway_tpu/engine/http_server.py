@@ -714,6 +714,15 @@ class MonitoringHttpServer:
             lines.append("# TYPE pathway_tpu_moe_buffer_rows_mean gauge")
             lines.append(f"pathway_tpu_moe_buffer_rows_mean "
                          f"{experts['buffer_rows_mean']}")
+            if "pairs" in experts:
+                # a router with identity experts: the chosen (token,
+                # expert) pairs that cost no product, of all chosen
+                lines.append(
+                    "# TYPE pathway_tpu_moe_zero_expert_pairs counter")
+                lines.append(f"pathway_tpu_moe_zero_expert_pairs "
+                             f"{experts['zero_pairs']}")
+                lines.append("# TYPE pathway_tpu_moe_pairs counter")
+                lines.append(f"pathway_tpu_moe_pairs {experts['pairs']}")
         scans = _scan_lowerings()
         if scans is not None:
             # which lowering the delta-rule scans of the compiled programs

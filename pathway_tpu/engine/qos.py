@@ -257,6 +257,12 @@ LEG_TICKS = 8
 FIRST_LEG_ROWS = 8
 #: readings of a row's cost of which the dearest stands, before smoothing
 EARLY_READINGS = 4
+#: ingest legs that may retire without a reading of a row's cost (legs that
+#: spent their time in XLA's compiler) before a drain is held to
+#: ``FIRST_LEG_ROWS`` no longer: a path that compiles nearly every tick
+#: gives no reading ever and is not to be held for good; a first leg that
+#: compiled on a cold cache is followed by one that does not
+UNREAD_LEGS = 4
 
 
 def _half_more(rows: int) -> int:
@@ -276,9 +282,11 @@ class DeviceBackpressure:
     ``LEG_TICKS`` commit intervals, by the cost of the legs that have
     retired (:class:`IngestCost`; the first leg has retired by the first
     submit that waits, so the bound stands from the third tick after a
-    release; until the first ingest leg of the runtime's life has retired
-    a drain takes ``FIRST_LEG_ROWS`` at most, and that leg's cost is the
-    first bound). The rest stays in its session and rides later ticks, as
+    release; until a leg of the runtime's life has given a reading of a
+    row's cost a drain takes ``FIRST_LEG_ROWS`` at most, and that leg's
+    cost is the first bound: the first leg that retires, or, where it
+    spent its time in the compiler, one of the ``UNREAD_LEGS`` behind it).
+    The rest stays in its session and rides later ticks, as
     under the controller's budget. A submit that found the device idle
     (no leg in flight but its own) raises the bound by half while rows
     are held back, up to a leg of ``LEG_TICKS`` intervals by the readings,
@@ -300,8 +308,11 @@ class DeviceBackpressure:
         self.tick_interval_ms = max(1.0, tick_interval_s * 1e3)
         self._cost = IngestCost()
         self._rows: int | None = None
-        # whether an ingest leg has retired yet: before, nothing is known
+        # whether the device's pace is known (a leg's cost was read, or
+        # ``UNREAD_LEGS`` ingest legs retired without a reading): before,
+        # a drain is held to ``FIRST_LEG_ROWS``
         self._paced = False
+        self._legs_retired = 0
         self._readings = 0
         # (tick, ingest rows, query rows) of the ticks whose legs have
         # not retired, and what the last look saw of the bridge
@@ -332,8 +343,8 @@ class DeviceBackpressure:
                 and self._unretired[0][0] <= bridge["resolved_watermark"]:
             _tick, rows, queries = self._unretired.popleft()
             retired += rows
+            self._legs_retired += rows > 0
             clean = clean and not queries
-        self._paced = self._paced or retired > 0
         compile_ms = (self._compile_s() - self._compile_s_seen) * 1e3
         exec_ms = bridge["exec_ms"] - self._exec_ms_seen
         # a leg which spent most of its time in XLA's compiler says nothing
@@ -356,6 +367,13 @@ class DeviceBackpressure:
             self._readings += 1
             if self._readings <= EARLY_READINGS:
                 self._cost.ms_per_row = dearest
+        # a first leg that compiled (a cold cache) is no reading, and with
+        # the hold lifted behind it the next drain took the whole backlog:
+        # one leg of every document, no tick edge and no answer to a
+        # request until it retired (3,000 sections of 0.4 s each behind
+        # it, `/v1/statistics` timed out after 120 s: my chip run, PR 35)
+        self._paced = self._paced or self._readings > 0 \
+            or self._legs_retired >= UNREAD_LEGS
         rows = self._cost.rows_in(LEG_TICKS * self.tick_interval_ms)
         if waited:
             if rows is not None:

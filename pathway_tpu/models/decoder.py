@@ -5,7 +5,7 @@ retrieval.
 Pre-norm blocks ``x = x + mixer(norm1(x)); x = x + moe(norm2(x))`` with
 RMSNorm and no biases; every layer's feed-forward is routed experts (top-k
 of many, weights renormalised over the k). What a layer does follows from
-:class:`DecoderConfig`, which takes the published keys of two families:
+:class:`DecoderConfig`, which takes the published keys of three families:
 
 - Qwen3-Next (``config.json`` of ``Qwen/Qwen3-Next-80B-A3B-Instruct``; the
   defaults): zero-centred norms (``x / rms(x) * (1 + w)``); layer ``i`` is
@@ -18,10 +18,19 @@ of many, weights renormalised over the k). What a layer does follows from
   is 1 and full otherwise, rotary over the whole head where
   ``rope_layout[i]`` is 1 and no positions at all otherwise; ReGLU experts,
   no shared one, the router reading the mixer's input
-  (``router_input="mixer_input"``).
+  (``router_input="mixer_input"``);
+- LongCat-Flash (``meituan-longcat/LongCat-Flash-Omni``'s language model;
+  ``attention_method="MLA"``): plain norms; a layer is **two** latent
+  attention sublayers, two dense SwiGLU feed-forwards and one expert layer
+  whose input is the first sublayer's state and whose output joins the
+  residual only at the layer's end (:func:`shortcut_layer`); the router's
+  last ``zero_expert_num`` outputs are identity experts, its choice is over
+  the probabilities plus a correction bias, its weights are the
+  probabilities themselves times ``routed_scaling_factor``.
 
 - one layer function per kind: :func:`deltanet_layer`,
-  :func:`attention_layer`, :func:`moe_layer`;
+  :func:`attention_layer`, :func:`latent_attention_layer`,
+  :func:`dense_ffn`, :func:`moe_layer`;
 - :func:`moe_layer` is told the range of experts it holds
   (``config.experts_held``), routes over all and adds what its own experts
   give: under expert parallelism the shares of all chips, with the shared
@@ -56,8 +65,10 @@ from pathway_tpu.ops import attention, deltanet, moe
 
 @dataclasses.dataclass(frozen=True)
 class LayerKind:
-    """What the mixer of one layer is: ``"deltanet"``, or ``"attention"``
-    with its window (None: full) and whether it rotates q and k."""
+    """What the mixer of one layer is: ``"deltanet"``, ``"attention"``
+    with its window (None: full) and whether it rotates q and k, or
+    ``"latent"``: the shortcut-connected layer of two latent attention
+    sublayers."""
 
     mixer: str
     window: int | None = None
@@ -89,6 +100,23 @@ class DecoderConfig:
     sliding_window_layout: tuple[int, ...] | None = None
     rope_layout: tuple[int, ...] | None = None
     sliding_window_size: int | None = None
+    #: ``"MLA"``: every layer is :func:`shortcut_layer`, its attention
+    #: latent: queries through a bottleneck of ``q_lora_rank``, keys and
+    #: values expanded from one latent of ``kv_lora_rank`` a token, a head's
+    #: key ``qk_nope_head_dim`` features of its own beside
+    #: ``qk_rope_head_dim`` rotary ones shared by all heads, its value
+    #: ``v_head_dim``; ``mla_scale_*``: the bottlenecks' outputs scaled by
+    #: ``(hidden_size / rank) ** 0.5``
+    attention_method: str | None = None
+    q_lora_rank: int = 1536
+    kv_lora_rank: int = 512
+    qk_nope_head_dim: int = 128
+    qk_rope_head_dim: int = 64
+    v_head_dim: int = 128
+    mla_scale_q_lora: bool = True
+    mla_scale_kv_lora: bool = True
+    #: the width of each of a latent layer's two dense feed-forwards
+    ffn_hidden_size: int = 12288
     # Gated DeltaNet
     linear_num_key_heads: int = 16
     linear_num_value_heads: int = 32
@@ -102,6 +130,12 @@ class DecoderConfig:
     #: None: no shared expert
     shared_expert_intermediate_size: int | None = 512
     norm_topk_prob: bool = True
+    #: router outputs behind the ``num_experts`` with weights that return
+    #: their input (``zero_expert_type`` "identity"): no product, no weights
+    zero_expert_num: int = 0
+    zero_expert_type: str = "identity"
+    #: what the chosen probabilities are multiplied by
+    routed_scaling_factor: float = 1.0
     #: the experts' activation: ``"silu"``, ``"relu"``
     hidden_act: str = "silu"
     #: what the router reads: the expert layer's own normed input
@@ -130,7 +164,20 @@ class DecoderConfig:
     def is_attention(self, layer: int) -> bool:
         return self.layer_kind(layer).mixer == "attention"
 
+    @property
+    def router_outputs(self) -> int:
+        """Experts with weights and identity experts together."""
+        return self.num_experts + self.zero_expert_num
+
     def layer_kind(self, layer: int) -> LayerKind:
+        if self.attention_method is not None:
+            if (self.attention_method, self.zero_expert_type) \
+                    != ("MLA", "identity"):
+                raise ValueError(
+                    f"attention_method {self.attention_method!r} with "
+                    f"zero_expert_type {self.zero_expert_type!r}: "
+                    f"\"MLA\" with \"identity\" is what runs")
+            return LayerKind("latent", None, True)
         if self.sliding_window_layout is not None:
             rotary = self.rope_layout is None or bool(self.rope_layout[layer])
             window = self.sliding_window_size \
@@ -142,10 +189,12 @@ class DecoderConfig:
 
     @property
     def attention_windows(self) -> tuple:
-        """Each attention layer's window, None for full attention (the
-        packer counts a dispatch's attention work from it)."""
+        """Each attention core's window, None for full attention (the
+        packer counts a dispatch's attention work from it): one a layer, a
+        latent layer's two sublayers one each."""
         kinds = map(self.layer_kind, range(self.num_hidden_layers))
-        return tuple(k.window for k in kinds if k.mixer == "attention")
+        return tuple(k.window for k in kinds if k.mixer != "deltanet"
+                     for _ in range(2 if k.mixer == "latent" else 1))
 
     @staticmethod
     def tiny(**kw) -> "DecoderConfig":
@@ -173,6 +222,23 @@ class DecoderConfig:
                     rope_layout=(0, 1, 1, 1), sliding_window_size=24,
                     shared_expert_intermediate_size=None, hidden_act="relu",
                     router_input="mixer_input")
+        base.update(kw)
+        return DecoderConfig.tiny(**base)
+
+    @staticmethod
+    def tiny_latent(**kw) -> "DecoderConfig":
+        """Small config of the LongCat-Flash pattern: two layers of two
+        latent attention sublayers (4 heads, keys 16 + 8, values 16), two
+        dense feed-forwards and 8 experts with weights beside 4 identity
+        experts, top-3, a correction bias, weights scaled and not
+        renormalised."""
+        base = dict(zero_centred_norm=False, rms_norm_eps=1e-5,
+                    num_hidden_layers=2, attention_method="MLA",
+                    q_lora_rank=24, kv_lora_rank=16, qk_nope_head_dim=16,
+                    qk_rope_head_dim=8, v_head_dim=16, ffn_hidden_size=96,
+                    num_experts_per_tok=3, zero_expert_num=4,
+                    routed_scaling_factor=6.0, norm_topk_prob=False,
+                    shared_expert_intermediate_size=None)
         base.update(kw)
         return DecoderConfig.tiny(**base)
 
@@ -207,7 +273,8 @@ def init_params(key, config: DecoderConfig, dtype=jnp.float32) -> dict:
     configuration's layers use: no gate's half of ``q_proj``, no q/k norm
     and no shared expert where the configuration has none."""
     c = config
-    keys = iter(jax.random.split(key, 16 * c.num_hidden_layers + 2))
+    per_layer = 32 if c.attention_method is not None else 16
+    keys = iter(jax.random.split(key, per_layer * c.num_hidden_layers + 2))
 
     def dense(*shape):
         return (jax.random.normal(next(keys), shape, jnp.float32)
@@ -221,8 +288,42 @@ def init_params(key, config: DecoderConfig, dtype=jnp.float32) -> dict:
         return (jnp.zeros if c.zero_centred_norm else jnp.ones)(
             (n,), jnp.float32)
 
+    def experts():
+        f, fs = c.moe_intermediate_size, c.shared_expert_intermediate_size
+        tree = {"router": dense(h, c.router_outputs),
+                "gate": dense(hi - lo, h, f), "up": dense(hi - lo, h, f),
+                "down": dense(hi - lo, f, h)}
+        if fs is not None:
+            tree.update(shared_gate=dense(h, fs), shared_up=dense(h, fs),
+                        shared_down=dense(fs, h), shared_router=dense(h, 1))
+        return tree
+
+    def latent():
+        nh, dn = c.num_attention_heads, c.qk_nope_head_dim
+        return {"q_a": dense(h, c.q_lora_rank), "q_norm": norm(c.q_lora_rank),
+                "q_b": dense(c.q_lora_rank, nh * (dn + c.qk_rope_head_dim)),
+                "kv_a": dense(h, c.kv_lora_rank + c.qk_rope_head_dim),
+                "kv_norm": norm(c.kv_lora_rank),
+                "kv_b": dense(c.kv_lora_rank, nh * (dn + c.v_head_dim)),
+                "o": dense(nh * c.v_head_dim, h)}
+
+    def ffn():
+        return {"gate": dense(h, c.ffn_hidden_size),
+                "up": dense(h, c.ffn_hidden_size),
+                "down": dense(c.ffn_hidden_size, h)}
+
     layers = []
     for i in range(c.num_hidden_layers):
+        if c.layer_kind(i).mixer == "latent":
+            moe_tree = experts()
+            # the correction bias: small beside a chosen probability
+            moe_tree["bias"] = 0.01 * jax.random.normal(
+                next(keys), (c.router_outputs,), jnp.float32)
+            layers.append({"norm_in": [norm(h), norm(h)],
+                           "norm_post": [norm(h), norm(h)],
+                           "mixer": [latent(), latent()],
+                           "ffn": [ffn(), ffn()], "moe": moe_tree})
+            continue
         if c.is_attention(i):
             mixer = {
                 "q_proj": dense(h, c.num_attention_heads * c.head_dim
@@ -242,16 +343,8 @@ def init_params(key, config: DecoderConfig, dtype=jnp.float32) -> dict:
                 "dt_bias": jnp.ones((nv,), jnp.float32),
                 "norm": jnp.ones((c.linear_value_head_dim,), jnp.float32),
                 "out_proj": dense(vd, h)}
-        f, fs = c.moe_intermediate_size, c.shared_expert_intermediate_size
-        experts = {"router": dense(h, c.num_experts),
-                   "gate": dense(hi - lo, h, f), "up": dense(hi - lo, h, f),
-                   "down": dense(hi - lo, f, h)}
-        if fs is not None:
-            experts.update(shared_gate=dense(h, fs), shared_up=dense(h, fs),
-                           shared_down=dense(fs, h),
-                           shared_router=dense(h, 1))
         layers.append({"norm1": norm(h), "norm2": norm(h), "mixer": mixer,
-                       "moe": experts})
+                       "moe": experts()})
     return {"embed": dense(c.vocab_size, h), "layers": layers,
             "final_norm": norm(h)}
 
@@ -319,6 +412,67 @@ def _rotary(x, pos, rotary_dim: int, theta: float):
         [x1 * cos - x2 * sin, x2 * cos + x1 * sin, rest], axis=-1)
 
 
+def _rotary_pairs(x, pos, theta: float):
+    """Rotate every pair of neighbouring features ``(x[2i], x[2i + 1])`` of
+    the last axis by the token's position (the interleaved convention of
+    the latent-attention families). x (B, T, ..., d); pos (B, T)."""
+    half = x.shape[-1] // 2
+    freq = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
+    angle = pos.astype(jnp.float32).reshape(
+        pos.shape + (1,) * (x.ndim - 2)) * freq
+    cos, sin = jnp.cos(angle), jnp.sin(angle)
+    even, odd = x[..., 0::2], x[..., 1::2]
+    return jnp.stack([even * cos - odd * sin, odd * cos + even * sin],
+                     axis=-1).reshape(x.shape)
+
+
+def latent_attention_layer(x, p, pos, seg, config: DecoderConfig):
+    """Multi-head latent attention in prefill, the unabsorbed form: queries
+    through a bottleneck (``q_a``, norm, ``q_b``), keys and values expanded
+    a head from one normed latent a token (``kv_a``, norm, ``kv_b``), a
+    head's key its own ``qk_nope_head_dim`` features beside one rotary key
+    of ``qk_rope_head_dim`` shared by all heads, values ``v_head_dim``
+    wide, scores scaled by ``(nope + rope) ** -0.5``. (The absorbed form
+    over the latent is a decode path and needs a cache this system does
+    not keep.) x (B, T, H) normed; seg (B, T): a token's document."""
+    c = config
+    b, t, h = x.shape
+    nh, dn, dr, dv = (c.num_attention_heads, c.qk_nope_head_dim,
+                      c.qk_rope_head_dim, c.v_head_dim)
+    cd = c.compute_dtype
+    with jax.named_scope("decoder.attention.latent"):
+        q = _rms_norm(_proj(x, p["q_a"], c), p["q_norm"], c.rms_norm_eps,
+                      c.zero_centred_norm)
+        q = _proj(q, p["q_b"], c).reshape(b, t, nh, dn + dr)
+        if c.mla_scale_q_lora:
+            q = q * (h / c.q_lora_rank) ** 0.5
+        kv = _proj(x, p["kv_a"], c)
+        latent = _rms_norm(kv[..., :c.kv_lora_rank], p["kv_norm"],
+                           c.rms_norm_eps, c.zero_centred_norm)
+        if c.mla_scale_kv_lora:
+            latent = latent * (h / c.kv_lora_rank) ** 0.5
+        # no arithmetic follows on a head's own keys and its values: they
+        # leave the product in the compute dtype (512 MB of float32 less
+        # at 8,192 tokens)
+        kv_heads = _proj(latent, p["kv_b"], c).astype(cd).reshape(
+            b, t, nh, dn + dv)
+        q_rope = _rotary_pairs(q[..., dn:], pos, c.rope_theta)
+        k_rope = _rotary_pairs(kv[..., c.kv_lora_rank:], pos, c.rope_theta)
+    with jax.named_scope("decoder.attention.full"):
+        o = attention.latent_attention(
+            q[..., :dn].astype(cd), q_rope.astype(cd), kv_heads[..., :dn],
+            k_rope.astype(cd), kv_heads[..., dn:], seg, pos,
+            scale=(dn + dr) ** -0.5)
+    return _proj(o.reshape(b, t, nh * dv), p["o"], c)
+
+
+def dense_ffn(x, p, config: DecoderConfig):
+    """A dense gated feed-forward, ``W_down(act(W_gate x) * (W_up x))``."""
+    hidden = moe.ACTIVATIONS[config.hidden_act](
+        _proj(x, p["gate"], config)) * _proj(x, p["up"], config)
+    return _proj(hidden, p["down"], config)
+
+
 def attention_layer(x, p, pos, seg, config: DecoderConfig,
                     kind: LayerKind = LayerKind("attention")):
     """Softmax attention, causal within a document, grouped heads; by the
@@ -358,23 +512,33 @@ def moe_layer(x, p, valid, config: DecoderConfig, router_x=None):
     """Routed experts (the held range's part) plus the shared expert, where
     the configuration has one. x (B, T, H) normed input; valid (B, T) False
     at padding; router_x (B, T, H): what the router reads (None: ``x``).
-    Returns (y (B, T, H) float32, tokens each held expert took, what this
-    execution adds to the pair buffer's counters: ``moe.buffer_use``)."""
+    Returns (y (B, T, H) float32, this execution's counters:
+    ``tokens_per_expert`` each held expert took, ``buffer`` what it adds to
+    the pair buffer's counters (``moe.buffer_use``) and, where the router
+    has identity experts, ``zero_pairs`` and ``pairs``: the chosen pairs of
+    real tokens that took one, and all of them)."""
     c = config
     b, t, h = x.shape
     flat = x.reshape(b * t, h).astype(c.compute_dtype)
     lo, hi = c.held
     lengths = moe.buffer_lengths(b * t * c.num_experts_per_tok,
-                                 (hi - lo) / c.num_experts)
+                                 (hi - lo) / c.router_outputs)
     with jax.named_scope("decoder.moe.route"):
         weights, experts = moe.route(
             flat if router_x is None else router_x.reshape(b * t, h),
-            p["router"], c.num_experts_per_tok, c.norm_topk_prob)
+            p["router"], c.num_experts_per_tok, c.norm_topk_prob,
+            p.get("bias"), c.routed_scaling_factor)
     with jax.named_scope("decoder.moe.experts"):
         y, load = moe.grouped_experts(
             flat, weights, experts, p["gate"], p["up"], p["down"], c.held,
             valid.reshape(b * t), lengths, c.hidden_act)
-        buffer = moe.buffer_use(load, lengths)
+        counters = {"tokens_per_expert": load,
+                    "buffer": moe.buffer_use(load, lengths)}
+        if c.zero_expert_num:
+            same, (counters["zero_pairs"], counters["pairs"]) = \
+                moe.identity_part(flat, weights, experts, c.num_experts,
+                                  valid.reshape(b * t))
+            y = y + same
     if c.shared_expert_intermediate_size is not None:
         with jax.named_scope("decoder.moe.shared"):
             hidden = moe.ACTIVATIONS[c.hidden_act](
@@ -382,39 +546,79 @@ def moe_layer(x, p, valid, config: DecoderConfig, router_x=None):
                 * _proj(flat, p["shared_up"], c)
             y = y + _proj(hidden, p["shared_down"], c) * jax.nn.sigmoid(
                 _proj(flat, p["shared_router"], c))
-    return y.reshape(b, t, h), load, buffer
+    return y.reshape(b, t, h), counters
+
+
+def shortcut_layer(x, p, pos, seg, valid, config: DecoderConfig):
+    """One layer of the LongCat-Flash pattern: two latent attention
+    sublayers, two dense feed-forwards, and one expert layer that reads the
+    first sublayer's normed state and joins the residual only at the
+    layer's end (shortcut-connected: nothing between depends on it, so the
+    compiler is free to run it beside the second sublayer)::
+
+        h = x + MLA_0(norm_in0(x));  a = norm_post0(h);  s = MoE(a)
+        h = h + FFN_0(a)
+        h = h + MLA_1(norm_in1(h))
+        y = h + FFN_1(norm_post1(h)) + s
+
+    Returns (y (B, T, H) float32, the expert layer's counters)."""
+    c = config
+    norm = lambda x, w: _rms_norm(x, w, c.rms_norm_eps, c.zero_centred_norm)
+    shortcut = counters = None
+    for i in range(2):
+        with jax.named_scope("decoder.attention"):
+            x = x + latent_attention_layer(norm(x, p["norm_in"][i]),
+                                           p["mixer"][i], pos, seg, c)
+        normed = norm(x, p["norm_post"][i])
+        if i == 0:
+            shortcut, counters = moe_layer(normed, p["moe"], valid, c)
+        with jax.named_scope("decoder.ffn"):
+            x = x + dense_ffn(normed, p["ffn"][i], c)
+    return x + shortcut, counters
 
 
 def _forward(params, token_ids, pos, seg, config: DecoderConfig):
     """Embedding + stack -> (final-norm hidden states (B, T, H) float32,
     the expert layers' counters summed over the layers:
     ``{"tokens_per_expert": (held,) int32, "buffer": (3,) float32
-    [executions, those at the full length, pair-buffer rows]}``, which an
-    embedder sums as its ``aux``)."""
+    [executions, those at the full length, pair-buffer rows]}`` and, for a
+    router with identity experts, ``"zero_pairs"`` and ``"pairs"`` (float32
+    scalars), which an embedder sums as its ``aux``)."""
     c = config
     norm = lambda x, w: _rms_norm(x, w, c.rms_norm_eps, c.zero_centred_norm)
     with jax.named_scope("decoder.embed"):
         x = params["embed"][token_ids].astype(jnp.float32)
     valid = seg >= 0
-    lo, hi = c.held
-    load = jnp.zeros((hi - lo,), jnp.int32)
-    buffer = jnp.zeros((3,), jnp.float32)
+    counters = None
     for i, layer in enumerate(params["layers"]):
-        normed, kind = norm(x, layer["norm1"]), c.layer_kind(i)
-        if kind.mixer == "attention":
-            with jax.named_scope("decoder.attention"):
-                x = x + attention_layer(normed, layer["mixer"], pos, seg, c,
-                                        kind)
+        kind = c.layer_kind(i)
+        if kind.mixer == "latent":
+            # a layer's weights wait for the layer's input. Left free, the
+            # TPU's compiler re-lays every layer's projection weights at
+            # the program's start and keeps all the copies: 4.0 GiB of
+            # temporaries for 1.9 at four layers of the published widths,
+            # more than the weights and an index leave of the chip
+            # (compiled for the described chip, PR 35). Inside a layer
+            # nothing is pinned
+            x, layer = jax.lax.optimization_barrier((x, layer))
+            x, used = shortcut_layer(x, layer, pos, seg, valid, c)
         else:
-            with jax.named_scope("decoder.deltanet"):
-                x = x + deltanet_layer(normed, layer["mixer"], pos, c,
-                                       valid)
-        y, took, used = moe_layer(
-            norm(x, layer["norm2"]), layer["moe"], valid, c,
-            normed if c.router_input == "mixer_input" else None)
-        x, load, buffer = x + y, load + took, buffer + used
-    return (norm(x, params["final_norm"]),
-            {"tokens_per_expert": load, "buffer": buffer})
+            normed = norm(x, layer["norm1"])
+            if kind.mixer == "attention":
+                with jax.named_scope("decoder.attention"):
+                    x = x + attention_layer(normed, layer["mixer"], pos,
+                                            seg, c, kind)
+            else:
+                with jax.named_scope("decoder.deltanet"):
+                    x = x + deltanet_layer(normed, layer["mixer"], pos, c,
+                                           valid)
+            y, used = moe_layer(
+                norm(x, layer["norm2"]), layer["moe"], valid, c,
+                normed if c.router_input == "mixer_input" else None)
+            x = x + y
+        counters = used if counters is None else jax.tree.map(
+            jnp.add, counters, used)
+    return norm(x, params["final_norm"]), counters
 
 
 def _pool(x, rows, at, config: DecoderConfig):

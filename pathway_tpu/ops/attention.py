@@ -122,7 +122,10 @@ def flash_attention(q, k, v, mask, *, interpret: bool = False):
 # One algorithm, two lowerings, chosen by what the function observes
 # (platform, head size, row length): on a TPU with heads of whole lanes it
 # is one Pallas kernel (:func:`_segment_kernel`), everywhere else the same
-# loop in plain ``jax.numpy`` (:func:`_blockwise`).
+# loop in plain ``jax.numpy`` (:func:`_blockwise`). Keys and values may
+# differ in width and the scale may be given: latent attention in prefill
+# (:func:`latent_attention`) is this core over keys of 128 + 64 features
+# and values of 128.
 
 #: the running max a row starts from, and a masked score: both large and
 #: finite, so that nothing makes a NaN, and the masked one the lower, so
@@ -213,25 +216,33 @@ def attention_lowerings() -> dict:
     return lowering_count.counts("attention", ("kernel", "blockwise"))
 
 
-def _kernel_tiles(q_shape: tuple, padded: int) -> bool:
-    """The shapes the kernel tiles: a head fills whole lanes of the chip's
-    vector registers and a row whole blocks of 128 slots."""
-    return q_shape[3] % 128 == 0 and padded % 128 == 0
+#: features a vector register of the chip holds side by side
+LANES = 128
 
 
-@functools.partial(jax.jit, static_argnames=("window",))
-def segment_attention(q, k, v, seg, pos, *, window: int | None = None):
+def _kernel_tiles(v_shape: tuple, padded: int) -> bool:
+    """The shapes the kernel tiles: a head's values fill whole lanes of the
+    chip's vector registers and a row whole blocks of 128 slots. (Keys of
+    another width than the values are padded with zeros to whole lanes on
+    the way in, which is exact.)"""
+    return v_shape[3] % LANES == 0 and padded % 128 == 0
+
+
+@functools.partial(jax.jit, static_argnames=("window", "scale"))
+def segment_attention(q, k, v, seg, pos, *, window: int | None = None,
+                      scale: float | None = None):
     """Causal softmax attention inside each document of packed rows, with
     grouped heads and an optional window.
 
-    q (B, T, nh, d); k, v (B, T, nkv, d), ``nh // nkv`` query heads a key
-    head, in the dtype the products run in (sums are float32); seg (B, T)
+    q (B, T, nh, d); k (B, T, nkv, d); v (B, T, nkv, dv), ``nh // nkv``
+    query heads a key head, in the dtype the products run in (sums are
+    float32); seg (B, T)
     int32 a slot's document, -1 padding; pos (B, T) int32 its position in
     its document (a document's tokens lie in consecutive slots, in
     order). ``visible(t, s) = seg[t] == seg[s] >= 0 and s <= t and
     (window is None or pos[t] - pos[s] < window)``; scores are scaled by
-    ``d ** -0.5``. Returns (B, T, nh, d) float32, defined at the real
-    slots (padding reads zeros).
+    ``scale`` (None: ``d ** -0.5``). Returns (B, T, nh, dv) float32, defined
+    at the real slots (padding reads zeros).
 
     One algorithm, two lowerings: lowered for a TPU at the shapes of
     :func:`_kernel_tiles` it is :func:`_segment_kernel`, on every other
@@ -248,17 +259,22 @@ def segment_attention(q, k, v, seg, pos, *, window: int | None = None):
         pos = jnp.pad(pos, grow)
     seg, pos = seg.astype(jnp.int32), pos.astype(jnp.int32)
     lo, count = _block_ranges(jnp, seg, pos, window, bq, bk)
-    sizes = dict(window=window, bq=bq, bk=bk)
+    # the scale is that of the keys as given: the kernel may widen them
+    sizes = dict(window=window, bq=bq, bk=bk,
+                 scale=d ** -0.5 if scale is None else scale)
 
     def blockwise(q, k, v, seg, pos, lo, count):
         return lowering_count.took(
             _blockwise(q, k, v, seg, pos, lo, count, **sizes),
             "attention", "blockwise")
 
-    if not _kernel_tiles(q.shape, padded):
+    if not _kernel_tiles(v.shape, padded):
         out = blockwise(q, k, v, seg, pos, lo, count)
     else:
         def kernel(q, k, v, seg, pos, lo, count):
+            if d % LANES:
+                grow = ((0, 0),) * 3 + ((0, -d % LANES),)
+                q, k = jnp.pad(q, grow), jnp.pad(k, grow)
             return lowering_count.took(
                 _segment_kernel(q, k, v, seg, pos, lo, count, **sizes),
                 "attention", "kernel")
@@ -266,6 +282,30 @@ def segment_attention(q, k, v, seg, pos, *, window: int | None = None):
         out = jax.lax.platform_dependent(q, k, v, seg, pos, lo, count,
                                          tpu=kernel, default=blockwise)
     return out[:, :t]
+
+
+def latent_attention(q_nope, q_rope, k_nope, k_rope, v, seg, pos, *,
+                     scale: float):
+    """The core of multi-head latent attention in prefill (the unabsorbed
+    form: keys and values expanded a head), causal inside each document of
+    packed rows: ``score[t, s, head] = (q_nope[t, head] . k_nope[s, head] +
+    q_rope[t, head] . k_rope[s]) * scale``.
+
+    q_nope, k_nope (B, T, nh, dn): the parts without positions; q_rope
+    (B, T, nh, dr) and k_rope (B, T, dr), rotated by the caller: **one
+    rotary key for all heads**; v (B, T, nh, dv), of another width than the
+    keys; ``scale`` given (the model's, over dn + dr). Returns (B, T, nh,
+    dv) float32. One head's keys are its own part beside the shared one,
+    dn + dr wide, and the core is :func:`segment_attention`'s with no
+    grouping: its kernel on the chip where the values fill whole lanes (the
+    keys' 192 features padded with zeros to 256), the blockwise loop
+    elsewhere."""
+    b, t, nh, _ = q_nope.shape
+    shared = jnp.broadcast_to(k_rope[:, :, None, :],
+                              (b, t, nh, k_rope.shape[-1]))
+    return segment_attention(
+        jnp.concatenate([q_nope, q_rope], axis=-1),
+        jnp.concatenate([k_nope, shared], axis=-1), v, seg, pos, scale=scale)
 
 
 def _visible(seg_q, pos_q, slot_q, seg_k, pos_k, slot_k, window):
@@ -277,17 +317,18 @@ def _visible(seg_q, pos_q, slot_q, seg_k, pos_k, slot_k, window):
     return see
 
 
-def _blockwise(q, k, v, seg, pos, lo, count, *, window, bq: int, bk: int):
+def _blockwise(q, k, v, seg, pos, lo, count, *, window, bq: int, bk: int,
+               scale: float | None = None):
     """:func:`segment_attention` in plain JAX: a ``lax.map`` over the
     query blocks, inside it a loop over the key blocks that block can see
     (of any row: the rows of a dispatch walk the union of their ranges),
     the online softmax of ``parallel/ring_attention.py``. The largest
     array is one block's scores, (B, nh, bq, bk)."""
     b, t, nh, d = q.shape
-    nkv = k.shape[2]
+    nkv, dv = k.shape[2], v.shape[3]
     nq, nk = t // bq, t // bk
     q = q.reshape(b, nq, bq, nkv, nh // nkv, d)
-    scale = d ** -0.5
+    scale = d ** -0.5 if scale is None else scale
     first = jnp.min(jnp.where(count > 0, lo, nk), axis=0)         # (nq,)
     steps = jnp.maximum(
         jnp.max(jnp.where(count > 0, lo + count, 0), axis=0) - first, 0)
@@ -322,26 +363,27 @@ def _blockwise(q, k, v, seg, pos, lo, count, *, window, bq: int, bk: int):
         _m, l, acc = jax.lax.fori_loop(
             0, steps[i], key_block,
             (jnp.full(stats, _UNSEEN, f32), jnp.zeros(stats, f32),
-             jnp.zeros(stats + (d,), f32)))
+             jnp.zeros(stats + (dv,), f32)))
         return acc * jnp.where(l > 0, 1.0 / l, 0.0)[..., None]
 
-    out = jax.lax.map(query_block, jnp.arange(nq))      # (nq,B,g,r,bq,d)
-    return jnp.transpose(out, (1, 0, 4, 2, 3, 5)).reshape(b, t, nh, d)
+    out = jax.lax.map(query_block, jnp.arange(nq))      # (nq,B,g,r,bq,dv)
+    return jnp.transpose(out, (1, 0, 4, 2, 3, 5)).reshape(b, t, nh, dv)
 
 
 def _segment_body(lo_ref, count_ref, qdoc_ref, kdoc_ref, q_ref, k_ref,
                   v_ref, qcols_ref, krows_ref, o_ref, qs_ref, m_ref, l_ref,
-                  acc_ref, *, rep: int, d: int, bq: int, bk: int, nk: int,
-                  window):
+                  acc_ref, *, rep: int, d: int, dv: int, bq: int, bk: int,
+                  nk: int, window, scale: float):
     """One key block of one query block of one key head's ``rep`` query
-    heads. Blocks: q, o (bq, rep * d); k, v (bk, d); qcols (bq, 128): the
+    heads. Blocks: q (bq, rep * d), o (bq, rep * dv); k (bk, d), v (bk,
+    dv); qcols (bq, 128): the
     queries' document in lane 0 and position in lane 1; krows (8, bk): the
     keys' in sublanes 0 and 1. Scalar memory: the query block's first key
     block and its count, and for every query block and key block the
     document all its slots belong to (or a negative number where they do
     not share one). Vector memory, for the length of a query block: the
     heads' queries stacked as rows (rep * bq, d), the running max and sum
-    (rep * bq, 1) and the accumulator (rep * bq, d), float32."""
+    (rep * bq, 1) and the accumulator (rep * bq, dv), float32."""
     from jax.experimental import pallas as pl
 
     row, qi, j = pl.program_id(0), pl.program_id(2), pl.program_id(3)
@@ -363,7 +405,7 @@ def _segment_body(lo_ref, count_ref, qdoc_ref, kdoc_ref, q_ref, k_ref,
     def accumulate(see):
         s = jax.lax.dot_general(
             qs_ref[...], k_ref[...], (((1,), (1,)), ((), ())),
-            preferred_element_type=f32) * (d ** -0.5)          # (R, bk)
+            preferred_element_type=f32) * scale                # (R, bk)
         if see is not None:
             # one mask for the block, added to every head's rows
             hide = jax.lax.select(see, jnp.zeros((bq, bk), f32),
@@ -408,11 +450,12 @@ def _segment_body(lo_ref, count_ref, qdoc_ref, kdoc_ref, q_ref, k_ref,
         l = l_ref[...]
         out = acc_ref[...] * jnp.where(l > 0, 1.0 / l, 0.0)
         for r in range(rep):
-            o_ref[:, r * d:(r + 1) * d] = out[r * bq:(r + 1) * bq]
+            o_ref[:, r * dv:(r + 1) * dv] = out[r * bq:(r + 1) * bq]
 
 
 def _segment_kernel(q, k, v, seg, pos, lo, count, *, window, bq: int,
-                    bk: int, interpret: bool = False):
+                    bk: int, scale: float | None = None,
+                    interpret: bool = False):
     """:func:`segment_attention` as one Pallas kernel. Grid (row, key
     head, query block, key-block step), the steps innermost: step ``j`` of
     a query block reads key block ``lo + j`` while ``j < count`` and stays
@@ -428,7 +471,7 @@ def _segment_kernel(q, k, v, seg, pos, lo, count, *, window, bq: int,
     from jax.experimental.pallas import tpu as pltpu
 
     b, t, nh, d = q.shape
-    nkv = k.shape[2]
+    nkv, dv = k.shape[2], v.shape[3]
     rep = nh // nkv
     nq, nk = t // bq, t // bk
     steps = _max_steps(t, window, bq, bk)
@@ -455,26 +498,27 @@ def _segment_kernel(q, k, v, seg, pos, lo, count, *, window, bq: int,
     keys = lambda r, g, i, j, *s: (r, key_block(r, g, i, j, *s), g)
     rows = rep * bq
     out = pl.pallas_call(
-        functools.partial(_segment_body, rep=rep, d=d, bq=bq, bk=bk,
-                          nk=nk, window=window),
+        functools.partial(_segment_body, rep=rep, d=d, dv=dv, bq=bq, bk=bk,
+                          nk=nk, window=window,
+                          scale=d ** -0.5 if scale is None else scale),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=4,
             grid=(b, nkv, nq, steps),
             in_specs=[
                 pl.BlockSpec((None, bq, rep * d), queries),
                 pl.BlockSpec((None, bk, d), keys),
-                pl.BlockSpec((None, bk, d), keys),
+                pl.BlockSpec((None, bk, dv), keys),
                 pl.BlockSpec((None, bq, 128),
                              lambda r, g, i, j, *_: (r, i, 0)),
                 pl.BlockSpec((None, 8, bk), lambda r, g, i, j, *s: (
                     r, 0, key_block(r, g, i, j, *s))),
             ],
-            out_specs=pl.BlockSpec((None, bq, rep * d), queries),
+            out_specs=pl.BlockSpec((None, bq, rep * dv), queries),
             scratch_shapes=[pltpu.VMEM((rows, d), q.dtype),
                             pltpu.VMEM((rows, 1), jnp.float32),
                             pltpu.VMEM((rows, 1), jnp.float32),
-                            pltpu.VMEM((rows, d), jnp.float32)]),
-        out_shape=jax.ShapeDtypeStruct((b, t, nh * d), jnp.float32),
+                            pltpu.VMEM((rows, dv), jnp.float32)]),
+        out_shape=jax.ShapeDtypeStruct((b, t, nh * dv), jnp.float32),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary"),
@@ -482,5 +526,5 @@ def _segment_kernel(q, k, v, seg, pos, lo, count, *, window, bq: int,
         interpret=interpret,
     )(lo.reshape(-1), count.reshape(-1), one_document(seg, bq, -2),
       one_document(seg, bk, -3), q.reshape(b, t, nh * d),
-      k.reshape(b, t, nkv * d), v.reshape(b, t, nkv * d), qcols, krows)
-    return out.reshape(b, t, nh, d)
+      k.reshape(b, t, nkv * d), v.reshape(b, t, nkv * dv), qcols, krows)
+    return out.reshape(b, t, nh, dv)

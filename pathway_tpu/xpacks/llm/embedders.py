@@ -60,20 +60,26 @@ def expert_load_stats() -> dict | None:
     experts: {"max", "mean" tokens an expert, "dispatches",
     "full_buffer_layers": expert-layer executions whose pair buffer took
     its full length, "buffer_rows_mean": the buffer's rows an execution
-    (ops/moe.py)} over all of them; None where there is none. Fetches from
-    the device."""
+    (ops/moe.py), and where a router has identity experts "zero_pairs",
+    "pairs": the chosen pairs of real tokens that took one, and all of
+    them} over all of them; None where there is none. Fetches from the
+    device."""
     loads = [ld for e in list(_AUX_EMBEDDERS)
              if (ld := e.expert_load()) is not None]
     if not loads:
         return None
     tokens = np.concatenate([ld["tokens_per_expert"] for ld in loads])
     layers = sum(ld["expert_layers"] for ld in loads)
-    return {"max": float(tokens.max()), "mean": float(tokens.mean()),
-            "dispatches": sum(ld["dispatches"] for ld in loads),
-            "full_buffer_layers": sum(ld["full_buffer_layers"]
-                                      for ld in loads),
-            "buffer_rows_mean": sum(ld["buffer_rows"] for ld in loads)
-            / layers if layers else 0.0}
+    stats = {"max": float(tokens.max()), "mean": float(tokens.mean()),
+             "dispatches": sum(ld["dispatches"] for ld in loads),
+             "full_buffer_layers": sum(ld["full_buffer_layers"]
+                                       for ld in loads),
+             "buffer_rows_mean": sum(ld["buffer_rows"] for ld in loads)
+             / layers if layers else 0.0}
+    if any("pairs" in ld for ld in loads):
+        stats.update({name: sum(ld.get(name, 0.0) for ld in loads)
+                      for name in ("zero_pairs", "pairs")})
+    return stats
 
 
 # live embedders whose model has attention layers the packer counts the
@@ -109,8 +115,9 @@ class JaxEncoderEmbedder(BaseEmbedder):
     models/decoder.py ``DecoderConfig``). A forward may return ``(embeddings,
     aux)``: ``aux`` is a decoder's expert-layer counters, a dict of small
     device arrays (``tokens_per_expert``; ``buffer``: executions, those at
-    the full pair-buffer length, buffer rows), summed on the device and
-    fetched only by :meth:`expert_load`.
+    the full pair-buffer length, buffer rows; with identity experts also
+    ``zero_pairs``, ``pairs``), summed on the device and fetched only by
+    :meth:`expert_load`.
 
     ``ragged_max_seqs``: packed rows a ragged dispatch holds at most
     (default ``PATHWAY_RAGGED_MAX_SEQS``, else 8).
@@ -289,17 +296,22 @@ class JaxEncoderEmbedder(BaseEmbedder):
         """Tokens each held expert took, summed over every dispatch so
         far and every layer, and the pair buffer's counters (ops/moe.py:
         expert-layer executions, those that took the full length, the
-        buffer's rows summed over them), fetched from the device now (the
-        one transfer: call it from a metrics request, not from a tick).
-        None where the model routes nothing."""
+        buffer's rows summed over them) and, where the router has identity
+        experts, ``zero_pairs`` and ``pairs`` (the chosen pairs of real
+        tokens that took one, and all of them), fetched from the device
+        now (the one transfer: call it from a metrics request, not from a
+        tick). None where the model routes nothing."""
         with self._aux_lock:
             total, dispatches = self._aux_sum, self._aux_dispatches
         if total is None:
             return None
         layers, full, rows = np.asarray(total["buffer"]).tolist()
-        return {"tokens_per_expert": np.asarray(total["tokens_per_expert"]),
+        load = {"tokens_per_expert": np.asarray(total["tokens_per_expert"]),
                 "dispatches": dispatches, "expert_layers": int(layers),
                 "full_buffer_layers": int(full), "buffer_rows": rows}
+        load.update({name: float(total[name])
+                     for name in ("zero_pairs", "pairs") if name in total})
+        return load
 
     def dispatch_work(self, args: tuple) -> dict:
         """What the attention layers have to do in the ragged dispatch of

@@ -145,7 +145,8 @@ def test_the_bound_stands_from_the_first_submit_that_waits():
 def test_a_leg_that_compiled_or_served_queries_is_no_reading():
     """The padded encoder path compiles nearly every tick: its legs are
     long whatever they hold, and a bound would shrink ticks without
-    shortening them."""
+    shortening them. Until a leg has given a reading a drain stays held to
+    ``FIRST_LEG_ROWS``, as before the first leg retired."""
     limiter = qos.DeviceBackpressure(0.05)
     compile_s = [0.0]
     limiter._compile_s = lambda: compile_s[0]
@@ -155,17 +156,52 @@ def test_a_leg_that_compiled_or_served_queries_is_no_reading():
     # compiler, not for the device
     compile_s[0] += 0.55
     assert _look(limiter, 3, 50, watermark=1, exec_ms=600.0,
-                 blocked=1) is None
+                 blocked=1) == qos.FIRST_LEG_ROWS
     assert limiter._cost.ms_per_row is None
     # a leg that served queries beside its rows is no cost sample either,
     # and with no sample a submit that waited bounds nothing
     assert _look(limiter, 4, 50, watermark=2, exec_ms=900.0,
-                 blocked=2) is None
+                 blocked=2) == qos.FIRST_LEG_ROWS
     # 20 of this leg's 120 ms were a small program's compile: taken out,
     # 50 rows took 100 ms
     compile_s[0] += 0.02
     assert _look(limiter, 5, 50, watermark=3, exec_ms=1020.0,
                  blocked=3) == int(qos.LEG_TICKS * 50.0 / 2.0)
+
+
+def test_a_first_leg_that_compiled_does_not_lift_the_hold():
+    """A cold cache: the first ingest leg of one long document spends more
+    of its time in the compiler than on the device and is no reading. With
+    the hold lifted behind it the next drain took the whole backlog: one
+    leg of 3,000 sections at 0.4 s each, and ``/v1/statistics`` timed out
+    after 120 s (my chip run, PR 35: the run failed). The hold
+    stands until the leg behind it, which compiles nothing, is read; a path
+    whose every leg compiles is let go after ``UNREAD_LEGS`` of them."""
+    limiter = qos.DeviceBackpressure(0.05)
+    compile_s = [0.0]
+    limiter._compile_s = lambda: compile_s[0]
+    rows = qos.FIRST_LEG_ROWS
+    assert _look(limiter, 1, 1, watermark=0, exec_ms=0.0, blocked=0,
+                 depth=1) == rows
+    assert _look(limiter, 2, rows, watermark=0, exec_ms=0.0, blocked=0,
+                 depth=2) == rows
+    # the first leg: one document, 430 ms on the device behind 740 ms of
+    # compiles
+    compile_s[0] += 0.74
+    assert _look(limiter, 3, rows, watermark=1, exec_ms=1170.0, blocked=1,
+                 depth=2) == rows
+    # the second: eight documents in 3,440 ms, 430 ms a row: one row a leg
+    assert _look(limiter, 4, rows, watermark=2, exec_ms=4610.0, blocked=2,
+                 depth=2) == 1
+    # every leg compiles: no reading ever, and no hold for good
+    padded = qos.DeviceBackpressure(0.05)
+    padded._compile_s = lambda: compile_s[0]
+    for tick in range(1, qos.UNREAD_LEGS + 2):
+        compile_s[0] += 0.55
+        budget = _look(padded, tick, rows, watermark=tick - 1,
+                       exec_ms=600.0 * (tick - 1), blocked=tick - 1)
+        assert budget == (rows if tick <= qos.UNREAD_LEGS else None), tick
+    assert padded._cost.ms_per_row is None
 
 
 @pytest.mark.parametrize("leg_ms, first_bound", [

@@ -41,18 +41,33 @@ def _tiny_windowed():
                                        max_len=512, sliding_window_size=100)
 
 
+def _tiny_latent():
+    import jax.numpy as jnp
+
+    from pathway_tpu.models.decoder import DecoderConfig
+
+    return DecoderConfig.tiny_latent(compute_dtype=jnp.float32, max_len=512)
+
+
 def test_smoke_function_passes_on_cpu_at_tiny_size(tmp_path):
     out = tmp_path / "topk.json"
     summary = chip_smoke.run_smoke(
         expected_platform="cpu", config=_tiny, n_docs=48, max_words=40,
         max_len=48, scan_rows=2048, request_timeout_s=60,
-        out_path=str(out), decoder_config=_tiny_windowed, decoder_row=512)
+        out_path=str(out), decoder_config=_tiny_windowed, decoder_row=512,
+        latent_config=_tiny_latent, latent_row=512)
     # one forward of the windowed decoder on a row of 512 slots: a document
     # of 320 tokens, window 100; heads of 32 features take the blockwise loop
     windowed = summary["windowed_decoder"]
     assert windowed["alone_vs_packed_cos"] > 0.9999
     assert windowed["lowerings"]["kernel"] == 0 \
         and windowed["lowerings"]["blockwise"] >= 4
+    # and one of the latent decoder: two layers of two latent attention
+    # sublayers; values of 16 features take the blockwise loop
+    latent = summary["latent_decoder"]
+    assert latent["alone_vs_packed_cos"] > 0.9999
+    assert latent["lowerings"]["kernel"] == 0 \
+        and latent["lowerings"]["blockwise"] >= 1
     assert summary["device"]["platform"] == "cpu"
     assert summary["n_docs"] == 48 + 3
     assert summary["bridge_legs_resolved"] > 0

@@ -179,7 +179,9 @@ def test_the_shares_of_an_expert_layer_sum_to_the_whole(weights):
                     down=p["down"][lo:hi])
         config = decoder.DecoderConfig.tiny(compute_dtype=jnp.float32,
                                             experts_held=(lo, hi))
-        return decoder.moe_layer(x, part, valid, config)
+        y, counters = decoder.moe_layer(x, part, valid, config)
+        assert set(counters) == {"tokens_per_expert", "buffer"}
+        return y, counters["tokens_per_expert"], counters["buffer"]
 
     whole, load, used = layer(0, 8)
     (low, load_low, _), (high, load_high, _) = layer(0, 4), layer(4, 8)
