@@ -53,6 +53,30 @@ from typing import Callable
 from pathway_tpu.engine.profiler import current_profiler
 from pathway_tpu.testing import faults
 
+# the running leg's [dispatches, token slots, real tokens, documents] of the
+# fixed-shape ingest dispatches it made: the bridge worker's thread alone
+# has one
+_LEG = threading.local()
+
+
+def note_ingest_dispatch(slots: int, tokens: int, docs: int) -> None:
+    """Hook for the index's ingest (ops/knn.py ``add_batch``): one
+    fixed-shape dispatch of ``slots`` token slots went to the device,
+    ``tokens`` of them real (the rest padding), of ``docs`` documents. The
+    leg that runs on this thread counts it and the bridge sums it when the
+    leg retires (:meth:`DeviceBridge.stats`), which is how the ingest
+    budget learns the documents that fill a dispatch (engine/qos.py
+    ``DeviceBackpressure``). Ingest alone reports: a query's text goes
+    through the same embedder, and ten tokens are no document. No-op off a
+    bridge leg (one thread-local read)."""
+    counts = getattr(_LEG, "counts", None)
+    if counts is not None:
+        counts[0] += 1
+        counts[1] += slots
+        counts[2] += tokens
+        counts[3] += docs
+
+
 def device_inflight_from_env() -> int:
     """The configured in-flight window (>=1); 1 means synchronous."""
     raw = os.environ.get("PATHWAY_DEVICE_INFLIGHT", "2")
@@ -103,6 +127,10 @@ class DeviceBridge:
         self.legs_overlapped = 0
         self.queue_wait_ms = 0.0  # submit -> start, summed
         self.exec_ms = 0.0        # start -> finish, summed
+        # what the resolved legs reported through ``note_ingest_dispatch``,
+        # summed with ``exec_ms`` so that both are of the same legs
+        # [dispatches, token slots, real tokens, documents]
+        self._ingest = [0, 0, 0, 0]
         self.max_depth = 0
 
     # ------------------------------------------------------------------
@@ -240,6 +268,10 @@ class DeviceBridge:
                                   if resolved else 0.0),
                 "queue_wait_ms": round(self.queue_wait_ms, 3),
                 "exec_ms": round(self.exec_ms, 3),
+                "ingest_dispatches": self._ingest[0],
+                "ingest_slots": self._ingest[1],
+                "ingest_tokens": self._ingest[2],
+                "ingest_docs": self._ingest[3],
                 "max_depth": self.max_depth,
             }
 
@@ -276,6 +308,7 @@ class DeviceBridge:
             prof = current_profiler()
             if prof is not None:
                 prof.begin_leg(tick)
+            counts = _LEG.counts = [0, 0, 0, 0]
             started = _time.perf_counter()
             try:
                 # fault points at the new watermark boundaries
@@ -322,6 +355,7 @@ class DeviceBridge:
             with self._cv:
                 self.queue_wait_ms += (started - submitted_at) * 1e3
                 self.exec_ms += (finished - started) * 1e3
+                self._ingest = [a + b for a, b in zip(self._ingest, counts)]
                 self.legs_resolved += 1
                 if not waited_at_start and self._waiters == 0:
                     self.legs_overlapped += 1

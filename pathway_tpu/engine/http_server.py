@@ -529,6 +529,24 @@ class MonitoringHttpServer:
             lines.append("# TYPE pathway_tpu_device_exec_ms_total counter")
             lines.append(
                 f"pathway_tpu_device_exec_ms_total {bridge['exec_ms']}")
+        limiter = getattr(self.runtime, "_backpressure", None)
+        if limiter is not None:
+            # the ingest budget of a runtime with no QoS armed
+            # (engine/qos.py DeviceBackpressure): the rows a drain is held
+            # to while the device is the slower side (0 while no bound
+            # stands), the documents that fill one dispatch, under which a
+            # bound never stands, and the looks at which that floor and
+            # not milliseconds a row set the bound
+            lines.append("# TYPE pathway_tpu_ingest_bound_rows gauge")
+            lines.append(f"pathway_tpu_ingest_bound_rows "
+                         f"{limiter.ingest_row_budget() or 0}")
+            lines.append("# TYPE pathway_tpu_ingest_bound_floor_rows gauge")
+            lines.append(f"pathway_tpu_ingest_bound_floor_rows "
+                         f"{limiter.rows_per_dispatch()}")
+            lines.append(
+                "# TYPE pathway_tpu_ingest_bound_floored_total counter")
+            lines.append(f"pathway_tpu_ingest_bound_floored_total "
+                         f"{limiter.floored_looks}")
         woken = getattr(self.runtime, "ticks_woken_by", None)
         if woken is not None:
             # commit ticks by what ended the loop's wait

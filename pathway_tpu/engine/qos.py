@@ -220,10 +220,26 @@ class IngestCost:
     """Device milliseconds one ingest row costs, smoothed over the legs
     that retired: what turns a stretch of device time into a row
     allowance. Both ingest budgets reckon with it: the controller's fixed
-    query reservation and :class:`DeviceBackpressure`'s leg length."""
+    query reservation and :class:`DeviceBackpressure`'s leg length.
+
+    The cost of a row is linear; the device's is a step, in dispatches of
+    a fixed shape that take as long half empty as full. So it also keeps
+    what a dispatch holds, where the ingest path reports it
+    (``device_bridge.note_ingest_dispatch``): the token slots of a dispatch
+    and the real tokens a document brought, and from them the documents
+    that fill one dispatch (:meth:`rows_per_dispatch`), under which
+    :class:`DeviceBackpressure` never holds a drain."""
+
+    #: documents whose lengths make up the mean: a leg of one document is
+    #: one reading of a length that differs tenfold, a leg of eighty is
+    #: eighty
+    RECENT_ROWS = 30
 
     def __init__(self):
         self.ms_per_row: float | None = None
+        self.slots_per_dispatch: float | None = None
+        self.tokens_per_row: float | None = None
+        self._rows_counted = 0
 
     def sample(self, rows: int, device_ms: float) -> None:
         cost = device_ms / rows
@@ -236,6 +252,26 @@ class IngestCost:
             return None
         return int(ms / self.ms_per_row)
 
+    def note_dispatches(self, dispatches: int, slots: int, tokens: int,
+                        rows: int) -> None:
+        """What retired ingest legs reported of their dispatches: how
+        many, their token slots, and the real tokens of the ``rows``
+        documents in them."""
+        if dispatches <= 0 or rows <= 0 or tokens <= 0:
+            return
+        self.slots_per_dispatch = slots / dispatches
+        kept = self._rows_counted
+        self.tokens_per_row = (kept * (self.tokens_per_row or 0.0)
+                               + tokens) / (kept + rows)
+        self._rows_counted = min(kept + rows, self.RECENT_ROWS)
+
+    def rows_per_dispatch(self) -> int:
+        """Documents that fill one dispatch, by the mean of the recent
+        ones' lengths; 1 behind a path that reported nothing."""
+        if not self.tokens_per_row:
+            return 1
+        return max(1, int(self.slots_per_dispatch / self.tokens_per_row))
+
 
 #: commit intervals a device leg lasts while :class:`DeviceBackpressure`
 #: bounds the drain: long enough that the partial dispatch a tick ends in
@@ -243,8 +279,10 @@ class IngestCost:
 #: any reader of progress) keep falling several times a second. Swept on
 #: the chip behind a decoder embedder of 70 ms a dispatch, 50 ms ticks
 #: (PERF.md, PR 28): 1, 2, 4 intervals ingest 126-127 docs/s, 8 136, 16 139
-#: with a leg's p95 at 0.94 s; under a dispatch a leg the cost of a row
-#: is all fixed cost and the bound falls to one row
+#: with a leg's p95 at 0.94 s. Where one dispatch is a leg or longer the
+#: cost of a row is all fixed cost and the linear reading falls under one:
+#: the bound then stands at the documents that fill one dispatch
+#: (``IngestCost.rows_per_dispatch``), and a leg is a dispatch or two
 LEG_TICKS = 8
 #: rows a drain takes at most before the device has retired its first
 #: ingest leg: nothing is known of its pace yet, and the window lets two
@@ -263,6 +301,12 @@ EARLY_READINGS = 4
 #: gives no reading ever and is not to be held for good; a first leg that
 #: compiled on a cold cache is followed by one that does not
 UNREAD_LEGS = 4
+
+
+#: ``DeviceBridge.stats()``'s sums of ``note_ingest_dispatch``'s reports, in
+#: the order of ``IngestCost.note_dispatches``
+_REPORTED = ("ingest_dispatches", "ingest_slots", "ingest_tokens",
+             "ingest_docs")
 
 
 def _half_more(rows: int) -> int:
@@ -294,6 +338,20 @@ class DeviceBackpressure:
     is never held back, and what a bound held back does not arrive as one
     tick.
 
+    A bound in force is never under the documents that fill one dispatch
+    (``IngestCost.rows_per_dispatch``, from what the ingest path reported
+    of the retired legs' dispatches; 1 where nothing reports). Behind an
+    embedder whose one dispatch of a row of 8,192 slots takes a whole leg,
+    whether it holds one section or two, milliseconds a row read a
+    dispatch a section, ``rows_in`` a leg came to 0 and every tick carried
+    one section in a row three fifths empty (ledger, PR 35). Held to that
+    floor a leg is one dispatch, or two where its documents did not share
+    a row, however long a dispatch is. The floor lifts a bound by half a
+    look at most, as a reading does: the mean of a first leg's one or two
+    documents says little of the next ones' lengths. Where the linear
+    reading is above the floor, which is wherever a leg holds several
+    dispatches, the floor changes nothing.
+
     The time a leg spent in XLA's compiler is taken out of its cost, and
     a submit that waited for legs which spent most of their time there
     counts as one that did not wait: a compile says nothing of the pace
@@ -319,6 +377,12 @@ class DeviceBackpressure:
         self._unretired: collections.deque = collections.deque()
         self._exec_ms_seen = 0.0
         self._blocked_seen = 0
+        # the bridge's sums of what the ingest path reported of its
+        # dispatches (how many, slots, real tokens, documents), as last
+        # seen
+        self._reported_seen = (0, 0, 0, 0)
+        # looks at which the floor, not the linear reading, set the bound
+        self.floored_looks = 0
         # the bridge worker's compiles: the legs' own
         self._compile_s = install_compile_clock("device-bridge")
         self._compile_s_seen = self._compile_s()
@@ -328,6 +392,10 @@ class DeviceBackpressure:
         if self._rows is None and not self._paced:
             return FIRST_LEG_ROWS
         return self._rows
+
+    def rows_per_dispatch(self) -> int:
+        """The floor of a bound: one dispatch's documents, as reckoned."""
+        return self._cost.rows_per_dispatch()
 
     def note_deferral(self, n_rows: int) -> None:
         """Nothing to count: the drain's ``deferred`` flag comes back
@@ -355,6 +423,11 @@ class DeviceBackpressure:
         self._exec_ms_seen += exec_ms
         self._blocked_seen = bridge["submits_blocked"]
         self._compile_s_seen += compile_ms / 1e3
+        reported = tuple(bridge.get(k, 0) for k in _REPORTED)
+        self._cost.note_dispatches(
+            *(now - seen for now, seen in zip(reported,
+                                              self._reported_seen)))
+        self._reported_seen = reported
         first = self._cost.ms_per_row is None
         if retired and clean and steady and exec_ms > compile_ms:
             # the first readings are of a few rows each, and rows differ
@@ -375,6 +448,7 @@ class DeviceBackpressure:
         self._paced = self._paced or self._readings > 0 \
             or self._legs_retired >= UNREAD_LEGS
         rows = self._cost.rows_in(LEG_TICKS * self.tick_interval_ms)
+        held = self._rows
         if waited:
             if rows is not None:
                 # down at once, up by half at most: a reading can flatter
@@ -407,6 +481,16 @@ class DeviceBackpressure:
                 grown = _half_more(self._rows)
                 self._rows = grown if rows is None \
                     else max(self._rows, min(grown, rows))
+        # a dispatch takes as long half empty as full: whatever milliseconds
+        # a row say, a bound is one dispatch's documents at the least. Up
+        # by half a look at most here too: the mean of a few documents'
+        # lengths can flatter as a reading can (one document of 50 tokens
+        # in a row of 16,384 slots), and each longer leg brings more
+        floor = self._cost.rows_per_dispatch()
+        if self._rows is not None and self._rows < floor:
+            self._rows = min(floor, max(self._rows,
+                                        _half_more(held or self._rows)))
+            self.floored_looks += 1
 
 
 class QosController:

@@ -286,8 +286,10 @@ class StreamingRuntime:
         self._backpressure = None
         # while recording, what the latest drain took for the tick's
         # spans: (rows by source name, requests picked up — a serving
-        # source's insertions; its retractions are rows, not requests)
-        self._last_drain_counts: tuple[dict[str, int], int] = ({}, 0)
+        # source's insertions; its retractions are rows, not requests;
+        # the budget its ingest sources were held to, None for none)
+        self._last_drain_counts: tuple[dict[str, int], int, int | None] \
+            = ({}, 0, None)
         # cumulative bridge exec_ms at the last QoS tick (delta = this
         # tick's resolved device time, the cost-model signal)
         self._qos_exec_ms_seen = 0.0
@@ -799,7 +801,8 @@ class StreamingRuntime:
                 all_closed = False
         self._last_drain = (ingest_rows, query_rows, deferred)
         if by_source is not None:
-            self._last_drain_counts = (by_source, requests)
+            self._last_drain_counts = (by_source, requests,
+                                       None if serving_only else budget)
         return any_data, all_closed, pushes
 
     def _tick_sync(self, tick, any_data, all_closed, pushes):
@@ -828,15 +831,17 @@ class StreamingRuntime:
         the leg the tick submitted and, through ``RequestSpan.tick``, with
         the requests it picked up: ``tick`` from the loop's wake-up to
         ``run_time``'s return (every tick; ``woken_by`` says what ended
-        the loop's wait, ``"period"`` or ``"request"``), and on a tick
-        that carried rows ``tick.drain`` around the drain and the cluster exchange and
+        the loop's wait, ``"period"`` or ``"request"``, and ``bound`` the
+        rows its ingest drain was held to, where a budget stood), and on a
+        tick that carried rows ``tick.drain`` around the drain and the cluster exchange and
         ``tick.host`` around ``run_time``, which returns with the device
         leg submitted (``t_host == t_end``: the tick skipped it)."""
         cause = ("tick", tick)
-        by_source, requests = self._last_drain_counts
+        by_source, requests, bound = self._last_drain_counts
         rec.span("tick", t_wake, t_end, cause,
                  rows=sum(by_source.values()), requests=requests,
-                 woken_by=woken_by)
+                 woken_by=woken_by,
+                 **({} if bound is None else {"bound": bound}))
         if any_data:
             rec.span("tick.drain", t_drain, t_host, cause, **by_source)
             if t_end > t_host:

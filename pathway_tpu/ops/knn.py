@@ -50,6 +50,7 @@ from typing import Any, Callable
 import numpy as np
 
 from pathway_tpu.engine import flight_recorder as _fr
+from pathway_tpu.engine.device_bridge import note_ingest_dispatch
 from pathway_tpu.engine.profiler import (current_profiler,
                                          ingest_scatter_cost,
                                          knn_search_cost)
@@ -1210,6 +1211,9 @@ class DeviceEmbeddingKnnIndex:
     def add_batch(self, keys: list[Pointer], texts,
                   filter_data: list[Any] | None = None) -> None:
         texts = [str(t) for t in texts]
+        # (token slots, real tokens, documents) of each fixed-shape chunk
+        # the ragged packer made of this batch
+        held: list[tuple[int, int, int]] = []
         if self._fused is not None:
             try:
                 if self._ragged:
@@ -1218,22 +1222,30 @@ class DeviceEmbeddingKnnIndex:
                     d0 = 0
                     spans = _fr.recording()
                     work = getattr(self.embedder, "dispatch_work", None)
-                    for args, n_docs, n_pad in \
-                            self.embedder.pack_ragged(texts):
+                    chunks = self.embedder.pack_ragged(texts)
+                    # args[1] is the packed rows' document map
+                    held = [(args[0].size, int(np.count_nonzero(
+                        args[1] >= 0)), n_docs)
+                        for args, n_docs, _n_pad in chunks]
+                    for (args, n_docs, n_pad), (slots, tokens, _n) in zip(
+                            chunks, held):
                         t0 = _time.perf_counter()
                         self._fused(keys[d0:d0 + n_docs],
                                     self.embedder.params, *args,
                                     n_rows=n_pad)
                         t1 = _time.perf_counter()
+                        # the ingest budget's floor is the documents that
+                        # fill a dispatch (engine/qos.py): this is ingest
+                        # for certain, which the embedder cannot know
+                        note_ingest_dispatch(slots, tokens, n_docs)
                         # what attention had to do there, where the model
                         # has such layers (summed for /metrics)
                         attn = work(args) if work is not None else {}
                         if spans:
-                            # args[1] is the packed rows' document map
                             _fr.live_span(
                                 "embedder.dispatch", t0, t1, docs=n_docs,
-                                rows=int(args[0].shape[0]),
-                                tokens=int((args[1] >= 0).sum()), **attn)
+                                rows=int(args[0].shape[0]), tokens=tokens,
+                                **attn)
                         d0 += n_docs
                 else:
                     ids, lens = self.embedder.pack_tokens(texts)
@@ -1246,6 +1258,10 @@ class DeviceEmbeddingKnnIndex:
                 # to the two-dispatch path (re-adds every key, so a
                 # partially-fused ragged batch stays consistent)
                 self.fused_fallbacks += 1
+        # ``encode_batch_device`` packs the same chunks again and makes a
+        # dispatch of each
+        for slots, tokens, n_docs in held:
+            note_ingest_dispatch(slots, tokens, n_docs)
         vecs = self.embedder.encode_batch_device(texts)
         self.inner.add_batch_device(keys, vecs, filter_data)
 
