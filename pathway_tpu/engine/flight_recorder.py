@@ -8,11 +8,23 @@ of operator-step events — tick, operator id + class + user frame
 Scheduler (engine/graph.py), and beside it one bounded store of spans on
 the same ``perf_counter`` clock: ``tick`` / ``tick.drain`` / ``tick.host``
 from the commit loop (engine/streaming.py), ``bridge.wait`` /
-``bridge.leg`` from the bridge worker (engine/device_bridge.py) and
-``connector.pass`` from a polling source (io/fs). Spans of one piece of
+``bridge.leg`` from the bridge worker (engine/device_bridge.py),
+``connector.pass`` from a polling source (io/fs) and, of a backlog's pass,
+one ``connector.progress`` every 256 files read. Spans of one piece of
 work share a ``cause`` — ``("tick", n)`` or ``("pass", source uid, n)`` —
 and a request carries its tick, so request -> tick -> leg -> operator
 steps is one chain by identifier.
+
+Inside a leg the index and the embedder write the stages of their own work
+through :func:`live_span`, with the leg's tick as their cause (None with
+the bridge off, where they lie inside ``tick.host`` by time): a search is
+``index.search`` holding ``search.embed`` (the query's text to its
+embedding on the host) and ``search.scan`` (first search program
+dispatched -> last result fetched), its other stages as counts
+(``flush_rows``, ``prepare_ms``, ``rank_ms``, ``rounds``); an ingest call
+is ``index.add_batch`` holding ``embedder.pack`` (which holds
+``embedder.tokenize``) and one ``embedder.dispatch`` a fused dispatch
+(ops/knn.py, xpacks/llm/embedders.py).
 
 Consumers:
 
@@ -61,8 +73,10 @@ LATENCY_BUCKETS_MS = (
 # second, some twenty steps a tick) and the minutes of checks after it
 _DEFAULT_BUFFER_EVENTS = 65_536
 # every tick of several minutes: one ``tick`` and two ``bridge.*`` spans a
-# tick, two more on a tick that carried rows
-_SPAN_BUFFER = 16_384
+# tick, two more on a tick that carried rows, six a search and four a
+# second of a backlog's pass (the operator ring's size; memory only while
+# a recorder is on)
+_SPAN_BUFFER = 65_536
 _DEFAULT_TAIL_TICKS = 8
 
 
@@ -616,6 +630,9 @@ class FlightRecorder:
         ``bridge.wait`` as async events, since a leg waits while the one
         before it runs), requests (tid 2) and one track per other thread
         that wrote spans (a connector's passes), named after the thread.
+        A span of any other name lies on the track of the thread that
+        wrote it: the index's and the embedder's stages under their
+        ``bridge.leg`` (under ``tick.host`` with the bridge off).
         Every slice is a recorded span or operator step."""
         pid = int(os.environ.get("PATHWAY_PROCESS_ID", "0"))
         tids = {"host": 0, "device": 1}
@@ -656,6 +673,9 @@ class FlightRecorder:
         # where a request's flow arrows land: the start of its tick's
         # ``tick`` span and of that tick's ``bridge.leg``
         flow_start_us: dict[tuple, float] = {}
+        # the commit loop's and the bridge worker's threads have theirs
+        own_tids = {sp[4]: 0 if sp[0] == "tick" else 1 for sp in spans
+                    if sp[0] in ("tick", "bridge.leg")}
         other_tids: dict[int, int] = {}
         for name, t0, t1, cause, ident, counts in spans:
             args = dict(counts or ())
@@ -681,7 +701,7 @@ class FlightRecorder:
                 if cause in waits:
                     args["queue_wait_ms"] = waits[cause]
             else:
-                tid = other_tids.get(ident)
+                tid = own_tids.get(ident, other_tids.get(ident))
                 if tid is None:
                     # tid 2 is the requests track
                     tid = other_tids[ident] = 3 + len(other_tids)

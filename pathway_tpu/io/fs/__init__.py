@@ -27,6 +27,8 @@ from pathway_tpu.io._datasource import (DataSource, Session,
 #: a pass that read more files than this (a backlog) records their count and
 #: not each one's instants
 _PASS_FILES_MAX = 64
+#: and, while it reads, one ``connector.progress`` span every so many files
+_PROGRESS_FILES = 256
 
 
 def _list_files(path: str) -> list[Path]:
@@ -185,14 +187,33 @@ class FsSource(DataSource):
             if fkey in suffix_rows:
                 self._resume_emitted[fkey] = suffix_rows[fkey]
 
+    def _progress(self, rec, n_pass: int, done: tuple, now: float,
+                  totals: tuple) -> tuple:
+        """One ``connector.progress`` span of the pass ``n_pass``: the
+        stretch from ``done`` (instant, thread CPU and the pass's
+        ``totals`` at the span before it, or at the end of the listing) to
+        ``now``. ``totals``: changed files, rows, and the seconds in
+        ``stat``, the parser and the pushes. Returns the next ``done``."""
+        t_done, cpu_done, before = done
+        cpu = _time.thread_time()
+        files, rows, stat_s, parse_s, push_s = (
+            a - b for a, b in zip(totals, before))
+        rec.span("connector.progress", t_done, now,
+                 ("pass", self._uid, n_pass), files=files, rows=rows,
+                 cpu_ms=(cpu - cpu_done) * 1e3, stat_ms=stat_s * 1e3,
+                 parse_ms=parse_s * 1e3, push_ms=push_s * 1e3)
+        return now, cpu, totals
+
     def run(self, session: Session) -> None:
         seen: dict[str, float] = dict(getattr(self, "_resume_seen", {}))
         emitted: dict[str, list] = dict(getattr(self, "_resume_emitted", {}))
         resume_skip: dict[str, tuple] = dict(getattr(self, "_resume_skip", {}))
         seq = getattr(self, "_resume_seq", 0)
         # flight recorder (engine/flight_recorder.py): one ``connector.pass``
-        # span per polling pass while one records; off costs this lookup
-        # and one test per pass and per changed file, and no clock read
+        # span per polling pass while one records, and of a backlog's pass
+        # a ``connector.progress`` every ``_PROGRESS_FILES`` files; off
+        # costs this lookup and a few tests per pass and per changed file,
+        # and no clock read
         rec = getattr(session, "recorder", None)
         n_pass = 0
         while not session.stop_requested:
@@ -202,15 +223,28 @@ class FsSource(DataSource):
                 # pass's first changed files
                 pushed = []
                 changed = n_rows = 0
+                # seconds in ``stat`` (and the test that follows it, of
+                # every listed file), in the parser and the row's key (a
+                # rewritten file's retractions too), and inside the rows'
+                # ``session.push``; the reader thread's own CPU
+                stat_s = parse_s = push_s = 0.0
+                cpu_pass = _time.thread_time()
                 t_pass = _time.perf_counter()
             files = _list_files(self.path)
             if pushed is not None:
-                t_listed = _time.perf_counter()
+                # ``done``: where the stretch a progress span covers began
+                # and what the pass had counted by then
+                t_listed = t_file = _time.perf_counter()
+                done = (t_listed, cpu_pass, (0, 0, 0.0, 0.0, 0.0))
             for f in files:
                 mtime = f.stat().st_mtime
                 fkey = str(f)
                 if fkey in seen and seen[fkey] == mtime:
                     continue
+                if pushed is not None:
+                    t_read = _time.perf_counter()
+                    stat_s += t_read - t_file
+                    t_push, pushing = 0.0, push_s
                 skip = 0
                 if fkey in resume_skip:
                     r_mtime, r_count = resume_skip.pop(fkey)
@@ -235,32 +269,59 @@ class FsSource(DataSource):
                     if pending_values is not None:
                         key, row = self.row_to_engine(pending_values, seq)
                         seq += 1
+                        if pushed is not None:
+                            t_push = _time.perf_counter()
                         session.push(key, row, 1,
                                      offset=("row", fkey, mtime, idx - 1,
                                              False))
+                        if pushed is not None:
+                            push_s += _time.perf_counter() - t_push
+                            t_push = 0.0
                         rows.append((key, row))
                     pending_values = values if idx >= skip else None
                 if pending_values is not None:
                     key, row = self.row_to_engine(pending_values, seq)
                     seq += 1
+                    if pushed is not None:
+                        t_push = _time.perf_counter()
                     session.push(key, row, 1,
                                  offset=("row", fkey, mtime, idx, True))
                     rows.append((key, row))
                 emitted[fkey] = rows
                 if pushed is not None:
+                    # the file's last push ends where the file does: a file
+                    # of one row costs three clock reads
+                    t_file = _time.perf_counter()
+                    if t_push:
+                        push_s += t_file - t_push
+                    parse_s += (t_file - t_read) - (push_s - pushing)
                     changed += 1
                     n_rows += max(0, idx + 1 - skip)
                     if changed <= _PASS_FILES_MAX:
-                        pushed.append((mtime, _time.perf_counter()))
+                        pushed.append((mtime, t_file))
+                    elif changed % _PROGRESS_FILES == 0:
+                        done = self._progress(
+                            rec, n_pass, done, t_file,
+                            (changed, n_rows, stat_s, parse_s, push_s))
             if pushed is not None:
+                t_end = _time.perf_counter()
+                stat_s += t_end - t_file
+                if changed > max(_PASS_FILES_MAX, done[2][0]):
+                    self._progress(
+                        rec, n_pass, done, t_end,
+                        (changed, n_rows, stat_s, parse_s, push_s))
                 counts = {"listed": len(files), "changed": changed,
                           "rows": n_rows,
-                          "list_ms": (t_listed - t_pass) * 1e3}
+                          "list_ms": (t_listed - t_pass) * 1e3,
+                          "cpu_ms": (_time.thread_time() - cpu_pass) * 1e3,
+                          "stat_ms": stat_s * 1e3,
+                          "parse_ms": parse_s * 1e3,
+                          "push_ms": push_s * 1e3}
                 if changed <= _PASS_FILES_MAX:
                     # a trickle of live files: each one's write and push
                     # instants, for the time to its commit
                     counts["files"] = pushed
-                rec.span("connector.pass", t_pass, _time.perf_counter(),
+                rec.span("connector.pass", t_pass, t_end,
                          ("pass", self._uid, n_pass), **counts)
                 n_pass += 1
             if self.mode != "streaming":

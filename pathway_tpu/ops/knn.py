@@ -920,10 +920,21 @@ class BruteForceKnnIndex:
 
     def _device_topk(self, qmat, fetch_k: int):
         """(scores, global slot ids) as host arrays, exactly ``fetch_k``
-        columns, best first. Lock held, device state flushed."""
+        columns, best first. Lock held, device state flushed. While a
+        flight recorder is on this is the span ``search.scan``: first
+        search program dispatched -> last result fetched."""
         prof = current_profiler()
+        # [established extents scanned, seconds until their jitted calls
+        # returned], filled by the parts loop
+        scan = [0, 0.0] if _fr.recording() else None
+        if prof is None and scan is None:
+            return self._device_topk_parts(qmat, fetch_k)
         t0 = _time.perf_counter()
-        out = self._device_topk_parts(qmat, fetch_k)
+        out = self._device_topk_parts(qmat, fetch_k, scan)
+        if scan is not None:
+            _fr.live_span("search.scan", t0, _time.perf_counter(),
+                          queries=int(qmat.shape[0]), fetch_k=fetch_k,
+                          extents=scan[0], dispatch_ms=scan[1] * 1e3)
         if prof is not None:
             # the per-extent kernels scan exactly the established rows
             # (each np.asarray in the parts loop materializes, so the wall
@@ -939,15 +950,21 @@ class BruteForceKnnIndex:
                                      (_time.perf_counter() - t0) * 1e3)
         return out
 
-    def _device_topk_parts(self, qmat, fetch_k: int):
+    def _device_topk_parts(self, qmat, fetch_k: int,
+                           scan: list | None = None):
         parts = []
         for ext in self._pool.extents:
             if not ext.established:
                 continue  # never written → no valid rows to score
             k_e = min(fetch_k, self._extent_fetch_cap(ext))
             fn = self._get_search_fn(k_e)
+            if scan is not None:
+                t0 = _time.perf_counter()
             ts, ti = fn(qmat, ext.vectors, self._extent_extras(ext),
                         ext.valid)
+            if scan is not None:
+                scan[0] += 1
+                scan[1] += _time.perf_counter() - t0
             parts.append((np.asarray(ts), np.asarray(ti) + ext.base))
         if not parts:
             B = int(qmat.shape[0])
@@ -977,9 +994,30 @@ class BruteForceKnnIndex:
         """Batched search: [(qkey, vector, limit, filter)] →
         per query a tuple of (match_key, score) pairs, best first.
         Scores follow the reference convention: L2sq distance (lower=better,
-        reported as distance) or cosine distance 1-cos_sim."""
+        reported as distance) or cosine distance 1-cos_sim.
+
+        While a flight recorder is on, the call is the span
+        ``index.search`` with the counts :meth:`_search` hands up."""
         if not queries:
             return []
+        if not _fr.recording():
+            return self._search(queries, None)
+        counts: dict = {}
+        t0 = _time.perf_counter()
+        out = self._search(queries, counts)
+        _fr.live_span("index.search", t0, _time.perf_counter(),
+                      queries=len(queries), **counts)
+        return out
+
+    def _search(self, queries: list[tuple],
+                counts: dict | None) -> list[tuple]:
+        """:meth:`search` of one or more queries. A ``counts`` dict is
+        filled with what the stages outside the scan amounted to:
+        ``flush_rows`` (pending rows the flush wrote), ``prepare_ms`` (lock
+        taken -> the query matrix handed to the device), ``rank_ms`` (slot
+        -> key, filter, distance; every round) and ``rounds`` (scans: one
+        without a selective filter). None: no clock is read."""
+        timed = counts is not None
         if self._tenant is not None:
             # per-tenant serving metrics: the query keys ARE the engine
             # keys the request tracker registered at enqueue, so this is
@@ -995,6 +1033,10 @@ class BruteForceKnnIndex:
                 if self.result_cache is not None:
                     self.last_search_coverage = frozenset()
                 return [() for _ in queries]
+            if timed:
+                t_prepare = _time.perf_counter()
+                counts.update(flush_rows=len(self._dirty), rank_ms=0.0,
+                              rounds=0)
             self._flush_to_device()
             if self.result_cache is not None:
                 # coverage AFTER the flush — it must describe exactly the
@@ -1015,9 +1057,14 @@ class BruteForceKnnIndex:
             qmat = jnp.asarray(
                 np.stack([np.asarray(q[1], dtype=np.float32).reshape(-1)
                           for q in queries]))
+            if timed:
+                counts["prepare_ms"] = (_time.perf_counter()
+                                        - t_prepare) * 1e3
 
             while True:
                 top_scores, top_idx = self._device_topk(qmat, fetch_k)
+                if timed:
+                    t_rank = _time.perf_counter()
 
                 out = []
                 exhausted = True
@@ -1054,6 +1101,10 @@ class BruteForceKnnIndex:
                         # and more live slots remain: escalate the fetch
                         exhausted = False
                     out.append(tuple(matches))
+                if timed:
+                    counts["rank_ms"] += (_time.perf_counter()
+                                          - t_rank) * 1e3
+                    counts["rounds"] += 1
                 if exhausted or not has_filter:
                     return out
                 if fetch_k >= fetch_cap:
@@ -1210,6 +1261,22 @@ class DeviceEmbeddingKnnIndex:
 
     def add_batch(self, keys: list[Pointer], texts,
                   filter_data: list[Any] | None = None) -> None:
+        """While a flight recorder is on, the call is the span
+        ``index.add_batch``: ``docs``, the encoder ``dispatches`` of the
+        path it took, and whether that was the ``fused`` one."""
+        spans = _fr.recording()
+        if spans:
+            t0 = _time.perf_counter()
+        dispatches, fused = self._add_batch(keys, texts, filter_data, spans)
+        if spans:
+            _fr.live_span("index.add_batch", t0, _time.perf_counter(),
+                          docs=len(keys), dispatches=dispatches,
+                          fused=fused)
+
+    def _add_batch(self, keys: list[Pointer], texts,
+                   filter_data: list[Any] | None,
+                   spans: bool) -> tuple[int, int]:
+        """(encoder dispatches, 1 where they were the fused ones)."""
         texts = [str(t) for t in texts]
         # (token slots, real tokens, documents) of each fixed-shape chunk
         # the ragged packer made of this batch
@@ -1220,7 +1287,6 @@ class DeviceEmbeddingKnnIndex:
                     # ragged-packed fused ingest: one donated dispatch per
                     # fixed-shape chunk; padded doc rows scatter-drop
                     d0 = 0
-                    spans = _fr.recording()
                     work = getattr(self.embedder, "dispatch_work", None)
                     chunks = self.embedder.pack_ragged(texts)
                     # args[1] is the packed rows' document map
@@ -1229,11 +1295,13 @@ class DeviceEmbeddingKnnIndex:
                         for args, n_docs, _n_pad in chunks]
                     for (args, n_docs, n_pad), (slots, tokens, _n) in zip(
                             chunks, held):
-                        t0 = _time.perf_counter()
+                        if spans:
+                            t0 = _time.perf_counter()
                         self._fused(keys[d0:d0 + n_docs],
                                     self.embedder.params, *args,
                                     n_rows=n_pad)
-                        t1 = _time.perf_counter()
+                        if spans:
+                            t1 = _time.perf_counter()
                         # the ingest budget's floor is the documents that
                         # fill a dispatch (engine/qos.py): this is ingest
                         # for certain, which the embedder cannot know
@@ -1252,7 +1320,7 @@ class DeviceEmbeddingKnnIndex:
                     self._fused(keys, self.embedder.params, ids, lens)
                 self.inner.set_filter_data(keys, filter_data)
                 self.fused_batches += 1
-                return
+                return len(held) or 1, 1
             except FusedIngestUnplaceable:
                 # batch spans extents / fits no single one — fall through
                 # to the two-dispatch path (re-adds every key, so a
@@ -1264,6 +1332,7 @@ class DeviceEmbeddingKnnIndex:
             note_ingest_dispatch(slots, tokens, n_docs)
         vecs = self.embedder.encode_batch_device(texts)
         self.inner.add_batch_device(keys, vecs, filter_data)
+        return len(held) or 1, 0
 
     def add(self, key: Pointer, text, filter_data: Any | None = None) -> None:
         self.add_batch([key], [text],
@@ -1284,10 +1353,25 @@ class DeviceEmbeddingKnnIndex:
         return len(self.inner)
 
     def search(self, queries: list[tuple]) -> list[tuple]:
+        """While a flight recorder is on, the call is the span
+        ``index.search`` (the inner index's counts on it, and not a second
+        span of its own) holding ``search.embed``: tokenize, pack, the
+        encoder's dispatch and the blocking fetch of the embeddings."""
         if not queries:
             return []
+        counts = {} if _fr.recording() else None
+        if counts is not None:
+            t0 = _time.perf_counter()
         qvecs = np.asarray(self.embedder.encode_batch_device(
             [str(q[1]) for q in queries]), dtype=np.float32)
-        return self.inner.search(
+        if counts is not None:
+            _fr.live_span("search.embed", t0, _time.perf_counter(),
+                          queries=len(queries))
+        out = self.inner._search(
             [(qkey, qvecs[i], limit, filt)
-             for i, (qkey, _text, limit, filt) in enumerate(queries)])
+             for i, (qkey, _text, limit, filt) in enumerate(queries)],
+            counts)
+        if counts is not None:
+            _fr.live_span("index.search", t0, _time.perf_counter(),
+                          queries=len(queries), **counts)
+        return out

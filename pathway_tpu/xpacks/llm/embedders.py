@@ -20,6 +20,7 @@ from typing import Any
 
 import numpy as np
 
+from pathway_tpu.engine import flight_recorder as _fr
 from pathway_tpu.internals import udfs
 from pathway_tpu.xpacks.llm._utils import _import_or_raise
 
@@ -235,16 +236,39 @@ class JaxEncoderEmbedder(BaseEmbedder):
 
     def pack_tokens(self, texts: list[str]) -> tuple[np.ndarray, np.ndarray]:
         """Tokenize + bucket-pad, returning ``(ids, lens)`` ready for the
-        packed device producer — int16 ids when the vocab fits."""
+        packed device producer — int16 ids when the vocab fits. While a
+        flight recorder is on, the call is the span ``embedder.pack`` and
+        the tokenizer's part of it ``embedder.tokenize``."""
+        spans = _fr.recording()
+        if spans:
+            t0 = _perf_counter()
         ids, mask = self.tokenizer.batch(
             [t or "." for t in texts], max_len=self.max_len)
+        if spans:
+            t_tokens = _perf_counter()
         pad_to = self._bucket(ids.shape[1])
         if ids.shape[1] < pad_to:
             ids = np.pad(ids, ((0, 0), (0, pad_to - ids.shape[1])))
         else:
             ids, mask = ids[:, :pad_to], mask[:, :pad_to]
         lens = mask.sum(axis=1).astype(np.int32)
-        return ids.astype(np.int16 if self._pack_ids else np.int32), lens
+        ids = ids.astype(np.int16 if self._pack_ids else np.int32)
+        if spans:
+            self._pack_spans(t0, t_tokens, len(texts), int(lens.sum()),
+                             [ids.shape])
+        return ids, lens
+
+    @staticmethod
+    def _pack_spans(t0: float, t_tokens: float, texts: int, tokens: int,
+                    shapes: list[tuple]) -> None:
+        """The packer's two spans: ``embedder.tokenize`` ``[t0, t_tokens]``
+        inside ``embedder.pack`` ``[t0, now]``, which made dispatches of
+        ``shapes`` (rows, width) holding ``tokens`` real tokens."""
+        _fr.live_span("embedder.tokenize", t0, t_tokens, texts=texts,
+                      tokens=tokens)
+        _fr.live_span("embedder.pack", t0, _perf_counter(), texts=texts,
+                      rows=sum(r for r, _w in shapes),
+                      slots=sum(r * w for r, w in shapes), tokens=tokens)
 
     def device_producer(self, params, ids, lens):
         """Pure (traceable) forward over packed tokens: mask rebuilt on
@@ -361,9 +385,14 @@ class JaxEncoderEmbedder(BaseEmbedder):
         its static output row count (pad rows carry doc_map -1 and are
         dropped by the caller / the fused scatter). ``doc_off`` is the
         offset of the token the model pools: a document's first, or under
-        ``pooling: "last"`` its last."""
+        ``pooling: "last"`` its last. The spans are ``pack_tokens``'s."""
+        spans = _fr.recording()
+        if spans:
+            t0 = _perf_counter()
         ids, mask = self.tokenizer.batch(
             [t or "." for t in texts], max_len=self.max_len)
+        if spans:
+            t_tokens = _perf_counter()
         lens = mask.sum(axis=1).astype(np.int64)
         W, cap = self.max_len, self._ragged_doc_cap
         pool_last = self.config.pooling == "last"
@@ -406,6 +435,9 @@ class JaxEncoderEmbedder(BaseEmbedder):
             chunks.append(((c_ids, c_map, c_pos, c_dseq, c_doff),
                            n_docs, n_pad))
             d0 = d1
+        if spans:
+            self._pack_spans(t0, t_tokens, len(texts), int(lens.sum()),
+                             [c[0][0].shape for c in chunks])
         return chunks
 
     def ragged_warmup_operands(self, n_seqs: int) -> tuple[tuple, int]:

@@ -783,3 +783,228 @@ def test_rederived_counter_on_metrics_and_in_the_trace_file(tmp_path):
     assert op.take_rederived() == 0          # the recorder took them
     assert _groupby_stats(rec)["rederived"] == 2
     assert metric() == 2 and in_trace_file() == 2
+
+
+# ---------------------------------------------------------------------------
+# the stages inside a leg: a search, an ingest call, the packer (ops/knn.py,
+# xpacks/llm/embedders.py through ``live_span``)
+# ---------------------------------------------------------------------------
+
+def _inside(child, parent) -> bool:
+    return parent[1] <= child[1] <= child[2] <= parent[2]
+
+
+@pytest.fixture
+def live_text_rag(monkeypatch, tmp_path):
+    """``live_rag`` with the embedder inside the index, as the benchmark
+    serves it: the index takes text, a query's text is packed and embedded
+    inside its search. Yields (runtime, client, watched directory)."""
+    import socket
+
+    from pathway_tpu.engine import streaming
+    from pathway_tpu.models.encoder import EncoderConfig
+    from pathway_tpu.ops.knn import KnnMetric
+    from pathway_tpu.stdlib.indexing import (
+        default_brute_force_knn_document_index)
+    from pathway_tpu.xpacks.llm.embedders import JaxEncoderEmbedder
+    from pathway_tpu.xpacks.llm.vector_store import (VectorStoreClient,
+                                                     VectorStoreServer)
+
+    monkeypatch.setenv("PATHWAY_DEVICE_INFLIGHT", "2")
+    emb = JaxEncoderEmbedder(config=EncoderConfig.tiny(), ragged=True,
+                             max_len=64)
+    watched = tmp_path / "watched"
+    watched.mkdir()
+    (watched / "old.txt").write_text("the quick brown fox")
+    source = pw.io.fs.read(str(watched), format="plaintext_by_file",
+                           mode="streaming", with_metadata=True,
+                           refresh_interval_s=0.05)
+
+    def build_index(chunks):
+        return default_brute_force_knn_document_index(
+            chunks.text, chunks, embedder=emb,
+            dimensions=emb.get_embedding_dimension(),
+            metadata_column=chunks.metadata, metric=KnnMetric.COS)
+
+    server = VectorStoreServer(source, embedder=emb,
+                               index_builder=build_index)
+    with socket.socket() as s:
+        s.bind(("127.0.0.1", 0))
+        port = s.getsockname()[1]
+    thread = server.run_server(host="127.0.0.1", port=port, threaded=True,
+                               with_cache=False,
+                               trace_path=str(tmp_path / "flight.json"))
+    client = VectorStoreClient("127.0.0.1", port, timeout=60)
+    deadline = time.monotonic() + 60
+    while True:
+        try:
+            if client.get_vectorstore_statistics()["file_count"]:
+                break
+        except OSError:
+            pass
+        assert time.monotonic() < deadline, "the server did not come up"
+        time.sleep(0.05)
+    (runtime,) = streaming.live_runtimes()
+    try:
+        yield runtime, client, watched
+    finally:
+        streaming.stop_all()
+        thread.join(15.0)
+
+
+def test_a_search_and_an_ingest_call_are_chains_inside_their_legs(
+        live_text_rag):
+    """One new file and one query: request -> tick -> ``bridge.leg`` ->
+    ``index.search`` -> ``search.embed`` -> ``embedder.pack`` ->
+    ``embedder.tokenize`` is one chain by identifier and by containment,
+    and so is the file's ``index.add_batch`` in the leg of its tick."""
+    runtime, client, watched = live_text_rag
+    rec = runtime.recorder
+    (watched / "new.txt").write_text("systolic arrays multiply matrices")
+    deadline = time.monotonic() + 60
+    while True:
+        hits = client.query("systolic arrays multiply matrices", k=1)
+        if hits and hits[0]["metadata"]["path"].endswith("new.txt"):
+            break
+        assert time.monotonic() < deadline, "the new file never surfaced"
+    while runtime.scheduler.bridge_depth():
+        assert time.monotonic() < deadline, "the leg never retired"
+        time.sleep(0.005)
+    spans = rec.spans()
+    legs = {sp[3]: sp for sp in spans if sp[0] == "bridge.leg"}
+
+    def of(cause) -> dict:
+        out: dict = {}
+        for sp in spans:
+            if sp[3] == cause:
+                out.setdefault(sp[0], []).append(sp)
+        return out
+
+    # -- the last query ------------------------------------------------------
+    request = [r for r in rec.requests.trace_spans()
+               if r["route"] == "/v1/retrieve"][-1]
+    cause = ("tick", request["tick"])
+    mine = of(cause)
+    (search,), (embed,), (scan,) = (mine["index.search"],
+                                    mine["search.embed"],
+                                    mine["search.scan"])
+    (pack,), (tokenize,) = mine["embedder.pack"], mine["embedder.tokenize"]
+    leg = legs[cause]
+    assert _inside(search, leg)
+    assert _inside(embed, search) and _inside(scan, search)
+    assert _inside(pack, embed) and _inside(tokenize, pack)
+    assert embed[2] <= scan[1]
+    assert search[5]["queries"] == embed[5]["queries"] \
+        == scan[5]["queries"] == tick_requests(mine) >= 1
+    assert search[5]["rounds"] == 1 and scan[5]["extents"] == 1
+    assert pack[5]["texts"] == tokenize[5]["texts"] == search[5]["queries"]
+    # written on the bridge worker's thread, the leg's
+    assert {sp[4] for sp in (search, embed, scan, pack, tokenize)} \
+        == {leg[4]}
+    assert _self_time(search, [embed, scan]) >= 0.0
+    assert _self_time(leg, [search]) >= 0.0
+
+    # -- the new file's ingest call --------------------------------------------
+    adds = [sp for sp in spans if sp[0] == "index.add_batch"]
+    assert len(adds) == 2 and [sp[5]["docs"] for sp in adds] == [1, 1]
+    add = adds[-1]
+    assert add[5] == {"docs": 1, "dispatches": 1, "fused": 1}
+    assert add[3] in legs and _inside(add, legs[add[3]])
+    its = of(add[3])
+    (dispatch,) = its["embedder.dispatch"]
+    (pack,) = [sp for sp in its["embedder.pack"] if _inside(sp, add)]
+    (tokenize,) = [sp for sp in its["embedder.tokenize"]
+                   if _inside(sp, pack)]
+    assert _inside(dispatch, add) and pack[2] <= dispatch[1]
+    assert dispatch[5]["tokens"] == pack[5]["tokens"] == tokenize[5]["tokens"]
+    # the tick that carried the file drained its source
+    (drain,) = its["tick.drain"]
+    assert any(k.startswith("fs-") for k in drain[5])
+
+    # -- the surfaces: the tracks that exist, no new one -----------------------
+    payload = rec.trace_payload()
+    assert {s["name"] for s in payload["spans"]} >= {
+        "index.search", "search.embed", "search.scan", "index.add_batch",
+        "embedder.pack", "embedder.tokenize", "embedder.dispatch"}
+    events = rec.chrome_trace_events()
+    _check_nesting(events)
+    tracks = {e["tid"]: e["args"]["name"] for e in events
+              if e["ph"] == "M" and e["name"] == "thread_name"}
+    assert tracks[0] == "host leg" and tracks[1] == "device leg"
+    assert all("src-" in name or name == "requests"
+               for tid, name in tracks.items() if tid > 1), tracks
+    staged = [e for e in events if e["ph"] == "B" and e["name"].split()[0]
+              in ("index.search", "search.embed", "search.scan",
+                  "index.add_batch", "embedder.pack", "embedder.tokenize",
+                  "embedder.dispatch")]
+    assert staged and {e["tid"] for e in staged} == {1}
+
+
+def tick_requests(spans_of_tick: dict) -> int:
+    (tick,) = spans_of_tick["tick"]
+    return tick[5]["requests"]
+
+
+def test_chrome_export_nests_the_stages_under_their_leg_and_their_pass():
+    """The stages lie on the track of the thread that wrote them, nested by
+    interval: under ``bridge.leg`` on the device-leg track (under
+    ``tick.host`` on the host's with the bridge off), ``connector.progress``
+    under its ``connector.pass``."""
+    rec = FlightRecorder()
+    rec.enabled = True
+    rec._epoch = 0.0
+    # both alive at once: a thread's identifier is free again when it ends
+    both, done = threading.Barrier(2), threading.Event()
+
+    def bridge():
+        both.wait(5.0)
+        rec.span("bridge.wait", 1.05, 1.06, ("tick", 2), depth=1)
+        rec.span("embedder.tokenize", 1.061, 1.062, ("tick", 2), texts=1)
+        rec.span("embedder.pack", 1.061, 1.063, ("tick", 2), texts=1)
+        rec.span("search.embed", 1.061, 1.07, ("tick", 2), queries=1)
+        rec.span("search.scan", 1.071, 1.09, ("tick", 2), queries=1)
+        rec.span("index.search", 1.061, 1.095, ("tick", 2), queries=1)
+        rec.span("bridge.leg", 1.06, 1.10, ("tick", 2))
+        done.set()
+
+    def reader():
+        both.wait(5.0)
+        done.wait(5.0)
+        rec.span("connector.progress", 0.6, 0.9, ("pass", 0, 7), files=256)
+        rec.span("connector.progress", 0.9, 1.4, ("pass", 0, 7), files=44)
+        rec.span("connector.pass", 0.5, 1.4, ("pass", 0, 7), listed=300)
+
+    # bridge off: the same stages on the commit loop's thread
+    rec.span("tick", 2.00, 2.05, ("tick", 3), rows=1, requests=1)
+    rec.span("search.scan", 2.021, 2.04, None, queries=1)
+    rec.span("index.search", 2.02, 2.045, None, queries=1)
+    rec.span("tick.host", 2.01, 2.05, ("tick", 3))
+    threads = [threading.Thread(target=bridge, name="device-bridge"),
+               threading.Thread(target=reader, name="src-fs-0")]
+    for th in threads:
+        th.start()
+    for th in threads:
+        th.join()
+    events = rec.chrome_trace_events()
+    _check_nesting(events)
+    tracks = {e["tid"]: e["args"]["name"] for e in events
+              if e["ph"] == "M" and e["name"] == "thread_name"}
+    assert tracks == {0: "host leg", 1: "device leg", 3: "src-fs-0"}
+    begun = {tid: [e["name"] for e in events if e["ph"] == "B"
+                   and e["tid"] == tid] for tid in tracks}
+    assert begun[1] == ["bridge.leg 2", "index.search 2", "search.embed 2",
+                        "embedder.pack 2", "embedder.tokenize 2",
+                        "search.scan 2"]
+    assert begun[0] == ["tick 3", "tick.host 3", "index.search",
+                        "search.scan"]
+    assert begun[3] == ["connector.pass 7", "connector.progress 7",
+                        "connector.progress 7"]
+
+
+def test_the_span_store_is_as_large_as_the_operator_ring():
+    from pathway_tpu.engine.flight_recorder import (_DEFAULT_BUFFER_EVENTS,
+                                                    _SPAN_BUFFER)
+
+    assert _SPAN_BUFFER == _DEFAULT_BUFFER_EVENTS == 65_536
+    rec = FlightRecorder()
+    assert rec._spans.maxlen == _SPAN_BUFFER

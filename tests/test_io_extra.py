@@ -497,3 +497,87 @@ def test_reference_convenience_wrappers():
         with _pytest.raises(ImportError, match="psycopg2"):
             pw.io.postgres.write_snapshot(
                 pw.debug.table_from_markdown("a\n1"), {}, "t", ["a"])
+
+
+# ---------------------------------------------------------------------------
+# the fs reader's progress through a backlog, as the flight recorder's spans
+# (io/fs ``FsSource.run``; engine/flight_recorder.py)
+# ---------------------------------------------------------------------------
+
+def _recorded_pass(directory, n_files: int):
+    """One pass of the fs source over ``n_files`` new one-passage files
+    with a recorder on its session: (the ``connector.pass`` span, the
+    ``connector.progress`` spans, the rows it pushed)."""
+    import pathway_tpu.io.fs as fs
+    from pathway_tpu.engine.flight_recorder import FlightRecorder
+    from pathway_tpu.io._datasource import Session
+
+    for i in range(n_files):
+        (directory / f"doc{i:04d}.txt").write_text(f"passage number {i}")
+    source = fs.FsSource(
+        str(directory), "plaintext_by_file",
+        fs._schema_for("plaintext_by_file", None, True), "static", True)
+    session = Session()
+    session.recorder = FlightRecorder()
+    session.recorder.enabled = True
+    source.run(session)
+    spans = session.recorder.spans()
+    (whole,) = [sp for sp in spans if sp[0] == "connector.pass"]
+    assert whole[3] == ("pass", source._uid, 0)
+    return (whole, [sp for sp in spans if sp[0] == "connector.progress"],
+            session.drain())
+
+
+def test_a_backlog_s_pass_shows_its_progress_every_256_files(tmp_path):
+    whole, progress, pushed = _recorded_pass(tmp_path, 600)
+    assert len(pushed) == 600
+    assert [sp[5]["files"] for sp in progress] == [256, 256, 88]
+    assert sum(sp[5]["files"] for sp in progress) == whole[5]["changed"]
+    assert sum(sp[5]["rows"] for sp in progress) == whole[5]["rows"] == 600
+    assert all(sp[3] == whole[3] for sp in progress)
+    # one stretch after the other from the end of the listing to the end of
+    # the pass, on the reader's thread
+    listed = whole[1] + whole[5]["list_ms"] / 1e3
+    assert progress[0][1] == pytest.approx(listed, abs=1e-9)
+    for before, after in zip(progress, progress[1:]):
+        assert before[2] == after[1]
+    assert progress[-1][2] == whole[2]
+    assert {sp[4] for sp in progress} == {whole[4]}
+    # what a stretch's files cost, by where: never more than its wall time
+    for sp in progress:
+        counts, wall_ms = sp[5], (sp[2] - sp[1]) * 1e3
+        assert set(counts) == {"files", "rows", "cpu_ms", "stat_ms",
+                               "parse_ms", "push_ms"}
+        assert min(counts.values()) >= 0
+        assert counts["stat_ms"] + counts["parse_ms"] + counts["push_ms"] \
+            <= wall_ms + 1e-6
+    for count in ("stat_ms", "parse_ms", "push_ms"):
+        assert sum(sp[5][count] for sp in progress) == pytest.approx(
+            whole[5][count], rel=1e-9, abs=1e-9)
+    assert "files" not in whole[5]   # a backlog: counts, not instants
+
+
+def test_a_trickle_s_pass_writes_no_progress_and_carries_the_counts(
+        tmp_path):
+    whole, progress, pushed = _recorded_pass(tmp_path, 3)
+    assert len(pushed) == 3 and progress == []
+    counts = whole[5]
+    assert counts["changed"] == 3 and len(counts["files"]) == 3
+    assert {"cpu_ms", "stat_ms", "parse_ms", "push_ms", "list_ms"} \
+        <= set(counts)
+    wall_ms = (whole[2] - whole[1]) * 1e3
+    assert counts["list_ms"] + counts["stat_ms"] + counts["parse_ms"] \
+        + counts["push_ms"] == pytest.approx(wall_ms, abs=1e-6)
+    assert 0 <= counts["cpu_ms"]
+    # each live file's push instant lies inside the pass
+    assert all(whole[1] <= push <= whole[2] for _m, push in counts["files"])
+
+
+def test_a_pass_just_over_the_trickle_s_size_is_one_stretch(tmp_path):
+    import pathway_tpu.io.fs as fs
+
+    n = fs._PASS_FILES_MAX + 1
+    assert n < fs._PROGRESS_FILES
+    whole, progress, _pushed = _recorded_pass(tmp_path, n)
+    assert [sp[5]["files"] for sp in progress] == [n]
+    assert "files" not in whole[5]

@@ -625,3 +625,164 @@ def test_ragged_warmup_compile_count_under_six():
     assert len(idx) == 0  # warmup scratch rows retracted
     # the width-bucket zoo this replaces is ~18 compiles
     assert len(emb.bucket_widths()) >= 15
+
+
+# ---------------------------------------------------------------------------
+# the stages a search and an ingest call record of themselves while a flight
+# recorder is on (engine/flight_recorder.py ``live_span``)
+# ---------------------------------------------------------------------------
+
+@pytest.fixture
+def live_recorder():
+    """A recorder that is on, as ``pw.run`` leaves one while it traces."""
+    from pathway_tpu.engine.flight_recorder import FlightRecorder
+
+    rec = FlightRecorder.from_env(auto_on=True)
+    try:
+        yield rec
+    finally:
+        rec.enabled = False
+
+
+def _inside(child, parent) -> bool:
+    return parent[1] <= child[1] <= child[2] <= parent[2]
+
+
+def _text_index():
+    from pathway_tpu.models.encoder import EncoderConfig
+    from pathway_tpu.ops.knn import DeviceEmbeddingKnnIndex
+    from pathway_tpu.xpacks.llm.embedders import JaxEncoderEmbedder
+
+    cfg = EncoderConfig.tiny()
+    emb = JaxEncoderEmbedder(config=cfg, ragged=True, max_len=64)
+    return DeviceEmbeddingKnnIndex(
+        emb, BruteForceKnnIndex(cfg.hidden, metric=KnnMetric.COS))
+
+
+def test_a_vector_index_searched_directly_records_one_search(live_recorder):
+    idx = _mk(reserved_space=64)
+    rng = np.random.default_rng(0)
+    vecs = rng.standard_normal((6, 8)).astype(np.float32)
+    idx.add_batch([Pointer(i) for i in range(6)], vecs)
+    assert idx.search([]) == [] and not live_recorder.spans()
+    (hits,) = idx.search([(Pointer(99), vecs[2], 2, None)])
+    assert hits[0][0] == Pointer(2)
+    scan, search = live_recorder.spans()
+    assert (scan[0], search[0]) == ("search.scan", "index.search")
+    assert _inside(scan, search) and scan[3] is None is search[3]
+    assert scan[5]["queries"] == 1 and scan[5]["fetch_k"] == 2
+    assert scan[5]["extents"] == 1
+    assert 0 <= scan[5]["dispatch_ms"] <= (scan[2] - scan[1]) * 1e3
+    # the six pending rows went to the device inside this search
+    counts = search[5]
+    assert counts["queries"] == 1 and counts["flush_rows"] == 6
+    assert counts["rounds"] == 1
+    assert counts["prepare_ms"] + counts["rank_ms"] \
+        <= (search[2] - search[1]) * 1e3
+    # the steady state flushes nothing; two queries are one search
+    idx.search([(Pointer(98), vecs[1], 1, None),
+                (Pointer(97), vecs[3], 1, None)])
+    search = live_recorder.spans()[-1]
+    assert search[5]["queries"] == 2 and search[5]["flush_rows"] == 0
+
+
+def test_a_selective_filter_s_rounds_are_counted_on_the_search(
+        live_recorder):
+    n = 40
+    idx = _mk(reserved_space=64)
+    rng = np.random.default_rng(1)
+    vecs = rng.standard_normal((n, 8)).astype(np.float32)
+    order = np.argsort(((vecs - vecs[0]) ** 2).sum(axis=1))
+    # only the farthest row passes: the first candidate lists hold none
+    idx.add_batch([Pointer(i) for i in range(n)], vecs,
+                  filter_data=[{"ok": i == order[-1]} for i in range(n)])
+    (hits,) = idx.search([(Pointer(99), vecs[0], 1, lambda d: d["ok"])])
+    assert hits[0][0] == Pointer(int(order[-1]))
+    spans = live_recorder.spans()
+    scans = [sp for sp in spans if sp[0] == "search.scan"]
+    (search,) = [sp for sp in spans if sp[0] == "index.search"]
+    assert search[5]["rounds"] == len(scans) > 1
+    assert [sp[5]["fetch_k"] for sp in scans] == sorted(
+        sp[5]["fetch_k"] for sp in scans)
+    assert all(_inside(sp, search) for sp in scans)
+
+
+def test_a_text_index_records_its_search_once_and_its_stages_inside(
+        live_recorder):
+    idx = _text_index()
+    texts = [f"document number {i} with content {i * 7}" for i in range(9)]
+    idx.add_batch([Pointer(i) for i in range(9)], texts)
+    by_name = {sp[0]: sp for sp in live_recorder.spans()}
+    assert set(by_name) == {"embedder.tokenize", "embedder.pack",
+                            "embedder.dispatch", "index.add_batch"}
+    add, pack = by_name["index.add_batch"], by_name["embedder.pack"]
+    assert add[5] == {"docs": 9, "dispatches": 1, "fused": 1}
+    assert _inside(by_name["embedder.tokenize"], pack)
+    assert _inside(pack, add) and _inside(by_name["embedder.dispatch"], add)
+    assert pack[2] <= by_name["embedder.dispatch"][1]
+    tokens = by_name["embedder.tokenize"][5]["tokens"]
+    assert pack[5]["texts"] == 9 and pack[5]["tokens"] == tokens
+    assert pack[5]["slots"] == pack[5]["rows"] * 64 >= tokens
+    assert by_name["embedder.dispatch"][5]["tokens"] == tokens
+    before = len(live_recorder.spans())
+    (hits,) = idx.search([(Pointer(99), texts[4], 1, None)])
+    assert hits[0][0] == Pointer(4)
+    spans = live_recorder.spans()[before:]
+    # one ``index.search``: the wrapped index hands its counts up and
+    # writes no span of its own
+    assert [sp[0] for sp in spans] == [
+        "embedder.tokenize", "embedder.pack", "search.embed",
+        "search.scan", "index.search"]
+    tokenize, pack, embed, scan, search = spans
+    assert _inside(tokenize, pack) and _inside(pack, embed)
+    assert _inside(embed, search) and _inside(scan, search)
+    assert embed[2] <= scan[1]
+    assert search[5]["queries"] == embed[5]["queries"] == 1
+    assert {"flush_rows", "prepare_ms", "rank_ms", "rounds"} <= set(
+        search[5])
+
+
+def test_the_padded_packer_records_the_same_two_spans(live_recorder):
+    from pathway_tpu.models.encoder import EncoderConfig
+    from pathway_tpu.xpacks.llm.embedders import JaxEncoderEmbedder
+
+    emb = JaxEncoderEmbedder(config=EncoderConfig.tiny(), max_len=64)
+    ids, lens = emb.pack_tokens(["one passage", "another longer passage"])
+    tokenize, pack = live_recorder.spans()
+    assert (tokenize[0], pack[0]) == ("embedder.tokenize", "embedder.pack")
+    assert _inside(tokenize, pack)
+    assert pack[5] == {"texts": 2, "rows": ids.shape[0],
+                       "slots": ids.size, "tokens": int(lens.sum())}
+
+
+def test_the_two_dispatch_path_says_so_on_the_ingest_span(live_recorder):
+    from pathway_tpu.ops.knn import FusedIngestUnplaceable
+
+    idx = _text_index()
+
+    def unplaceable(*_args, **_kw):
+        raise FusedIngestUnplaceable("the batch fits no single extent")
+
+    idx._fused = unplaceable
+    idx.add_batch([Pointer(1), Pointer(2)], ["one passage", "another"])
+    assert len(idx) == 2 and idx.fused_fallbacks == 1
+    (add,) = [sp for sp in live_recorder.spans()
+              if sp[0] == "index.add_batch"]
+    assert add[5] == {"docs": 2, "dispatches": 1, "fused": 0}
+    # the fallback packs the batch again: both packs lie inside the call
+    packs = [sp for sp in live_recorder.spans() if sp[0] == "embedder.pack"]
+    assert len(packs) == 2 and all(_inside(sp, add) for sp in packs)
+
+
+@pytest.mark.parametrize("recording, clock, spans", [(False, 0, 0),
+                                                     (True, None, 11)])
+def test_a_search_and_an_ingest_call_read_no_clock_with_no_recorder_on(
+        recording, clock, spans):
+    """Off is free (the guard is tests/trace_canary.py's): no clock read,
+    no tuple. On: four spans an ingest call, five a text search, two a
+    vector search."""
+    from tests.trace_canary import index_clock_reads
+
+    reads, written = index_clock_reads(recording)
+    assert written == spans
+    assert reads == clock if clock is not None else reads > 0

@@ -14,7 +14,9 @@ end to end (same pattern as pipelining_canary.py / watchdog_canary.py).
    window + groupby shape the streaming example runs, over many ticks,
    min-of-K to de-noise; the device UDF is left out and the bridge pinned
    synchronous so the comparison measures the scheduler hook, not XLA
-   compile or thread-scheduling variance.
+   compile or thread-scheduling variance. The same holds for the stages
+   the index and the embedder record of a search and an ingest call: with
+   no recorder on, neither reads the clock nor writes a span.
 
 Exits 0 iff both hold. Run: ``python tests/trace_canary.py``.
 """
@@ -172,27 +174,34 @@ def _etl_like_graph(n_rows: int, n_ticks: int):
     return runner
 
 
+class _CountingClock:
+    """``time`` with its ``perf_counter`` and ``thread_time`` counted."""
+
+    def __init__(self):
+        self.reads = 0
+
+    def __getattr__(self, name):
+        return getattr(time, name)
+
+    def perf_counter(self):
+        self.reads += 1
+        return time.perf_counter()
+
+    def thread_time(self):
+        self.reads += 1
+        return time.thread_time()
+
+
 def fs_pass_clock_reads(directory, recording: bool) -> int:
-    """``perf_counter`` reads one polling pass of the fs source makes over
-    ``directory`` (one file is added to it), with or without a recorder on
-    its session: the ``connector.pass`` span may cost clock reads only
-    while something records."""
+    """``perf_counter`` and ``thread_time`` reads one polling pass of the
+    fs source makes over ``directory`` (one file is added to it), with or
+    without a recorder on its session: the ``connector.pass`` span may cost
+    clock reads only while something records."""
     import pathway_tpu.io.fs as fs
     from pathway_tpu.engine.flight_recorder import FlightRecorder
     from pathway_tpu.io._datasource import Session
 
     (pathlib.Path(directory) / "canary.txt").write_text("one passage")
-    reads = 0
-
-    class _CountingClock:
-        def __getattr__(self, name):
-            return getattr(time, name)
-
-        def perf_counter(self):
-            nonlocal reads
-            reads += 1
-            return time.perf_counter()
-
     source = fs.FsSource(
         str(directory), "plaintext_by_file",
         fs._schema_for("plaintext_by_file", None, False), "static", False)
@@ -203,6 +212,7 @@ def fs_pass_clock_reads(directory, recording: bool) -> int:
     real, fs._time = fs._time, _CountingClock()
     try:
         source.run(session)
+        reads = fs._time.reads
     finally:
         fs._time = real
     if not session.drain():
@@ -210,6 +220,41 @@ def fs_pass_clock_reads(directory, recording: bool) -> int:
     if recording and not session.recorder.spans():
         raise AssertionError("the recorded pass wrote no span")
     return reads
+
+
+def index_clock_reads(recording: bool) -> tuple[int, int]:
+    """(clock reads, spans written) of one ingest call and one search of a
+    text index (packer, fused dispatch, query embedding, scan) and one
+    search of a vector index, with or without a live recorder: the stages
+    they record may cost a clock read or a tuple only while one is on."""
+    import pathway_tpu.ops.knn as knn
+    import pathway_tpu.xpacks.llm.embedders as embedders
+    from pathway_tpu.engine.flight_recorder import FlightRecorder
+    from pathway_tpu.internals.keys import Pointer
+    from pathway_tpu.models.encoder import EncoderConfig
+
+    emb = embedders.JaxEncoderEmbedder(config=EncoderConfig.tiny(),
+                                       ragged=True)
+    index = knn.DeviceEmbeddingKnnIndex(
+        emb, knn.BruteForceKnnIndex(64, reserved_space=64))
+    # on as ``pw.run`` turns one on: live, so ``live_span`` finds it
+    rec = FlightRecorder.from_env(auto_on=True) if recording \
+        else FlightRecorder()
+    clock = _CountingClock()
+    real = knn._time, embedders._perf_counter
+    knn._time, embedders._perf_counter = clock, clock.perf_counter
+    try:
+        index.add_batch([Pointer(1), Pointer(2)],
+                        ["one passage", "another passage"])
+        (hits,) = index.search([(Pointer(9), "one passage", 1, None)])
+        vector = [1.0] + [0.0] * 63
+        index.inner.search([(Pointer(9), vector, 1, None)])
+    finally:
+        knn._time, embedders._perf_counter = real
+        rec.enabled = False
+    if hits[0][0] != Pointer(1):
+        raise AssertionError(f"the search answered {hits}")
+    return clock.reads, len(rec.spans())
 
 
 def check_overhead(attempts: int = 3) -> str | None:
@@ -224,8 +269,12 @@ def check_overhead(attempts: int = 3) -> str | None:
     with tempfile.TemporaryDirectory() as td:
         reads = fs_pass_clock_reads(td, recording=False)
     if reads:
-        return (f"the fs source's pass read perf_counter {reads} times "
+        return (f"the fs source's pass read the clock {reads} times "
                 f"with no recorder on its session")
+    reads, spans = index_clock_reads(recording=False)
+    if reads or spans:
+        return (f"an ingest call and two searches read the clock {reads} "
+                f"times and wrote {spans} spans with no recorder on")
     last = None
     for i in range(attempts):
         last = _measure_overhead()
