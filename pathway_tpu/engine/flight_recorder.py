@@ -18,10 +18,13 @@ steps is one chain by identifier.
 Inside a leg the index and the embedder write the stages of their own work
 through :func:`live_span`, with the leg's tick as their cause (None with
 the bridge off, where they lie inside ``tick.host`` by time): a search is
-``index.search`` holding ``search.embed`` (the query's text to its
-embedding on the host) and ``search.scan`` (first search program
+``index.search`` holding ``search.embed`` (the query's text tokenized,
+packed and uploaded; it ends at the encoder's dispatch, the embedding
+stays on the device) and ``search.scan`` (first search program
 dispatched -> last result fetched), its other stages as counts
-(``flush_rows``, ``prepare_ms``, ``rank_ms``, ``rounds``); an ingest call
+(``flush_rows``, ``prepare_ms``, ``rank_ms``, ``rounds``) beside
+``uploads`` and ``fetches``, the transfers between host and device that
+the search made for its queries (:func:`note_transfers`); an ingest call
 is ``index.add_batch`` holding ``embedder.pack`` (which holds
 ``embedder.tokenize``) and one ``embedder.dispatch`` a fused dispatch
 (ops/knn.py, xpacks/llm/embedders.py).
@@ -178,6 +181,35 @@ def recording() -> bool:
     """Whether any live recorder is on: callers that must compute a span's
     counts ask first."""
     return any(rec.enabled for rec in list(_LIVE))
+
+
+# the counts dict of the ``index.search`` this thread is inside while a
+# recorder is on: the embedder's upload and the index's fetches are made
+# in code that holds no reference to it
+_TRANSFERS = threading.local()
+
+
+@contextlib.contextmanager
+def counting_transfers(counts: dict):
+    """``counts`` gains ``uploads`` and ``fetches``: the host-to-device and
+    device-to-host transfers :func:`note_transfers` is told of on this
+    thread inside the block. Opened by whoever writes the span the counts
+    go on, so only while a recorder is on."""
+    counts.update(uploads=0, fetches=0)
+    _TRANSFERS.counts = counts
+    try:
+        yield counts
+    finally:
+        _TRANSFERS.counts = None
+
+
+def note_transfers(uploads: int = 0, fetches: int = 0) -> None:
+    """Transfers the caller has just made between host and device, for
+    the search that counts them on this thread (no-op outside one)."""
+    counts = getattr(_TRANSFERS, "counts", None)
+    if counts is not None:
+        counts["uploads"] += uploads
+        counts["fetches"] += fetches
 
 
 def attach_note(e: BaseException, note: str) -> None:

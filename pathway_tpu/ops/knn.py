@@ -164,6 +164,34 @@ def _chunked_search(k: int, score_block, prep_queries):
     return search
 
 
+@functools.lru_cache(maxsize=None)
+def _packed_search_fn(search_fn):
+    """``search_fn`` (a :func:`_chunked_search` program) with its two
+    results as ONE int32 array ``(B, 2k)``, the scores' bits and then the
+    slots, so that both reach the host in one transfer
+    (:func:`_unpack_topk`; integers pass through the device untouched).
+    Named ``search`` as the program it wraps: the XLA module stays
+    ``jit_search``."""
+    import jax
+    import jax.numpy as jnp
+
+    @jax.jit
+    def search(queries, vectors, extras, valid):
+        ts, ti = search_fn(queries, vectors, extras, valid)
+        return jnp.concatenate(
+            [jax.lax.bitcast_convert_type(ts, jnp.int32), ti], axis=1)
+
+    return search
+
+
+def _unpack_topk(packed) -> tuple[np.ndarray, np.ndarray]:
+    """(scores float32, slots int32) of a :func:`_packed_search_fn`
+    result: the one device-to-host transfer of an extent's scan."""
+    packed = np.asarray(packed)
+    k = packed.shape[1] // 2
+    return packed[:, :k].view(np.float32), packed[:, k:]
+
+
 def _prep_queries(metric: KnnMetric, cast_dtype=None):
     import jax.numpy as jnp
 
@@ -937,7 +965,7 @@ class BruteForceKnnIndex:
                           extents=scan[0], dispatch_ms=scan[1] * 1e3)
         if prof is not None:
             # the per-extent kernels scan exactly the established rows
-            # (each np.asarray in the parts loop materializes, so the wall
+            # (the parts loop fetches every extent's result, so the wall
             # is honest device time); cost the scan over those rows, not
             # the pool's capacity
             rows = sum(e.rows for e in self._pool.extents if e.established)
@@ -952,20 +980,29 @@ class BruteForceKnnIndex:
 
     def _device_topk_parts(self, qmat, fetch_k: int,
                            scan: list | None = None):
-        parts = []
+        # every extent's scan is dispatched before any result is waited
+        # for, and an extent's scores and slots come back as one array
+        found = []
         for ext in self._pool.extents:
             if not ext.established:
                 continue  # never written → no valid rows to score
             k_e = min(fetch_k, self._extent_fetch_cap(ext))
-            fn = self._get_search_fn(k_e)
+            fn = _packed_search_fn(self._get_search_fn(k_e))
             if scan is not None:
                 t0 = _time.perf_counter()
-            ts, ti = fn(qmat, ext.vectors, self._extent_extras(ext),
-                        ext.valid)
+            found.append((ext.base, fn(
+                qmat, ext.vectors, self._extent_extras(ext), ext.valid)))
             if scan is not None:
                 scan[0] += 1
                 scan[1] += _time.perf_counter() - t0
-            parts.append((np.asarray(ts), np.asarray(ti) + ext.base))
+        if len(found) > 1:
+            for _base, packed in found:
+                packed.copy_to_host_async()
+        _fr.note_transfers(fetches=len(found))
+        parts = []
+        for base, packed in found:
+            ts, ti = _unpack_topk(packed)
+            parts.append((ts, ti + base))
         if not parts:
             B = int(qmat.shape[0])
             return (np.full((B, fetch_k), -np.inf, np.float32),
@@ -997,26 +1034,38 @@ class BruteForceKnnIndex:
         reported as distance) or cosine distance 1-cos_sim.
 
         While a flight recorder is on, the call is the span
-        ``index.search`` with the counts :meth:`_search` hands up."""
+        ``index.search`` with the counts :meth:`_search` hands up, and
+        ``uploads`` and ``fetches``: the transfers made for the queries
+        (their matrix up, an extent's result down; a flush of pending
+        rows is ``flush_rows``'s to tell)."""
         if not queries:
             return []
         if not _fr.recording():
             return self._search(queries, None)
         counts: dict = {}
         t0 = _time.perf_counter()
-        out = self._search(queries, counts)
+        with _fr.counting_transfers(counts):
+            out = self._search(queries, counts)
         _fr.live_span("index.search", t0, _time.perf_counter(),
                       queries=len(queries), **counts)
         return out
 
-    def _search(self, queries: list[tuple],
-                counts: dict | None) -> list[tuple]:
-        """:meth:`search` of one or more queries. A ``counts`` dict is
-        filled with what the stages outside the scan amounted to:
-        ``flush_rows`` (pending rows the flush wrote), ``prepare_ms`` (lock
-        taken -> the query matrix handed to the device), ``rank_ms`` (slot
-        -> key, filter, distance; every round) and ``rounds`` (scans: one
-        without a selective filter). None: no clock is read."""
+    def _search(self, queries: list[tuple], counts: dict | None,
+                qmat=None) -> list[tuple]:
+        """:meth:`search` of one or more queries. ``qmat``: the queries'
+        vectors as a device array ``(len(queries), dim)``, which goes to
+        the scan as it is (cast to float32 on the device where it is not,
+        which is exact) while ``q[1]`` is not read; None: the matrix is
+        stacked from the host vectors ``q[1]`` and uploaded. The ranking
+        loop fetches a device matrix only where a branch needs a query's
+        vector on the host (an L2 distance, the exhaustive filtered pass).
+
+        A ``counts`` dict is filled with what the stages outside the scan
+        amounted to: ``flush_rows`` (pending rows the flush wrote),
+        ``prepare_ms`` (lock taken -> the query matrix handed to the
+        device), ``rank_ms`` (slot -> key, filter, distance; every round)
+        and ``rounds`` (scans: one without a selective filter). None: no
+        clock is read."""
         timed = counts is not None
         if self._tenant is not None:
             # per-tenant serving metrics: the query keys ARE the engine
@@ -1054,9 +1103,27 @@ class BruteForceKnnIndex:
             fetch_k = min(fetch_cap,
                           max_k * 4 if has_filter else max_k)
             fetch_k = max(fetch_k, 1)
-            qmat = jnp.asarray(
-                np.stack([np.asarray(q[1], dtype=np.float32).reshape(-1)
-                          for q in queries]))
+            host_q = None
+
+            def host_rows():
+                """The queries' vectors on the host, a row a query: the
+                caller's, or the device matrix fetched when first asked."""
+                nonlocal host_q
+                if host_q is None:
+                    # pwt-ok: PWT402 — reached by an L2 distance and the
+                    # exhaustive filtered pass alone; the cosine path of
+                    # a text query never asks
+                    host_q = np.asarray(qmat)
+                    _fr.note_transfers(fetches=1)
+                return host_q
+
+            if qmat is None:
+                host_q = np.stack([np.asarray(q[1], dtype=np.float32)
+                                   .reshape(-1) for q in queries])
+                qmat = jnp.asarray(host_q)
+                _fr.note_transfers(uploads=1)
+            elif qmat.dtype != jnp.float32:
+                qmat = qmat.astype(jnp.float32)
             if timed:
                 counts["prepare_ms"] = (_time.perf_counter()
                                         - t_prepare) * 1e3
@@ -1068,7 +1135,7 @@ class BruteForceKnnIndex:
 
                 out = []
                 exhausted = True
-                for qi, (qkey, qvec, limit, filt) in enumerate(queries):
+                for qi, (qkey, _qvec, limit, filt) in enumerate(queries):
                     limit = int(limit or 3)
                     matches = []
                     qnorm_sq = None
@@ -1089,8 +1156,7 @@ class BruteForceKnnIndex:
                             dist = 1.0 - float(score)
                         else:
                             if qnorm_sq is None:
-                                q = np.asarray(qvec,
-                                               dtype=np.float32).reshape(-1)
+                                q = host_rows()[qi]
                                 qnorm_sq = float(q @ q)
                             dist = max(0.0, qnorm_sq - float(score))
                         matches.append((key, dist))
@@ -1116,8 +1182,8 @@ class BruteForceKnnIndex:
                     return [
                         r if len(r) >= int(q[2] or 3) or q[3] is None
                         else self._exhaustive_filtered_search(
-                            q[1], int(q[2] or 3), q[3])
-                        for q, r in zip(queries, out)
+                            host_rows()[qi], int(q[2] or 3), q[3])
+                        for qi, (q, r) in enumerate(zip(queries, out))
                     ]
                 fetch_k = min(fetch_cap, fetch_k * 4)
 
@@ -1229,7 +1295,9 @@ class DeviceEmbeddingKnnIndex:
     brute_force_knn_integration.rs), paying a device→host→device round
     trip per document that this path deletes. Both dispatches (encode,
     scatter) are asynchronous, so the next engine batch's host work
-    overlaps device compute.
+    overlaps device compute. A query's embedding never visits the host
+    either: the encoder's output is the scan's operand, and a text search
+    makes one upload (the packed tokens) and one fetch (scores and slots).
 
     ``embedder`` must expose ``encode_batch_device(texts) -> (B, dim)``
     jax array (JaxEncoderEmbedder does).
@@ -1356,22 +1424,21 @@ class DeviceEmbeddingKnnIndex:
         """While a flight recorder is on, the call is the span
         ``index.search`` (the inner index's counts on it, and not a second
         span of its own) holding ``search.embed``: tokenize, pack, the
-        encoder's dispatch and the blocking fetch of the embeddings."""
+        one upload and the encoder's dispatch. The embeddings stay on the
+        device and are the scan's operand."""
         if not queries:
             return []
-        counts = {} if _fr.recording() else None
-        if counts is not None:
-            t0 = _time.perf_counter()
-        qvecs = np.asarray(self.embedder.encode_batch_device(
-            [str(q[1]) for q in queries]), dtype=np.float32)
-        if counts is not None:
+        texts = [str(q[1]) for q in queries]
+        if not _fr.recording():
+            return self.inner._search(
+                queries, None, self.embedder.encode_batch_device(texts))
+        counts: dict = {}
+        t0 = _time.perf_counter()
+        with _fr.counting_transfers(counts):
+            qmat = self.embedder.encode_batch_device(texts)
             _fr.live_span("search.embed", t0, _time.perf_counter(),
                           queries=len(queries))
-        out = self.inner._search(
-            [(qkey, qvecs[i], limit, filt)
-             for i, (qkey, _text, limit, filt) in enumerate(queries)],
-            counts)
-        if counts is not None:
-            _fr.live_span("index.search", t0, _time.perf_counter(),
-                          queries=len(queries), **counts)
+            out = self.inner._search(queries, counts, qmat)
+        _fr.live_span("index.search", t0, _time.perf_counter(),
+                      queries=len(queries), **counts)
         return out

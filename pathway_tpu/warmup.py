@@ -175,8 +175,7 @@ def _warmup_impl(embedder: Any = None, *, index: Any = None,
                 try:
                     fused(scratch, embedder.params, *ops, n_rows=n_docs)
                 except FusedIngestUnplaceable:
-                    jax.block_until_ready(embedder._encode_ragged(
-                        embedder.params, *ops))
+                    jax.block_until_ready(embedder.encode_ragged_chunk(ops))
                     out["compiled"].append(("ragged_encode", (n_seqs, W)))
                     continue
                 for k in scratch:
@@ -184,14 +183,12 @@ def _warmup_impl(embedder: Any = None, *, index: Any = None,
                 out["compiled"].append(("ragged_fused_ingest", (n_seqs, W)))
                 if ks:
                     # same query-path warm as the packed branch: text
-                    # queries use the plain ragged encoder, not the
-                    # fused ingest dispatch
-                    jax.block_until_ready(embedder._encode_ragged(
-                        embedder.params, *ops))
+                    # queries use the plain ragged encoder (the chunk as
+                    # one buffer), not the fused ingest dispatch
+                    jax.block_until_ready(embedder.encode_ragged_chunk(ops))
                     out["compiled"].append(("ragged_encode", (n_seqs, W)))
             else:
-                jax.block_until_ready(embedder._encode_ragged(
-                    embedder.params, *ops))
+                jax.block_until_ready(embedder.encode_ragged_chunk(ops))
                 out["compiled"].append(("ragged_encode", (n_seqs, W)))
         if fused is not None:
             inner.flush_device()

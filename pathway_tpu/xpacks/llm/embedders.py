@@ -12,6 +12,7 @@ from __future__ import annotations
 
 import asyncio
 import collections
+import functools
 import operator
 import threading
 import weakref
@@ -98,6 +99,20 @@ def attention_tile_stats() -> dict | None:
         return None
     return {"tiles_run": sum(t[0] for t in totals),
             "tiles_all": sum(t[1] for t in totals)}
+
+
+@functools.lru_cache(maxsize=None)
+def _first_rows_fn():
+    """Jitted ``first_rows(rows, n) -> rows[:n]``, ``n`` static: the
+    real documents of a ragged dispatch's padded rows. One program a
+    (padded rows, n), as the eager slice it replaces, which also uploaded
+    a start index for each dimension at every call."""
+    import jax
+
+    def first_rows(rows, n):
+        return rows[:n]
+
+    return jax.jit(first_rows, static_argnums=1)
 
 
 class JaxEncoderEmbedder(BaseEmbedder):
@@ -202,7 +217,16 @@ class JaxEncoderEmbedder(BaseEmbedder):
         # docs-per-sequence cap bounds the padded doc dimension of a chunk
         # (W//16: a doc is never shorter than CLS+token+SEP anyway)
         self._ragged_doc_cap = max(1, self.max_len // 16)
-        self._encode_ragged = jax.jit(self.ragged_device_producer)
+
+        # the plain ragged encoder takes a chunk as ONE int32 buffer (one
+        # upload, :meth:`encode_ragged_chunk`) and splits it at static
+        # offsets; named as the method it calls, since the name is the
+        # XLA module's (``jit_ragged_device_producer``)
+        def ragged_device_producer(params, flat):
+            return self.ragged_device_producer(
+                params, *self._split_ragged(flat))
+
+        self._encode_ragged = jax.jit(ragged_device_producer)
 
     def _bucket(self, n: int) -> int:
         """Pad target for a batch whose longest row has ``n`` tokens.
@@ -460,18 +484,44 @@ class JaxEncoderEmbedder(BaseEmbedder):
         doff = np.tile(np.arange(cap, dtype=np.int32) * tok, n_seqs)
         return (ids, doc_map, pos, dseq, doff), n_docs
 
-    def encode_batch_device(self, texts: list[str]):
-        """Tokenize + encoder forward, returning the (B, hidden) embedding
-        still ON DEVICE (a jax array, dispatch left asynchronous). The
-        fused index path (ops/knn.py DeviceEmbeddingKnnIndex) scatters it
-        straight into the HBM slab — embeddings never visit the host."""
+    def _split_ragged(self, flat):
+        """The five arrays of a ragged chunk out of its one buffer
+        (``ids``, ``doc_map``, ``pos_ids`` of (n_seqs, W), then
+        ``doc_seq``, ``doc_off`` of (n_seqs * doc cap,)): the buffer's
+        length names ``n_seqs``, so the offsets are static under jit."""
+        W, cap = self.max_len, self._ragged_doc_cap
+        n_seqs = flat.shape[0] // (3 * W + 2 * cap)
+        rows, n_pad = n_seqs * W, n_seqs * cap
+        ids, doc_map, pos_ids = (
+            flat[i * rows:(i + 1) * rows].reshape(n_seqs, W)
+            for i in range(3))
+        return (ids, doc_map, pos_ids, flat[3 * rows:3 * rows + n_pad],
+                flat[3 * rows + n_pad:])
+
+    def encode_ragged_chunk(self, args: tuple):
+        """The plain ragged forward over one chunk of :meth:`pack_ragged`
+        (its ``args``): the five int32 arrays go up as one buffer in one
+        explicit transfer. Returns what the forward returns (padded rows,
+        or ``(rows, aux)``), the dispatch left asynchronous."""
         import jax.numpy as jnp
 
         # residency is established EXPLICITLY (jnp.asarray) rather than by
-        # letting the jit dispatch transfer its numpy operands implicitly:
+        # letting the jit dispatch transfer its numpy operand implicitly:
         # same bytes over PCIe either way, but the explicit form stays
         # legal under the device sanitizer's steady-state transfer guard
         # (engine/device_sanitizer.py) and under PWT404's discipline
+        flat = jnp.asarray(np.concatenate([a.ravel() for a in args]))
+        _fr.note_transfers(uploads=1)
+        return self._encode_ragged(self.params, flat)
+
+    def encode_batch_device(self, texts: list[str]):
+        """Tokenize + encoder forward, returning the (B, hidden) embedding
+        still ON DEVICE (a jax array, dispatch left asynchronous). The
+        index that embeds text itself (ops/knn.py DeviceEmbeddingKnnIndex)
+        hands a query's straight to its scan and scatters a document's
+        into the HBM slab — embeddings never visit the host."""
+        import jax.numpy as jnp
+
         from pathway_tpu.engine.profiler import current_profiler
 
         prof = current_profiler()
@@ -480,8 +530,9 @@ class JaxEncoderEmbedder(BaseEmbedder):
             outs = []
             for args, n_docs, _n_pad in self.pack_ragged(texts):
                 t0 = _perf_counter() if prof is not None else 0.0
-                outs.append(self._embeddings(self._encode_ragged(
-                    self.params, *(jnp.asarray(a) for a in args)))[:n_docs])
+                out = self._embeddings(self.encode_ragged_chunk(args))
+                outs.append(out if n_docs == out.shape[0]
+                            else _first_rows_fn()(out, n_docs))
                 if prof is not None:
                     b, s = args[0].shape  # packed (n_seqs, W) token ids
                     cost = cfg.cost(int(b), int(s), ragged=True)
@@ -491,8 +542,10 @@ class JaxEncoderEmbedder(BaseEmbedder):
             return outs[0] if len(outs) == 1 else jnp.concatenate(outs, 0)
         ids, lens = self.pack_tokens(texts)
         t0 = _perf_counter() if prof is not None else 0.0
+        # explicit residency, as in :meth:`encode_ragged_chunk`
         out = self._embeddings(self._encode_packed(
             self.params, jnp.asarray(ids), jnp.asarray(lens)))
+        _fr.note_transfers(uploads=2)
         if prof is not None:
             b, s = ids.shape
             cost = cfg.cost(int(b), int(s), ragged=False)

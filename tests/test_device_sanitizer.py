@@ -12,7 +12,6 @@ import numpy as np
 import pytest
 
 jax = pytest.importorskip("jax")
-import jax.numpy as jnp  # noqa: E402
 
 from pathway_tpu.engine import device_sanitizer as ds  # noqa: E402
 
@@ -191,7 +190,7 @@ def test_ragged_encoder_ladder_pin_under_sanitizer(monkeypatch):
     # steady state: the exact warmed (bucket, width) dispatch is free
     bucket = emb.ragged_buckets()[0]
     ops, _n_docs = emb.ragged_warmup_operands(bucket)
-    emb._encode_ragged(emb.params, *(jnp.asarray(a) for a in ops))
+    emb.encode_ragged_chunk(ops)
     assert ds.post_warmup_compiles() == 0
     assert ds.violations() == []
 

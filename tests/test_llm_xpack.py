@@ -493,3 +493,58 @@ def test_parse_slides_non_deck_fallback():
     text, meta = out[0]
     assert "plain notes" in text
     assert meta["page"] == 1 and meta["total_pages"] == 1
+
+
+# ---------------------------------------------------------------------------
+# the index that embeds text itself hands a query's embedding from the
+# encoder to the scan on the device (ops/knn.py DeviceEmbeddingKnnIndex)
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("ragged, uploads", [(True, 1), (False, 2)])
+@pytest.mark.parametrize("metric", ["cos", "l2sq"])
+def test_the_built_text_index_answers_as_its_inner_index_given_the_rows(
+        metric, ragged, uploads):
+    """The index the document-index factory builds for a device-capable
+    embedder answers a text query with the keys and distances, to the
+    last bit, that its inner index gives for the embedder's fetched rows:
+    with the ragged packer (one buffer up) and the padded one (ids and
+    lengths: two), and the transfers are counted on the search's span."""
+    from pathway_tpu.engine.flight_recorder import FlightRecorder
+    from pathway_tpu.internals.keys import Pointer
+    from pathway_tpu.models.encoder import EncoderConfig
+    from pathway_tpu.ops.knn import DeviceEmbeddingKnnIndex, KnnMetric
+    from pathway_tpu.stdlib.indexing import (
+        default_brute_force_knn_document_index,
+    )
+    from pathway_tpu.xpacks.llm.embedders import JaxEncoderEmbedder
+
+    emb = JaxEncoderEmbedder(config=EncoderConfig.tiny(), ragged=ragged,
+                             max_len=64)
+    docs = _docs_table()
+    index = default_brute_force_knn_document_index(
+        docs.data, docs, embedder=emb, dimensions=64,
+        metric=KnnMetric(metric), dtype="bfloat16")
+    built = index.inner_index.factory().build()
+    assert isinstance(built, DeviceEmbeddingKnnIndex)
+    texts = ["the quick brown fox jumps over the lazy dog",
+             "TPU systolic arrays multiply matrices fast",
+             "ring attention rotates blocks around the interconnect"]
+    built.add_batch([Pointer(i) for i in range(3)], texts)
+    asked = ["systolic arrays multiply", "a lazy dog", texts[2]]
+    rows = np.asarray(emb.encode_batch_device(asked), dtype=np.float32)
+    rec = FlightRecorder.from_env(auto_on=True)
+    try:
+        by_text = built.search([(Pointer(100 + i), t, 2, None)
+                                for i, t in enumerate(asked)])
+    finally:
+        rec.enabled = False
+    by_rows = built.inner.search([(Pointer(100 + i), rows[i], 2, None)
+                                  for i in range(3)])
+    assert by_text == by_rows
+    assert [r[0][0] for r in by_text] == [Pointer(1), Pointer(0),
+                                          Pointer(2)]
+    (search,) = [sp for sp in rec.spans() if sp[0] == "index.search"]
+    # an L2 distance asks for the queries' vectors on the host: one
+    # fetch more than the scan's
+    assert search[5]["uploads"] == uploads
+    assert search[5]["fetches"] == (1 if metric == "cos" else 2)

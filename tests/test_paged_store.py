@@ -786,3 +786,223 @@ def test_a_search_and_an_ingest_call_read_no_clock_with_no_recorder_on(
     reads, written = index_clock_reads(recording)
     assert written == spans
     assert reads == clock if clock is not None else reads > 0
+
+
+# ---------------------------------------------------------------------------
+# a text search keeps its data on the device between the packer and the
+# ranking loop: one upload (the packer's five arrays as one buffer), the
+# embedding handed from the encoder to the scan, one fetch an extent
+# ---------------------------------------------------------------------------
+
+_DOCS = [f"document number {i} with content {i * 7}" for i in range(1100)]
+_QUERIES = [_DOCS[4], "content 91 of a document", _DOCS[1050],
+            "number with", _DOCS[131]]
+_RARE = (7, 600, 1090)
+
+
+@pytest.fixture(scope="module")
+def text_embedder():
+    from pathway_tpu.models.encoder import EncoderConfig
+    from pathway_tpu.xpacks.llm.embedders import JaxEncoderEmbedder
+
+    return JaxEncoderEmbedder(config=EncoderConfig.tiny(), ragged=True,
+                              max_len=64)
+
+
+@pytest.fixture(scope="module")
+def text_indexes(text_embedder):
+    """One text index a (metric, slab dtype, extents), built when first
+    asked for: the 1,100 documents in one reserved extent or grown into
+    a second, every third row allowed by the filter ``ok`` and three rows
+    by ``rare``."""
+    from pathway_tpu.ops.knn import DeviceEmbeddingKnnIndex
+
+    built = {}
+
+    def get(metric, dtype, extents):
+        key = (metric, dtype, extents)
+        if key not in built:
+            inner = BruteForceKnnIndex(
+                text_embedder.config.hidden, metric=metric, dtype=dtype,
+                reserved_space=2048 if extents == 1 else 0)
+            idx = DeviceEmbeddingKnnIndex(text_embedder, inner)
+            for lo in range(0, len(_DOCS), 100):
+                idx.add_batch(
+                    [Pointer(i) for i in range(lo, lo + 100)],
+                    _DOCS[lo:lo + 100],
+                    [{"ok": i % 3 == 0, "rare": i in _RARE}
+                     for i in range(lo, lo + 100)])
+            assert idx.fused_fallbacks == 0
+            assert _extents(inner) == extents
+            built[key] = idx
+        return built[key]
+
+    return get
+
+
+@pytest.mark.parametrize("filt", [None, "ok", "rare", "rare-exhaustive"])
+@pytest.mark.parametrize("batch", [1, 5])
+@pytest.mark.parametrize("extents", [1, 2])
+@pytest.mark.parametrize("dtype", ["bfloat16", "int8"])
+@pytest.mark.parametrize("metric", [KnnMetric.COS, KnnMetric.L2SQ])
+def test_a_text_search_answers_as_the_inner_index_given_the_fetched_rows(
+        text_embedder, text_indexes, monkeypatch, metric, dtype, extents,
+        batch, filt):
+    """Same keys, same distances to the last bit as the path that fetched
+    the embeddings and uploaded them again: the same float32 rows reach
+    the same scan. ``ok`` escalates the fetch once or twice, ``rare``
+    until every row is a candidate, and with the chunk cut to 32 rows a
+    scan cannot list them all: the exact pass over the host mirror."""
+    import pathway_tpu.ops.knn as knn_mod
+
+    idx = text_indexes(metric, dtype, extents)
+    if filt == "rare-exhaustive":
+        monkeypatch.setattr(knn_mod, "_CHUNK_ROWS", 32)
+    name = filt and filt.split("-")[0]
+    test = name and (lambda d: bool(d and d[name]))
+    texts = _QUERIES[:batch]
+    fetched = np.asarray(text_embedder.encode_batch_device(texts),
+                         dtype=np.float32)
+    by_text = idx.search([(Pointer(10**9 + i), t, 3, test)
+                          for i, t in enumerate(texts)])
+    by_rows = idx.inner.search([(Pointer(10**9 + i), fetched[i], 3, test)
+                                for i in range(batch)])
+    assert by_text == by_rows
+    assert all(len(r) == 3 for r in by_text)
+    if filt is None:
+        assert by_text[0][0][0] == Pointer(4)
+    elif name == "rare":
+        assert all({int(k) for k, _ in r} == set(_RARE) for r in by_text)
+    else:
+        assert all(int(k) % 3 == 0 for r in by_text for k, _ in r)
+
+
+def _search_counts(rec, idx, queries) -> dict:
+    before = len(rec.spans())
+    idx.search(queries)
+    (search,) = [sp for sp in rec.spans()[before:]
+                 if sp[0] == "index.search"]
+    return search[5]
+
+
+def test_a_search_counts_its_uploads_and_fetches_on_its_span(
+        live_recorder, text_indexes):
+    """One upload and one fetch a text query, and as many a vector query
+    (its matrix, its result); a fetch more an extent scanned, one more
+    where an L2 distance asks for the query's vector on the host."""
+    import jax
+
+    one = text_indexes(KnnMetric.COS, "bfloat16", 1)
+    text = [(Pointer(10**9), _QUERIES[0], 3, None)]
+    counts = _search_counts(live_recorder, one, text)
+    assert (counts["uploads"], counts["fetches"]) == (1, 1)
+    five = [(Pointer(10**9 + i), t, 3, None)
+            for i, t in enumerate(_QUERIES)]
+    counts = _search_counts(live_recorder, one, five)
+    assert (counts["uploads"], counts["fetches"]) == (1, 1)
+    row = np.asarray(one.embedder.encode_batch_device([_QUERIES[0]]))[0]
+    counts = _search_counts(live_recorder, one.inner,
+                            [(Pointer(10**9), row, 3, None)])
+    assert (counts["uploads"], counts["fetches"]) == (1, 1)
+    counts = _search_counts(
+        live_recorder, text_indexes(KnnMetric.COS, "bfloat16", 2), text)
+    assert (counts["uploads"], counts["fetches"]) == (1, 2)
+    counts = _search_counts(
+        live_recorder, text_indexes(KnnMetric.L2SQ, "bfloat16", 1), text)
+    assert (counts["uploads"], counts["fetches"]) == (1, 2)
+    # explicit transfers only: nothing rides a dispatch or an eager
+    # operation as a host operand
+    with jax.transfer_guard("disallow"):
+        assert one.search(five)[0][0][0] == Pointer(4)
+        assert one.inner.search([(Pointer(10**9), row, 3, None)])
+
+
+def test_the_query_path_s_programs_keep_their_module_names(text_embedder,
+                                                           text_indexes):
+    """The benchmark finds the query path's encoder and scan in a profile
+    by the names JAX gives their modules (benchmark/lib/trace.py
+    ``MODULE_PATTERNS``)."""
+    import jax
+    import jax.numpy as jnp
+
+    import pathway_tpu.ops.knn as knn_mod
+
+    # one sequence of 64 tokens: three arrays of 64, two of its 4 documents
+    flat = jax.ShapeDtypeStruct((3 * 64 + 2 * 4,), jnp.int32)
+    lowered = text_embedder._encode_ragged.lower(text_embedder.params, flat)
+    assert "module @jit_ragged_device_producer " in lowered.as_text()
+    for dtype in ("bfloat16", "int8"):
+        inner = text_indexes(KnnMetric.COS, dtype, 1).inner
+        (ext,) = inner._pool.extents
+        lowered = knn_mod._packed_search_fn(inner._get_search_fn(3)).lower(
+            jnp.zeros((1, inner.dim), jnp.float32), ext.vectors,
+            inner._extent_extras(ext), ext.valid)
+        assert "module @jit_search " in lowered.as_text()
+
+
+def test_a_warmed_process_compiles_nothing_at_a_text_query(monkeypatch):
+    """``pw.warmup`` walks the program the query path calls (the chunk as
+    one buffer) at every sequence bucket: with the scan and the slice of
+    each batch compiled before (they are shared by every index and
+    embedder; the harness's ``warm_queries`` walks them), a fresh
+    embedder's first text search of each bucket compiles nothing."""
+    import pathway_tpu as pw
+    from pathway_tpu.engine import device_sanitizer as ds
+    from pathway_tpu.models.encoder import EncoderConfig
+    from pathway_tpu.ops.knn import DeviceEmbeddingKnnIndex
+    from pathway_tpu.xpacks.llm.embedders import JaxEncoderEmbedder
+
+    long = " ".join(["word"] * 40)  # two of them overflow a row of 64
+
+    def index():
+        emb = JaxEncoderEmbedder(config=EncoderConfig.tiny(), ragged=True,
+                                 max_len=64, ragged_max_seqs=4)
+        idx = DeviceEmbeddingKnnIndex(emb, BruteForceKnnIndex(
+            emb.config.hidden, metric=KnnMetric.COS, reserved_space=256))
+        idx.add_batch([Pointer(i) for i in range(20)], _DOCS[:20])
+        return emb, idx
+
+    def search_each_bucket(emb, idx):
+        for n_seqs in emb.ragged_buckets():
+            texts = [long] * n_seqs
+            assert emb.pack_ragged(texts)[0][0][0].shape[0] == n_seqs
+            idx.search([(Pointer(10**9 + i), t, 3, None)
+                        for i, t in enumerate(texts)])
+
+    search_each_bucket(*index())  # the shared scan and slice programs
+    ds._reset_for_tests()
+    monkeypatch.setenv("PATHWAY_DEVICE_SANITIZER", "1")
+    try:
+        emb, idx = index()
+        out = pw.warmup(emb, index=idx, ks=(3,))
+        assert [shape for kind, shape in out["compiled"]
+                if kind == "ragged_encode"] == [(1, 64), (2, 64), (4, 64)]
+        assert ds.in_steady_state() and ds.warmup_compiles() > 0
+        search_each_bucket(emb, idx)
+        assert ds.post_warmup_compiles() == 0
+        assert ds.violations() == []
+    finally:
+        ds._reset_for_tests()
+
+
+def test_a_search_s_coverage_is_listed_once_an_established_extent():
+    """Every search records the pages it scanned (the result cache's
+    coverage): the set is made when an extent is established, not at
+    every search."""
+    idx = _mk(metric=KnnMetric.COS)
+    rng = np.random.default_rng(3)
+    vecs = rng.normal(size=(1400, 8)).astype(np.float32)
+    idx.add_batch([Pointer(i) for i in range(600)], vecs[:600])
+    q = [(Pointer(10**9), vecs[5], 3, None)]
+    idx.search(q)
+    first = idx._pool.touched_page_ids()
+    assert first == frozenset(range(idx.capacity // page_rows()))
+    idx.search(q)
+    assert idx._pool.touched_page_ids() is first
+    idx.add_batch([Pointer(i) for i in range(600, 1400)], vecs[600:])
+    assert _extents(idx) == 2 and idx._pool.touched_page_ids() is first
+    idx.search(q)  # the flush establishes the second extent
+    assert idx._pool.touched_page_ids() == frozenset(
+        range(idx.capacity // page_rows()))
+    if idx.result_cache is not None:
+        assert idx.last_search_coverage is idx._pool.touched_page_ids()
