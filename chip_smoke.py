@@ -172,12 +172,14 @@ def _chunked_scan_check(rows: int, dim: int) -> None:
 def _decoder_check(cfg, row: int, seed: int, what: str) -> dict:
     """One forward of a decoder whose attention is the blocked core over
     packed rows (models/decoder.py, ops/attention.py ``segment_attention``:
-    window and full attention mixed, or latent attention) at ``cfg``'s
+    window and full attention mixed, latent attention, or latent attention
+    over an indexer's choice of keys) at ``cfg``'s
     widths on packed rows of ``row`` slots, weights drawn on the device in
     the compute dtype: a long document (longer than the window, where there
     is one) alone in a row, then behind two others. Finite unit embeddings,
     and the document's embedding does not depend on where it lies; on a TPU
-    with values of whole lanes the attention took the kernel."""
+    with values of whole lanes the attention took the kernel (of a model
+    that chooses its keys: the sparse one)."""
     import jax
     import jax.numpy as jnp
 
@@ -210,8 +212,8 @@ def _decoder_check(cfg, row: int, seed: int, what: str) -> dict:
     alone, _ = forward(params, *map(jnp.asarray, packed([long_doc])))
     behind, _ = forward(params, *map(jnp.asarray,
                                      packed(others + [long_doc])))
-    took = {k: v - before[k] for k, v in attention.attention_lowerings(
-        ).items()}
+    took = {k: v - before.get(k, 0)
+            for k, v in attention.attention_lowerings().items()}
     alone, behind = np.asarray(alone, np.float32), np.asarray(
         behind, np.float32)
     _check(np.isfinite(alone).all() and np.isfinite(behind).all()
@@ -224,10 +226,11 @@ def _decoder_check(cfg, row: int, seed: int, what: str) -> dict:
            f"others in its row: cos {cos:.5f} >= 0.98")
     lanes = cfg.v_head_dim if cfg.attention_method else cfg.head_dim
     kernel = jax.devices()[0].platform == "tpu" and lanes % 128 == 0
-    _check(took["blockwise" if kernel else "kernel"] == 0
-           and took["kernel" if kernel else "blockwise"] > 0,
-           f"attention lowered as the "
-           f"{'kernel' if kernel else 'blockwise loop'}: {took}")
+    sparse = "sparse_" if cfg.attention_index else ""
+    wanted = sparse + ("kernel" if kernel else "blockwise")
+    _check(took[wanted] > 0 and not any(
+        n for name, n in took.items() if name != wanted),
+           f"attention lowered as the {wanted.replace('_', ' ')}: {took}")
     del params
     return {"row": row, "layers": cfg.num_hidden_layers,
             "hidden": cfg.hidden_size, "experts": cfg.num_experts,
@@ -425,7 +428,8 @@ def run_smoke(*, expected_platform: str, config, n_docs: int,
               request_timeout_s: int = 600,
               out_path: str | None = None, decoder_config=None,
               decoder_row: int = 0, latent_config=None,
-              latent_row: int = 0) -> dict:
+              latent_row: int = 0, indexed_config=None,
+              indexed_row: int = 0) -> dict:
     """Drive the main path once and check it; returns the summary dict
     (also printed). Raises on the first failed check.
 
@@ -440,7 +444,8 @@ def run_smoke(*, expected_platform: str, config, n_docs: int,
     returning a ``DecoderConfig`` of the windowed pattern, forwarded once
     on rows of ``decoder_row`` slots after the server is down (None: no
     such check); ``latent_config``, ``latent_row``: the same for a
-    ``DecoderConfig`` of the latent attention pattern."""
+    ``DecoderConfig`` of the latent attention pattern; ``indexed_config``,
+    ``indexed_row``: of the pattern that chooses its keys."""
     t_start = time.perf_counter()
     import jax
 
@@ -501,6 +506,8 @@ def run_smoke(*, expected_platform: str, config, n_docs: int,
             if decoder_config else None
         latent = _decoder_check(latent_config(), latent_row, seed,
                                 "latent") if latent_config else None
+        indexed = _decoder_check(indexed_config(), indexed_row, seed,
+                                 "indexed") if indexed_config else None
     finally:
         jax.monitoring.unregister_event_duration_listener(on_jit_event)
 
@@ -528,6 +535,7 @@ def run_smoke(*, expected_platform: str, config, n_docs: int,
         "bf16_vs_f32_min_cos": round(min_cos, 6),
         "windowed_decoder": windowed,
         "latent_decoder": latent,
+        "indexed_decoder": indexed,
         "topk_digest": hashlib.sha256(json.dumps(sorted(
             (q, [name for name, _dist in hits])
             for q, hits in answers.items())).encode()).hexdigest()[:16],
@@ -593,6 +601,31 @@ def main() -> int:
                 experts_held=(0, 16), max_len=8192,
                 compute_dtype=jnp.bfloat16)
 
+        def glm_layers():
+            """A dense layer with an indexer and an expert layer that
+            shares its choice, of GLM-5.2 at its published widths, 16 of
+            its 256 experts held: benchmark/configs/glm-5.2-embed.json
+            (1.21 billion parameters, 2.4 GB in bfloat16)."""
+            import jax.numpy as jnp
+
+            from pathway_tpu.models.decoder import DecoderConfig
+
+            return DecoderConfig(
+                vocab_size=19360, hidden_size=6144, num_hidden_layers=2,
+                rms_norm_eps=1e-5, zero_centred_norm=False,
+                num_attention_heads=64, rope_theta=8e6,
+                attention_method="MLA", q_lora_rank=2048, kv_lora_rank=512,
+                qk_nope_head_dim=192, qk_rope_head_dim=64, v_head_dim=256,
+                mla_scale_q_lora=False, mla_scale_kv_lora=False,
+                ffn_hidden_size=12288, mlp_layer_types=("dense", "sparse"),
+                indexer_types=("full", "shared"), index_topk=2048,
+                index_n_heads=32, index_head_dim=128, num_experts=256,
+                num_experts_per_tok=8, moe_intermediate_size=2048,
+                shared_expert_intermediate_size=None, n_shared_experts=1,
+                scoring_func="sigmoid", routed_scaling_factor=2.5,
+                experts_held=(0, 16), max_len=16384,
+                compute_dtype=jnp.bfloat16)
+
         summary = run_smoke(
             expected_platform="tpu", config=bge_small, n_docs=3000,
             max_words=120, max_len=128, scan_rows=1 << 20,
@@ -600,7 +633,8 @@ def main() -> int:
             out_path=os.path.join(out_dir,
                                   f"chip_smoke_topk_{n_devices}chip.json"),
             decoder_config=smallthinker_period, decoder_row=16384,
-            latent_config=longcat_layer, latent_row=8192)
+            latent_config=longcat_layer, latent_row=8192,
+            indexed_config=glm_layers, indexed_row=16384)
     except Exception:  # any failed check or error: exit != 0, no JSON line
         import traceback
 

@@ -741,6 +741,18 @@ class MonitoringHttpServer:
                              f"{experts['zero_pairs']}")
                 lines.append("# TYPE pathway_tpu_moe_pairs counter")
                 lines.append(f"pathway_tpu_moe_pairs {experts['pairs']}")
+            if "visible_pairs" in experts:
+                # a model that chooses the keys a query attends over: the
+                # (query, key) pairs attended, of those visible (the
+                # device's own counts, every attention layer)
+                lines.append(
+                    "# TYPE pathway_tpu_attention_pairs_selected counter")
+                lines.append(f"pathway_tpu_attention_pairs_selected "
+                             f"{experts['selected_pairs']}")
+                lines.append(
+                    "# TYPE pathway_tpu_attention_pairs_visible counter")
+                lines.append(f"pathway_tpu_attention_pairs_visible "
+                             f"{experts['visible_pairs']}")
         scans = _scan_lowerings()
         if scans is not None:
             # which lowering the delta-rule scans of the compiled programs

@@ -26,7 +26,17 @@ of many, weights renormalised over the k). What a layer does follows from
   residual only at the layer's end (:func:`shortcut_layer`); the router's
   last ``zero_expert_num`` outputs are identity experts, its choice is over
   the probabilities plus a correction bias, its weights are the
-  probabilities themselves times ``routed_scaling_factor``.
+  probabilities themselves times ``routed_scaling_factor``;
+- GLM-5.2 (``zai-org/GLM-5.2``, ``glm_moe_dsa``; ``attention_method="MLA"``
+  with ``indexer_types``): plain norms; a layer is one latent attention
+  **over a learned choice of keys** and one feed-forward
+  (:func:`indexed_layer`), dense or routed by ``mlp_layer_types[i]``; where
+  ``indexer_types[i]`` is ``"full"`` the layer's indexer ranks every visible
+  key for every query and keeps ``index_topk`` of them, where it is
+  ``"shared"`` the layer attends over the choice of the nearest ``"full"``
+  layer before it; the router scores with a sigmoid, chooses over the scores
+  plus a correction bias, renormalises the chosen scores and scales them;
+  the shared expert is added with no gate.
 
 - one layer function per kind: :func:`deltanet_layer`,
   :func:`attention_layer`, :func:`latent_attention_layer`,
@@ -66,13 +76,18 @@ from pathway_tpu.ops import attention, deltanet, moe
 @dataclasses.dataclass(frozen=True)
 class LayerKind:
     """What the mixer of one layer is: ``"deltanet"``, ``"attention"``
-    with its window (None: full) and whether it rotates q and k, or
+    with its window (None: full) and whether it rotates q and k,
     ``"latent"``: the shortcut-connected layer of two latent attention
-    sublayers."""
+    sublayers, or ``"indexed"``: latent attention over a learned choice of
+    keys, which the layer's own indexer makes (``indexer`` ``"full"``) or
+    the nearest such layer before it made (``"shared"``), before a
+    feed-forward that is ``"dense"`` or ``"sparse"`` (routed experts)."""
 
     mixer: str
     window: int | None = None
     rotary: bool = True
+    indexer: str | None = None
+    mlp: str = "sparse"
 
 
 @dataclasses.dataclass(frozen=True)
@@ -115,8 +130,24 @@ class DecoderConfig:
     v_head_dim: int = 128
     mla_scale_q_lora: bool = True
     mla_scale_kv_lora: bool = True
-    #: the width of each of a latent layer's two dense feed-forwards
+    #: the width of each of a latent layer's two dense feed-forwards, and
+    #: of an indexed layer's dense one
     ffn_hidden_size: int = 12288
+    #: given (with ``attention_method="MLA"``), every layer is
+    #: :func:`indexed_layer`: its feed-forward ``"dense"`` or ``"sparse"``
+    #: by ``mlp_layer_types[i]`` (None: all sparse), its choice of keys its
+    #: own indexer's (``"full"``: ``index_n_heads`` heads of
+    #: ``index_head_dim`` features rank the visible keys of every query, the
+    #: ``index_topk`` best are attended over) or the nearest full layer's
+    #: before it (``"shared"``)
+    indexer_types: tuple[str, ...] | None = None
+    mlp_layer_types: tuple[str, ...] | None = None
+    index_topk: int = 2048
+    index_n_heads: int = 32
+    index_head_dim: int = 128
+    #: the leading dense layers of the published model: kept as published
+    #: and not read (``mlp_layer_types`` is the list it expands to)
+    first_k_dense_replace: int = 0
     # Gated DeltaNet
     linear_num_key_heads: int = 16
     linear_num_value_heads: int = 32
@@ -129,7 +160,13 @@ class DecoderConfig:
     moe_intermediate_size: int = 512
     #: None: no shared expert
     shared_expert_intermediate_size: int | None = 512
+    #: given, the shared expert is ``n_shared_experts`` experts' width of
+    #: ``moe_intermediate_size`` and is added with no gate
+    n_shared_experts: int | None = None
     norm_topk_prob: bool = True
+    #: the router's scores: ``"softmax"`` over its outputs, ``"sigmoid"`` of
+    #: each alone
+    scoring_func: str = "softmax"
     #: router outputs behind the ``num_experts`` with weights that return
     #: their input (``zero_expert_type`` "identity"): no product, no weights
     zero_expert_num: int = 0
@@ -169,14 +206,32 @@ class DecoderConfig:
         """Experts with weights and identity experts together."""
         return self.num_experts + self.zero_expert_num
 
+    @property
+    def shared_width(self) -> int | None:
+        """The shared expert's width, None where the model has none."""
+        if self.n_shared_experts is not None:
+            return self.n_shared_experts * self.moe_intermediate_size
+        return self.shared_expert_intermediate_size
+
     def layer_kind(self, layer: int) -> LayerKind:
+        if self.attention_method == "MLA" and self.indexer_types is not None:
+            indexer = self.indexer_types[layer]
+            if indexer not in ("full", "shared") \
+                    or "full" not in self.indexer_types[:layer + 1]:
+                raise ValueError(
+                    f"indexer_types[{layer}] {indexer!r}: \"full\", or "
+                    f"\"shared\" behind a \"full\" layer, is what runs")
+            mlp = "sparse" if self.mlp_layer_types is None \
+                else self.mlp_layer_types[layer]
+            return LayerKind("indexed", None, True, indexer, mlp)
         if self.attention_method is not None:
             if (self.attention_method, self.zero_expert_type) \
                     != ("MLA", "identity"):
                 raise ValueError(
                     f"attention_method {self.attention_method!r} with "
-                    f"zero_expert_type {self.zero_expert_type!r}: "
-                    f"\"MLA\" with \"identity\" is what runs")
+                    f"zero_expert_type {self.zero_expert_type!r} and no "
+                    f"indexer_types: \"MLA\" with \"identity\", or with "
+                    f"indexer_types, is what runs")
             return LayerKind("latent", None, True)
         if self.sliding_window_layout is not None:
             rotary = self.rope_layout is None or bool(self.rope_layout[layer])
@@ -195,6 +250,15 @@ class DecoderConfig:
         kinds = map(self.layer_kind, range(self.num_hidden_layers))
         return tuple(k.window for k in kinds if k.mixer != "deltanet"
                      for _ in range(2 if k.mixer == "latent" else 1))
+
+    @property
+    def attention_index(self) -> tuple[int, int] | None:
+        """(``index_topk``, the layers that hold an indexer) where the
+        model chooses its keys (the packer counts the indexers' and the
+        cores' work from it), else None."""
+        if self.attention_method != "MLA" or self.indexer_types is None:
+            return None
+        return self.index_topk, self.indexer_types.count("full")
 
     @staticmethod
     def tiny(**kw) -> "DecoderConfig":
@@ -239,6 +303,27 @@ class DecoderConfig:
                     num_experts_per_tok=3, zero_expert_num=4,
                     routed_scaling_factor=6.0, norm_topk_prob=False,
                     shared_expert_intermediate_size=None)
+        base.update(kw)
+        return DecoderConfig.tiny(**base)
+
+    @staticmethod
+    def tiny_indexed(**kw) -> "DecoderConfig":
+        """Small config of the GLM-5.2 pattern: a dense layer with an
+        indexer, two expert layers that share its choice and one that
+        chooses again; latent attention (4 heads, keys 16 + 8, values 32)
+        over the 24 best keys of 2 index heads of 16 features; 8 experts
+        top-2 behind a sigmoid router with a correction bias, renormalised
+        and scaled by 2.5, one ungated shared expert."""
+        base = dict(zero_centred_norm=False, rms_norm_eps=1e-5,
+                    attention_method="MLA", q_lora_rank=24, kv_lora_rank=16,
+                    qk_nope_head_dim=16, qk_rope_head_dim=8, v_head_dim=32,
+                    mla_scale_q_lora=False, mla_scale_kv_lora=False,
+                    ffn_hidden_size=96,
+                    mlp_layer_types=("dense", "sparse", "sparse", "sparse"),
+                    indexer_types=("full", "shared", "shared", "full"),
+                    index_topk=24, index_n_heads=2, index_head_dim=16,
+                    scoring_func="sigmoid", routed_scaling_factor=2.5,
+                    shared_expert_intermediate_size=None, n_shared_experts=1)
         base.update(kw)
         return DecoderConfig.tiny(**base)
 
@@ -289,13 +374,15 @@ def init_params(key, config: DecoderConfig, dtype=jnp.float32) -> dict:
             (n,), jnp.float32)
 
     def experts():
-        f, fs = c.moe_intermediate_size, c.shared_expert_intermediate_size
+        f, fs = c.moe_intermediate_size, c.shared_width
         tree = {"router": dense(h, c.router_outputs),
                 "gate": dense(hi - lo, h, f), "up": dense(hi - lo, h, f),
                 "down": dense(hi - lo, f, h)}
         if fs is not None:
             tree.update(shared_gate=dense(h, fs), shared_up=dense(h, fs),
-                        shared_down=dense(fs, h), shared_router=dense(h, 1))
+                        shared_down=dense(fs, h))
+            if c.n_shared_experts is None:
+                tree["shared_router"] = dense(h, 1)
         return tree
 
     def latent():
@@ -312,9 +399,29 @@ def init_params(key, config: DecoderConfig, dtype=jnp.float32) -> dict:
                 "up": dense(h, c.ffn_hidden_size),
                 "down": dense(c.ffn_hidden_size, h)}
 
+    def indexer():
+        ni, di = c.index_n_heads, c.index_head_dim
+        return {"q_b": dense(c.q_lora_rank, ni * di), "k": dense(h, di),
+                "k_norm": jnp.ones((di,), jnp.float32),
+                "k_bias": jnp.zeros((di,), jnp.float32), "w": dense(h, ni)}
+
     layers = []
     for i in range(c.num_hidden_layers):
-        if c.layer_kind(i).mixer == "latent":
+        kind = c.layer_kind(i)
+        if kind.mixer == "indexed":
+            layer = {"norm1": norm(h), "norm2": norm(h), "mixer": latent()}
+            if kind.indexer == "full":
+                layer["mixer"]["indexer"] = indexer()
+            if kind.mlp == "dense":
+                layer["ffn"] = ffn()
+            else:
+                # a tenth of the latent family's deviation: a sigmoid's
+                # scores lie closer together than a softmax's largest
+                layer["moe"] = dict(experts(), bias=0.001 * jax.random.normal(
+                    next(keys), (c.router_outputs,), jnp.float32))
+            layers.append(layer)
+            continue
+        if kind.mixer == "latent":
             moe_tree = experts()
             # the correction bias: small beside a chosen probability
             moe_tree["bias"] = 0.01 * jax.random.normal(
@@ -466,6 +573,117 @@ def latent_attention_layer(x, p, pos, seg, config: DecoderConfig):
     return _proj(o.reshape(b, t, nh * dv), p["o"], c)
 
 
+#: heads whose queries, keys and values an indexed layer expands at a time.
+#: All 64 heads of a row of 16,384 tokens at once are 2.0 GB of bfloat16
+#: queries, keys and values beside 1.1 GB of float32 output; a quarter of
+#: them at a time leaves the chip room for the weights and the index
+HEAD_GROUP = 16
+
+
+def _layer_norm(x, w, b, eps: float = 1e-6):
+    """LayerNorm over the last axis, float32."""
+    xf = x.astype(jnp.float32)
+    xf = xf - jnp.mean(xf, axis=-1, keepdims=True)
+    xf = xf * jax.lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True) + eps)
+    return xf * w.astype(jnp.float32) + b.astype(jnp.float32)
+
+
+def _rotary_first(x, pos, rotary_dim: int, theta: float):
+    """:func:`_rotary_pairs` over the first ``rotary_dim`` features of the
+    last axis, the rest as they are."""
+    return jnp.concatenate(
+        [_rotary_pairs(x[..., :rotary_dim], pos, theta),
+         x[..., rotary_dim:]], axis=-1)
+
+
+def choose_keys(x, q_latent, p, pos, seg, config: DecoderConfig):
+    """A layer's indexer: which keys each query attends over
+    (``ops/attention.py`` ``select_keys``). Index queries of
+    ``index_n_heads`` heads from the queries' normed latent ``q_latent``,
+    one LayerNormed index key a token and the heads' weights from the
+    layer's input ``x``, queries and key rotated over their first
+    ``qk_rope_head_dim`` features; products in the compute dtype, the
+    weights, ReLU, sum and choice float32. Returns (the choice, the float32
+    count of chosen pairs)."""
+    c = config
+    b, t, _ = x.shape
+    ni, di, rot = c.index_n_heads, c.index_head_dim, c.qk_rope_head_dim
+    cd = c.compute_dtype
+    q = _rotary_first(_proj(q_latent, p["q_b"], c).reshape(b, t, ni, di),
+                      pos, rot, c.rope_theta)
+    k = _rotary_first(_layer_norm(_proj(x, p["k"], c), p["k_norm"],
+                                  p["k_bias"]), pos, rot, c.rope_theta)
+    w = _proj(x, p["w"], c) * (ni ** -0.5 * di ** -0.5)
+    return attention.select_keys(q.astype(cd), k.astype(cd), w, seg, pos,
+                                 topk=c.index_topk)
+
+
+def indexed_attention_layer(x, p, pos, seg, config: DecoderConfig,
+                            choice=None):
+    """Latent attention (:func:`latent_attention_layer`'s form: unabsorbed,
+    one rotary key a token) over a learned choice of keys: the layer's own
+    indexer's (``p["indexer"]``, where ``choice`` is None) or the one handed
+    in, which an earlier layer made. :data:`HEAD_GROUP` heads at a time:
+    their queries, keys and values are expanded, attended and projected
+    back before the next group's. Returns (y (B, T, H) float32, the choice
+    attended over, the float32 count of its chosen pairs or None where it
+    was handed in)."""
+    c = config
+    b, t, h = x.shape
+    nh, dn, dr, dv = (c.num_attention_heads, c.qk_nope_head_dim,
+                      c.qk_rope_head_dim, c.v_head_dim)
+    cd = c.compute_dtype
+    with jax.named_scope("decoder.attention.latent"):
+        q_latent = _rms_norm(_proj(x, p["q_a"], c), p["q_norm"],
+                             c.rms_norm_eps, c.zero_centred_norm)
+        if c.mla_scale_q_lora:
+            q_latent = q_latent * (h / c.q_lora_rank) ** 0.5
+        kv = _proj(x, p["kv_a"], c)
+        latent = _rms_norm(kv[..., :c.kv_lora_rank], p["kv_norm"],
+                           c.rms_norm_eps, c.zero_centred_norm)
+        if c.mla_scale_kv_lora:
+            latent = latent * (h / c.kv_lora_rank) ** 0.5
+        k_rope = _rotary_pairs(kv[..., c.kv_lora_rank:], pos,
+                               c.rope_theta).astype(cd)
+    chosen = None
+    if choice is None:
+        with jax.named_scope("decoder.attention.index"):
+            choice, chosen = choose_keys(x, q_latent, p["indexer"], pos, seg,
+                                         c)
+
+    def heads(y, ws):
+        """``y`` plus what the heads of ``ws`` (their columns of ``q_b``
+        and ``kv_b``, their rows of ``o``) give."""
+        q_b, kv_b, o_w = ws
+        g = o_w.shape[0] // dv
+        with jax.named_scope("decoder.attention.latent"):
+            q = _proj(q_latent, q_b, c).reshape(b, t, g, dn + dr)
+            kv_heads = _proj(latent, kv_b, c).astype(cd).reshape(
+                b, t, g, dn + dv)
+            q_rope = _rotary_pairs(q[..., dn:], pos, c.rope_theta)
+        with jax.named_scope("decoder.attention.sparse"):
+            o = attention.latent_attention(
+                q[..., :dn].astype(cd), q_rope.astype(cd),
+                kv_heads[..., :dn], k_rope, kv_heads[..., dn:], seg, pos,
+                scale=(dn + dr) ** -0.5, choice=choice)
+        return y + _proj(o.reshape(b, t, g * dv), o_w, c)
+
+    groups = nh // HEAD_GROUP if nh % HEAD_GROUP == 0 else 1
+    weights = (p["q_b"], p["kv_b"], p["o"])
+    y = jnp.zeros((b, t, h), jnp.float32)
+    if groups == 1:
+        return heads(y, weights), choice, chosen
+    # a group's columns of the two expansions side by side, (groups, rank,
+    # heads x width); ``o``'s rows are a group's already
+    by_group = lambda w: jnp.swapaxes(
+        w.reshape(w.shape[0], groups, -1), 0, 1)
+    y, _ = jax.lax.scan(
+        lambda y, ws: (heads(y, ws), None), y,
+        (by_group(p["q_b"]), by_group(p["kv_b"]),
+         p["o"].reshape(groups, -1, h)))
+    return y, choice, chosen
+
+
 def dense_ffn(x, p, config: DecoderConfig):
     """A dense gated feed-forward, ``W_down(act(W_gate x) * (W_up x))``."""
     hidden = moe.ACTIVATIONS[config.hidden_act](
@@ -527,7 +745,7 @@ def moe_layer(x, p, valid, config: DecoderConfig, router_x=None):
         weights, experts = moe.route(
             flat if router_x is None else router_x.reshape(b * t, h),
             p["router"], c.num_experts_per_tok, c.norm_topk_prob,
-            p.get("bias"), c.routed_scaling_factor)
+            p.get("bias"), c.routed_scaling_factor, c.scoring_func)
     with jax.named_scope("decoder.moe.experts"):
         y, load = moe.grouped_experts(
             flat, weights, experts, p["gate"], p["up"], p["down"], c.held,
@@ -539,13 +757,16 @@ def moe_layer(x, p, valid, config: DecoderConfig, router_x=None):
                 moe.identity_part(flat, weights, experts, c.num_experts,
                                   valid.reshape(b * t))
             y = y + same
-    if c.shared_expert_intermediate_size is not None:
+    if c.shared_width is not None:
         with jax.named_scope("decoder.moe.shared"):
             hidden = moe.ACTIVATIONS[c.hidden_act](
                 _proj(flat, p["shared_gate"], c)) \
                 * _proj(flat, p["shared_up"], c)
-            y = y + _proj(hidden, p["shared_down"], c) * jax.nn.sigmoid(
-                _proj(flat, p["shared_router"], c))
+            shared = _proj(hidden, p["shared_down"], c)
+            if c.n_shared_experts is None:
+                shared = shared * jax.nn.sigmoid(
+                    _proj(flat, p["shared_router"], c))
+            y = y + shared
     return y.reshape(b, t, h), counters
 
 
@@ -577,22 +798,65 @@ def shortcut_layer(x, p, pos, seg, valid, config: DecoderConfig):
     return x + shortcut, counters
 
 
+def indexed_layer(x, p, pos, seg, valid, config: DecoderConfig,
+                  kind: LayerKind, choice=None):
+    """One layer of the GLM-5.2 pattern, plain pre-norm::
+
+        h = x + MLA(norm1(x))        over the layer's choice of keys
+        y = h + FFN(norm2(h))        dense, or routed experts and a shared one
+
+    ``choice``: the nearest full layer's before this one, which a
+    ``"shared"`` layer attends over (a ``"full"`` layer makes its own and
+    does not read it). Returns (y (B, T, H) float32, the expert layer's
+    counters or None for a dense layer, the choice attended over, the count
+    of its chosen pairs)."""
+    c = config
+    norm = lambda x, w: _rms_norm(x, w, c.rms_norm_eps, c.zero_centred_norm)
+    with jax.named_scope("decoder.attention"):
+        y, choice, chosen = indexed_attention_layer(
+            norm(x, p["norm1"]), p["mixer"], pos, seg, c,
+            choice if kind.indexer == "shared" else None)
+        x = x + y
+    normed = norm(x, p["norm2"])
+    if kind.mlp == "dense":
+        with jax.named_scope("decoder.ffn"):
+            return x + dense_ffn(normed, p["ffn"], c), None, choice, chosen
+    y, counters = moe_layer(normed, p["moe"], valid, c)
+    return x + y, counters, choice, chosen
+
+
 def _forward(params, token_ids, pos, seg, config: DecoderConfig):
     """Embedding + stack -> (final-norm hidden states (B, T, H) float32,
     the expert layers' counters summed over the layers:
     ``{"tokens_per_expert": (held,) int32, "buffer": (3,) float32
     [executions, those at the full length, pair-buffer rows]}`` and, for a
     router with identity experts, ``"zero_pairs"`` and ``"pairs"`` (float32
-    scalars), which an embedder sums as its ``aux``)."""
+    scalars), and for a model that chooses its keys ``"selected_pairs"``
+    and ``"visible_pairs"``: the (query, key) pairs its attention layers
+    attended over, counted from the choices themselves, and the pairs they
+    could see (float32 scalars), which an embedder sums as its ``aux``)."""
     c = config
     norm = lambda x, w: _rms_norm(x, w, c.rms_norm_eps, c.zero_centred_norm)
     with jax.named_scope("decoder.embed"):
         x = params["embed"][token_ids].astype(jnp.float32)
     valid = seg >= 0
     counters = None
+    # the choice of keys the last full indexer made, the count of its
+    # pairs, and the pairs attended over so far
+    choice = chosen = None
+    selected = jnp.float32(0.0)
     for i, layer in enumerate(params["layers"]):
         kind = c.layer_kind(i)
-        if kind.mixer == "latent":
+        if kind.mixer == "indexed":
+            # a layer's weights wait for its input, as a latent layer's
+            x, layer = jax.lax.optimization_barrier((x, layer))
+            x, used, choice, made = indexed_layer(x, layer, pos, seg, valid,
+                                                  c, kind, choice)
+            chosen = chosen if made is None else made
+            selected = selected + chosen
+            if used is None:
+                continue
+        elif kind.mixer == "latent":
             # a layer's weights wait for the layer's input. Left free, the
             # TPU's compiler re-lays every layer's projection weights at
             # the program's start and keeps all the copies: 4.0 GiB of
@@ -618,6 +882,13 @@ def _forward(params, token_ids, pos, seg, config: DecoderConfig):
             x = x + y
         counters = used if counters is None else jax.tree.map(
             jnp.add, counters, used)
+    if choice is not None and counters is not None:
+        # beside the expert layers' counters (a model with none returns no
+        # ``aux`` at all)
+        visible = len(c.attention_windows) * jnp.sum(
+            jnp.where(valid, pos + 1, 0).astype(jnp.float32))
+        counters = dict(counters, selected_pairs=selected,
+                        visible_pairs=visible)
     return norm(x, params["final_norm"]), counters
 
 

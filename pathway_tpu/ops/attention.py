@@ -126,6 +126,15 @@ def flash_attention(q, k, v, mask, *, interpret: bool = False):
 # differ in width and the scale may be given: latent attention in prefill
 # (:func:`latent_attention`) is this core over keys of 128 + 64 features
 # and values of 128.
+#
+# **A learned choice of keys.** Where a model ranks the keys a query sees
+# (:func:`select_keys`: a small scorer over every visible pair, and of each
+# query's visible keys the ``topk`` best, exactly), the core takes the
+# choice as one more operand: a mask of the row, (T, T) int8, which outlives
+# the layer that made it (the layers after it attend over the same choice),
+# and a flag a tile that says whether anything in it was chosen. Both
+# lowerings read the mask's tile beside their ranges and skip a tile with
+# nothing chosen.
 
 #: the running max a row starts from, and a masked score: both large and
 #: finite, so that nothing makes a NaN, and the masked one the lower, so
@@ -176,8 +185,8 @@ def _max_steps(t: int, window, bq: int, bk: int) -> int:
     return min(t // bk, -(-(window - 1 + bq) // bk) + 1)
 
 
-def attention_work(seg: np.ndarray, pos: np.ndarray,
-                   windows: tuple) -> dict:
+def attention_work(seg: np.ndarray, pos: np.ndarray, windows: tuple,
+                   index_topk: int | None = None, indexers: int = 0) -> dict:
     """What the attention layers of one dispatch have to do, counted on
     the host from the packed rows (``seg``, ``pos`` (B, T) as the packer
     made them; ``windows``: each attention layer's window, None for full
@@ -186,7 +195,12 @@ def attention_work(seg: np.ndarray, pos: np.ndarray,
     ``attn_pairs_window`` of a window layer (where the model has one),
     ``attn_tiles_run`` the key blocks the kernel's ranges admit and
     ``attn_tiles_all`` all key blocks up to the diagonal, both summed over
-    query blocks and attention layers."""
+    query blocks and attention layers. Where the model chooses its keys
+    (``index_topk``; ``indexers``: the layers that hold a scorer):
+    ``attn_pairs_indexed`` the pairs the scorers rank (every visible pair, a
+    scorer) and ``attn_pairs_selected`` the pairs the cores attend over (a
+    query's visible keys or ``index_topk``, the fewer, an attention
+    layer)."""
     t = seg.shape[1]
     bq, bk, padded = block_sizes(t)
     if padded != t:
@@ -195,6 +209,10 @@ def attention_work(seg: np.ndarray, pos: np.ndarray,
     real = seg >= 0
     reach = (pos.astype(np.int64) + 1)[real]
     out = {"attn_pairs_full": int(reach.sum())}
+    if index_topk is not None:
+        out["attn_pairs_indexed"] = indexers * out["attn_pairs_full"]
+        out["attn_pairs_selected"] = len(windows) * int(
+            np.minimum(reach, index_topk).sum())
     to_diagonal = seg.shape[0] * int(
         ((np.arange(1, padded // bq + 1) * bq - 1) // bk + 1).sum())
     out["attn_tiles_run"], out["attn_tiles_all"] = 0, 0
@@ -211,9 +229,14 @@ def attention_work(seg: np.ndarray, pos: np.ndarray,
 def attention_lowerings() -> dict:
     """Attention cores lowered in this process by the lowering they took:
     ``kernel`` (the Pallas TPU kernel) or ``blockwise`` (plain JAX), one
-    count a call of a compiled program (``ops/lowering_count.py``).
-    ``/metrics`` shows it as ``pathway_tpu_attention_programs``."""
-    return lowering_count.counts("attention", ("kernel", "blockwise"))
+    count a call of a compiled program (``ops/lowering_count.py``), and,
+    once a program holds a core over a learned choice of keys,
+    ``sparse_kernel`` and ``sparse_blockwise`` for those. ``/metrics``
+    shows it as ``pathway_tpu_attention_programs``."""
+    sparse = lowering_count.counts("attention", ("sparse_kernel",
+                                                 "sparse_blockwise"))
+    return dict(lowering_count.counts("attention", ("kernel", "blockwise")),
+                **(sparse if any(sparse.values()) else {}))
 
 
 #: features a vector register of the chip holds side by side
@@ -230,7 +253,7 @@ def _kernel_tiles(v_shape: tuple, padded: int) -> bool:
 
 @functools.partial(jax.jit, static_argnames=("window", "scale"))
 def segment_attention(q, k, v, seg, pos, *, window: int | None = None,
-                      scale: float | None = None):
+                      scale: float | None = None, choice=None):
     """Causal softmax attention inside each document of packed rows, with
     grouped heads and an optional window.
 
@@ -242,7 +265,9 @@ def segment_attention(q, k, v, seg, pos, *, window: int | None = None,
     order). ``visible(t, s) = seg[t] == seg[s] >= 0 and s <= t and
     (window is None or pos[t] - pos[s] < window)``; scores are scaled by
     ``scale`` (None: ``d ** -0.5``). Returns (B, T, nh, dv) float32, defined
-    at the real slots (padding reads zeros).
+    at the real slots (padding reads zeros). ``choice``
+    (:func:`select_keys`'s): the keys each query attends over, of those it
+    sees; the softmax runs over them alone.
 
     One algorithm, two lowerings: lowered for a TPU at the shapes of
     :func:`_kernel_tiles` it is :func:`_segment_kernel`, on every other
@@ -263,29 +288,35 @@ def segment_attention(q, k, v, seg, pos, *, window: int | None = None,
     sizes = dict(window=window, bq=bq, bk=bk,
                  scale=d ** -0.5 if scale is None else scale)
 
-    def blockwise(q, k, v, seg, pos, lo, count):
-        return lowering_count.took(
-            _blockwise(q, k, v, seg, pos, lo, count, **sizes),
-            "attention", "blockwise")
+    # a core over a choice is counted under a name of its own
+    name = "" if choice is None else "sparse_"
 
+    def blockwise(q, k, v, seg, pos, lo, count, *choice):
+        return lowering_count.took(
+            _blockwise(q, k, v, seg, pos, lo, count, *choice, **sizes),
+            "attention", name + "blockwise")
+
+    choice = () if choice is None else (tuple(choice),)
     if not _kernel_tiles(v.shape, padded):
-        out = blockwise(q, k, v, seg, pos, lo, count)
+        out = blockwise(q, k, v, seg, pos, lo, count, *choice)
     else:
-        def kernel(q, k, v, seg, pos, lo, count):
+        def kernel(q, k, v, seg, pos, lo, count, *choice):
             if d % LANES:
                 grow = ((0, 0),) * 3 + ((0, -d % LANES),)
                 q, k = jnp.pad(q, grow), jnp.pad(k, grow)
             return lowering_count.took(
-                _segment_kernel(q, k, v, seg, pos, lo, count, **sizes),
-                "attention", "kernel")
+                _segment_kernel(q, k, v, seg, pos, lo, count, *choice,
+                                **sizes),
+                "attention", name + "kernel")
 
         out = jax.lax.platform_dependent(q, k, v, seg, pos, lo, count,
-                                         tpu=kernel, default=blockwise)
+                                         *choice, tpu=kernel,
+                                         default=blockwise)
     return out[:, :t]
 
 
 def latent_attention(q_nope, q_rope, k_nope, k_rope, v, seg, pos, *,
-                     scale: float):
+                     scale: float, choice=None):
     """The core of multi-head latent attention in prefill (the unabsorbed
     form: keys and values expanded a head), causal inside each document of
     packed rows: ``score[t, s, head] = (q_nope[t, head] . k_nope[s, head] +
@@ -299,13 +330,124 @@ def latent_attention(q_nope, q_rope, k_nope, k_rope, v, seg, pos, *,
     dn + dr wide, and the core is :func:`segment_attention`'s with no
     grouping: its kernel on the chip where the values fill whole lanes (the
     keys' 192 features padded with zeros to 256), the blockwise loop
-    elsewhere."""
+    elsewhere. ``choice``: :func:`segment_attention`'s."""
     b, t, nh, _ = q_nope.shape
     shared = jnp.broadcast_to(k_rope[:, :, None, :],
                               (b, t, nh, k_rope.shape[-1]))
     return segment_attention(
         jnp.concatenate([q_nope, q_rope], axis=-1),
-        jnp.concatenate([k_nope, shared], axis=-1), v, seg, pos, scale=scale)
+        jnp.concatenate([k_nope, shared], axis=-1), v, seg, pos, scale=scale,
+        choice=choice)
+
+
+def _order_bits(x):
+    """float32 -> uint32 that orders as the floats do (``-0.0`` as
+    ``0.0``): the float's bits with the sign turned for a positive number
+    and every bit for a negative one."""
+    bits = jax.lax.bitcast_convert_type(x + 0.0, jnp.uint32)
+    return jnp.where(bits >> 31 == 1, ~bits, bits | jnp.uint32(1 << 31))
+
+
+def _largest(keys, see, topk: int):
+    """Of each row's entries of ``keys`` (Q, T) uint32 that ``see`` admits,
+    the ``topk`` largest, a tie at the edge going to the earlier entry:
+    (Q, T) bool. Exact: the ``topk``-th largest key of a row is found a bit
+    at a time from the top (32 counts over the row, no sort), everything
+    above it is taken, and of its equals the first that are still
+    wanted."""
+    keys = jnp.where(see, keys, 0)
+
+    def bit(i, found):
+        with_bit = found | (jnp.uint32(1 << 31) >> i)
+        enough = jnp.sum(keys >= with_bit[:, None], axis=1) >= topk
+        return jnp.where(enough, with_bit, found)
+
+    edge = jax.lax.fori_loop(0, 32, bit, jnp.zeros(keys.shape[0], jnp.uint32))
+    above = see & (keys > edge[:, None])
+    equal = see & (keys == edge[:, None])
+    wanted = topk - jnp.sum(above, axis=1)
+    # equals at the edge beyond those wanted: rare (two float32 scores the
+    # same to the last bit), and only then are they counted along the row
+    first = lambda: equal & (jnp.cumsum(equal, axis=1) <= wanted[:, None])
+    return above | jax.lax.cond(
+        jnp.any(jnp.sum(equal, axis=1) > wanted), first, lambda: equal)
+
+
+@functools.partial(jax.jit, static_argnames=("topk",))
+def select_keys(q_idx, k_idx, w_idx, seg, pos, *, topk: int):
+    """The learned choice of keys (DeepSeek sparse attention's lightning
+    indexer): every visible (query, key) pair is scored
+
+        ``I[t, s] = sum_j w_idx[t, j] * relu(q_idx[t, j] . k_idx[s])``
+
+    over the index heads ``j``, and a query attends over all the keys it
+    sees (its own document, at or before its own slot) where they are at
+    most ``topk``, else over the ``topk`` of largest ``I[t, .]``, exactly
+    (a tie at the edge: the earlier key; never an approximate top-k).
+
+    q_idx (B, T, nI, dI) and k_idx (B, T, dI), **one key a token for all
+    index heads**, in the dtype the products run in (ReLU, the weighted
+    sum and the choice are float32); w_idx (B, T, nI) float32; seg, pos
+    (B, T) as :func:`segment_attention`'s. Returns (``choice``, ``chosen``):
+    ``choice`` is what :func:`segment_attention` takes, a pair (the mask
+    (B, T', T') int8, one where query ``t`` attends over key ``s``, T' the
+    row padded to whole blocks; (B, T' / bq, T' / bk) int32, the chosen
+    pairs of each tile of the core's blocks), a value that the layers after
+    this one attend over as well; ``chosen`` the float32 count of chosen
+    pairs of the dispatch.
+
+    A block of queries at a time: its scores against the key blocks it can
+    see (:func:`_block_ranges`), then the edge of each of its queries
+    (:func:`_largest`). Nothing is as large as all heads' scores of a row:
+    the largest arrays are one block's scores of one key block, (bq, nI,
+    bk) float32, and the mask."""
+    b, t, ni, di = q_idx.shape
+    bq, bk, padded = block_sizes(t)
+    if padded != t:
+        grow = ((0, 0), (0, padded - t))
+        q_idx = jnp.pad(q_idx, grow + ((0, 0), (0, 0)))
+        k_idx, w_idx = (jnp.pad(a, grow + ((0, 0),)) for a in (k_idx, w_idx))
+        seg = jnp.pad(seg, grow, constant_values=-1)
+        pos = jnp.pad(pos, grow)
+    seg, pos = seg.astype(jnp.int32), pos.astype(jnp.int32)
+    lo, count = _block_ranges(jnp, seg, pos, None, bq, bk)
+    nq, nk = padded // bq, padded // bk
+    slot = jnp.arange(padded, dtype=jnp.int32)
+    f32 = jnp.float32
+    blocks = lambda a: a.reshape((b * nq, bq) + a.shape[2:])
+
+    def query_block(xs):
+        at, qb, wb, seg_q, pos_q, first, steps = xs
+        row = at // nq
+        keys, seg_k, pos_k = (jax.lax.dynamic_index_in_dim(
+            a, row, keepdims=False) for a in (k_idx, seg, pos))
+
+        def key_block(j, scores):
+            start = (first + j) * bk
+            kb = jax.lax.dynamic_slice_in_dim(keys, start, bk)
+            s = jnp.einsum("qjd,kd->qjk", qb, kb, preferred_element_type=f32)
+            return jax.lax.dynamic_update_slice(
+                scores, jnp.sum(jax.nn.relu(s) * wb[:, :, None], axis=1),
+                (0, start))
+
+        scores = jax.lax.fori_loop(0, steps, key_block,
+                                   jnp.zeros((bq, padded), f32))
+        see = _visible(seg_q[:, None], pos_q[:, None],
+                       ((at % nq) * bq + jnp.arange(bq))[:, None],
+                       seg_k[None, :], pos_k[None, :], slot[None, :], None)
+        # a block none of whose queries sees more than ``topk`` keys (a
+        # document's first ones; short documents) has nothing to rank
+        chosen = jax.lax.cond(
+            jnp.max(jnp.sum(see, axis=1)) > topk,
+            lambda: _largest(_order_bits(scores), see, topk), lambda: see)
+        return chosen.astype(jnp.int8), jnp.sum(
+            chosen.reshape(bq, nk, bk), axis=(0, 2), dtype=jnp.int32)
+
+    mask, tiles = jax.lax.map(query_block, (
+        jnp.arange(b * nq), blocks(q_idx), blocks(w_idx.astype(f32)),
+        blocks(seg), blocks(pos), lo.reshape(-1), count.reshape(-1)))
+    choice = (mask.reshape(b, padded, padded), tiles.reshape(b, nq, nk))
+    return choice, jnp.sum(tiles.astype(f32))
 
 
 def _visible(seg_q, pos_q, slot_q, seg_k, pos_k, slot_k, window):
@@ -317,13 +459,15 @@ def _visible(seg_q, pos_q, slot_q, seg_k, pos_k, slot_k, window):
     return see
 
 
-def _blockwise(q, k, v, seg, pos, lo, count, *, window, bq: int, bk: int,
-               scale: float | None = None):
+def _blockwise(q, k, v, seg, pos, lo, count, choice=None, *, window,
+               bq: int, bk: int, scale: float | None = None):
     """:func:`segment_attention` in plain JAX: a ``lax.map`` over the
     query blocks, inside it a loop over the key blocks that block can see
     (of any row: the rows of a dispatch walk the union of their ranges),
     the online softmax of ``parallel/ring_attention.py``. The largest
-    array is one block's scores, (B, nh, bq, bk)."""
+    array is one block's scores, (B, nh, bq, bk). ``choice``: the mask's
+    tile is read beside the block's own mask, and a step in which no row's
+    tile holds a chosen key computes nothing."""
     b, t, nh, d = q.shape
     nkv, dv = k.shape[2], v.shape[3]
     nq, nk = t // bq, t // bk
@@ -347,7 +491,11 @@ def _blockwise(q, k, v, seg, pos, lo, count, *, window, bq: int, bk: int,
             seg_k, pos_k = (cut(a, at, bk)[:, None, :] for a in (seg, pos))
             see = _visible(seg_q, pos_q, slot_q, seg_k, pos_k,
                            (at + jnp.arange(bk))[None, None, :],
-                           window)[:, None, None]         # (B,1,1,bq,bk)
+                           window)                           # (B, bq, bk)
+            if choice is not None:
+                see = see & (jax.lax.dynamic_slice(
+                    choice[0], (0, i * bq, at), (b, bq, bk)) != 0)
+            see = see[:, None, None]                      # (B,1,1,bq,bk)
             s = jnp.einsum("bqgrd,bkgd->bgrqk", qb, kb,
                            preferred_element_type=f32) * scale
             s = jnp.where(see, s, _MASKED)
@@ -358,6 +506,12 @@ def _blockwise(q, k, v, seg, pos, lo, count, *, window, bq: int, bk: int,
                 "bgrqk,bkgd->bgrqd", p.astype(vb.dtype), vb,
                 preferred_element_type=f32)
             return m_new, alpha * l + jnp.sum(p, axis=-1), acc
+
+        if choice is not None:
+            chosen = key_block
+            key_block = lambda j, carry: jax.lax.cond(
+                jnp.any(choice[1][:, i, first[i] + j] > 0),
+                lambda: chosen(j, carry), lambda: carry)
 
         stats = (b, nkv, nh // nkv, bq)
         _m, l, acc = jax.lax.fori_loop(
@@ -370,10 +524,9 @@ def _blockwise(q, k, v, seg, pos, lo, count, *, window, bq: int, bk: int,
     return jnp.transpose(out, (1, 0, 4, 2, 3, 5)).reshape(b, t, nh, dv)
 
 
-def _segment_body(lo_ref, count_ref, qdoc_ref, kdoc_ref, q_ref, k_ref,
-                  v_ref, qcols_ref, krows_ref, o_ref, qs_ref, m_ref, l_ref,
-                  acc_ref, *, rep: int, d: int, dv: int, bq: int, bk: int,
-                  nk: int, window, scale: float):
+def _segment_body(lo_ref, count_ref, qdoc_ref, kdoc_ref, *refs, rep: int,
+                  d: int, dv: int, bq: int, bk: int, nk: int, window,
+                  scale: float, sparse: bool = False):
     """One key block of one query block of one key head's ``rep`` query
     heads. Blocks: q (bq, rep * d), o (bq, rep * dv); k (bk, d), v (bk,
     dv); qcols (bq, 128): the
@@ -383,9 +536,18 @@ def _segment_body(lo_ref, count_ref, qdoc_ref, kdoc_ref, q_ref, k_ref,
     document all its slots belong to (or a negative number where they do
     not share one). Vector memory, for the length of a query block: the
     heads' queries stacked as rows (rep * bq, d), the running max and sum
-    (rep * bq, 1) and the accumulator (rep * bq, dv), float32."""
+    (rep * bq, 1) and the accumulator (rep * bq, dv), float32. ``sparse``:
+    one more scalar array, whether anything of a tile was chosen, and one
+    more block, the tile (bq, bk) of the choice's mask, which is the
+    block's mask whole (a chosen key is a visible one)."""
     from jax.experimental import pallas as pl
 
+    if sparse:
+        tiles_ref, q_ref, k_ref, v_ref, qcols_ref, krows_ref, choice_ref, \
+            o_ref, qs_ref, m_ref, l_ref, acc_ref = refs
+    else:
+        q_ref, k_ref, v_ref, qcols_ref, krows_ref, o_ref, qs_ref, m_ref, \
+            l_ref, acc_ref = refs
     row, qi, j = pl.program_id(0), pl.program_id(2), pl.program_id(3)
     at = row * pl.num_programs(2) + qi
     count = count_ref[at]
@@ -421,21 +583,9 @@ def _segment_body(lo_ref, count_ref, qdoc_ref, kdoc_ref, q_ref, k_ref,
             preferred_element_type=f32)
         m_ref[...] = m_new
 
-    # every query of the block sees every key of the block: both lie in
-    # one document, the keys wholly before the queries and inside the
-    # window of the last of them. Only the other blocks build a mask
-    inside = (qdoc_ref[at] == kdoc_ref[row * nk + kb]) \
-        & ((kb + 1) * bk - 1 <= qi * bq)
-    if window is not None:
-        inside = inside & ((qi + 1) * bq - 1 - kb * bk < window)
     live = j < count
 
-    @pl.when(live & inside)
-    def _():
-        accumulate(None)
-
-    @pl.when(live & jnp.logical_not(inside))
-    def _():
+    def on_an_edge():
         shape = (bq, bk)
         spread = lambda a: jnp.broadcast_to(a, shape)
         see = _visible(
@@ -445,6 +595,21 @@ def _segment_body(lo_ref, count_ref, qdoc_ref, kdoc_ref, q_ref, k_ref,
             kb * bk + jax.lax.broadcasted_iota(jnp.int32, shape, 1), window)
         accumulate(see)
 
+    if sparse:
+        pl.when(live & (tiles_ref[at * nk + kb] > 0))(
+            lambda: accumulate(choice_ref[...].astype(jnp.int32) != 0))
+    else:
+        # every query of the block sees every key of the block: both lie
+        # in one document, the keys wholly before the queries and inside
+        # the window of the last of them. Only the other blocks build a
+        # mask
+        inside = (qdoc_ref[at] == kdoc_ref[row * nk + kb]) \
+            & ((kb + 1) * bk - 1 <= qi * bq)
+        if window is not None:
+            inside = inside & ((qi + 1) * bq - 1 - kb * bk < window)
+        pl.when(live & inside)(lambda: accumulate(None))
+        pl.when(live & jnp.logical_not(inside))(on_an_edge)
+
     @pl.when(j == jnp.maximum(count, 1) - 1)
     def _():
         l = l_ref[...]
@@ -453,8 +618,8 @@ def _segment_body(lo_ref, count_ref, qdoc_ref, kdoc_ref, q_ref, k_ref,
             o_ref[:, r * dv:(r + 1) * dv] = out[r * bq:(r + 1) * bq]
 
 
-def _segment_kernel(q, k, v, seg, pos, lo, count, *, window, bq: int,
-                    bk: int, scale: float | None = None,
+def _segment_kernel(q, k, v, seg, pos, lo, count, choice=None, *, window,
+                    bq: int, bk: int, scale: float | None = None,
                     interpret: bool = False):
     """:func:`segment_attention` as one Pallas kernel. Grid (row, key
     head, query block, key-block step), the steps innermost: step ``j`` of
@@ -466,7 +631,9 @@ def _segment_kernel(q, k, v, seg, pos, lo, count, *, window, bq: int,
     once for all of them; operands in the dtype given (bfloat16 on the
     chip), the running max, sum and accumulator float32 in vector memory;
     the mask is built only in blocks on an edge (the diagonal, the
-    window's edge, a document's edge, padding)."""
+    window's edge, a document's edge, padding). With a ``choice`` the
+    block's mask is the tile of the choice's, fetched beside the keys, and
+    a tile with nothing chosen is not multiplied."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -497,12 +664,21 @@ def _segment_kernel(q, k, v, seg, pos, lo, count, *, window, bq: int,
     queries = lambda r, g, i, j, *_: (r, i, g)
     keys = lambda r, g, i, j, *s: (r, key_block(r, g, i, j, *s), g)
     rows = rep * bq
+    # with a choice: its tiles' counts behind the scalars, its mask's tile
+    # behind the blocks
+    sparse = choice is not None
+    counts, mask_spec, mask = (), (), ()
+    if sparse:
+        mask, counts = (choice[0],), (choice[1].reshape(-1),)
+        mask_spec = (pl.BlockSpec((None, bq, bk), lambda r, g, i, j, *s: (
+            r, i, key_block(r, g, i, j, *s))),)
     out = pl.pallas_call(
         functools.partial(_segment_body, rep=rep, d=d, dv=dv, bq=bq, bk=bk,
                           nk=nk, window=window,
-                          scale=d ** -0.5 if scale is None else scale),
+                          scale=d ** -0.5 if scale is None else scale,
+                          **({"sparse": True} if sparse else {})),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=4,
+            num_scalar_prefetch=4 + len(counts),
             grid=(b, nkv, nq, steps),
             in_specs=[
                 pl.BlockSpec((None, bq, rep * d), queries),
@@ -512,6 +688,7 @@ def _segment_kernel(q, k, v, seg, pos, lo, count, *, window, bq: int,
                              lambda r, g, i, j, *_: (r, i, 0)),
                 pl.BlockSpec((None, 8, bk), lambda r, g, i, j, *s: (
                     r, 0, key_block(r, g, i, j, *s))),
+                *mask_spec,
             ],
             out_specs=pl.BlockSpec((None, bq, rep * dv), queries),
             scratch_shapes=[pltpu.VMEM((rows, d), q.dtype),
@@ -525,6 +702,7 @@ def _segment_kernel(q, k, v, seg, pos, lo, count, *, window, bq: int,
             vmem_limit_bytes=64 * 1024 * 1024),
         interpret=interpret,
     )(lo.reshape(-1), count.reshape(-1), one_document(seg, bq, -2),
-      one_document(seg, bk, -3), q.reshape(b, t, nh * d),
-      k.reshape(b, t, nkv * d), v.reshape(b, t, nkv * dv), qcols, krows)
+      one_document(seg, bk, -3), *counts, q.reshape(b, t, nh * d),
+      k.reshape(b, t, nkv * d), v.reshape(b, t, nkv * dv), qcols, krows,
+      *mask)
     return out.reshape(b, t, nh, dv)

@@ -61,16 +61,23 @@ ACTIVATIONS = {"silu": jax.nn.silu, "relu": jax.nn.relu}
 
 
 def route(x, router, k: int, renormalise: bool, bias=None,
-          scale: float = 1.0):
-    """Softmax over all of the router's outputs in float32, the ``k``
-    largest a token. x (N, H); router (H, E); ``bias`` (E,) float32: a
-    correction added to the probabilities **for the choice only** (the
-    weights are the probabilities themselves); ``scale``: what the weights
-    are multiplied by. Returns (weights (N, k) float32, experts (N, k)
-    int32)."""
+          scale: float = 1.0, scoring: str = "softmax"):
+    """The router's scores over all of its outputs in float32 (``scoring``:
+    ``"softmax"`` over the outputs, or ``"sigmoid"`` of each output alone),
+    the ``k`` largest a token. x (N, H); router (H, E); ``bias`` (E,)
+    float32: a correction added to the scores **for the choice only** (the
+    weights are the scores themselves); ``scale``: what the weights are
+    multiplied by, after they are renormalised to one where ``renormalise``
+    says so. Returns (weights (N, k) float32, experts (N, k) int32)."""
     logits = jnp.matmul(x.astype(jnp.float32), router.astype(jnp.float32),
                         precision=jax.lax.Precision.HIGHEST)
-    probs = jax.nn.softmax(logits, axis=-1)
+    if scoring == "softmax":
+        probs = jax.nn.softmax(logits, axis=-1)
+    elif scoring == "sigmoid":
+        probs = jax.nn.sigmoid(logits)
+    else:
+        raise ValueError(f"scoring {scoring!r}: \"softmax\" and "
+                         f"\"sigmoid\" are what runs")
     if bias is None:
         weights, experts = jax.lax.top_k(probs, k)
     else:

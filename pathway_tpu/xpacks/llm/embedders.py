@@ -62,10 +62,12 @@ def expert_load_stats() -> dict | None:
     experts: {"max", "mean" tokens an expert, "dispatches",
     "full_buffer_layers": expert-layer executions whose pair buffer took
     its full length, "buffer_rows_mean": the buffer's rows an execution
-    (ops/moe.py), and where a router has identity experts "zero_pairs",
+    (ops/moe.py), where a router has identity experts "zero_pairs",
     "pairs": the chosen pairs of real tokens that took one, and all of
-    them} over all of them; None where there is none. Fetches from the
-    device."""
+    them, and where a model chooses the keys a query attends over
+    "selected_pairs", "visible_pairs": the (query, key) pairs its attention
+    layers attended over, of those they could see} over all of them; None
+    where there is none. Fetches from the device."""
     loads = [ld for e in list(_AUX_EMBEDDERS)
              if (ld := e.expert_load()) is not None]
     if not loads:
@@ -78,9 +80,11 @@ def expert_load_stats() -> dict | None:
                                        for ld in loads),
              "buffer_rows_mean": sum(ld["buffer_rows"] for ld in loads)
              / layers if layers else 0.0}
-    if any("pairs" in ld for ld in loads):
-        stats.update({name: sum(ld.get(name, 0.0) for ld in loads)
-                      for name in ("zero_pairs", "pairs")})
+    for names in (("zero_pairs", "pairs"),
+                  ("selected_pairs", "visible_pairs")):
+        if any(names[0] in ld for ld in loads):
+            stats.update({name: sum(ld.get(name, 0.0) for ld in loads)
+                          for name in names})
     return stats
 
 
@@ -346,7 +350,10 @@ class JaxEncoderEmbedder(BaseEmbedder):
         expert-layer executions, those that took the full length, the
         buffer's rows summed over them) and, where the router has identity
         experts, ``zero_pairs`` and ``pairs`` (the chosen pairs of real
-        tokens that took one, and all of them), fetched from the device
+        tokens that took one, and all of them), where the model chooses its
+        keys ``selected_pairs`` and ``visible_pairs`` (the device's own
+        count of the pairs attended over, of those visible, all attention
+        layers), fetched from the device
         now (the one transfer: call it from a metrics request, not from a
         tick). None where the model routes nothing."""
         with self._aux_lock:
@@ -358,15 +365,17 @@ class JaxEncoderEmbedder(BaseEmbedder):
                 "dispatches": dispatches, "expert_layers": int(layers),
                 "full_buffer_layers": int(full), "buffer_rows": rows}
         load.update({name: float(total[name])
-                     for name in ("zero_pairs", "pairs") if name in total})
+                     for name in ("zero_pairs", "pairs", "selected_pairs",
+                                  "visible_pairs") if name in total})
         return load
 
     def dispatch_work(self, args: tuple) -> dict:
         """What the attention layers have to do in the ragged dispatch of
         ``args`` (a chunk of :meth:`pack_ragged`), counted on the host from
         its documents' places: ``attn_pairs_full``, ``attn_pairs_window``,
-        ``attn_tiles_run``, ``attn_tiles_all`` (ops/attention.py
-        ``attention_work``), which the ``embedder.dispatch`` span carries
+        ``attn_tiles_run``, ``attn_tiles_all`` and, where the model chooses
+        its keys, ``attn_pairs_indexed`` and ``attn_pairs_selected``
+        (ops/attention.py ``attention_work``), which the ``embedder.dispatch`` span carries
         and :meth:`attention_tiles` sums. Empty where the model's config
         names no attention layers."""
         windows = getattr(self.config, "attention_windows", None)
@@ -374,7 +383,9 @@ class JaxEncoderEmbedder(BaseEmbedder):
             return {}
         from pathway_tpu.ops.attention import attention_work
 
-        work = attention_work(args[1], args[2], windows)
+        work = attention_work(
+            args[1], args[2], windows,
+            *(getattr(self.config, "attention_index", None) or ()))
         with self._aux_lock:
             _ATTENTION_EMBEDDERS.add(self)
             self._attention_tiles[0] += work["attn_tiles_run"]

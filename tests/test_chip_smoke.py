@@ -75,6 +75,25 @@ def test_smoke_function_passes_on_cpu_at_tiny_size(tmp_path):
     assert summary["mesh"] is None and out.exists()
 
 
+def test_the_decoder_check_of_the_pattern_that_chooses_its_keys():
+    """The third pattern of ``_decoder_check`` at a tiny size: a dense
+    layer with an indexer, two expert layers that share its choice and one
+    that chooses again; a document of 320 tokens over the 24 best keys a
+    query, alone in its row and behind two others; values of 32 features
+    take the sparse blockwise loop and no other."""
+    import jax.numpy as jnp
+
+    from pathway_tpu.models.decoder import DecoderConfig
+
+    indexed = chip_smoke._decoder_check(
+        DecoderConfig.tiny_indexed(compute_dtype=jnp.float32, max_len=512),
+        512, 5, "indexed")
+    assert indexed["alone_vs_packed_cos"] > 0.9999
+    assert indexed["lowerings"]["sparse_blockwise"] >= 1
+    assert not any(n for name, n in indexed["lowerings"].items()
+                   if name != "sparse_blockwise")
+
+
 def test_smoke_refuses_the_wrong_platform_before_building_anything():
     built = []
 
