@@ -228,13 +228,21 @@ def _decoder_check(cfg, row: int, seed: int, what: str) -> dict:
     kernel = jax.devices()[0].platform == "tpu" and lanes % 128 == 0
     sparse = "sparse_" if cfg.attention_index else ""
     wanted = sparse + ("kernel" if kernel else "blockwise")
+    # the cores' query block follows the heads a key head stacks: a larger
+    # one where there is no stacking, which the counter names
+    block = attention.block_sizes(row, cfg.attention_rep)[0]
+    wide = f"query_block_{block}"
     _check(took[wanted] > 0 and not any(
-        n for name, n in took.items() if name != wanted),
-           f"attention lowered as the {wanted.replace('_', ' ')}: {took}")
+        n for name, n in took.items() if name not in (wanted, wide))
+        and (took.get(wide, 0) > 0) == (block > 256),
+           f"attention lowered as the {wanted.replace('_', ' ')} at query "
+           f"blocks of {block} ({cfg.attention_rep} query heads a key "
+           f"head): {took}")
     del params
     return {"row": row, "layers": cfg.num_hidden_layers,
             "hidden": cfg.hidden_size, "experts": cfg.num_experts,
-            "alone_vs_packed_cos": round(cos, 6), "lowerings": took}
+            "alone_vs_packed_cos": round(cos, 6), "lowerings": took,
+            "query_block": block}
 
 
 def _device_memory(devices) -> list[dict]:

@@ -252,6 +252,16 @@ class DecoderConfig:
                      for _ in range(2 if k.mixer == "latent" else 1))
 
     @property
+    def attention_rep(self) -> int:
+        """The query heads a key head of the attention cores serves (the
+        packer counts the cores' tiles at the query block it decides,
+        ``ops/attention.py`` ``block_sizes``): 1 for latent attention,
+        whose every head has keys of its own."""
+        if self.attention_method == "MLA":
+            return 1
+        return self.num_attention_heads // self.num_key_value_heads
+
+    @property
     def attention_index(self) -> tuple[int, int] | None:
         """(``index_topk``, the layers that hold an indexer) where the
         model chooses its keys (the packer counts the indexers' and the

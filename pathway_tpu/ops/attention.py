@@ -119,13 +119,14 @@ def flash_attention(q, k, v, mask, *, interpret: bool = False):
 # block a query block sees and how many follow are computed on the device
 # from ``seg``, ``pos`` and ``window`` before the loop (:func:`_block_ranges`).
 #
-# One algorithm, two lowerings, chosen by what the function observes
-# (platform, head size, row length): on a TPU with heads of whole lanes it
-# is one Pallas kernel (:func:`_segment_kernel`), everywhere else the same
-# loop in plain ``jax.numpy`` (:func:`_blockwise`). Keys and values may
-# differ in width and the scale may be given: latent attention in prefill
-# (:func:`latent_attention`) is this core over keys of 128 + 64 features
-# and values of 128.
+# One algorithm, two lowerings, chosen by what the function observes (platform,
+# head size, row length): on a TPU with heads of whole lanes it is one Pallas
+# kernel (:func:`_segment_kernel`), everywhere else the same loop in plain
+# ``jax.numpy`` (:func:`_blockwise`), both over the same blocks, which follow
+# the row's length and the query heads a key head serves (:func:`block_sizes`:
+# read from the operands' shape). Keys and values may differ in width and the
+# scale may be given: latent attention in prefill (:func:`latent_attention`) is
+# this core over keys of 128 + 64 features and values of 128.
 #
 # **A learned choice of keys.** Where a model ranks the keys a query sees
 # (:func:`select_keys`: a small scorer over every visible pair, and of each
@@ -144,21 +145,31 @@ _UNSEEN = -1e30
 _MASKED = -2e30
 
 
-def block_sizes(t: int) -> tuple[int, int, int]:
+def block_sizes(t: int, rep: int) -> tuple[int, int, int]:
     """(query block, key block, padded row length) for rows of ``t``
-    slots: blocks of 256 queries and 1,024 keys, fewer keys where a row
-    is shorter, one block where it is shorter than 256. A query block's
-    heads are stacked as rows of one product (1,792 rows for 7 heads a
-    key head), so a key block read once serves them all. On the chip a
-    row of 16,384 slots that is one document takes a full layer 27.8 ms
-    with key blocks of 512, 18.4 ms with 1,024 (51.7 with 256; query
-    blocks of 128 read 32.5): a step's fixed work, the running max, sum
-    and accumulator read and written, is spread over more keys (my chip
-    run, PR 33)."""
+    slots whose key heads serve ``rep`` query heads each: key blocks of
+    1,024, fewer keys where a row is shorter, one block where it is shorter
+    than 256. A query block's ``rep`` heads are stacked as rows of one
+    product, so a key block read once serves them all: blocks of 256
+    queries where heads are grouped (1,792 rows for 7 heads a key head),
+    and as many queries as keys where every head has keys of its own
+    (``rep`` 1: latent attention). On the chip a row of 16,384 slots that
+    is one document takes a full layer 27.8 ms with key blocks of 512,
+    18.4 ms with 1,024 (51.7 with 256; query blocks of 128 read 32.5): a
+    step's fixed work, the running max, sum and accumulator read and
+    written, is spread over more keys (my chip run, PR 33). Ungrouped, a
+    call of 16 heads over a choice on a row of 16,384 slots (three
+    documents, values of 256) takes 13.0 ms with query blocks of 256, 10.2
+    with 512, 9.0 with 1,024, and one of 64 heads on a row of 8,192 (two
+    documents, values of 128) 13.7, 11.5, 11.3 ms, to the same bits: a
+    grid step that does nothing costs a third of a microsecond and the
+    grid has a quarter of them; ``ingest_docs_per_s`` read 3.11, 3.45, 3.58
+    in the first cell and 3.41, 3.52, 3.54 in the second (my chip run,
+    PR 42)."""
     if t <= 256:
         return t, t, t
     bk = next(size for size in (1024, 512, 256) if t >= size)
-    return 256, bk, -(-t // bk) * bk
+    return (256 if rep >= 2 else bk), bk, -(-t // bk) * bk
 
 
 def _block_ranges(xp, seg, pos, window, bq: int, bk: int):
@@ -186,29 +197,33 @@ def _max_steps(t: int, window, bq: int, bk: int) -> int:
 
 
 def attention_work(seg: np.ndarray, pos: np.ndarray, windows: tuple,
-                   index_topk: int | None = None, indexers: int = 0) -> dict:
+                   index_topk: int | None = None, indexers: int = 0, *,
+                   rep: int) -> dict:
     """What the attention layers of one dispatch have to do, counted on
     the host from the packed rows (``seg``, ``pos`` (B, T) as the packer
     made them; ``windows``: each attention layer's window, None for full
-    attention): ``attn_pairs_full`` the visible (query, key) pairs of a
-    full layer (a document of n tokens has n (n + 1) / 2),
+    attention; ``rep``: the query heads a key head serves, which decides
+    the cores' query block): ``attn_pairs_full`` the visible (query, key)
+    pairs of a full layer (a document of n tokens has n (n + 1) / 2),
     ``attn_pairs_window`` of a window layer (where the model has one),
     ``attn_tiles_run`` the key blocks the kernel's ranges admit and
     ``attn_tiles_all`` all key blocks up to the diagonal, both summed over
-    query blocks and attention layers. Where the model chooses its keys
-    (``index_topk``; ``indexers``: the layers that hold a scorer):
+    query blocks and attention layers, ``attn_query_block`` the queries of
+    a block they are counted at (the kernel's own: :func:`block_sizes`).
+    Where the model chooses its keys (``index_topk``; ``indexers``: the
+    layers that hold a scorer):
     ``attn_pairs_indexed`` the pairs the scorers rank (every visible pair, a
     scorer) and ``attn_pairs_selected`` the pairs the cores attend over (a
     query's visible keys or ``index_topk``, the fewer, an attention
     layer)."""
     t = seg.shape[1]
-    bq, bk, padded = block_sizes(t)
+    bq, bk, padded = block_sizes(t, rep)
     if padded != t:
         seg = np.pad(seg, ((0, 0), (0, padded - t)), constant_values=-1)
         pos = np.pad(pos, ((0, 0), (0, padded - t)))
     real = seg >= 0
     reach = (pos.astype(np.int64) + 1)[real]
-    out = {"attn_pairs_full": int(reach.sum())}
+    out = {"attn_pairs_full": int(reach.sum()), "attn_query_block": bq}
     if index_topk is not None:
         out["attn_pairs_indexed"] = indexers * out["attn_pairs_full"]
         out["attn_pairs_selected"] = len(windows) * int(
@@ -231,12 +246,20 @@ def attention_lowerings() -> dict:
     ``kernel`` (the Pallas TPU kernel) or ``blockwise`` (plain JAX), one
     count a call of a compiled program (``ops/lowering_count.py``), and,
     once a program holds a core over a learned choice of keys,
-    ``sparse_kernel`` and ``sparse_blockwise`` for those. ``/metrics``
-    shows it as ``pathway_tpu_attention_programs``."""
+    ``sparse_kernel`` and ``sparse_blockwise`` for those; once one holds a
+    core of ungrouped heads, ``query_block_512`` or ``query_block_1024``:
+    the cores, of those above, that took a query block larger than 256, by
+    the block (:func:`block_sizes`). ``/metrics`` shows it as
+    ``pathway_tpu_attention_programs``."""
+    took = lowering_count.counts("attention", ("kernel", "blockwise"))
     sparse = lowering_count.counts("attention", ("sparse_kernel",
                                                  "sparse_blockwise"))
-    return dict(lowering_count.counts("attention", ("kernel", "blockwise")),
-                **(sparse if any(sparse.values()) else {}))
+    if any(sparse.values()):
+        took.update(sparse)
+    wide = lowering_count.counts("attention", ("query_block_512",
+                                               "query_block_1024"))
+    took.update({name: n for name, n in wide.items() if n})
+    return took
 
 
 #: features a vector register of the chip holds side by side
@@ -276,7 +299,7 @@ def segment_attention(q, k, v, seg, pos, *, window: int | None = None,
     took. Jitted, so that the layers of one program that share a window
     share one trace and one lowering."""
     b, t, nh, d = q.shape
-    bq, bk, padded = block_sizes(t)
+    bq, bk, padded = block_sizes(t, nh // k.shape[2])
     if padded != t:
         grow = ((0, 0), (0, padded - t))
         q, k, v = (jnp.pad(a, grow + ((0, 0), (0, 0))) for a in (q, k, v))
@@ -296,7 +319,14 @@ def segment_attention(q, k, v, seg, pos, *, window: int | None = None,
             _blockwise(q, k, v, seg, pos, lo, count, *choice, **sizes),
             "attention", name + "blockwise")
 
-    choice = () if choice is None else (tuple(choice),)
+    if choice is None:
+        choice = ()
+    else:
+        # the choice counts a tile at its own loop's query block
+        # (:func:`select_keys`); a larger one of this core holds several
+        mask, tiles = choice
+        choice = ((mask, tiles.reshape(b, padded // bq, -1,
+                                       padded // bk).sum(axis=2)),)
     if not _kernel_tiles(v.shape, padded):
         out = blockwise(q, k, v, seg, pos, lo, count, *choice)
     else:
@@ -312,6 +342,8 @@ def segment_attention(q, k, v, seg, pos, *, window: int | None = None,
         out = jax.lax.platform_dependent(q, k, v, seg, pos, lo, count,
                                          *choice, tpu=kernel,
                                          default=blockwise)
+    if bq > 256:
+        out = lowering_count.took(out, "attention", f"query_block_{bq}")
     return out[:, :t]
 
 
@@ -392,17 +424,20 @@ def select_keys(q_idx, k_idx, w_idx, seg, pos, *, topk: int):
     ``choice`` is what :func:`segment_attention` takes, a pair (the mask
     (B, T', T') int8, one where query ``t`` attends over key ``s``, T' the
     row padded to whole blocks; (B, T' / bq, T' / bk) int32, the chosen
-    pairs of each tile of the core's blocks), a value that the layers after
-    this one attend over as well; ``chosen`` the float32 count of chosen
-    pairs of the dispatch.
+    pairs of each tile of this loop's blocks, which a core sums to its
+    own), a value that the layers after this one attend over as well;
+    ``chosen`` the float32 count of chosen pairs of the dispatch.
 
-    A block of queries at a time: its scores against the key blocks it can
-    see (:func:`_block_ranges`), then the edge of each of its queries
-    (:func:`_largest`). Nothing is as large as all heads' scores of a row:
-    the largest arrays are one block's scores of one key block, (bq, nI,
-    bk) float32, and the mask."""
+    A block of 256 queries at a time, whatever the cores' query block: its
+    scores against the key blocks it can see (:func:`_block_ranges`), then
+    the edge of each of its queries (:func:`_largest`). Nothing is as
+    large as all heads' scores of a row: the largest arrays are one block's
+    scores of one key block, (bq, nI, bk) float32, and the mask; a block's
+    scores over the row, (bq, T') float32, stay in vector memory through
+    :func:`_largest`'s 32 passes (PR 40)."""
     b, t, ni, di = q_idx.shape
-    bq, bk, padded = block_sizes(t)
+    _, bk, padded = block_sizes(t, 1)
+    bq = min(256, padded)
     if padded != t:
         grow = ((0, 0), (0, padded - t))
         q_idx = jnp.pad(q_idx, grow + ((0, 0), (0, 0)))

@@ -373,9 +373,11 @@ class JaxEncoderEmbedder(BaseEmbedder):
         """What the attention layers have to do in the ragged dispatch of
         ``args`` (a chunk of :meth:`pack_ragged`), counted on the host from
         its documents' places: ``attn_pairs_full``, ``attn_pairs_window``,
-        ``attn_tiles_run``, ``attn_tiles_all`` and, where the model chooses
-        its keys, ``attn_pairs_indexed`` and ``attn_pairs_selected``
-        (ops/attention.py ``attention_work``), which the ``embedder.dispatch`` span carries
+        ``attn_tiles_run``, ``attn_tiles_all``, ``attn_query_block`` (the
+        cores' own, from the configuration's head counts) and, where the
+        model chooses its keys, ``attn_pairs_indexed`` and
+        ``attn_pairs_selected`` (ops/attention.py ``attention_work``),
+        which the ``embedder.dispatch`` span carries
         and :meth:`attention_tiles` sums. Empty where the model's config
         names no attention layers."""
         windows = getattr(self.config, "attention_windows", None)
@@ -385,7 +387,8 @@ class JaxEncoderEmbedder(BaseEmbedder):
 
         work = attention_work(
             args[1], args[2], windows,
-            *(getattr(self.config, "attention_index", None) or ()))
+            *(getattr(self.config, "attention_index", None) or ()),
+            rep=self.config.attention_rep)
         with self._aux_lock:
             _ATTENTION_EMBEDDERS.add(self)
             self._attention_tiles[0] += work["attn_tiles_run"]

@@ -62,12 +62,15 @@ def test_smoke_function_passes_on_cpu_at_tiny_size(tmp_path):
     assert windowed["alone_vs_packed_cos"] > 0.9999
     assert windowed["lowerings"]["kernel"] == 0 \
         and windowed["lowerings"]["blockwise"] >= 4
+    assert windowed["query_block"] == 256
     # and one of the latent decoder: two layers of two latent attention
     # sublayers; values of 16 features take the blockwise loop
     latent = summary["latent_decoder"]
     assert latent["alone_vs_packed_cos"] > 0.9999
     assert latent["lowerings"]["kernel"] == 0 \
         and latent["lowerings"]["blockwise"] >= 1
+    assert latent["query_block"] == 512 \
+        and latent["lowerings"]["query_block_512"] >= 1
     assert summary["device"]["platform"] == "cpu"
     assert summary["n_docs"] == 48 + 3
     assert summary["bridge_legs_resolved"] > 0
@@ -80,7 +83,8 @@ def test_the_decoder_check_of_the_pattern_that_chooses_its_keys():
     layer with an indexer, two expert layers that share its choice and one
     that chooses again; a document of 320 tokens over the 24 best keys a
     query, alone in its row and behind two others; values of 32 features
-    take the sparse blockwise loop and no other."""
+    take the sparse blockwise loop and no other, at the query block of
+    ungrouped heads, which the check returns."""
     import jax.numpy as jnp
 
     from pathway_tpu.models.decoder import DecoderConfig
@@ -91,7 +95,10 @@ def test_the_decoder_check_of_the_pattern_that_chooses_its_keys():
     assert indexed["alone_vs_packed_cos"] > 0.9999
     assert indexed["lowerings"]["sparse_blockwise"] >= 1
     assert not any(n for name, n in indexed["lowerings"].items()
-                   if name != "sparse_blockwise")
+                   if name not in ("sparse_blockwise", "query_block_512"))
+    assert indexed["query_block"] == 512
+    assert indexed["lowerings"]["query_block_512"] \
+        == indexed["lowerings"]["sparse_blockwise"]
 
 
 def test_smoke_refuses_the_wrong_platform_before_building_anything():

@@ -141,7 +141,7 @@ def test_the_kernel_at_one_published_shape_block_equals_the_blockwise_loop():
     k = np.concatenate([k_nope, np.broadcast_to(
         k_rope[:, :, None], q_rope.shape)], axis=-1)
     grow = ((0, 0),) * 3 + ((0, 64),)
-    bq, bk, padded = attention.block_sizes(t)
+    bq, bk, padded = attention.block_sizes(t, 1)
     assert (bq, bk, padded) == (256, 256, 256)
     assert attention._kernel_tiles(v.shape, padded)
     lo, count = attention._block_ranges(jnp, jnp.asarray(seg),
@@ -157,6 +157,71 @@ def test_the_kernel_at_one_published_shape_block_equals_the_blockwise_loop():
     assert np.abs(loop - want)[real].max() < 2e-5
     assert np.abs(kernel - loop)[real].max() < 2e-5
     assert not kernel[~real].any()
+
+
+#: rows of two query blocks of 1,024, the documents' edges off every
+#: block's (256, 512, 1,024): name: (slots a row, lengths row by row)
+LONG_LATENT_ROWS = {
+    "two_key_blocks": (2048, [(300, 500, 700, 400), (1, 2047)]),
+    "a_row_of_no_whole_block": (1100, [(300, 500, 290), (1100,)]),
+}
+
+
+@pytest.mark.parametrize("rows", LONG_LATENT_ROWS)
+def test_the_latent_core_at_query_blocks_of_1024_equals_a_dense_softmax(
+        rows):
+    """Every head of latent attention has keys of its own, so the core takes
+    as many queries a block as keys, 1,024: rows of two key blocks against
+    the whole masked score tensor, and the counter names the block."""
+    t, docs = LONG_LATENT_ROWS[rows]
+    ops = _latent_operands(t, docs, heads=2, dn=48, dr=16, dv=16)
+    assert attention.block_sizes(t, 1) == (1024, 1024, 2048)
+    want = _dense_latent(*ops, 0.31)
+    real = ops[5] >= 0
+    got = np.asarray(attention.latent_attention(*ops, scale=0.31))
+    assert np.abs(got - want)[real].max() < 2e-5
+    assert not got[~real].any()
+    assert attention.attention_lowerings()["query_block_1024"] >= 1
+
+
+@pytest.mark.parametrize("docs, run, of", [
+    # a row of 2,048 slots of ungrouped heads: 2 query blocks of 1,024 on 2
+    # key blocks; to the diagonal 1 + 2 = 3 key blocks a core
+    ([(2048,)], 3, 3),
+    # the third document of 512 lies in the second block and sees key block
+    # 1 alone
+    ([(512, 512, 512)], 1 + 1, 3),
+    # the second document, slots 700-1,399, starts inside key block 0: its
+    # queries from 1,024 on see both key blocks
+    ([(700, 700)], 1 + 2, 3),
+    ([(2048,), (100,)], 3 + 1, 6),
+])
+def test_the_host_counts_ungrouped_cores_tiles_at_blocks_of_1024(weights,
+                                                                 docs, run,
+                                                                 of):
+    """``attention_work`` told that no heads are stacked counts the key
+    blocks ``_block_ranges`` admits on the device's side at query blocks of
+    1,024, and the embedder tells it so from the configuration's head
+    counts (``dispatch_work``, which the ``embedder.dispatch`` span
+    carries)."""
+    seg, pos = _rows(2048, docs)
+    cores = CONFIG.attention_windows
+    assert cores == (None,) * 4 and CONFIG.attention_rep == 1
+    assert decoder.DecoderConfig.tiny_windowed().attention_rep == 2
+    work = attention.attention_work(seg, pos, cores, rep=1)
+    assert (work["attn_tiles_run"], work["attn_tiles_all"]) \
+        == (4 * run, 4 * of)
+    assert work["attn_query_block"] == 1024
+    bq, bk, _ = attention.block_sizes(2048, 1)
+    _lo, count = attention._block_ranges(jnp, jnp.asarray(seg),
+                                         jnp.asarray(pos), None, bq, bk)
+    assert int(count.sum()) == run
+    # stacked heads count the same rows at 256 queries: other numbers
+    grouped = attention.attention_work(seg, pos, cores, rep=2)
+    assert grouped["attn_query_block"] == 256
+    assert grouped["attn_tiles_all"] == 4 * work["attn_tiles_all"]
+    assert _embedder(weights, ragged=True).dispatch_work(
+        (None, seg, pos)) == work
 
 
 def test_the_kernel_is_taken_for_the_chip_at_the_published_head_alone():
@@ -177,13 +242,18 @@ def test_the_kernel_is_taken_for_the_chip_at_the_published_head_alone():
             x, p, pos, pos, config)).trace(x, p, pos).lower(
                 lowering_platforms=(platform,)).as_text(debug_info=True)
         after = attention.attention_lowerings()
-        return text, {name: after[name] - before[name] for name in after}
+        # the counts this lowering added, no other
+        return text, {name: after[name] - before.get(name, 0)
+                      for name in after
+                      if after[name] != before.get(name, 0)}
 
     wide = decoder.DecoderConfig.tiny_latent(
         num_attention_heads=2, qk_nope_head_dim=128, qk_rope_head_dim=64,
         v_head_dim=128)
+    # a row of 512 slots of ungrouped heads: one block of 512 queries and
+    # as many keys
     text, took = lowered(wide, "tpu")
-    assert took == {"kernel": 1, "blockwise": 0}
+    assert took == {"kernel": 1, "query_block_512": 1}
     calls = [ln for ln in text.splitlines() if "tpu_custom_call" in ln]
     assert len(calls) == 1 and "_segment_body" in calls[0]
     assert "decoder.attention.full" in text
@@ -191,15 +261,15 @@ def test_the_kernel_is_taken_for_the_chip_at_the_published_head_alone():
     # the keys reach the kernel 256 wide, the values 128
     assert "512x512xbf16" in calls[0] and "512x256xbf16" in calls[0]
     text, took = lowered(wide, "cpu")
-    assert took == {"kernel": 0, "blockwise": 1}
+    assert took == {"blockwise": 1, "query_block_512": 1}
     assert "tpu_custom_call" not in text
     text, took = lowered(decoder.DecoderConfig.tiny_latent(), "tpu")
-    assert took == {"kernel": 0, "blockwise": 1}
+    assert took == {"blockwise": 1, "query_block_512": 1}
     assert "tpu_custom_call" not in text
     samples = {(f, labels.get("lowering")): v for f, labels, v in
                _parse_samples(_metrics_lines(_FakeRuntime()))}
     counted = attention.attention_lowerings()
-    for name in ("kernel", "blockwise"):
+    for name in ("kernel", "blockwise", "query_block_512"):
         assert samples["pathway_tpu_attention_programs", name] \
             == counted[name]
 
@@ -589,6 +659,7 @@ def test_expert_load_and_metrics_show_the_identity_pairs(weights,
         # rows of 128 slots are one block: four cores, one key block a row
         assert span["attn_tiles_run"] == span["attn_tiles_all"] \
             == 4 * span["rows"]
+        assert span["attn_query_block"] == 128
     load = emb.expert_load()
     assert load["pairs"] == sum(lens) * 3 * 2
     assert 0 < load["zero_pairs"] < load["pairs"]

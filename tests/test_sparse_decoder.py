@@ -125,19 +125,21 @@ def test_the_choice_is_top_k_s_set_query_by_query():
     # beyond the row and at padding nothing is chosen; the count and the
     # tiles' counts are the mask's
     assert int(np.asarray(mask).sum()) == int(want.sum()) == int(chosen)
-    bq, bk, padded = attention.block_sizes(640)
+    # the tiles are counted at the choice's own 256 queries, whatever the
+    # cores' query block
+    (_, bk, padded), bq = attention.block_sizes(640, 1), 256
     assert tiles.shape == (2, padded // bq, padded // bk)
     assert (np.asarray(tiles) == np.asarray(mask).astype(np.int64).reshape(
         2, padded // bq, bq, padded // bk, bk).sum(axis=(2, 4))).all()
     # what the packer counts on the host from the documents' places
     reach = (pos + 1)[seg >= 0]
-    work = attention.attention_work(seg, pos, (None,) * 4, TOPK, 2)
+    work = attention.attention_work(seg, pos, (None,) * 4, TOPK, 2, rep=1)
     assert work["attn_pairs_full"] == reach.sum()
     assert work["attn_pairs_indexed"] == 2 * reach.sum()
     assert work["attn_pairs_selected"] == 4 * np.minimum(reach, TOPK).sum() \
         == 4 * int(chosen)
     assert "attn_pairs_indexed" not in attention.attention_work(
-        seg, pos, (None,))
+        seg, pos, (None,), rep=1)
 
 
 def test_a_tie_at_the_edge_goes_to_the_earlier_key():
@@ -191,8 +193,9 @@ def _masked_softmax(q, k, v, mask, scale):
 def test_the_kernel_at_values_of_256_equals_the_blockwise_loop():
     """Heads with keys and values of 256 features (192 + 64, and the
     published 256) over a choice of 48 keys a query, two rows of two blocks
-    of 256 slots, through the interpreter: the sparse kernel against the
-    sparse blockwise loop and the dense softmax over the chosen keys."""
+    of 256 slots (the grouped cores' blocks, which are the choice's own
+    grain), through the interpreter: the sparse kernel against the sparse
+    blockwise loop and the dense softmax over the chosen keys."""
     t, docs = 512, [(100, 300, 100), (512,)]
     seg, pos = _rows(t, docs)
     rng = np.random.default_rng(1)
@@ -202,7 +205,7 @@ def test_the_kernel_at_values_of_256_equals_the_blockwise_loop():
                                       topk=48)
     mask = np.asarray(choice[0])[:, :t, :t] != 0
     want = _masked_softmax(q, k, v, mask, 256 ** -0.5)
-    bq, bk, padded = attention.block_sizes(t)
+    bq, bk, padded = attention.block_sizes(t, 2)
     assert (bq, bk, padded) == (256, 512, 512)
     assert attention._kernel_tiles(v.shape, padded)
     lo, count = attention._block_ranges(jnp, jnp.asarray(seg),
@@ -229,6 +232,106 @@ def test_the_kernel_at_values_of_256_equals_the_blockwise_loop():
             q, k, v, seg, pos, lo, count, emptied))
     assert np.abs(again - want)[real].max() < 2e-5
     assert not again[1, 256:].any() and again[1, :256].any()
+
+
+# -- the query block of ungrouped heads --------------------------------------------
+
+#: name: (slots a row, the documents' lengths row by row). Every document of
+#: a first row starts and ends off every edge of a block of 256, 512 or
+#: 1,024 slots; a second row is one document, whose blocks below the
+#: diagonal build no mask
+UNGROUPED_ROWS = {
+    "one_key_block": (512, [(100, 300, 90), (512,)]),
+    "two_key_blocks": (2048, [(300, 500, 700, 400), (2048,)]),
+    "a_row_of_no_whole_block": (1200, [(300, 500, 390), (1200,)]),
+}
+
+
+@pytest.mark.parametrize("chosen", [False, True],
+                         ids=["every_visible_key", "a_choice_of_48"])
+@pytest.mark.parametrize("rows", UNGROUPED_ROWS)
+def test_ungrouped_heads_run_query_blocks_as_long_as_key_blocks(rows,
+                                                                chosen):
+    """Two heads with keys of their own (``rep`` 1): ``block_sizes`` gives
+    as many queries a block as keys (512 on a row of 512, 1,024 on a longer
+    one), and at them the kernel through the interpreter, the blockwise
+    loop and ``segment_attention`` as the CPU runs it equal a plain masked
+    softmax, over every visible key and over a choice; the choice's tile
+    counts, made at 256 queries, summed to the core's tiles are the mask's
+    sums there, and a core's tile whose count is 0 is not multiplied; the
+    host counts the tiles the device's ranges admit."""
+    t, docs = UNGROUPED_ROWS[rows]
+    seg, pos = _rows(t, docs)
+    rng = np.random.default_rng(5)
+    normal = lambda *shape: rng.standard_normal(shape, dtype=np.float32)
+    q, k, v = normal(2, t, 2, 128), normal(2, t, 2, 128), normal(2, t, 2, 128)
+    bq, bk, padded = attention.block_sizes(t, q.shape[2] // k.shape[2])
+    assert (bq, bk, padded) == ((512, 512, 512) if t == 512
+                                else (1024, 1024, 2048))
+    nq, nk = padded // bq, padded // bk
+    mask, choice = _visible(seg, pos), None
+    if chosen:
+        choice, _ = attention.select_keys(*_index_operands(seg), seg, pos,
+                                          topk=48)
+        whole = np.asarray(choice[0]).astype(np.int64)
+        mask = whole[:, :t, :t] != 0
+        assert choice[1].shape == (2, padded // 256, nk)
+        summed = np.asarray(choice[1]).reshape(2, nq, -1, nk).sum(axis=2)
+        assert (summed == whole.reshape(2, nq, bq, nk, bk).sum(
+            axis=(2, 4))).all()
+    want = _masked_softmax(q, k, v, mask, 128 ** -0.5)
+    real = seg >= 0
+    # the core as the CPU runs it: the blockwise loop at the larger block
+    before = attention.attention_lowerings().get(f"query_block_{bq}", 0)
+    got = np.asarray(attention.segment_attention(q, k, v, seg, pos,
+                                                 choice=choice))
+    assert attention.attention_lowerings()[f"query_block_{bq}"] >= max(
+        before, 1)
+    assert np.abs(got - want)[real].max() < 2e-5 and not got[~real].any()
+    # both lowerings themselves, on the row padded to whole blocks
+    grow = ((0, 0), (0, padded - t))
+    wide = [jnp.pad(a, grow + ((0, 0), (0, 0))) for a in (q, k, v)] \
+        + [jnp.pad(seg, grow, constant_values=-1), jnp.pad(pos, grow)]
+    lo, count = attention._block_ranges(jnp, wide[3], wide[4], None, bq, bk)
+    at_core = () if choice is None else ((choice[0], jnp.asarray(summed)),)
+    sizes = dict(window=None, bq=bq, bk=bk)
+    lowerings = (attention._blockwise, functools.partial(
+        attention._segment_kernel, interpret=True))
+    for lowering in lowerings:
+        out = np.asarray(jax.jit(functools.partial(lowering, **sizes))(
+            *wide, lo, count, *at_core))[:, :t]
+        assert np.abs(out - want)[real].max() < 2e-5, lowering
+        assert not out[~real].any()
+    # the host's count is the device's, at the grain the core runs
+    work = attention.attention_work(seg, pos, (None,) * 3, rep=1)
+    assert work["attn_query_block"] == bq
+    assert work["attn_tiles_run"] == 3 * int(np.asarray(count).sum())
+    assert work["attn_tiles_all"] == 3 * 2 * sum(
+        ((i + 1) * bq - 1) // bk + 1 for i in range(nq))
+    if not chosen:
+        return
+    # a row's first query block sees key block 0 alone. With that tile's
+    # count at 0 neither lowering multiplies it, whatever its mask holds
+    # (the loop walks a step while any row's tile holds a key: both rows'
+    # are emptied): its queries read zeros, every other one what it read
+    emptied = (choice[0], jnp.asarray(summed).at[:, 0, 0].set(0))
+    for lowering in lowerings:
+        out = np.asarray(jax.jit(functools.partial(lowering, **sizes))(
+            *wide, lo, count, emptied))[:, :t]
+        assert not out[:, :bq].any()
+        assert np.abs(out - want)[:, bq:][real[:, bq:]].max(initial=0) < 2e-5
+    # ``segment_attention`` sums the choice's own tiles: with one of the two
+    # or four that make the core's at 0 the tile is still multiplied, with
+    # all of them not
+    half = (choice[0], choice[1].at[:, 0, 0].set(0))
+    both = (choice[0], choice[1].at[:, :bq // 256, 0].set(0))
+    still = np.asarray(attention.segment_attention(q, k, v, seg, pos,
+                                                   choice=half))
+    assert np.abs(still - want)[real].max() < 2e-5
+    gone = np.asarray(attention.segment_attention(q, k, v, seg, pos,
+                                                  choice=both))
+    assert not gone[:, :bq].any() and gone[:, bq:].any() == (t > bq)
+    assert np.abs(gone - want)[:, bq:][real[:, bq:]].max(initial=0) < 2e-5
 
 
 # -- the router -------------------------------------------------------------------
@@ -349,7 +452,8 @@ def test_the_forward_equals_the_reference(weights):
     assert float(aux["selected_pairs"]) == 4 * np.minimum(reach, TOPK).sum()
     work = attention.attention_work(args[1], args[2],
                                     CONFIG.attention_windows,
-                                    *CONFIG.attention_index)
+                                    *CONFIG.attention_index,
+                                    rep=CONFIG.attention_rep)
     assert work["attn_pairs_selected"] == float(aux["selected_pairs"])
     assert int(aux["buffer"][0]) == 3          # three expert layers ran
     # a padded batch runs the same forward
@@ -446,19 +550,20 @@ def test_the_scopes_and_the_lowering_for_the_chip():
             lowering_platforms=(platform,)).as_text(debug_info=True)
         after = attention.attention_lowerings()
         return text, {name: after[name] - before.get(name, 0)
-                      for name in after}
+                      for name in after
+                      if after[name] != before.get(name, 0)}
 
     text, took = lowered("tpu")
-    # the two layers' cores share one trace and one lowering
-    assert took == {"kernel": 0, "blockwise": 0, "sparse_kernel": 1,
-                    "sparse_blockwise": 0}
+    # the two layers' cores share one trace and one lowering, at the
+    # query block of ungrouped heads
+    assert took == {"sparse_kernel": 1, "query_block_512": 1}
     assert text.count("tpu_custom_call") >= 1
     for scope in ("decoder.attention.latent", "decoder.attention.index",
                   "decoder.attention.sparse"):
         assert scope in text, scope
     assert "decoder.attention.full" not in text
     _text, took = lowered("cpu")
-    assert took["sparse_blockwise"] == 1 and took["sparse_kernel"] == 0
+    assert took == {"sparse_blockwise": 1, "query_block_512": 1}
 
 
 def test_the_embedder_counts_the_pairs_and_metrics_show_them(weights):

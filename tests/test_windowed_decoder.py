@@ -168,20 +168,49 @@ def test_both_lowerings_equal_the_dense_masked_softmax(rows, window, heads):
 
 def test_a_window_spares_whole_key_blocks_of_a_long_row():
     """A row of 2,048 slots, one document, at the sizes of
-    ``block_sizes``: eight query blocks of 256 on two key blocks of 1,024.
-    Under a window of 600 the last query block sees key block 1 alone."""
+    ``block_sizes`` for grouped heads: eight query blocks of 256 on two key
+    blocks of 1,024. Under a window of 600 the last query block sees key
+    block 1 alone."""
     q, k, v, seg, pos = _operands(2048, [(2048,)], 2, d=32)
-    assert attention.block_sizes(2048) == (256, 1024, 2048)
-    assert attention.block_sizes(16384) == (256, 1024, 16384)
-    assert attention.block_sizes(512) == (256, 512, 512)
-    assert attention.block_sizes(1200) == (256, 1024, 2048)
-    assert attention.block_sizes(128) == (128, 128, 128)
+    assert attention.block_sizes(2048, 2) == (256, 1024, 2048)
+    assert attention.block_sizes(16384, 2) == (256, 1024, 16384)
+    assert attention.block_sizes(512, 2) == (256, 512, 512)
+    assert attention.block_sizes(1200, 2) == (256, 1024, 2048)
+    assert attention.block_sizes(128, 2) == (128, 128, 128)
     lo, count = attention._block_ranges(np, seg, pos, 600, 256, 1024)
     assert lo.tolist() == [[0, 0, 0, 0, 0, 0, 0, 1]]
     assert count.tolist() == [[1, 1, 1, 1, 2, 2, 2, 1]]
     got = np.asarray(attention.segment_attention(q, k, v, seg, pos,
                                                  window=600))
     assert np.abs(got - _dense(q, k, v, seg, pos, 600)).max() < 2e-5
+
+
+@pytest.mark.parametrize("t, grouped, ungrouped", [
+    (16384, (256, 1024, 16384), (1024, 1024, 16384)),
+    (8192, (256, 1024, 8192), (1024, 1024, 8192)),
+    (2048, (256, 1024, 2048), (1024, 1024, 2048)),
+    (1200, (256, 1024, 2048), (1024, 1024, 2048)),
+    (1024, (256, 1024, 1024), (1024, 1024, 1024)),
+    # key blocks of 512: the query block is never the larger
+    (600, (256, 512, 1024), (512, 512, 1024)),
+    (512, (256, 512, 512), (512, 512, 512)),
+    (300, (256, 256, 512), (256, 256, 512)),
+    (256, (256, 256, 256), (256, 256, 256)),
+    (128, (128, 128, 128), (128, 128, 128)),
+])
+def test_the_query_block_follows_the_heads_a_key_head_stacks(t, grouped,
+                                                             ungrouped):
+    """``block_sizes``: with two or more query heads a key head every row
+    reads what it read before the stacking was an argument (SmallThinker's
+    7, Qwen's 8); a head with keys of its own takes as many queries a
+    block as keys, 1,024 on a long row; the key block and the padded length
+    follow the row alone."""
+    for rep in (2, 7, 8, 64):
+        assert attention.block_sizes(t, rep) == grouped
+    assert attention.block_sizes(t, 1) == ungrouped
+    bq, bk, padded = ungrouped
+    assert ungrouped[1:] == grouped[1:]
+    assert padded % bq == 0 and bq == bk and padded % bk == 0
 
 
 def test_no_array_of_the_blockwise_lowering_has_two_axes_of_the_row():
@@ -223,7 +252,11 @@ def test_the_kernel_is_taken_for_the_chip_at_its_shapes_alone():
             x, p, pos, pos, config, kind)).trace(x, p, pos).lower(
                 lowering_platforms=(platform,)).as_text(debug_info=True)
         after = attention.attention_lowerings()
-        return text, {name: after[name] - before[name] for name in after}
+        # the counts this lowering added; what else the process has
+        # lowered before (another file's cores) is none of it
+        return text, {name: after[name] - before.get(name, 0)
+                      for name in after
+                      if after[name] != before.get(name, 0)}
 
     window = decoder.LayerKind("attention", 200, True)
     full = decoder.LayerKind("attention", None, False)
@@ -233,20 +266,20 @@ def test_the_kernel_is_taken_for_the_chip_at_its_shapes_alone():
     for kind, scope in ((window, "decoder.attention.window"),
                         (full, "decoder.attention.full")):
         text, took = lowered(wide, "tpu", kind)
-        assert took == {"kernel": 1, "blockwise": 0}
+        assert took == {"kernel": 1}
         calls = [ln for ln in text.splitlines() if "tpu_custom_call" in ln]
         assert len(calls) == 1 and "_segment_body" in calls[0]
         assert scope in text
     text, took = lowered(wide, "cpu", window)
-    assert took == {"kernel": 0, "blockwise": 1}
+    assert took == {"blockwise": 1}
     assert "tpu_custom_call" not in text
     text, took = lowered(decoder.DecoderConfig.tiny_windowed(), "tpu", window)
-    assert took == {"kernel": 0, "blockwise": 1}
+    assert took == {"blockwise": 1}
     assert "tpu_custom_call" not in text
     # the other family's attention layer takes the same core
     text, took = lowered(decoder.DecoderConfig.tiny(head_dim=128), "tpu",
                          decoder.LayerKind("attention"), layer=3)
-    assert took == {"kernel": 1, "blockwise": 0}
+    assert took == {"kernel": 1}
     assert "decoder.attention.full" in text
     samples = {(f, labels.get("lowering")): v for f, labels, v in
                _parse_samples(_metrics_lines(_FakeRuntime()))}
@@ -427,9 +460,10 @@ def test_a_fused_dispatch_s_span_counts_what_attention_has_to_do(
 def test_tiles_counted_on_the_host_are_the_kernel_s_ranges(docs, windows,
                                                            run, of):
     seg, pos = _rows(2048, docs)
-    work = attention.attention_work(seg, pos, windows)
+    work = attention.attention_work(seg, pos, windows, rep=7)
     assert (work["attn_tiles_run"], work["attn_tiles_all"]) == (run, of)
-    bq, bk, _ = attention.block_sizes(2048)
+    assert work["attn_query_block"] == 256
+    bq, bk, _ = attention.block_sizes(2048, 7)
     on_device = sum(int(attention._block_ranges(
         jnp, jnp.asarray(seg), jnp.asarray(pos), w, bq, bk)[1].sum())
         for w in windows)
