@@ -142,6 +142,9 @@ class _SupervisedSession:
         if inner.stopping.is_set():
             self.stopping.set()
         self.recorder = getattr(inner, "recorder", None)
+        # the source's QoS backpressure flag, for a reader that waits on
+        # something other than sleep() (io/fs's watched mode)
+        self.backpressure = entry.backpressure
 
     @property
     def stop_requested(self) -> bool:

@@ -4,10 +4,14 @@ DeltaTableWriter's columnar sink, data_storage.rs:2687)."""
 
 from __future__ import annotations
 
+import time
+
 import pytest
 
 import pathway_tpu as pw
+from pathway_tpu.engine.flight_recorder import FlightRecorder
 from pathway_tpu.internals.parse_graph import G
+from pathway_tpu.io._datasource import Session
 from tests.utils import rows_of
 
 
@@ -581,3 +585,434 @@ def test_a_pass_just_over_the_trickle_s_size_is_one_stretch(tmp_path):
     whole, progress, _pushed = _recorded_pass(tmp_path, n)
     assert [sp[5]["files"] for sp in progress] == [n]
     assert "files" not in whole[5]
+
+
+# ---------------------------------------------------------------------------
+# the fs reader's watched mode: a streaming source over a directory waits on
+# the kernel's change notifications (io/fs ``_TreeWatch``); a glob, a
+# refused watch or a platform without one polls
+# ---------------------------------------------------------------------------
+
+class _LoggedSession(Session):
+    """A ``Session`` that keeps every push with its instant, and records the
+    reader's passes."""
+
+    def __init__(self):
+        super().__init__()
+        self.log = []   # (perf_counter, row, diff, offset)
+        self.recorder = FlightRecorder()
+        self.recorder.enabled = True
+
+    def push(self, key, row, diff=1, offset=None):
+        self.log.append((time.perf_counter(), row, diff, offset))
+        super().push(key, row, diff, offset)
+
+
+def _passes(session) -> list:
+    return [sp for sp in session.recorder.spans()
+            if sp[0] == "connector.pass"]
+
+
+def _reading_passes(session, n: int, timeout_s: float = 5.0) -> list:
+    """The passes that read a file, once ``n`` of them have been recorded
+    (a pass's span is written when it ends, after its pushes)."""
+    def reading():
+        return [sp for sp in _passes(session) if sp[5]["changed"]]
+
+    _wait(lambda: len(reading()) >= n, timeout_s, f"{n} reading passes")
+    return reading()
+
+
+def _wait(pred, timeout_s: float, what: str):
+    deadline = time.monotonic() + timeout_s
+    while not pred():
+        assert time.monotonic() < deadline, f"timed out waiting for {what}"
+        time.sleep(0.002)
+
+
+@pytest.fixture
+def fs_reader():
+    """Starts ``FsSource.run`` in streaming mode on a thread of its own;
+    stops and joins every one it started."""
+    import threading
+
+    import pathway_tpu.io.fs as fs
+
+    started = []
+
+    def start(path, *, refresh_interval_s=1.0, format="plaintext_by_file",
+              session=None):
+        source = fs.FsSource(str(path), format,
+                             fs._schema_for(format, None, False),
+                             "streaming", False,
+                             refresh_interval_s=refresh_interval_s)
+        session = session if session is not None else _LoggedSession()
+        thread = threading.Thread(target=source.run, args=(session,),
+                                  daemon=True, name="fs-reader-test")
+        started.append((session, thread, refresh_interval_s))
+        thread.start()
+        _wait(lambda: _passes(session), 10, "the first pass")
+        return session, thread
+
+    yield start
+    for session, thread, refresh_interval_s in started:
+        session.stopping.set()
+        # a watched reader sees the stop when its wait times out
+        thread.join(refresh_interval_s + 5.0)
+        assert not thread.is_alive(), "the reader did not stop"
+
+
+@pytest.mark.parametrize("relative", [False, True])
+def test_a_file_renamed_into_a_watched_subdirectory_is_pushed_at_once(
+        tmp_path, fs_reader, monkeypatch, relative):
+    import os
+
+    watched, stage = tmp_path / "watched", tmp_path / "stage"
+    (watched / "sub").mkdir(parents=True)
+    stage.mkdir()
+    (watched / "old.txt").write_text("old")
+    if relative:
+        # a file's key is its path as the listing gives it: "sub/new.txt"
+        monkeypatch.chdir(watched)
+    session, _thread = fs_reader("." if relative else watched,
+                                 refresh_interval_s=2.0)
+    assert [e[1] for e in session.log] == [("old",)]
+    (stage / "new.txt").write_text("new")
+    t_rename = time.perf_counter()
+    os.rename(stage / "new.txt", watched / "sub" / "new.txt")
+    _wait(lambda: len(session.log) == 2, 5, "the renamed file")
+    t_push, row, diff, offset = session.log[1]
+    assert (row, diff) == (("new",), 1)
+    assert offset[:2] == ("row", os.path.join(
+        "sub" if relative else str(watched / "sub"), "new.txt"))
+    # no wait for the 2 s refresh interval: the event wakes the reader
+    assert t_push - t_rename < 0.5
+    _reading_passes(session, 2)
+    assert [sp[5]["trigger"] for sp in _passes(session)] == [
+        "listing", "watch"]
+
+
+def test_a_directory_renamed_in_is_pushed_whole_once_in_sorted_order(
+        tmp_path, fs_reader):
+    import os
+    import random
+
+    watched, stage = tmp_path / "watched", tmp_path / "stage"
+    watched.mkdir()
+    batch = stage / "batch"
+    (batch / "deeper").mkdir(parents=True)
+    names = [f"doc{i:04d}.txt" for i in range(590)] + [
+        f"deeper/doc{i}.txt" for i in range(10)]
+    for name in random.Random(0).sample(names, len(names)):
+        (batch / name).write_text(name)
+    session, _thread = fs_reader(watched)
+    os.rename(batch, watched / "batch")
+    _wait(lambda: len(session.log) >= 600, 20, "600 files")
+    time.sleep(0.2)   # a second read of any of them would land by now
+    got = [e[1][0] for e in session.log]
+    assert got == [str(p.relative_to(watched / "batch")) for p in sorted(
+        (watched / "batch").rglob("*.txt"))]
+    assert len(set(got)) == 600 and all(e[2] == 1 for e in session.log)
+    # the new directory's subdirectory is watched too
+    (watched / "batch" / "deeper" / "late.txt").write_text("late")
+    _wait(lambda: len(session.log) == 601, 5, "the late file")
+    assert session.log[-1][1] == ("late",)
+    _reading_passes(session, 2)
+    counts = [sp[5] for sp in _passes(session)]
+    assert [(c["trigger"], c["listed"], c["changed"]) for c in counts] == [
+        ("listing", 0, 0), ("watch", 600, 600), ("watch", 1, 1)]
+
+
+@pytest.mark.parametrize("kind", ["watch", "poll"])
+def test_a_file_rewritten_in_place_retracts_and_pushes_as_a_poll_does(
+        tmp_path, fs_reader, kind):
+    import os
+
+    watched = tmp_path / "watched"
+    watched.mkdir()
+    f = watched / "a.txt"
+    f.write_text("old1\nold2\n")
+    path = watched if kind == "watch" else watched / "*.txt"
+    session, _thread = fs_reader(path, refresh_interval_s=0.05,
+                                 format="plaintext")
+    _wait(lambda: len(session.log) == 2, 5, "the first rows")
+    old_mtime = os.stat(f).st_mtime
+    f.write_text("new1\n")
+    mtime = os.stat(f).st_mtime
+    assert mtime != old_mtime
+    _wait(lambda: len(session.log) >= 5, 5, "the rewrite")
+    time.sleep(0.15)   # three refresh intervals: nothing more comes
+    fkey = str(f)
+    assert [e[1:] for e in session.log] == [
+        (("old1",), 1, ("row", fkey, old_mtime, 0, False)),
+        (("old2",), 1, ("row", fkey, old_mtime, 1, True)),
+        (("old1",), -1, ("retract", fkey, mtime, 0, False)),
+        (("old2",), -1, ("retract", fkey, mtime, 0, False)),
+        (("new1",), 1, ("row", fkey, mtime, 0, True))]
+    triggers = {sp[5]["trigger"] for sp in _passes(session)[1:]}
+    assert triggers == ({"watch"} if kind == "watch" else {"listing"})
+
+
+def test_an_overflow_is_followed_by_one_listing_that_pushes_nothing_twice(
+        tmp_path):
+    """The reader is held inside its first push while more files land than
+    the kernel's event queue holds (two events a file: made, closed)."""
+    import threading
+
+    import pathway_tpu.io.fs as fs
+
+    try:
+        with open("/proc/sys/fs/inotify/max_queued_events") as q:
+            limit = int(q.read())
+    except OSError:
+        limit = 16384   # the kernel's default
+    watched = tmp_path / "watched"
+    watched.mkdir()
+    (watched / "a-old.txt").write_text("old")
+    session = _LoggedSession()
+    held, release = threading.Event(), threading.Event()
+    push = session.push
+
+    def held_push(key, row, diff=1, offset=None):
+        if not held.is_set():
+            held.set()
+            assert release.wait(30)
+        push(key, row, diff, offset)
+
+    session.push = held_push
+    source = fs.FsSource(str(watched), "plaintext_by_file",
+                         fs._schema_for("plaintext_by_file", None, False),
+                         "streaming", False, refresh_interval_s=5.0)
+    thread = threading.Thread(target=source.run, args=(session,),
+                              daemon=True, name="fs-reader-test")
+    thread.start()
+    try:
+        assert held.wait(10)
+        n = limit // 2 + 64
+        for i in range(n):
+            (watched / f"b{i:06d}.txt").write_text(str(i))
+        release.set()
+        _wait(lambda: len(session.log) == 1 + n, 60, f"{n} files")
+        (watched / "c-late.txt").write_text("late")
+        _wait(lambda: len(session.log) == 2 + n, 5, "the late file")
+        rows = [e[1][0] for e in session.log]
+        assert len(set(rows)) == len(rows) == n + 2
+        assert all(e[2] == 1 for e in session.log)
+        _reading_passes(session, 3)
+        counts = [sp[5] for sp in _passes(session)]
+        assert [(c["trigger"], c["changed"]) for c in counts] == [
+            ("listing", 1), ("listing", n), ("watch", 1)]
+        assert counts[1]["listed"] == n + 1
+    finally:
+        release.set()
+        session.stopping.set()
+        thread.join(10.0)
+    assert not thread.is_alive()
+
+
+def _refused_watch(inotify):
+    """``inotify`` with its ``inotify_add_watch`` answering as the kernel
+    at its watch limit does."""
+    import ctypes
+    import errno
+
+    def add(fd, path, mask):
+        ctypes.set_errno(errno.ENOSPC)
+        return -1
+
+    return lambda: (inotify()[0], add, inotify()[2])
+
+
+@pytest.mark.parametrize("kind,trigger,listed", [
+    ("watch", "watch", 1), ("glob", "listing", 2),
+    ("no_inotify", "listing", 2), ("watch_refused", "listing", 2),
+    ("remote_fs", "listing", 2)])
+def test_a_watch_pass_counts_the_paths_it_examined(
+        tmp_path, fs_reader, monkeypatch, kind, trigger, listed):
+    """A watch pass examines the event's path; every other streaming
+    source (a glob, a platform without inotify, a tree the kernel will
+    not watch, a tree on a network filesystem) polls: each pass lists every
+    file, with or without news."""
+    import pathway_tpu.io.fs as fs
+
+    if kind == "no_inotify":
+        monkeypatch.setattr(fs, "_inotify", lambda: None)
+    elif kind == "watch_refused":
+        monkeypatch.setattr(fs, "_inotify", _refused_watch(fs._inotify))
+    elif kind == "remote_fs":
+        calls = fs._inotify()
+        nfs = 0x6969   # NFS_SUPER_MAGIC
+        monkeypatch.setattr(fs, "_inotify",
+                            lambda: (calls[0], calls[1], lambda path: nfs))
+    watched = tmp_path / "watched"
+    watched.mkdir()
+    (watched / "old.txt").write_text("old")
+    path = watched / "*.txt" if kind == "glob" else watched
+    session, _thread = fs_reader(path, refresh_interval_s=0.05)
+    (watched / "new.txt").write_text("new")
+    _wait(lambda: len(session.log) == 2, 5, "the new file")
+    found = _reading_passes(session, 2)[-1]
+    assert found[5]["trigger"] == trigger
+    assert found[5]["listed"] == listed and found[5]["rows"] == 1
+    time.sleep(0.3)   # six refresh intervals with nothing new
+    idle = [sp for sp in _passes(session) if not sp[5]["changed"]]
+    if kind == "watch":
+        assert idle == []
+    else:
+        assert len(idle) >= 2
+        assert all(sp[5]["trigger"] == "listing" for sp in idle)
+
+
+@pytest.mark.parametrize("how", ["renamed", "removed"])
+def test_a_watched_root_renamed_or_removed_falls_back_to_polling(
+        tmp_path, fs_reader, how):
+    """A directory put in the root's place is no longer watched: the reader
+    lists the path each refresh interval again, as a polling one does."""
+    import os
+    import shutil
+
+    watched = tmp_path / "watched"
+    watched.mkdir()
+    (watched / "a.txt").write_text("a")
+    session, _thread = fs_reader(watched, refresh_interval_s=0.05)
+    if how == "renamed":
+        os.rename(watched, tmp_path / "away")
+    else:
+        shutil.rmtree(watched)
+    _wait(lambda: any(sp[5]["trigger"] == "listing"
+                      for sp in _passes(session)[1:]), 5, "a listing")
+    watched.mkdir()
+    (watched / "b.txt").write_text("b")
+    _wait(lambda: len(session.log) == 2, 5, "the new root's file")
+    assert [e[1] for e in session.log] == [("a",), ("b",)]
+    assert {sp[5]["trigger"] for sp in _passes(session)} == {"listing"}
+
+
+@pytest.mark.parametrize("kind", ["watch", "poll"])
+def test_a_stop_request_ends_the_reader_within_its_refresh_interval(
+        tmp_path, fs_reader, kind):
+    watched = tmp_path / "watched"
+    watched.mkdir()
+    path = watched if kind == "watch" else watched / "*"
+    session, thread = fs_reader(path, refresh_interval_s=0.3)
+    time.sleep(0.05)
+    t_stop = time.perf_counter()
+    session.stopping.set()
+    thread.join(5.0)
+    assert not thread.is_alive()
+    # the wait on the kernel's events times out once a refresh interval;
+    # a second one allows for the scheduler
+    assert time.perf_counter() - t_stop < 0.3 + 0.3
+
+
+def test_a_watched_reader_under_backpressure_pauses_between_passes(
+        tmp_path, fs_reader):
+    """While QoS backpressure is up the reader waits a stretched refresh
+    interval after each pass, as a polling reader's sleep stretches."""
+    watched = tmp_path / "watched"
+    watched.mkdir()
+    session = _LoggedSession()
+    session.backpressure.set()
+    session.backpressure_factor = 4.0
+    session, _thread = fs_reader(watched, refresh_interval_s=0.25,
+                                 session=session)
+    t_first_pass = _passes(session)[0][2]
+    (watched / "a.txt").write_text("a")
+    _wait(lambda: len(session.log) == 1, 5, "the file")
+    assert session.log[0][0] - t_first_pass >= 1.0 - 0.01
+    session.backpressure.clear()
+    time.sleep(1.05)   # the pause in flight ends
+    (watched / "b.txt").write_text("b")
+    t_write = time.perf_counter()
+    _wait(lambda: len(session.log) == 2, 5, "the second file")
+    # no stretched pause of 1 s, nor a refresh interval, once it is down
+    assert session.log[1][0] - t_write < 0.25
+
+
+@pytest.mark.parametrize("kind", ["watch", "poll"])
+def test_a_file_its_writer_holds_open_is_pushed_as_a_poll_pushes_it(
+        tmp_path, fs_reader, kind):
+    """A file appended to and flushed, and never closed, raises no event
+    that says it is complete: it is examined each refresh interval, and
+    each flushed state is pushed, as a poll pushes it."""
+    watched = tmp_path / "watched"
+    watched.mkdir()
+    path = watched if kind == "watch" else watched / "*.log"
+    session, _thread = fs_reader(path, refresh_interval_s=0.1,
+                                 format="plaintext")
+    with open(watched / "a.log", "w") as w:
+        w.write("one\n")
+        w.flush()
+        _wait(lambda: len(session.log) == 1, 5, "the first flush")
+        time.sleep(0.05)   # the next write gets a later mtime
+        w.write("two\n")
+        w.flush()
+        _wait(lambda: len(session.log) == 4, 5, "the second flush")
+    time.sleep(0.3)   # the close reads nothing new
+    assert [e[1:3] for e in session.log] == [
+        (("one",), 1), (("one",), -1), (("one",), 1), (("two",), 1)]
+
+
+@pytest.mark.parametrize("when", ["before_start", "while_watched"])
+@pytest.mark.parametrize("link", ["symbolic", "hard"])
+@pytest.mark.parametrize("kind", ["watch", "poll"])
+def test_a_link_in_the_tree_is_read_and_followed_as_a_poll_does(
+        tmp_path, fs_reader, kind, link, when):
+    """A link raises no event when its target changes through a path
+    outside the tree, nor one that says it is complete when it is made:
+    it is examined each refresh interval, as a poll examines it."""
+    import os
+
+    watched, outside = tmp_path / "watched", tmp_path / "outside"
+    watched.mkdir()
+    outside.mkdir()
+    target = outside / "t.txt"
+    target.write_text("v1")
+    make = os.symlink if link == "symbolic" else os.link
+    if when == "before_start":
+        make(target, watched / "l.txt")
+    path = watched if kind == "watch" else watched / "*.txt"
+    session, _thread = fs_reader(path, refresh_interval_s=0.1)
+    if when == "while_watched":
+        make(target, watched / "l.txt")
+    _wait(lambda: len(session.log) == 1, 5, "the link")
+    time.sleep(0.05)   # the rewrite gets a later mtime
+    target.write_text("v2")   # in place: the same inode
+    _wait(lambda: len(session.log) == 3, 5, "the target's rewrite")
+    fkey = str(watched / "l.txt")
+    assert [(e[1], e[2], e[3][1]) for e in session.log] == [
+        (("v1",), 1, fkey), (("v1",), -1, fkey), (("v2",), 1, fkey)]
+
+
+def test_the_listing_is_rglob_s_files_in_path_order(tmp_path, monkeypatch):
+    """``_list_files`` types a directory's entries from the directory
+    itself; it lists what ``Path.rglob`` and ``is_file`` list, as the
+    ``Path`` objects print and in the order they sort."""
+    import os
+    from pathlib import Path
+
+    import pathway_tpu.io.fs as fs
+
+    root = tmp_path / "tree"
+    (root / "a" / "b").mkdir(parents=True)
+    (root / ".hidden").mkdir()
+    outside = tmp_path / "outside"
+    (outside / "deep").mkdir(parents=True)
+    (outside / "deep" / "x.txt").write_text("x")
+    for name in ("a-c", "a/b/z", "a/b/a", "a0", ".hidden/h", "B", "é",
+                 "a/-", ".dot"):
+        (root / name).write_text(name)
+    os.symlink(outside / "deep" / "x.txt", root / "link-to-file")
+    os.symlink(outside / "deep", root / "a" / "link-to-dir")
+    os.symlink(tmp_path / "missing", root / "dangling")
+    os.mkfifo(root / "a" / "fifo")
+    counts = []
+    monkeypatch.chdir(root)   # relative paths: "." and "./a"
+    for path in (root, Path(str(root) + "/"), root / "a" / ".." / "a",
+                 Path("."), Path("./a")):
+        want = sorted(f for f in Path(path).rglob("*") if f.is_file())
+        got = fs._list_files(str(path))
+        assert got == [str(f) for f in want]
+        counts.append(len(got))
+    # nine files and the link to one, under the root and in ``a``
+    assert counts == [10, 10, 3, 10, 3]

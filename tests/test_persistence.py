@@ -139,9 +139,13 @@ def test_python_source_resume_filesystem_backend(tmp_path):
 # in-process resume: fs source (seek protocol, file-granular offsets)
 # ---------------------------------------------------------------------------
 
-def _run_fs_counts(input_dir, backend) -> dict[str, int]:
+def _run_fs_counts(input_dir, backend, expected=None) -> dict[str, int]:
+    """Word counts of the files in ``input_dir``, persisted to ``backend``:
+    one batch pass, or, given the ``expected`` counts, a streaming run (the
+    fs source's watched mode) stopped once they hold."""
     G.clear()
-    t = pw.io.fs.read(str(input_dir), format="plaintext", mode="batch",
+    mode = "batch" if expected is None else "streaming"
+    t = pw.io.fs.read(str(input_dir), format="plaintext", mode=mode,
                       autocommit_duration_ms=10, persistent_id="fsrc")
     counts = t.groupby(t.data).reduce(w=t.data, c=pw.reducers.count())
     state: dict[str, int] = {}
@@ -153,36 +157,73 @@ def _run_fs_counts(input_dir, backend) -> dict[str, int]:
             del state[row["w"]]
 
     pw.io.subscribe(counts, on_change)
-    pw.run(persistence_config=pw.persistence.Config.simple_config(backend))
+    config = pw.persistence.Config.simple_config(backend)
+    if expected is None:
+        pw.run(persistence_config=config)
+        return state
+    import threading
+
+    from pathway_tpu.engine import streaming
+
+    thread = threading.Thread(target=pw.run, daemon=True,
+                              kwargs={"persistence_config": config})
+    thread.start()
+    try:
+        wait_result_with_checker(lambda: state == expected, 30, step=0.01)
+        time.sleep(0.3)   # a row read twice would be counted by now
+    finally:
+        streaming.stop_all()
+        thread.join(30)
+    assert not thread.is_alive()
     return state
 
 
-def test_fs_source_resume_new_files(tmp_path):
+def _resume_new_files(tmp_path, expect: bool) -> None:
     inp = tmp_path / "in"
     inp.mkdir()
     (inp / "a.txt").write_text("w1\nw2\n")
     (inp / "b.txt").write_text("w1\n")
     backend = pw.persistence.Backend.filesystem(str(tmp_path / "pstate"))
-    first = _run_fs_counts(inp, backend)
-    assert first == {"w1": 2, "w2": 1}
+    want = {"w1": 2, "w2": 1}
+    first = _run_fs_counts(inp, backend, want if expect else None)
+    assert first == want
     # restart with one new file: completed files must not re-emit
     (inp / "c.txt").write_text("w2\nw3\n")
-    second = _run_fs_counts(inp, backend)
-    assert second == {"w1": 2, "w2": 2, "w3": 1}
+    want = {"w1": 2, "w2": 2, "w3": 1}
+    second = _run_fs_counts(inp, backend, want if expect else None)
+    assert second == want
 
 
-def test_fs_source_resume_changed_file(tmp_path):
+def _resume_changed_file(tmp_path, expect: bool) -> None:
     inp = tmp_path / "in"
     inp.mkdir()
     (inp / "a.txt").write_text("old1\nold2\n")
     backend = pw.persistence.Backend.filesystem(str(tmp_path / "pstate"))
-    first = _run_fs_counts(inp, backend)
-    assert first == {"old1": 1, "old2": 1}
+    want = {"old1": 1, "old2": 1}
+    first = _run_fs_counts(inp, backend, want if expect else None)
+    assert first == want
     # file rewritten between runs: replayed rows must be retracted
     (inp / "a.txt").write_text("new1\n")
     os.utime(inp / "a.txt", (time.time() + 5, time.time() + 5))
-    second = _run_fs_counts(inp, backend)
-    assert second == {"new1": 1}
+    want = {"new1": 1}
+    second = _run_fs_counts(inp, backend, want if expect else None)
+    assert second == want
+
+
+def test_fs_source_resume_new_files(tmp_path):
+    _resume_new_files(tmp_path, expect=False)
+
+
+def test_fs_source_resume_changed_file(tmp_path):
+    _resume_changed_file(tmp_path, expect=False)
+
+
+@pytest.mark.parametrize("case", [_resume_new_files, _resume_changed_file],
+                         ids=["new_files", "changed_file"])
+def test_fs_source_resume_in_the_watched_mode(tmp_path, case):
+    """The same resumes with the source streaming over the directory, so
+    that it waits on the kernel's events after its first listing."""
+    case(tmp_path, expect=True)
 
 
 # ---------------------------------------------------------------------------
